@@ -136,19 +136,6 @@ assert r.get("overlap", {}).get("scheduled"), "prefetch schedule missing"
 assert frac >= 0.7, f"overlap_hidden_fraction {frac} < 0.7"
 print(f"overlap gate: {frac:.0%} of prefetchable ICI time hidden")'
 
-# bench regression ratchet (scripts/bench_gate.py): the freshest bench
-# JSON line must not regress the best prior BENCH_r0*.json round on any
-# ratcheted metric (tokens/sec/chip, mfu, overlap_hidden_fraction). On
-# a box with no TPU the bench emits its structured backend-down skip
-# line within seconds (retry budget pinned down here), which passes the
-# gate by design — the ratchet gates merit, not machine availability.
-# Running the REAL bench.py (not a cached trace JSON) is deliberate:
-# this gate doubles as the end-to-end proof that bench.py's structured
-# skip contract holds, which is itself a pinned behavior (BENCH_r05).
-{ JAX_PLATFORMS=tpu RLT_BENCH_MAX_WAIT=10 RLT_BENCH_INIT_RETRIES=1 \
-    python bench.py 2>/dev/null || true; } \
-    | python scripts/bench_gate.py -
-
 # resilience gate, three supervised CPU-SPMD legs: (1) an injected
 # worker kill must auto-resume from the step-cadence checkpoint and
 # converge (kill -> classify -> relaunch -> resume, end to end); (2) an

@@ -166,14 +166,13 @@ def collect(probe: bool = False) -> dict:
     if probe:
         from ray_lightning_tpu.utils.probe import (
             PEAK_TFLOPS,
-            device_peak_tflops,
             matmul_tflops,
         )
 
         info["probe_matmul_tflops"] = round(matmul_tflops(), 1)
-        info["peak_tflops"] = device_peak_tflops(devices[0].device_kind)
-        # unknown kinds get the v5e-class fallback — label it honestly
-        info["peak_is_assumed"] = devices[0].device_kind not in PEAK_TFLOPS
+        # None off the table (a CPU smoke run): there is no peak to
+        # compare the probe against, and none is assumed
+        info["peak_tflops"] = PEAK_TFLOPS.get(devices[0].device_kind)
     return info
 
 
@@ -739,9 +738,11 @@ def main(argv=None) -> int:
     if info.get("devices_truncated"):
         print(f"  ... and {info['devices_truncated']} more")
     if "probe_matmul_tflops" in info:
-        label = "assumed peak" if info["peak_is_assumed"] else "spec peak"
+        peak = (f"spec peak {info['peak_tflops']}"
+                if info["peak_tflops"] is not None
+                else "no spec peak on record for this device kind")
         print(f"probe: {info['probe_matmul_tflops']} TFLOP/s bf16 matmul "
-              f"({label} {info['peak_tflops']})")
+              f"({peak})")
     return 0
 
 

@@ -135,6 +135,10 @@ DCN_SPECS: Dict[str, Tuple[float, float]] = {
 #: HBM-OVERCOMMIT check still exercises on CI
 _CPU_HBM_BYTES = 16 * 1024**3
 
+#: the "cpu" pseudo-family's compute pseudo-peak (TFLOP/s): like its
+#: ICI/DCN rows a stated CI figure, not a measurement of anything
+_CPU_PEAK_TFLOPS = 1.0
+
 
 @dataclasses.dataclass(frozen=True)
 class Topology:
@@ -236,8 +240,10 @@ def topology_for_kind(device_kind: str, n_devices: int, *,
                       hbm_bytes: Optional[int] = None) -> Topology:
     """Topology from a PJRT ``device_kind`` string (the plan CLI's
     --device-kind vocabulary) instead of a family-dash-count name.
-    Unknown kinds get the cpu pseudo-family's conservative ICI figures —
-    the HBM side still honors ``hbm_bytes`` or the planner table."""
+    Unknown kinds get the cpu pseudo-family's conservative ICI figures
+    and the HBM side honors ``hbm_bytes`` or the planner table, but
+    the compute peak has no fallback: a kind outside
+    `utils.probe.PEAK_TFLOPS` raises."""
     family = _KIND_TO_FAMILY.get(device_kind, "cpu")
     _, gbps, lat = ICI_SPECS[family]
     if hbm_bytes is None:
@@ -252,8 +258,11 @@ def topology_for_kind(device_kind: str, n_devices: int, *,
 
 def _peak_tflops(device_kind: str) -> float:
     """Spec-sheet peak for the overlap roofline — one source of truth
-    with the bench/doctor probe (utils/probe.py); unknown kinds get the
-    v5e-class fallback, same contract as the probe."""
+    with the bench/doctor probe (utils/probe.py). The "cpu" pseudo-
+    family states its own pseudo-figure like its ICI/DCN rows; any
+    other kind outside the table raises."""
+    if device_kind == "cpu":
+        return _CPU_PEAK_TFLOPS
     from ray_lightning_tpu.utils.probe import device_peak_tflops
 
     return float(device_peak_tflops(device_kind))
@@ -261,8 +270,8 @@ def _peak_tflops(device_kind: str) -> float:
 
 #: fraction of spec-sheet peak a well-tuned matmul-dominated step
 #: actually sustains — the compute window for hiding collectives is
-#: charged at peak x efficiency. 0.6 is the repo's own measured MFU
-#: band at the flagship shapes (BENCH_r03: 0.59 best); a HIGHER
+#: charged at peak x efficiency. 0.6 is an assumption no chip run has
+#: confirmed for the current code (ROADMAP Queue 3 item 6); a HIGHER
 #: efficiency would shrink the window and under-claim hiding, a lower
 #: one would over-claim. Documented in docs/STATIC_ANALYSIS.md.
 MXU_EFFICIENCY = 0.6

@@ -17,6 +17,10 @@ from typing import Any, Iterable, Iterator, Optional
 
 import numpy as np
 
+from ray_lightning_tpu.utils import get_logger
+
+log = get_logger(__name__)
+
 
 class DataLoader:
     """Minimal array-backed loader: shuffling, batching, per-epoch reseed.
@@ -75,6 +79,12 @@ class DataLoader:
         if self._num_workers is not None:
             return max(1, self._num_workers)
         return max(1, int(os.environ.get("RLT_NUM_CPUS_PER_WORKER", 2)))
+
+    @property
+    def path(self) -> str:
+        """Which batch-assembly path the last iteration took: "native"
+        (the C++ prefetching batcher) or "numpy"."""
+        return "native" if self._batcher is not None else "numpy"
 
     def set_epoch(self, epoch: int) -> None:
         """Reference parity: DistributedSampler.set_epoch reshuffles per epoch."""
@@ -136,8 +146,10 @@ class DataLoader:
                 self.data, self.batch_size, drop_last=self.drop_last,
                 n_threads=self.num_workers,
             )
-        except (RuntimeError, ValueError):
+        except (RuntimeError, ValueError) as exc:
             self.prefetch = False  # don't retry every epoch
+            log.warning("native batcher unavailable (%s); DataLoader "
+                        "falls back to the numpy path", exc)
             return None
         return self._batcher
 

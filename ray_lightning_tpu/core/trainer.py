@@ -135,8 +135,10 @@ class Trainer:
         #: metric, not a mysteriously slow first batch; the eval step
         #: warms on its first batch. Shape drift falls back to lazy jit.
         self.warm_start = warm_start
-        #: persistent XLA compilation cache dir; restarts (resilience
-        #: supervisor) then deserialize the step instead of recompiling.
+        #: persistent XLA compilation cache dir, honoured only while
+        #: JAX_COMPILATION_CACHE_DIR is unset (pipeline/compile_cache.py
+        #: resolver; default <checkout>/.jax_cache). Restarts then
+        #: deserialize the step instead of recompiling.
         self.compile_cache_dir = compile_cache_dir
         #: trainguard (resilience/guard.py): True / GuardConfig compiles
         #: finiteness + loss-spike checks INTO the train step — an
@@ -246,11 +248,10 @@ class Trainer:
         self.has_validation = val_dataloaders is not None
         example_batch, train_dataloaders = self._peek(train_dataloaders)
 
-        if self.compile_cache_dir:
-            # persistent cache BEFORE any step compiles: a restarted
-            # worker (resilience supervisor) then deserializes every
-            # program instead of recompiling it
-            enable_persistent_cache(self.compile_cache_dir)
+        # persistent cache BEFORE any step compiles: a restarted worker
+        # (resilience supervisor) then deserializes every program
+        # instead of recompiling it
+        enable_persistent_cache(self.compile_cache_dir)
         self.tx = self._build_tx(module)
         self.state = self._init_state(module, example_batch, ckpt_path)
         self._train_step = self._make_train_step(module)
@@ -844,10 +845,18 @@ class Trainer:
                 metrics,
             )
 
+        # The state's layout is a fixed point of the step: left to
+        # itself GSPMD may hand back a small replicated leaf (a norm
+        # gain under fsdp x tensor) SHARDED, and step 2 then feeds the
+        # AOT executable an input layout it was not compiled for
+        # (ValueError; under plain jit, a second compile). Metrics stay
+        # the compiler's choice.
+        state_shardings = jax.tree.map(lambda x: x.sharding, self.state)
         # check_args=(1,): only the batch can drift — re-checking the
         # whole TrainState per step would put O(param leaves) host work
         # back on the hot path
-        return WarmStep(jax.jit(step, donate_argnums=(0,)),
+        return WarmStep(jax.jit(step, donate_argnums=(0,),
+                                out_shardings=(state_shardings, None)),
                         label="train_step", check_args=(1,),
                         recorder=self.telemetry_recorder)
 
@@ -871,10 +880,9 @@ class Trainer:
     def _warm_start_train_step(self, example_batch) -> None:
         """AOT lower().compile() the train step for the known shapes —
         the cold compile happens HERE, visible as compile_time_s, instead
-        of hiding inside the first batch. With a persistent cache
-        (compile_cache_dir / the supervisor's per-plan dir) a restarted
-        process deserializes instead of recompiling, so this reads ~zero
-        on every warm start after the first."""
+        of hiding inside the first batch. With the persistent cache a
+        restarted process deserializes instead of recompiling, so this
+        reads ~zero on every warm start after the first."""
         _, device_batch = self._place_train_batch(example_batch)
         stats = self._train_step.warm(self.state, device_batch,
                                       self._base_rng)

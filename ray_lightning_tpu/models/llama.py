@@ -33,7 +33,7 @@ from jax.sharding import PartitionSpec as P
 from ray_lightning_tpu.core.module import TpuModule
 from ray_lightning_tpu.ops.attention import (
     dot_product_attention,
-    flash_attention,
+    flash_attention_on_mesh,
 )
 from ray_lightning_tpu.ops.fused_ce import fused_cross_entropy
 from ray_lightning_tpu.ops.ring_attention import ring_attention
@@ -291,12 +291,14 @@ class LlamaBlock(nn.Module):
             else:
                 # use_flash=True -> auto (pallas on TPU, XLA fallback
                 # elsewhere); False -> always the XLA reference path.
+                # On a multi-device mesh the kernel runs in a manual
+                # region (XLA cannot partition a Mosaic call).
                 from ray_lightning_tpu.ops.attention import flash_uses_pallas
 
                 pallas_path = flash_uses_pallas(
                     q.shape, k.shape, None if cfg.use_flash else False)
-                attn = flash_attention(
-                    q, k, v, causal=True,
+                attn = flash_attention_on_mesh(
+                    q, k, v, self.mesh, causal=True,
                     use_pallas=None if cfg.use_flash else False)
             # name the attention output for remat_policy="attn_out" —
             # the save point the XLA-reference (and seq-parallel island)
@@ -400,8 +402,8 @@ class LlamaBlock(nn.Module):
                 # prefill from empty context: plain causal attention over
                 # the chunk itself (flash path — never materialize the
                 # [S, S_max] masked score matrix against the zero tail).
-                attn = flash_attention(
-                    q, k, v, causal=True,
+                attn = flash_attention_on_mesh(
+                    q, k, v, self.mesh, causal=True,
                     use_pallas=None if cfg.use_flash else False)
             else:
                 # single-token decode (or mid-sequence chunk, or a

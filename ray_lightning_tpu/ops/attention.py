@@ -128,6 +128,56 @@ def flash_attention(
                                  q_offset=q_offset)
 
 
+def flash_attention_on_mesh(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    mesh,
+    causal: bool = True,
+    use_pallas: bool | None = None,
+) -> jnp.ndarray:
+    """`flash_attention` for GSPMD-sharded [B, S, H, D] operands.
+
+    XLA refuses to partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so on a mesh of more than one device
+    the pallas path runs inside a manual region: batch over the mesh's
+    data-parallel axes (`parallel.mesh.dp_axis_names`; replicated when
+    the batch does not divide them, e.g. a one-prompt `generate` after
+    a sharded fit), heads over ``tensor``, sequence whole (sharded
+    sequences go through ring/ulysses). Off-TPU the kernel is
+    interpreted into ordinary ops, which would partition on their own;
+    they take the same region so the 8-device CPU tests run the program
+    the chips run. The XLA reference path and single-device meshes are
+    passed through untouched."""
+    if (mesh is None or mesh.size == 1
+            or not flash_uses_pallas(q.shape, k.shape, use_pallas)):
+        return flash_attention(q, k, v, causal=causal,
+                               use_pallas=use_pallas)
+    from jax.sharding import PartitionSpec as P
+
+    from ray_lightning_tpu.parallel.mesh import (
+        batch_size_divisor,
+        dp_axis_names,
+    )
+
+    t = mesh.shape.get("tensor", 1)
+    if q.shape[2] % t or k.shape[2] % t:
+        raise ValueError(
+            f"flash attention on a tensor={t} mesh needs n_heads "
+            f"({q.shape[2]}) and n_kv_heads ({k.shape[2]}) divisible by "
+            "it: the kernel runs per head shard")
+    batch_axes = (dp_axis_names(mesh)
+                  if q.shape[0] % batch_size_divisor(mesh) == 0 else None)
+    spec = P(batch_axes, None, "tensor" if t > 1 else None, None)
+
+    def local(q, k, v):
+        return flash_attention(q, k, v, causal=causal,
+                               use_pallas=use_pallas)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 # ---- paged decode attention (the serving engine's fused hot op) -----------
 
 

@@ -54,39 +54,16 @@ def force_pallas():
 
 
 def on_tpu() -> bool:
-    """True when the default backend is a real TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend init failure → reference path
-        return False
+    """True when the default backend is a real TPU. A backend that
+    fails to initialize raises here: a dead chip must not read as
+    "not a TPU" and quietly select interpret mode."""
+    return jax.default_backend() == "tpu"
 
 
 def interpret_mode() -> bool:
     """Pallas kernels run in interpret mode everywhere but TPU (so tests
     exercise kernel logic on the CPU mesh)."""
     return not on_tpu()
-
-
-def shard_map(f, mesh, in_specs, out_specs, *,
-              check_replication: bool = True):
-    """Version-portable shard_map: `jax.shard_map` (current jax, where
-    the replication-check kwarg is `check_vma`) with a fallback to
-    `jax.experimental.shard_map.shard_map` (jax <= 0.4.x, `check_rep`).
-    The manual islands (ring/ulysses attention, the GPipe pipeline) go
-    through here so a jax upgrade/downgrade is one-file work — the same
-    contract as `parallel.plan.abstract_mesh`."""
-    if hasattr(jax, "shard_map"):
-        import inspect
-
-        params = inspect.signature(jax.shard_map).parameters
-        kw = "check_vma" if "check_vma" in params else "check_rep"
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             **{kw: check_replication})
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_replication)
 
 
 # ---- collective-overlap shim (models/llama.py double-buffered FSDP) ------
@@ -114,28 +91,20 @@ def prefetch_named(tree):
 
 @jax.custom_vjp
 def overlap_barrier(trees):
-    """Differentiable, version-portable `lax.optimization_barrier`.
+    """Differentiable `lax.optimization_barrier`.
 
     The double-buffered schedule must pin "issue layer i+1's weight
     gather BEFORE layer i's compute consumes x" — without a data
     dependence XLA's scheduler is free to sink the gather to its use and
     re-expose the latency. `optimization_barrier` provides the ordering
-    but (as of jax 0.4.x) has no differentiation rule, so this wraps it
-    in a custom_vjp: barrier applied in the forward, cotangents passed
-    straight through (the backward scan builds its own schedule from the
-    transposed collectives). On jax builds without the primitive the
-    barrier degrades to identity — the schedule is then merely advisory,
-    never wrong."""
-    return _barrier(trees)
-
-
-def _barrier(trees):
-    fn = getattr(jax.lax, "optimization_barrier", None)
-    return fn(trees) if fn is not None else trees
+    and this wraps it in a custom_vjp: barrier applied in the forward,
+    cotangents passed straight through (the backward scan builds its
+    own schedule from the transposed collectives)."""
+    return jax.lax.optimization_barrier(trees)
 
 
 def _overlap_barrier_fwd(trees):
-    return _barrier(trees), None
+    return jax.lax.optimization_barrier(trees), None
 
 
 def _overlap_barrier_bwd(_, g):
@@ -158,15 +127,15 @@ def fusion_fence(trees):
     the block region so it is an identical compilation unit under the
     prefetched and serial schedules — the bitwise-parity guarantee
     rests on it."""
-    return _barrier(trees)
+    return jax.lax.optimization_barrier(trees)
 
 
 def _fence_fwd(trees):
-    return _barrier(trees), None
+    return jax.lax.optimization_barrier(trees), None
 
 
 def _fence_bwd(_, g):
-    return (_barrier(g),)
+    return (jax.lax.optimization_barrier(g),)
 
 
 fusion_fence.defvjp(_fence_fwd, _fence_bwd)

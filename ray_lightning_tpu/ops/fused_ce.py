@@ -216,9 +216,9 @@ def _ce_inline_fwd(chunk_tokens, dtype_name, hidden, lm_head, targets, m):
         # Straight-line chunk chain instead of a `while` loop: n_chunks is
         # static, and a lax.scan whose CARRY is the [D, V] f32 dW
         # accumulator (~1 GB at Llama-3 vocab) is the program shape the
-        # TPU compile path handled worst in our sweeps (observed on v5e:
-        # minutes-long or helper-crashing compiles at n_chunks >= 2,
-        # scripts/sweep_flagship_results.jsonl); unrolling removes the
+        # TPU compile path handled worst in the July 2026 v5e sweeps
+        # (minutes-long or failing compiles at n_chunks >= 2; not
+        # re-measured on the current toolchain); unrolling removes the
         # while-loop + giant-carry structure entirely. The
         # optimization_barrier threads each chunk's inputs through the
         # previous chunk's dW so the bodies form a data-dependence CHAIN:

@@ -106,14 +106,17 @@ def _decode_kernel(tbl_ref, len_ref, pad_ref, q_ref, k_ref, v_ref, o_ref,
         qg = q.reshape(hkv, n_rep, hd)
         kg = k.transpose(1, 0, 2)              # [Hkv, P, hd]
         vg = v.transpose(1, 0, 2)
-        s = jax.lax.dot_general(
+        s = (jax.lax.dot_general(
             qg, kg, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale                              # [Hkv, n_rep, P]
+        ) * scale).reshape(h, block_p)         # [Hkv, n_rep, P] -> [H, P]
+        # the mask is built in the stats layout [H, P] directly: Mosaic
+        # cannot reshape an i1 vector across the degenerate n_rep == 1
+        # axis (MHA; LLO_CHECK `vmand ... ProducesVreg` on v5e)
         kv_pos = kv_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
+            jnp.int32, s.shape, 1)
         visible = (kv_pos < length) & (kv_pos >= pad_ref[c])
-        s = jnp.where(visible, s, _NEG_INF).reshape(h, block_p)
+        s = jnp.where(visible, s, _NEG_INF)
         m_prev = m_scr[:, 0]                   # [H]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         # masked positions are zeroed EXPLICITLY, not only through the
@@ -121,8 +124,7 @@ def _decode_kernel(tbl_ref, len_ref, pad_ref, q_ref, k_ref, v_ref, o_ref,
         # pad) has s == m_new == _NEG_INF and exp(s - m_new) == 1 —
         # the sentinel-minus-sentinel trap would weight garbage at
         # full probability
-        p = jnp.where(visible.reshape(h, block_p),
-                      jnp.exp(s - m_new[:, None]), 0.0)  # [H, P]
+        p = jnp.where(visible, jnp.exp(s - m_new[:, None]), 0.0)  # [H, P]
         corr = jnp.exp(m_prev - m_new)
         l_scr[:, 0] = corr * l_scr[:, 0] + jnp.sum(p, axis=1)
         av = jax.lax.dot_general(

@@ -46,7 +46,8 @@ flash discipline (`ops.attention.paged_prefill_uses_pallas` as the
 single predicate; interpret mode off-TPU).
 
 Block sizes: the KV tile IS the pool block (`block_size`), the query
-tile halves down from 128 until it divides CH (`_fit_q_block`). The
+tile halves down from 128 until it divides CH and its tile-sized
+buffers fit the scoped VMEM budget (`_fit_q_block`). The
 on-TPU sweep over `block_size`/`blocks_per_slot` for BOTH paged
 kernels lives in `serve/sweep.py` (docs/SERVING.md "block-size
 autotune").
@@ -65,11 +66,29 @@ from ray_lightning_tpu.ops.dispatch import interpret_mode as _interpret
 _NEG_INF = -1e30  # never true -inf: exp(-inf - -inf) = nan on empty rows
 
 
-def _fit_q_block(ch: int, cap: int = 128) -> int:
+#: VMEM the q-tile-sized buffers may claim, under Mosaic's 16 MiB scoped
+#: default on v5e (the K/V tiles, stats columns and score panel take
+#: the rest). tests/test_tpu_aot_compile.py checks that every shape the
+#: predicate accepts compiles for "TPU v5 lite".
+_Q_TILE_VMEM_BUDGET = 12 * 1024 * 1024
+
+#: VMEM bytes per element of the [bq, H, hd] query tile: the f32
+#: accumulator (4), the double-buffered q and o tiles (4 x itemsize,
+#: priced at f32), and the f32 temporaries the body materializes at
+#: tile size: the upcast q, its head-grouped transpose, the AV product,
+#: its un-grouped transpose and the rescaled accumulator (measured:
+#: 17.62M at bq=128 H=32 hd=128 bf16, ~35 B per element).
+_Q_TILE_BYTES_PER_ELEM = 4 + 4 * 4 + 6 * 4
+
+
+def _fit_q_block(ch: int, h: int, hd: int, cap: int = 128) -> int:
     """Largest query tile <= ``cap`` that divides the chunk width
-    (halving search, the flash `_fit_block` discipline)."""
+    (halving search, the flash `_fit_block` discipline) and whose
+    tile-sized buffers fit `_Q_TILE_VMEM_BUDGET`."""
     b = min(cap, ch)
-    while b > 1 and ch % b != 0:
+    while b > 1 and (
+            ch % b != 0
+            or b * h * hd * _Q_TILE_BYTES_PER_ELEM > _Q_TILE_VMEM_BUDGET):
         b //= 2
     return b
 
@@ -96,7 +115,7 @@ def paged_prefill_shapes_supported(q_shape, pool_shape) -> bool:
         return False
     if p % 8 != 0:
         return False
-    if ch < 1 or (_fit_q_block(ch) * h) % 8 != 0:
+    if ch < 1 or (_fit_q_block(ch, h, hd) * h) % 8 != 0:
         return False
     return True
 
@@ -217,7 +236,7 @@ def paged_prefill_pallas(
     scale = scale if scale is not None else hd ** -0.5
     if pad is None:
         pad = jnp.zeros((b,), jnp.int32)
-    bq = _fit_q_block(ch)
+    bq = _fit_q_block(ch, h, hd)
     nq = ch // bq
     kernel = functools.partial(
         _prefill_kernel, scale=scale, block_p=p, block_q=bq,

@@ -151,14 +151,12 @@ def gpipe_apply(
         )
         return out.reshape(x_local.shape)
 
-    from ray_lightning_tpu.ops.dispatch import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, x_spec) + extra_specs,
         out_specs=x_spec,
-        check_replication=False,  # mixes pipe-varying and replicated
+        check_vma=False,  # mixes pipe-varying and replicated
     )(stacked_params, x, *extra)
 
 

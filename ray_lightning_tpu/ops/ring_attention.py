@@ -146,15 +146,13 @@ def seq_island(local_fn, mesh: Mesh, axis_name: str = "seq", **kwargs):
                   if ax in mesh.shape)
     head_ax = "tensor" if "tensor" in mesh.shape else None
     spec = P(bspec if bspec else None, axis_name, head_ax, None)
-    from ray_lightning_tpu.ops.dispatch import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         partial(local_fn, axis_name=axis_name,
                 axis_size=mesh.shape[axis_name], **kwargs),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_replication=False,  # collective-permute varying-axes opt-out
+        check_vma=False,  # collective-permute varying-axes opt-out
     )
 
 

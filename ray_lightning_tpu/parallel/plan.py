@@ -55,19 +55,9 @@ HBM_BYTES_BY_KIND: Dict[str, int] = {
 
 def abstract_mesh(spec: MeshSpec) -> AbstractMesh:
     """An AbstractMesh with this spec's axis names/sizes — NamedSharding
-    accepts it, `shard_shape` works, and no devices are required.
-
-    Handles both AbstractMesh signatures: the current
-    ``AbstractMesh(axis_sizes, axis_names)`` and the older
-    ``AbstractMesh(shape_tuple)`` of (name, size) pairs (jax <= 0.4.x),
-    so the planner keeps its zero-device guarantee across the jax
-    versions the runtime supports."""
+    accepts it, `shard_shape` works, and no devices are required."""
     sizes = spec.sizes()
-    shape = tuple(sizes[ax] for ax in AXIS_ORDER)
-    try:
-        return AbstractMesh(shape, AXIS_ORDER)
-    except TypeError:
-        return AbstractMesh(tuple(zip(AXIS_ORDER, shape)))
+    return AbstractMesh(tuple(sizes[ax] for ax in AXIS_ORDER), AXIS_ORDER)
 
 
 def hbm_bytes_for_kind(device_kind: str,

@@ -295,34 +295,6 @@ class Strategy:
         assert self.mesh is not None, "call setup() first"
         return build_sdc_probe(params, self.mesh)
 
-    # ---- compile-cache identity ------------------------------------------
-
-    def compile_cache_key(self) -> str:
-        """Stable identity of this sharding plan for the persistent
-        compilation cache (pipeline/compile_cache.py): strategy class +
-        mesh axis sizes + device platform. Two runs with the same key
-        lower the same step program, so they can share one cache dir;
-        the actual cache entry key is XLA's own (hash of the lowered
-        program), so this only partitions the directory space."""
-        from ray_lightning_tpu.pipeline.compile_cache import plan_cache_key
-
-        parts = [type(self).__name__]
-        if self.mesh is not None:
-            parts.append(sorted(self.mesh.shape.items()))
-            parts.append(self.mesh.devices.flat[0].platform)
-        if self._module is not None:
-            parts.append(type(self._module).__name__)
-        return plan_cache_key(*parts)
-
-    def compile_cache_dir(self, base_dir: str) -> str:
-        """Per-plan persistent cache directory under ``base_dir`` —
-        hand this to ``Trainer(compile_cache_dir=...)`` (the resilience
-        supervisor derives its own beside the checkpoint dir)."""
-        import os as _os
-
-        return _os.path.join(_os.path.abspath(base_dir),
-                             self.compile_cache_key())
-
     # ---- placement -------------------------------------------------------
 
     def shard_params(self, params) -> Any:

@@ -9,9 +9,9 @@ checkpoint. This package removes them (docs/PERFORMANCE.md):
     batch assembly + sharded device placement with the previous step's
     compute, so the jitted step's input is resident when it dispatches;
   * `compile_cache` — AOT ``lower().compile()`` warm start for the
-    train/eval steps plus the persistent XLA compilation cache keyed
-    per sharding plan, so restart N recompiles nothing and compile time
-    is a first-class metric (`CompileStats`);
+    train/eval steps plus the persistent XLA compilation cache in one
+    resolved directory, so restart N recompiles nothing and compile
+    time is a first-class metric (`CompileStats`);
   * `overlap` — the CPU-measurable proof harness: a deliberately slow
     synthetic loader must show prefetch hiding the host time (bench.py
     leg, ``python -m ray_lightning_tpu perf --smoke`` format.sh gate).
@@ -26,7 +26,7 @@ from ray_lightning_tpu.pipeline.compile_cache import (
     CompileStats,
     WarmStep,
     enable_persistent_cache,
-    plan_cache_dir,
+    resolve_cache_dir,
 )
 from ray_lightning_tpu.pipeline.prefetch import (
     DevicePrefetcher,
@@ -39,5 +39,5 @@ __all__ = [
     "CompileStats",
     "WarmStep",
     "enable_persistent_cache",
-    "plan_cache_dir",
+    "resolve_cache_dir",
 ]

@@ -20,20 +20,23 @@ shape drift (a user loader yielding a ragged batch) falls back to the
 jitted path permanently rather than erroring — AOT is an optimization,
 never a new constraint.
 
-`enable_persistent_cache` fixes the second: it points jax's persistent
-compilation cache (``jax_compilation_cache_dir``) at a per-plan
-directory (`plan_cache_dir`), with the entry thresholds dropped to zero
-so even fast-compiling steps are cached. Restart N then recompiles
-nothing: the lowered program hashes to the same key and the executable
-is deserialized from disk. The cache key is XLA's own (computed from
-the lowered HLO + compile options), so keying the *directory* per plan
-is only hygiene — different meshes/plans never collide anyway, but a
-shared dir across experiments grows without bound.
+`enable_persistent_cache` fixes the second: it turns on jax's
+persistent compilation cache (``jax_compilation_cache_dir``) with the
+entry thresholds dropped to zero so even fast-compiling steps are
+cached. Restart N then recompiles nothing: the lowered program hashes
+to the same key and the executable is deserialized from disk.
+
+The directory comes from ONE resolver, `resolve_cache_dir`, so it can
+be placed from outside and never moves underneath a run:
+``JAX_COMPILATION_CACHE_DIR`` wins when set (nothing in the package
+then points jax anywhere else); otherwise an explicit
+``compile_cache_dir=`` argument; otherwise whatever this process
+already uses; otherwise the fixed ``<checkout>/.jax_cache``. A cache
+that moves (a temp dir, a per-plan hash) is a cache that never hits.
 """
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import os
 import time
 from typing import Any, Callable, Optional, Tuple
@@ -63,54 +66,52 @@ class CompileStats:
         }
 
 
-def enable_persistent_cache(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if needed) and drop the size/time thresholds so every step program is
-    cached. Idempotent; returns the directory. Process-global — the last
-    caller wins, which is why the supervisor sets it once per worker from
-    one resolved config."""
-    cache_dir = os.path.abspath(cache_dir)
+#: where the cache lives when nothing places it: a fixed path inside the
+#: checkout (git-ignored), so every process of every run finds it again
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def resolve_cache_dir(explicit: Optional[str] = None) -> str:
+    """The persistent cache directory, by precedence: the
+    ``JAX_COMPILATION_CACHE_DIR`` variable, the caller's ``explicit``
+    argument, the directory this process already caches in, and last
+    `DEFAULT_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if explicit:
+        return os.path.abspath(explicit)
+    return jax.config.jax_compilation_cache_dir or DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache in the directory
+    `resolve_cache_dir` picks (created if needed) and drop the
+    size/time thresholds so every step program is cached. Idempotent;
+    returns the directory. Process-global."""
+    from jax._src import compilation_cache as _cc
+
+    cache_dir = resolve_cache_dir(cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    previous = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_enable_compilation_cache", True)
-    if previous != cache_dir:
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
         # jax binds the on-disk cache object to the directory on first
         # use; without a reset a dir change after any compile in this
         # process is silently ignored
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — private API; a jax that
-            # re-reads the config per compile doesn't need the nudge
-            log.debug("could not reset jax compilation cache",
-                      exc_info=True)
-    # cache everything: the trainer's step is THE program that matters
-    # here, and on a restart even a 0.5 s compile is pure waste
+        _cc.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", True)
+    # cache everything: the step is THE program that matters here, and
+    # on a restart even a 0.5 s compile is pure waste
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return cache_dir
 
 
 def active_cache_dir() -> Optional[str]:
-    """The persistent cache directory currently in effect (config beats
-    env, matching jax's own resolution), or None."""
-    configured = jax.config.jax_compilation_cache_dir
-    return configured or os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
-
-
-def plan_cache_key(*parts: Any) -> str:
-    """Stable short hash over plan-identifying parts (mesh axes, strategy
-    and module class names, precision...). Same key ⇒ same cache dir ⇒
-    restarts and repeat runs of the same plan share compiled artifacts."""
-    blob = "|".join(str(p) for p in parts)
-    return hashlib.sha256(blob.encode()).hexdigest()[:16]
-
-
-def plan_cache_dir(base_dir: str, *parts: Any) -> str:
-    """``<base_dir>/<plan_cache_key(parts)>`` — one cache dir per plan."""
-    return os.path.join(os.path.abspath(base_dir), plan_cache_key(*parts))
+    """The persistent cache directory currently in effect, or None."""
+    return jax.config.jax_compilation_cache_dir or None
 
 
 def _abstract(tree: Any) -> Any:
@@ -227,3 +228,9 @@ class WarmStep:
     @property
     def aot_active(self) -> bool:
         return self._compiled is not None
+
+    def compiled_text(self) -> Optional[str]:
+        """The AOT executable's optimized HLO, or None before `warm`:
+        what a smoke greps to see which kernels the step really runs
+        (a Mosaic kernel is a ``tpu_custom_call``)."""
+        return None if self._compiled is None else self._compiled.as_text()

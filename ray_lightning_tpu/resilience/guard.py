@@ -383,8 +383,6 @@ def build_sdc_probe(params, mesh):
     collective per probe, nothing per step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_lightning_tpu.ops import dispatch
-
     devices = list(mesh.devices.flat)
     groups = replica_groups(params, mesh)
     if len(devices) == 1:
@@ -396,9 +394,8 @@ def build_sdc_probe(params, mesh):
     def per_device(p):
         return _tree_digest(p).reshape((1,))
 
-    mapped = dispatch.shard_map(per_device, mesh, in_specs=(specs,),
-                                out_specs=P(axes),
-                                check_replication=False)
+    mapped = jax.shard_map(per_device, mesh=mesh, in_specs=(specs,),
+                           out_specs=P(axes), check_vma=False)
     fn = jax.jit(mapped, out_shardings=NamedSharding(mesh, P()))
     return fn, devices, groups
 

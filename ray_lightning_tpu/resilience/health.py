@@ -2,7 +2,7 @@
 
 The runtime's failure detector (group._check_liveness) only sees a worker
 that DIED. A worker that is alive-but-wedged — a deadlocked collective, a
-hung device tunnel — looks identical to one spending 20 minutes in XLA
+device that stopped answering — looks identical to one spending 20 minutes in XLA
 compilation, and the reference's answer (Ray actor health checks) is gone.
 The distinction this module draws:
 

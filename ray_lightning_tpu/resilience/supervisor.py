@@ -74,8 +74,9 @@ class ResilienceConfig:
     async_save: bool = True
     #: persistent XLA compile cache shared across restarts, so attempt N
     #: deserializes the train step instead of recompiling it (the cold
-    #: compile otherwise multiplies by the restart budget). None derives
-    #: ``<checkpoint_dir>/.compile_cache``; "off" disables.
+    #: compile otherwise multiplies by the restart budget). Passed to the
+    #: trainer; None leaves the pipeline/compile_cache.py resolver's
+    #: choice (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache).
     compile_cache_dir: Optional[str] = None
     #: trainguard (resilience/guard.py): a GuardConfig (or True for the
     #: defaults) compiles the in-step anomaly guard into every worker's
@@ -111,12 +112,6 @@ class ResilienceConfig:
     #: test-pinned). Breaches land in <checkpoint_dir>/incidents.jsonl
     #: and in SupervisedResult.incidents. None (default): off.
     watch: Any = None
-
-    def resolved_compile_cache_dir(self) -> Optional[str]:
-        if self.compile_cache_dir == "off":
-            return None
-        return self.compile_cache_dir or os.path.join(
-            self.checkpoint_dir, ".compile_cache")
 
     def resolved_telemetry_dir(self) -> Optional[str]:
         if not self.telemetry:
@@ -245,11 +240,8 @@ def _wrapped_trainer_factory(trainer_factory: Callable[[], Any],
 
     trainer = trainer_factory()
     reset_preemption()  # fresh process; stale flags impossible but cheap
-    cache_dir = cfg.resolved_compile_cache_dir()
-    if cache_dir and not trainer.compile_cache_dir:
-        # restart N must deserialize the step, not recompile it — the
-        # trainer reports the (near-zero) warm compile as compile_time_s
-        trainer.compile_cache_dir = cache_dir
+    if cfg.compile_cache_dir and not trainer.compile_cache_dir:
+        trainer.compile_cache_dir = cfg.compile_cache_dir
     has_periodic = any(
         isinstance(c, ModelCheckpoint)
         and getattr(c, "dirpath", None) == cfg.checkpoint_dir

@@ -108,22 +108,7 @@ def _spmd_main(
     if platform:
         jax.config.update("jax_platforms", platform)
     if num_cpu_devices:
-        try:
-            jax.config.update("jax_num_cpu_devices", num_cpu_devices)
-        except AttributeError:
-            # older jax (< 0.5) has no jax_num_cpu_devices config; the
-            # pre-backend XLA flag is the portable spelling. We run
-            # before any backend init (nothing has touched devices yet),
-            # so the flag is still honored. Strip an inherited count
-            # first — repeated flags must not fight.
-            import re as _re
-
-            flags = os.environ.get("XLA_FLAGS", "")
-            flags = _re.sub(
-                r"--xla_force_host_platform_device_count=\d+", "", flags)
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{num_cpu_devices}")
+        jax.config.update("jax_num_cpu_devices", num_cpu_devices)
         # Cross-process CPU collectives ride gloo (the CI fabric; on TPU
         # the fabric is ICI and this knob is untouched). Only with > 1
         # process: gloo requires the distributed client, which a

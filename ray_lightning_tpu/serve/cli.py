@@ -213,7 +213,6 @@ def run_smoke(args) -> int:
         drv2 = ServeDriver(cfg, pp, ReplicaGroupConfig(
             n_replicas=2, backend="process", engine=ecfg,
             run_dir=run2,
-            compile_cache_dir=os.path.join(tmp, "compile_cache"),
             env={"JAX_PLATFORMS": "cpu"},
             metrics_flush_every_n_ticks=4, flight_persist_every=4))
         # the driver copies requests before stamping, so the same list
@@ -751,13 +750,14 @@ def _run_example(args) -> int:
             pp = os.path.join(tmp, "params.npz")
             save_params_npz(params, pp)
             params_arg = pp
-            env = {"JAX_PLATFORMS":
-                   os.environ.get("JAX_PLATFORMS", "cpu")}
         else:
-            params_arg, env = params, None
+            params_arg = params
+        # process replicas inherit this process's platform; on a TPU
+        # host the driver refuses them here (this process built the
+        # weights with jax and holds the chips): use inline replicas
         drv = ServeDriver(cfg, params_arg, ReplicaGroupConfig(
             n_replicas=args.replicas, backend=args.backend, engine=ecfg,
-            run_dir=args.run_dir, env=env))
+            run_dir=args.run_dir))
         res = drv.run(reqs)
     ttfts = sorted(m["ttft_s"] for m in res.meta.values())
     line = {

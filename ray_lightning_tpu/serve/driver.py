@@ -64,6 +64,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ray_lightning_tpu.pipeline.compile_cache import enable_persistent_cache
 from ray_lightning_tpu.serve.engine import DecodeEngine, EngineConfig
 from ray_lightning_tpu.serve.scheduler import (
     Completion, Request, Scheduler, SLOConfig,
@@ -117,8 +118,10 @@ class ReplicaGroupConfig:
     reserve: str = "worst_case"
     #: run dir: telemetry spans + serving.json summary land here
     run_dir: Optional[str] = None
-    #: persistent compile cache (pipeline.compile_cache) — respawned
-    #: replicas deserialize the step instead of recompiling
+    #: persistent compile cache dir — respawned replicas deserialize
+    #: the step instead of recompiling. Honoured only while
+    #: JAX_COMPILATION_CACHE_DIR is unset; None = the
+    #: pipeline.compile_cache resolver's choice
     compile_cache_dir: Optional[str] = None
     max_restarts: int = 2
     #: extra env for process replicas (e.g. {"JAX_PLATFORMS": "cpu"})
@@ -417,12 +420,7 @@ def _replica_worker_main(model_cfg_kw: dict, params_path: str,
     from ray_lightning_tpu.models.llama import Llama, LlamaConfig
     from ray_lightning_tpu.runtime import session
 
-    if compile_cache_dir:
-        from ray_lightning_tpu.pipeline.compile_cache import (
-            enable_persistent_cache,
-        )
-
-        enable_persistent_cache(compile_cache_dir)
+    enable_persistent_cache(compile_cache_dir)
     dtype = model_cfg_kw.pop("dtype", "float32")
     cfg = LlamaConfig(**model_cfg_kw, dtype=jnp.dtype(dtype))
     model = Llama(cfg)
@@ -508,12 +506,7 @@ def _replica_session_main(model_cfg_kw: dict, params_path: str,
         request_to_wire,
     )
 
-    if compile_cache_dir:
-        from ray_lightning_tpu.pipeline.compile_cache import (
-            enable_persistent_cache,
-        )
-
-        enable_persistent_cache(compile_cache_dir)
+    enable_persistent_cache(compile_cache_dir)
     dtype = model_cfg_kw.pop("dtype", "float32")
     cfg = LlamaConfig(**model_cfg_kw, dtype=jnp.dtype(dtype))
     model = Llama(cfg)
@@ -724,6 +717,38 @@ def _replica_session_main(model_cfg_kw: dict, params_path: str,
 
 # ---- the driver ------------------------------------------------------------
 
+def _require_chip_free_parent(cfg: "ReplicaGroupConfig") -> None:
+    """One process for each chip: a parent that has touched the TPU
+    holds it, and a replica process that needs it then fails or hangs.
+    Refuse before spawning. Replicas pinned to the CPU do not need the
+    chip and are exempt."""
+    import jax
+    from jax._src import xla_bridge
+
+    child_platform = ((cfg.env or {}).get("JAX_PLATFORMS")
+                      or cfg.platform or os.environ.get("JAX_PLATFORMS"))
+    if child_platform == "cpu":
+        return
+    if (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu"):
+        raise RuntimeError(
+            "backend='process' from a process that already initialized "
+            "the TPU backend: this process holds the host's chips, so "
+            "replica processes could never reach them. Drive the chips "
+            "from one process with backend='inline' (one replica per "
+            "chip), or keep the parent off JAX (pass params as a .npz "
+            "path and do not touch jax before spawning).")
+
+
+def _inline_device(replica: int):
+    """Inline replica ``i`` lives on local device ``i mod n``: one
+    process drives every chip of the host, one replica per chip."""
+    import jax
+
+    devices = jax.local_devices()
+    return devices[replica % len(devices)]
+
+
 class _Replica:
     """One inline replica in a dynamic serving session: engine +
     scheduler + recorder and a three-state lifecycle
@@ -835,6 +860,7 @@ class ServeDriver:
                     fault: Optional[dict]) -> ServeResult:
         from ray_lightning_tpu.models.llama import Llama
 
+        enable_persistent_cache(self.cfg.compile_cache_dir)
         params = self.params
         if self.params_path is not None:
             params = load_params_npz(self.params_path)
@@ -860,7 +886,8 @@ class ServeDriver:
             engine = DecodeEngine(model, params, self.cfg.engine,
                                   metrics=metrics,
                                   draft_model=draft_model,
-                                  draft_params=self.draft_params)
+                                  draft_params=self.draft_params,
+                                  device=_inline_device(r))
             engine.warmup()
             sched = Scheduler(engine, reserve=self.cfg.reserve,
                               metrics=metrics, flight=flight,
@@ -1120,6 +1147,7 @@ class ServeDriver:
                                  "backend='process' — a replica must "
                                  "die for real to drill recovery")
             return self._run_inline(requests, fault)
+        _require_chip_free_parent(self.cfg)
         return self._run_process(requests, fault)
 
     # ---- dynamic serving session: the autoscale actuation seams ----------
@@ -1175,12 +1203,7 @@ class ServeDriver:
             raise ValueError("fault injection needs backend='process' "
                              "— a replica must die for real to drill "
                              "recovery")
-        if self.cfg.compile_cache_dir:
-            from ray_lightning_tpu.pipeline.compile_cache import (
-                enable_persistent_cache,
-            )
-
-            enable_persistent_cache(self.cfg.compile_cache_dir)
+        enable_persistent_cache(self.cfg.compile_cache_dir)
         if self.cfg.backend == "inline":
             from ray_lightning_tpu.models.llama import Llama
 
@@ -1189,6 +1212,7 @@ class ServeDriver:
                 Llama(self.cfg.draft_model_cfg)
                 if self.cfg.draft_model_cfg is not None else None)
         else:
+            _require_chip_free_parent(self.cfg)
             self._session_dir = self.cfg.run_dir or os.path.join(
                 os.getcwd(), "rlt_logs", "serve")
             os.makedirs(self._session_dir, exist_ok=True)
@@ -1279,7 +1303,8 @@ class ServeDriver:
         engine = DecodeEngine(self._model, params, self.cfg.engine,
                               metrics=metrics,
                               draft_model=self._draft_model,
-                              draft_params=self.draft_params)
+                              draft_params=self.draft_params,
+                              device=_inline_device(r))
         engine.warmup()
         sched = Scheduler(engine, reserve=self.cfg.reserve,
                           metrics=metrics, flight=flight,

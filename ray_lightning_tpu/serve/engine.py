@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_lightning_tpu.pipeline.compile_cache import enable_persistent_cache
 from ray_lightning_tpu.serve.kv_cache import (
     PagedPoolSpec,
     init_pool,
@@ -773,7 +774,12 @@ class DecodeEngine:
                  max_seq_len_check: bool = True,
                  use_pallas: Optional[bool] = None,
                  metrics=None, mesh=None,
-                 draft_model=None, draft_params=None):
+                 draft_model=None, draft_params=None, device=None):
+        """``device`` is where an unsharded replica (``mesh=None``)
+        keeps its weights, pool and logits; the driver hands each
+        inline replica its own. Default: the first local device."""
+        # before the step compiles: a respawned replica deserializes it
+        enable_persistent_cache()
         if max_seq_len_check and cfg.max_slot_len > model.cfg.max_seq_len:
             raise ValueError(
                 f"engine max_slot_len {cfg.max_slot_len} exceeds the "
@@ -795,6 +801,11 @@ class DecodeEngine:
         spec = cfg.pool_spec
         if use_pallas is None and not model.cfg.use_flash:
             use_pallas = False  # reference-forced model config
+        if mesh is not None and mesh.size > 1:
+            # XLA cannot partition a Mosaic kernel and the paged kernels
+            # have no manual region yet: a sharded replica takes the
+            # reference lanes, and attention_path/prefill_path say so
+            use_pallas = False
         pool_shape = (spec.n_blocks, spec.block_size,
                       model.cfg.n_kv_heads, model.cfg.head_dim)
         self.fused = paged_attention_uses_pallas(
@@ -883,7 +894,10 @@ class DecodeEngine:
             # flow; test-pinned). Committing the weights to one
             # concrete device keeps every signature
             # SingleDeviceSharding from the first tick on.
-            self.params = jax.device_put(params, jax.devices()[0])
+            if device is None:
+                device = jax.local_devices()[0]
+            self.device = device
+            self.params = jax.device_put(params, device)
             if cfg.draft is not None:
                 # donated: both pools + last_logits (positions 2-6 of
                 # the spec signature — params/draft params stay)
@@ -902,7 +916,6 @@ class DecodeEngine:
             # the moment the donated outputs cycle back in (same
             # phantom-recompile class as the params placement above;
             # the churn pin covers both)
-            device = jax.devices()[0]
             pool_k, pool_v = init_pool(model.cfg, self.spec)
             self.pool_k = jax.device_put(pool_k, device)
             self.pool_v = jax.device_put(pool_v, device)
@@ -1004,7 +1017,8 @@ class DecodeEngine:
         otherwise — the single-slot program is the historical one, with
         no pad inputs."""
         if self.mesh is None:
-            put = jnp.asarray
+            def put(x):
+                return jax.device_put(x, self.device)
         else:
             # every runtime input is replicated over the replica's own
             # mesh: each rank computed the SAME host values (lockstep

@@ -672,8 +672,8 @@ def _smoke_serving_run(run_dir: str, stall_s: float = 0.25):
             ctl.step(now=float(tick))
     # the stall: submit the last request, admit it (first tick), then
     # stall the host mid-prefill — its admission->first-token wall
-    # (TTFT) absorbs the sleep, exactly how a wedged device tunnel or
-    # an interactive-priority stall shows up in production
+    # (TTFT) absorbs the sleep, exactly how a device that stops
+    # answering or an interactive-priority stall shows up in production
     drv.submit(reqs[-1])
     drv.tick()
     time.sleep(stall_s)

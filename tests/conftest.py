@@ -16,9 +16,14 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # Persistent compilation cache: repeat runs (and the many subprocess
 # workers, which inherit this env) skip recompiles of identical programs —
 # the dominant cost of the suite. Keyed per jax version automatically.
+# The directory is the one the package's resolver falls back to
+# (pipeline/compile_cache.py DEFAULT_CACHE_DIR, pinned equal by
+# tests/test_chip_bringup.py); spelled out here because it must be in
+# the environment before jax is imported.
 os.environ.setdefault(
     "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.expanduser("~"), ".cache", "rlt_jax_cache"),
+    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                 ".jax_cache"),
 )
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.3")
 # Arm the lock-order sanitizer (analysis/lockwatch.py) for the whole

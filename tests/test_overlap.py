@@ -714,21 +714,34 @@ class TestBenchGate:
                         "overlap_hidden_fraction": 0.9},
                        best, 0.05) == []
 
-    def test_cli_against_repo_history(self):
-        """The gate must accept the repo's own best round (no
-        self-regression) and reject a gutted line."""
+    def test_cli_against_recorded_history(self, tmp_path):
+        """The gate must accept a history's own best round (no
+        self-regression) and reject a gutted line; with no prior round
+        on record it says so and passes."""
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         script = os.path.join(root, "scripts", "bench_gate.py")
+        self._priors(tmp_path)
+        hist = ["--repo-root", str(tmp_path)]
+        (tmp_path / "fresh.json").write_text(json.dumps({
+            "metric": "m", "value": 100.0, "mfu": 0.6,
+            "overlap_hidden_fraction": 0.9}))
         r = subprocess.run(
-            [sys.executable, script, os.path.join(root, "BENCH_r03.json")],
-            capture_output=True, text=True)
+            [sys.executable, script, str(tmp_path / "fresh.json"),
+             *hist], capture_output=True, text=True)
         assert r.returncode == 0, r.stderr
+        gutted = json.dumps({"metric": "m", "value": 1.0, "mfu": 0.01})
         r = subprocess.run(
-            [sys.executable, script, "-"],
-            input=json.dumps({"metric": "m", "value": 1.0, "mfu": 0.01}),
+            [sys.executable, script, "-", *hist], input=gutted,
             capture_output=True, text=True)
         assert r.returncode == 1
         assert "REGRESSION" in r.stderr
+        empty = tmp_path / "empty"
+        empty.mkdir()
+        r = subprocess.run(
+            [sys.executable, script, "-", "--repo-root", str(empty)],
+            input=gutted, capture_output=True, text=True)
+        assert r.returncode == 0, r.stderr
+        assert "no prior" in r.stdout
 
     def test_unparseable_fails(self, tmp_path):
         bg = _bench_gate()

@@ -154,10 +154,21 @@ def test_shapes_supported_contract():
 
 
 def test_fit_q_block_halving():
-    assert _fit_q_block(256) == 128
-    assert _fit_q_block(12) == 12
-    assert _fit_q_block(6) == 6
-    assert _fit_q_block(192) == 64  # 128 does not divide -> halve
+    assert _fit_q_block(256, 4, 64) == 128
+    assert _fit_q_block(12, 4, 64) == 12
+    assert _fit_q_block(6, 4, 64) == 6
+    assert _fit_q_block(192, 4, 64) == 64  # 128 does not divide -> halve
+
+
+def test_fit_q_block_respects_the_vmem_budget():
+    """32 heads of 128 at CH=128 (the llama3_8b geometry) blew v5e's
+    16 MiB scoped VMEM at bq=128 (AOT compile, tests/
+    test_tpu_aot_compile.py); the budget halves the tile instead of
+    promising what Mosaic refuses."""
+    assert _fit_q_block(128, 32, 128) == 64
+    assert _fit_q_block(128, 16, 128) == 128
+    assert paged_prefill_shapes_supported((1, 128, 32, 128),
+                                          (65, 16, 8, 128))
 
 
 def test_uses_pallas_respects_dispatch_context():

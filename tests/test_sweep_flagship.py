@@ -40,6 +40,12 @@ def test_run_one_records_success_and_flags(results_path, monkeypatch):
             remat_policy=remat_policy, scan_layers=scan)
 
     monkeypatch.setattr(bench, "_bench_cfg", tiny_cfg)
+    # the CPU has no spec-sheet peak and none is assumed: name the kind
+    # whose peak this smoke prices its (meaningless) MFU against
+    from ray_lightning_tpu.utils.probe import device_peak_tflops
+
+    monkeypatch.setattr(bench, "_device_peak_tflops",
+                        lambda kind: device_peak_tflops("TPU v5 lite"))
     rec = run_one("smoke-tiny", batch=2, policy="attn_out", chunk=16,
                   vocab=64, seq=32, inline=True, mu_bf16=True)
     assert rec["tokens_per_sec"] > 0
@@ -53,12 +59,12 @@ def test_run_one_records_failure_as_data(results_path, monkeypatch):
     import bench
 
     def boom(**kw):
-        raise RuntimeError("remote_compile HTTP 500")
+        raise RuntimeError("RESOURCE_EXHAUSTED: hbm")
 
     monkeypatch.setattr(bench, "_make_step", boom)
     rec = run_one("smoke-fail", batch=2, policy="nothing", chunk=16,
                   vocab=64, seq=32)
-    assert "HTTP 500" in rec["error"]
+    assert "RESOURCE_EXHAUSTED" in rec["error"]
     assert "tokens_per_sec" not in rec
     # a failed point must not become the incumbent
     assert best_so_far() is None

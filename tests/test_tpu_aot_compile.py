@@ -1,0 +1,258 @@
+"""Compile for the v5e WITHOUT a chip — what builders run before spending
+chip time.
+
+libtpu can describe a TPU topology with no TPU attached
+(`jax.experimental.topologies.get_topology_desc`), and lowering a jitted
+function for ShapeDtypeStructs sharded on those devices runs the real
+Mosaic and XLA:TPU compilers. So everything a predicate accepts can be
+shown to compile for "TPU v5 lite" from the CPU sandbox: the kernels over
+a grid of shapes, the 0.5B train step on one device and under two
+four-chip meshes, and the serving step. These are compile results only;
+they say nothing about what the chip computes or how fast.
+
+`dispatch.on_tpu` is patched to True for the duration of a test: the
+kernels then lower through Mosaic (interpret mode off) and dispatch takes
+the pallas lanes, exactly as on the chip.
+
+Recipe (also in .claude/skills/verify/SKILL.md):
+    python -m pytest tests/test_tpu_aot_compile.py -m slow -q
+"""
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+pytestmark = pytest.mark.slow
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four `TPU v5 lite` devices of a v5e 2x2, described by libtpu."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, no test
+        pytest.skip(f"libtpu cannot build the v5e:2x2 topology: {exc}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return list(topo.devices)
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    from ray_lightning_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _n_mosaic(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+# ---- kernels: every shape a predicate accepts compiles ---------------------
+
+HEADS = [(16, 16), (16, 8), (32, 8)]      # MHA (OLMoE), GQA 2:1, GQA 4:1
+HEAD_DIMS = [64, 128]
+BLOCKS = [8, 16, 32]
+SPAN = 1024                               # tokens per slot, held constant
+
+
+@pytest.mark.parametrize("heads,hd,p",
+                         itertools.product(HEADS, HEAD_DIMS, BLOCKS))
+def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p):
+    from ray_lightning_tpu.ops.pallas.paged_attention import (
+        paged_attention_pallas, paged_shapes_supported,
+    )
+
+    h, hkv = heads
+    c, m = 8, SPAN // p
+    nb = 1 + c * m
+    if not paged_shapes_supported((c, h, hd), (nb, p, hkv, hd)):
+        pytest.skip("refused by the predicate: dispatch takes the "
+                    "reference lane")
+    s = SingleDeviceSharding(v5e[0])
+    bf, i32 = jnp.bfloat16, jnp.int32
+    compiled = jax.jit(paged_attention_pallas).lower(
+        _sds((c, h, hd), bf, s), _sds((nb, p, hkv, hd), bf, s),
+        _sds((nb, p, hkv, hd), bf, s), _sds((c, m), i32, s),
+        _sds((c,), i32, s), _sds((c,), i32, s)).compile()
+    assert _n_mosaic(compiled) == 1
+
+
+@pytest.mark.parametrize(
+    "heads,hd,p,ch",
+    itertools.product(HEADS, HEAD_DIMS, BLOCKS, [64, 128]))
+def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch):
+    from ray_lightning_tpu.ops.pallas.paged_prefill import (
+        paged_prefill_pallas, paged_prefill_shapes_supported,
+    )
+
+    h, hkv = heads
+    b, m = 1, SPAN // p
+    nb = 1 + 8 * m
+    if not paged_prefill_shapes_supported((b, ch, h, hd),
+                                          (nb, p, hkv, hd)):
+        pytest.skip("refused by the predicate: dispatch takes the "
+                    "reference lane")
+    s = SingleDeviceSharding(v5e[0])
+    bf, i32 = jnp.bfloat16, jnp.int32
+    compiled = jax.jit(paged_prefill_pallas).lower(
+        _sds((b, ch, h, hd), bf, s), _sds((nb, p, hkv, hd), bf, s),
+        _sds((nb, p, hkv, hd), bf, s), _sds((b, m), i32, s),
+        _sds((), i32, s), _sds((b,), i32, s)).compile()
+    assert _n_mosaic(compiled) == 1
+
+
+@pytest.mark.parametrize("seq", [1024, 2048, 4096])
+def test_flash_fwd_bwd_compiles(v5e, as_on_tpu, seq):
+    from ray_lightning_tpu.ops.attention import flash_attention
+
+    s = SingleDeviceSharding(v5e[0])
+    q = _sds((2, seq, 16, 128), jnp.bfloat16, s)
+    kv = _sds((2, seq, 8, 128), jnp.bfloat16, s)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert _n_mosaic(compiled) == 3      # fwd, dkv, dq
+
+
+# ---- the 0.5B train step, through the Trainer's own step builder -----------
+
+def _train_step_compiled(strategy, batch=8, seq=2048):
+    """AOT-compile `Trainer._make_train_step` for the chip_smoke model
+    with every array abstract, sharded as the strategy shards it."""
+    import chip_smoke
+    from ray_lightning_tpu import Trainer
+    from ray_lightning_tpu.core.state import TrainState
+    from ray_lightning_tpu.models.llama import LlamaConfig, LlamaModule
+
+    module = LlamaModule(LlamaConfig(**chip_smoke.SmokeSize.full().model))
+    trainer = Trainer(strategy=strategy, enable_checkpointing=False,
+                      enable_progress_bar=False)
+    strategy.setup(module)
+    module.setup()
+    trainer.tx = trainer._build_tx(module)
+
+    def abstract(tree, shardings):
+        return jax.tree.map(
+            lambda x, sh: _sds(x.shape, x.dtype, sh), tree, shardings)
+
+    a_batch = {"tokens": _sds((batch, seq + 1), jnp.int32,
+                              strategy.batch_sharding())}
+    a_key = jax.eval_shape(lambda: jax.random.key(0))
+    a_params = jax.eval_shape(module.init_params, a_key, a_batch)
+    a_params = abstract(a_params, strategy.param_shardings(a_params))
+    a_opt = jax.eval_shape(trainer.tx.init, a_params)
+    a_opt = abstract(a_opt, strategy.opt_state_shardings(a_opt, a_params))
+    trainer.state = TrainState(
+        step=_sds((), jnp.int32, strategy.replicated()),
+        params=a_params, opt_state=a_opt)
+    step = trainer._make_train_step(module)
+    a_key = _sds(a_key.shape, a_key.dtype, strategy.replicated())
+    return step._jitted.lower(trainer.state, a_batch, a_key).compile()
+
+
+def _strategies(v5e):
+    from ray_lightning_tpu import FSDP, ShardedMesh, SingleDevice
+
+    return {
+        "one-device": lambda: SingleDevice(devices=v5e),
+        "fsdp4": lambda: FSDP(num_workers=4, devices=v5e),
+        "fsdp2xtensor2": lambda: ShardedMesh(
+            fsdp=2, tensor=2, num_workers=4, devices=v5e),
+    }
+
+
+@pytest.mark.parametrize("plan", ["one-device", "fsdp4", "fsdp2xtensor2"])
+def test_train_step_compiles(v5e, as_on_tpu, plan):
+    compiled = _train_step_compiled(_strategies(v5e)[plan]())
+    # flash fwd + its two backward kernels, inside the scanned layer
+    assert _n_mosaic(compiled) >= 3
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16 * 1024**3, (
+        f"{plan}: {total / 1024**3:.2f} GiB does not fit a v5e chip")
+
+
+# ---- the serving step -------------------------------------------------------
+
+def _serve_step_lowered(v5e, tp: int):
+    """Lower `build_step` as `DecodeEngine` jits it, params and pool
+    abstract. tp == 1: one device, both fused lanes. tp > 1: a tensor
+    mesh, where the engine takes the reference lanes."""
+    import chip_smoke
+    from ray_lightning_tpu.models.llama import Llama, LlamaConfig
+    from ray_lightning_tpu.ops.attention import (
+        paged_attention_uses_pallas, paged_prefill_uses_pallas,
+    )
+    from ray_lightning_tpu.parallel.mesh import make_mesh
+    from ray_lightning_tpu.serve.engine import (
+        EngineConfig, build_step, idle_prefill, serving_param_shardings,
+    )
+    from ray_lightning_tpu.serve.kv_cache import pool_partition_spec
+
+    size = chip_smoke.SmokeSize.full()
+    cfg = LlamaConfig(**size.model)
+    ecfg = EngineConfig(**size.engine)
+    model = Llama(cfg)
+    spec = ecfg.pool_spec
+    pool_shape = (cfg.n_layers, spec.n_blocks, spec.block_size,
+                  cfg.n_kv_heads, cfg.head_dim)
+    a_params = jax.eval_shape(
+        model.init, jax.random.key(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]
+    if tp == 1:
+        repl = pool_sh = SingleDeviceSharding(v5e[0])
+        param_sh = jax.tree.map(lambda _: repl, a_params)
+        fused = paged_attention_uses_pallas(
+            (ecfg.capacity, cfg.n_heads, cfg.head_dim), pool_shape[1:])
+        fused_prefill = paged_prefill_uses_pallas(
+            (1, ecfg.prefill_chunk, cfg.n_heads, cfg.head_dim),
+            pool_shape[1:])
+        assert fused and fused_prefill
+    else:
+        mesh = make_mesh(tensor=tp, devices=v5e[:tp])
+        repl = NamedSharding(mesh, P())
+        pool_sh = NamedSharding(mesh, pool_partition_spec(tp))
+        param_sh = serving_param_shardings(model, a_params, mesh)
+        fused = fused_prefill = False    # DecodeEngine's choice on a mesh
+    a_params = jax.tree.map(
+        lambda x, sh: _sds(x.shape, x.dtype, sh), a_params, param_sh)
+    C = ecfg.capacity
+    runtime = (
+        np.zeros((C, spec.blocks_per_slot), np.int32), np.zeros(C, np.int32),
+        np.zeros(C, bool), np.zeros(C, np.float32), np.zeros(C, np.int32),
+        np.zeros((C, 2), np.uint32), *idle_prefill(ecfg))
+    step = jax.jit(
+        build_step(model, ecfg, fused=fused, fused_prefill=fused_prefill),
+        donate_argnums=(1, 2, 3))
+    return step.lower(
+        a_params, _sds(pool_shape, cfg.dtype, pool_sh),
+        _sds(pool_shape, cfg.dtype, pool_sh),
+        _sds((C, cfg.vocab_size), jnp.float32, repl),
+        *[_sds(np.shape(x), np.asarray(x).dtype, repl) for x in runtime])
+
+
+def test_serving_step_compiles_on_one_chip(v5e, as_on_tpu):
+    compiled = _serve_step_lowered(v5e, tp=1).compile()
+    # one decode + one prefill kernel per scanned layer body
+    assert _n_mosaic(compiled) >= 2
+
+
+def test_serving_step_lowers_under_tensor_parallel(v5e, as_on_tpu):
+    """A sharded replica takes the reference lanes (XLA cannot partition
+    a Mosaic call): the step must lower with no Mosaic kernel in it."""
+    text = _serve_step_lowered(v5e, tp=2).as_text()
+    assert "tpu_custom_call" not in text

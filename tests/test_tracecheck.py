@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_lightning_tpu.analysis.costmodel import (
@@ -18,7 +19,6 @@ from ray_lightning_tpu.analysis.tracecheck import (
 )
 from ray_lightning_tpu.core.module import TpuModule
 from ray_lightning_tpu.models.mlp import MLPClassifier
-from ray_lightning_tpu.ops.dispatch import shard_map
 from ray_lightning_tpu.ops.pipeline import pipeline_perm
 from ray_lightning_tpu.ops.ring_attention import ring_perm
 from ray_lightning_tpu.parallel.strategy import DataParallel, ShardedMesh
@@ -42,10 +42,16 @@ def test_parse_topology_rejects_unknown_family():
         parse_topology("not a topology")
 
 
-def test_topology_for_kind_unknown_falls_back():
-    t = topology_for_kind("FPGA mystery", 4, hbm_bytes=2 * 1024**3)
+def test_topology_for_kind_unknown_has_no_assumed_peak():
+    """An unknown kind keeps the HBM override and the cpu family's ICI
+    figures, but its compute peak is an error, never a v5e-class guess;
+    the "cpu" pseudo-family states its own pseudo-figure."""
+    with pytest.raises(ValueError, match="no bf16 peak on record"):
+        topology_for_kind("FPGA mystery", 4, hbm_bytes=2 * 1024**3)
+    t = topology_for_kind("cpu", 4, hbm_bytes=2 * 1024**3)
     assert t.n_devices == 4
     assert t.hbm_bytes == 2 * 1024**3  # override honored
+    assert t.peak_tflops == 1.0
 
 
 def test_collective_cost_ring_algebra():
@@ -227,7 +233,7 @@ class _RingModule(TpuModule):
             return jax.lax.psum(x * y, "seq")
 
         f = shard_map(local, mesh=self.mesh, in_specs=P(None, "seq"),
-                      out_specs=P(None, "seq"), check_replication=False)
+                      out_specs=P(None, "seq"), check_vma=False)
         return (f(x) ** 2).mean()
 
 

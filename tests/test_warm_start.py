@@ -1,6 +1,6 @@
 """AOT warm start + persistent compilation cache
 (pipeline/compile_cache.py): compile-time metrics, cache hits across
-trainers, shape-drift fallback, and plan cache-key stability."""
+trainers and shape-drift fallback."""
 import os
 
 import jax
@@ -10,8 +10,7 @@ import pytest
 from ray_lightning_tpu import DataLoader, SingleDevice, Trainer
 from ray_lightning_tpu.pipeline.compile_cache import (
     WarmStep,
-    plan_cache_dir,
-    plan_cache_key,
+    enable_persistent_cache,
 )
 
 from tests.utils import BoringModel, random_dataset
@@ -77,11 +76,15 @@ class TestWarmStep:
         assert trainer.global_step == 4
         assert not trainer._train_step.aot_active  # drift disabled AOT
 
-    def test_second_trainer_hits_persistent_cache(self, tmp_path):
+    def test_second_trainer_hits_persistent_cache(self, tmp_path,
+                                                  monkeypatch):
         """Two trainers compiling the identical program against one
         persistent cache dir: the second must ADD no cache entries (its
         lowered program hashes to the first's key — a disk hit, which is
-        what makes supervisor restart N recompile nothing)."""
+        what makes supervisor restart N recompile nothing). An explicit
+        ``compile_cache_dir=`` is honoured only while
+        JAX_COMPILATION_CACHE_DIR is unset."""
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         cache = tmp_path / "cache"
         data = random_dataset(n=128)
         t1, _ = _fit(tmp_path / "a", cache, data=data)
@@ -92,27 +95,9 @@ class TestWarmStep:
         # both report the metric; the second's XLA share is a disk hit
         assert t1.callback_metrics["compile_time_s"] > 0
         assert t2.callback_metrics["compile_time_s"] > 0
-
-
-class TestPlanCacheKey:
-    def test_stable_and_distinct(self):
-        assert plan_cache_key("a", 1) == plan_cache_key("a", 1)
-        assert plan_cache_key("a", 1) != plan_cache_key("a", 2)
-        d = plan_cache_dir("/tmp/base", "a", 1)
-        assert d.startswith(os.path.abspath("/tmp/base") + os.sep)
-
-    def test_strategy_compile_cache_key(self):
-        from ray_lightning_tpu.parallel.strategy import DataParallel
-
-        s1 = DataParallel(num_workers=4)
-        s1.setup()
-        key = s1.compile_cache_key()
-        s2 = DataParallel(num_workers=4)
-        s2.setup()
-        assert s2.compile_cache_key() == key
-        s3 = DataParallel(num_workers=2)
-        s3.setup()
-        assert s3.compile_cache_key() != key
+        # hand the process-global cache back to the suite's directory
+        monkeypatch.undo()
+        enable_persistent_cache()
 
 
 class TestWarmStepUnit:
@@ -141,7 +126,7 @@ class TestWarmStepUnit:
 @pytest.mark.slow  # spawns a subprocess to prove the cross-process hit
 def test_cross_process_cache_reuse(tmp_path):
     """The supervisor's restart story: a FRESH process pointed at the
-    same per-plan cache dir must not add entries either."""
+    same cache dir must not add entries either."""
     import subprocess
     import sys
 
@@ -161,6 +146,7 @@ t.fit(BoringModel(), DataLoader(data, batch_size=32))
 print("COMPILE_S", t.callback_metrics["compile_time_s"])
 """
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # honour the explicit dir
     out1 = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=300)
     assert out1.returncode == 0, out1.stderr[-2000:]
