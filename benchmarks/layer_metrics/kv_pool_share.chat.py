@@ -1,0 +1,11 @@
+"""Per-layer metric `kv_pool_share.chat`: self time of the ops under the scope `kv_pool` (a layer's K/V taken out of the stacked pool and written back, all but the attention kernels) over the step program's device time."""
+from benchmarks.harness import program_trace
+
+LAYER = "serving step"
+UNIT = "%"
+MOVES = "itl_p95_ms"
+SOURCE = "device_trace"
+
+
+def reduce(run):
+    return program_trace.scope_share_pct(run, "kv_pool")

@@ -216,7 +216,12 @@ def _pallas_kernel_ident(eqn) -> str:
     ("_decode_kernel at .../paged_attention.py:76" style) — the SINGLE
     extraction both the step auditor and the serve audit's recursive
     scanner use, so the fingerprint can never drift between them."""
+    # jax 0.9 keeps the kernel's name and source line on the kernel
+    # jaxpr's debug info ("rlt_paged_decode at .../paged_attention.py:76");
+    # older releases carried the same string as `name_and_src_info`
+    debug = getattr(eqn.params.get("jaxpr"), "debug_info", None)
     ident = (eqn.params.get("name_and_src_info")
+             or getattr(debug, "func_src_info", None)
              or eqn.params.get("name") or "pallas")
     return str(ident)
 

@@ -22,7 +22,6 @@ exercises (reference tests/utils.py:26-93).
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import time
 from typing import Any, Dict, Iterable, List, Optional
@@ -96,7 +95,6 @@ class Trainer:
         default_root_dir: Optional[str] = None,
         enable_checkpointing: bool = True,
         enable_progress_bar: bool = True,
-        profiler_dir: Optional[str] = None,
         num_sanity_val_steps: int = 0,
         prefetch_to_device: int = 2,
         warm_start: bool = True,
@@ -123,7 +121,6 @@ class Trainer:
         self.default_root_dir = default_root_dir or os.path.join(
             os.getcwd(), "rlt_logs"
         )
-        self.profiler_dir = profiler_dir
         self.num_sanity_val_steps = num_sanity_val_steps
         #: device-prefetch buffer depth (pipeline/prefetch.py): a
         #: background stage overlaps host batch assembly + sharded
@@ -301,34 +298,32 @@ class Trainer:
         return dict(self.callback_metrics)
 
     def _fit_loop(self, train_loader, val_loader) -> None:
-        profile_ctx = self._maybe_profile()
-        with profile_ctx:
-            for epoch in range(self.current_epoch, self.max_epochs):
-                self.current_epoch = epoch
-                if hasattr(train_loader, "set_epoch"):
-                    train_loader.set_epoch(epoch)
-                self.module.on_train_epoch_start(self)
-                self._invoke("on_train_epoch_start")
-                self._run_train_epoch(train_loader, val_loader)
-                run_val = (
-                    self.has_validation
-                    and (epoch + 1) % self.check_val_every_n_epoch == 0
-                    # mid-epoch interval may have just validated this
-                    # exact step — don't run twice on identical weights
-                    and self.global_step != self._last_val_step
+        for epoch in range(self.current_epoch, self.max_epochs):
+            self.current_epoch = epoch
+            if hasattr(train_loader, "set_epoch"):
+                train_loader.set_epoch(epoch)
+            self.module.on_train_epoch_start(self)
+            self._invoke("on_train_epoch_start")
+            self._run_train_epoch(train_loader, val_loader)
+            run_val = (
+                self.has_validation
+                and (epoch + 1) % self.check_val_every_n_epoch == 0
+                # mid-epoch interval may have just validated this
+                # exact step — don't run twice on identical weights
+                and self.global_step != self._last_val_step
+            )
+            if run_val:
+                metrics = self._run_eval_epoch(
+                    val_loader, limit=self.limit_val_batches
                 )
-                if run_val:
-                    metrics = self._run_eval_epoch(
-                        val_loader, limit=self.limit_val_batches
-                    )
-                    self._last_val_step = self.global_step
-                    self.callback_metrics.update(metrics)
-                    self.module.on_validation_epoch_end(self, metrics)
-                    self._invoke("on_validation_epoch_end", metrics)
-                self.module.on_train_epoch_end(self)
-                self._invoke("on_train_epoch_end")
-                if self.should_stop or self._hit_max_steps():
-                    break
+                self._last_val_step = self.global_step
+                self.callback_metrics.update(metrics)
+                self.module.on_validation_epoch_end(self, metrics)
+                self._invoke("on_validation_epoch_end", metrics)
+            self.module.on_train_epoch_end(self)
+            self._invoke("on_train_epoch_end")
+            if self.should_stop or self._hit_max_steps():
+                break
 
     def _run_train_epoch(self, loader, val_loader=None) -> None:
         pending: Dict[str, Any] = {}
@@ -819,8 +814,10 @@ class Trainer:
                 grads = jax.tree.map(lambda g: g / accum, grads)
                 loss = losses.mean()
                 metrics = jax.tree.map(lambda m: m.mean(axis=0), metricses)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, state.opt_state,
+                                               state.params)
+                params = optax.apply_updates(state.params, updates)
             grad_norm = optax.global_norm(grads)
             metrics = {"loss": loss, "grad_norm": grad_norm, **metrics}
             if guard_cfg is not None:
@@ -977,12 +974,6 @@ class Trainer:
                 batch = out
         return batch
 
-    def _maybe_profile(self):
-        if not self.profiler_dir:
-            return contextlib.nullcontext()
-        os.makedirs(self.profiler_dir, exist_ok=True)
-        return _ProfilerCtx(self.profiler_dir)
-
     # ------------------------------------------------------------ telemetry
 
     def _setup_telemetry(self) -> None:
@@ -1055,22 +1046,6 @@ def _launch_seconds() -> float:
     except Exception:  # noqa: BLE001 — accounting must never fail a fit
         pass
     return 0.0
-
-
-class _ProfilerCtx:
-    """jax.profiler trace over the fit loop (SURVEY §5.1: absent in the
-    reference; table stakes on TPU — produces XPlane traces per host)."""
-
-    def __init__(self, logdir: str):
-        self.logdir = logdir
-
-    def __enter__(self):
-        jax.profiler.start_trace(self.logdir)
-        return self
-
-    def __exit__(self, *exc):
-        jax.profiler.stop_trace()
-        return False
 
 
 def _gather_out(tree) -> Any:

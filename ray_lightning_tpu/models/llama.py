@@ -18,6 +18,7 @@ tests/utils.py:96-120) — this is net-new capability designed for the MXU:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from functools import partial
@@ -263,170 +264,177 @@ class LlamaBlock(nn.Module):
                         param_dtype=jnp.float32,
                         dot_general=_f32_acc_dot_general)
 
-        attn_norm_w = self.param("attn_norm", nn.initializers.ones, (d,))
-        h = rms_norm(x, attn_norm_w, cfg.norm_eps)
-        # fused QKV projection: one [D, (H + 2*Hkv) * hd] matmul
-        n_q, n_kv = cfg.n_heads, cfg.n_kv_heads
-        qkv = dense((n_q + 2 * n_kv) * hd, name="wqkv")(h)
-        q, k, v = jnp.split(
-            qkv, [n_q * hd, (n_q + n_kv) * hd], axis=-1)
-        B, S = x.shape[0], x.shape[1]
-        q = q.reshape(B, S, n_q, hd)
-        k = k.reshape(B, S, n_kv, hd)
-        v = v.reshape(B, S, n_kv, hd)
-        if cache is None:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            pallas_path = False
-            if (cfg.seq_parallel and self.mesh is not None
-                    and self.mesh.shape.get("seq", 1) > 1):
-                # manual island: sequence sharded over `seq`; everything
-                # else stays compiler-sharded.
-                if cfg.seq_parallel_mode == "ulysses":
-                    attn = ulysses_attention(
+        # `attn` / `mlp`: the block's two halves in a profiler trace (flax
+        # scopes only the Dense calls; norms, RoPE, the kernel and the
+        # gate fall outside them)
+        with jax.named_scope("attn"):
+            attn_norm_w = self.param("attn_norm", nn.initializers.ones, (d,))
+            h = rms_norm(x, attn_norm_w, cfg.norm_eps)
+            # fused QKV projection: one [D, (H + 2*Hkv) * hd] matmul
+            n_q, n_kv = cfg.n_heads, cfg.n_kv_heads
+            qkv = dense((n_q + 2 * n_kv) * hd, name="wqkv")(h)
+            q, k, v = jnp.split(
+                qkv, [n_q * hd, (n_q + n_kv) * hd], axis=-1)
+            B, S = x.shape[0], x.shape[1]
+            q = q.reshape(B, S, n_q, hd)
+            k = k.reshape(B, S, n_kv, hd)
+            v = v.reshape(B, S, n_kv, hd)
+            if cache is None:
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+                pallas_path = False
+                if (cfg.seq_parallel and self.mesh is not None
+                        and self.mesh.shape.get("seq", 1) > 1):
+                    # manual island: sequence sharded over `seq`; everything
+                    # else stays compiler-sharded.
+                    if cfg.seq_parallel_mode == "ulysses":
+                        attn = ulysses_attention(
+                            q, k, v, self.mesh, causal=True,
+                            use_pallas=None if cfg.use_flash else False)
+                    else:
+                        attn = ring_attention(q, k, v, self.mesh, causal=True)
+                else:
+                    # use_flash=True -> auto (pallas on TPU, XLA fallback
+                    # elsewhere); False -> always the XLA reference path.
+                    # On a multi-device mesh the kernel runs in a manual
+                    # region (XLA cannot partition a Mosaic call).
+                    from ray_lightning_tpu.ops.attention import flash_uses_pallas
+
+                    pallas_path = flash_uses_pallas(
+                        q.shape, k.shape, None if cfg.use_flash else False)
+                    attn = flash_attention_on_mesh(
+                        q, k, v, self.mesh, causal=True,
+                        use_pallas=None if cfg.use_flash else False)
+                # name the attention output for remat_policy="attn_out" —
+                # the save point the XLA-reference (and seq-parallel island)
+                # paths offer. The pallas branch is deliberately NOT named:
+                # its full VJP residual set (incl. o) is already saved
+                # through the kernel's own remat_opt hoist, and naming the
+                # output again would keep a second [B, S, H·hd] residual per
+                # layer beyond what parallel/plan.py accounts. Under other
+                # policies the name is inert. flash_uses_pallas is the SAME
+                # predicate the dispatch uses, so the annotation cannot
+                # drift from the path actually taken.
+                if not pallas_path:
+                    from jax.ad_checkpoint import checkpoint_name
+
+                    attn = checkpoint_name(attn, "attn_out")
+                new_cache = None
+            elif paged is not None and _is_prefill_view(paged):
+                # paged PREFILL (serve/engine.py fused prefill lane): a
+                # CH-token chunk per head-group row against the SHARED
+                # block pool — the per-group dense cache copy never exists
+                # on this path. ``pos`` is the group's shared scalar write
+                # offset (chunk token j sits at cache position pos + j);
+                # ``pad`` is the per-row left pad of the right-aligned
+                # group (None on the single-slot lane).
+                positions = jnp.broadcast_to(
+                    (pos + jnp.arange(S))[None, :], (B, S))
+                if pad is not None:
+                    positions = jnp.maximum(positions - pad[:, None], 0)
+                q = apply_rope(q, cos, sin, positions=positions)
+                k = apply_rope(k, cos, sin, positions=positions)
+                pk, pv = cache  # [n_blocks, P, Hkv, hd] — one layer's pool
+                # write-then-attend, the decode fused lane's ordering: the
+                # whole chunk's K/V is scattered into OWNED pool blocks
+                # (vacant group rows arrive scratch-redirected — block 0 is
+                # masked garbage by contract) BEFORE attention, so each
+                # query's causal window covers the in-chunk prefix too.
+                with jax.named_scope("kv_pool"):
+                    pk = pk.at[paged.write_block, paged.write_offset].set(
+                        k.astype(pk.dtype))
+                    pv = pv.at[paged.write_block, paged.write_offset].set(
+                        v.astype(pv.dtype))
+                from ray_lightning_tpu.ops.attention import paged_prefill
+
+                # the view's STATIC use_pallas (the serve engine's
+                # build-time decision) pins the dispatch; absent that,
+                # fall back to the flash-style ambient policy
+                up = (paged.use_pallas if paged.use_pallas is not None
+                      else (None if cfg.use_flash else False))
+                attn = paged_prefill(q, pk, pv, paged.tables, pos, pad=pad,
+                                     use_pallas=up)
+                new_cache = (pk, pv)
+            elif paged is not None:
+                # paged decode (serve/engine.py fused lane): one token per
+                # slot against the SHARED block pool — no per-slot dense
+                # cache copy exists on the kernel path. ``pos`` is a [B]
+                # vector (per-slot cache position); its RoPE position is
+                # pos - pad for a left-pad-prefilled slot.
+                assert S == 1, "the paged cache path decodes one token/slot"
+                positions = pos[:, None] + jnp.arange(S)[None, :]
+                if pad is not None:
+                    positions = jnp.maximum(positions - pad[:, None], 0)
+                q = apply_rope(q, cos, sin, positions=positions)
+                k = apply_rope(k, cos, sin, positions=positions)
+                pk, pv = cache  # [n_blocks, P, Hkv, hd] — one layer's pool
+                # write-then-attend, exactly the dense cache path's
+                # dynamic_update_slice ordering: the token's own K/V is
+                # visible to its query. Idle/prefilling slots arrive
+                # scratch-redirected (write_block 0) — duplicate scratch
+                # writes race, but scratch is masked garbage by contract.
+                with jax.named_scope("kv_pool"):
+                    pk = pk.at[paged.write_block, paged.write_offset].set(
+                        k[:, 0].astype(pk.dtype))
+                    pv = pv.at[paged.write_block, paged.write_offset].set(
+                        v[:, 0].astype(pv.dtype))
+                from ray_lightning_tpu.ops.attention import paged_attention
+
+                # the view's STATIC use_pallas (the serve engine's
+                # build-time decision) pins the dispatch; absent that,
+                # fall back to the flash-style ambient policy
+                up = (paged.use_pallas if paged.use_pallas is not None
+                      else (None if cfg.use_flash else False))
+                attn = paged_attention(
+                    q[:, 0], pk, pv, paged.tables, paged.lengths, pad=pad,
+                    use_pallas=up)[:, None]
+                new_cache = (pk, pv)
+            else:
+                positions = pos + jnp.arange(S)
+                if pad is not None:
+                    # left-padded ragged batch: row b's first real token
+                    # sits at column pad[b] but is RoPE position 0; clamp
+                    # keeps the (discarded) pad rows' table reads in range
+                    positions = jnp.maximum(
+                        positions[None, :] - pad[:, None], 0)
+                q = apply_rope(q, cos, sin, positions=positions)
+                k = apply_rope(k, cos, sin, positions=positions)
+                ck, cv = cache  # [B, S_max, Hkv, hd]
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    ck, k.astype(ck.dtype), pos, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    cv, v.astype(cv.dtype), pos, axis=1)
+                if (S > 1 and isinstance(pos, int) and pos == 0
+                        and pad is None):
+                    # prefill from empty context: plain causal attention over
+                    # the chunk itself (flash path — never materialize the
+                    # [S, S_max] masked score matrix against the zero tail).
+                    attn = flash_attention_on_mesh(
                         q, k, v, self.mesh, causal=True,
                         use_pallas=None if cfg.use_flash else False)
                 else:
-                    attn = ring_attention(q, k, v, self.mesh, causal=True)
-            else:
-                # use_flash=True -> auto (pallas on TPU, XLA fallback
-                # elsewhere); False -> always the XLA reference path.
-                # On a multi-device mesh the kernel runs in a manual
-                # region (XLA cannot partition a Mosaic call).
-                from ray_lightning_tpu.ops.attention import flash_uses_pallas
+                    # single-token decode (or mid-sequence chunk, or a
+                    # left-padded prefill): masked reference SDPA over the
+                    # cache — S is tiny here.
+                    kv_pos = jnp.arange(ck.shape[1])[None, None, None, :]
+                    q_pos = (pos + jnp.arange(S))[None, None, :, None]
+                    mask = kv_pos <= q_pos
+                    if pad is not None:
+                        # pad columns are not context for anyone
+                        mask = mask & (kv_pos >= pad[:, None, None, None])
+                    attn = dot_product_attention(
+                        q, ck, cv, causal=False, mask=mask)
+                new_cache = (ck, cv)
+            attn = attn.reshape(B, S, n_q * hd)
+            x = x + dense(d, name="wo")(attn)
 
-                pallas_path = flash_uses_pallas(
-                    q.shape, k.shape, None if cfg.use_flash else False)
-                attn = flash_attention_on_mesh(
-                    q, k, v, self.mesh, causal=True,
-                    use_pallas=None if cfg.use_flash else False)
-            # name the attention output for remat_policy="attn_out" —
-            # the save point the XLA-reference (and seq-parallel island)
-            # paths offer. The pallas branch is deliberately NOT named:
-            # its full VJP residual set (incl. o) is already saved
-            # through the kernel's own remat_opt hoist, and naming the
-            # output again would keep a second [B, S, H·hd] residual per
-            # layer beyond what parallel/plan.py accounts. Under other
-            # policies the name is inert. flash_uses_pallas is the SAME
-            # predicate the dispatch uses, so the annotation cannot
-            # drift from the path actually taken.
-            if not pallas_path:
-                from jax.ad_checkpoint import checkpoint_name
-
-                attn = checkpoint_name(attn, "attn_out")
-            new_cache = None
-        elif paged is not None and _is_prefill_view(paged):
-            # paged PREFILL (serve/engine.py fused prefill lane): a
-            # CH-token chunk per head-group row against the SHARED
-            # block pool — the per-group dense cache copy never exists
-            # on this path. ``pos`` is the group's shared scalar write
-            # offset (chunk token j sits at cache position pos + j);
-            # ``pad`` is the per-row left pad of the right-aligned
-            # group (None on the single-slot lane).
-            positions = jnp.broadcast_to(
-                (pos + jnp.arange(S))[None, :], (B, S))
-            if pad is not None:
-                positions = jnp.maximum(positions - pad[:, None], 0)
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
-            pk, pv = cache  # [n_blocks, P, Hkv, hd] — one layer's pool
-            # write-then-attend, the decode fused lane's ordering: the
-            # whole chunk's K/V is scattered into OWNED pool blocks
-            # (vacant group rows arrive scratch-redirected — block 0 is
-            # masked garbage by contract) BEFORE attention, so each
-            # query's causal window covers the in-chunk prefix too.
-            pk = pk.at[paged.write_block, paged.write_offset].set(
-                k.astype(pk.dtype))
-            pv = pv.at[paged.write_block, paged.write_offset].set(
-                v.astype(pv.dtype))
-            from ray_lightning_tpu.ops.attention import paged_prefill
-
-            # the view's STATIC use_pallas (the serve engine's
-            # build-time decision) pins the dispatch; absent that,
-            # fall back to the flash-style ambient policy
-            up = (paged.use_pallas if paged.use_pallas is not None
-                  else (None if cfg.use_flash else False))
-            attn = paged_prefill(q, pk, pv, paged.tables, pos, pad=pad,
-                                 use_pallas=up)
-            new_cache = (pk, pv)
-        elif paged is not None:
-            # paged decode (serve/engine.py fused lane): one token per
-            # slot against the SHARED block pool — no per-slot dense
-            # cache copy exists on the kernel path. ``pos`` is a [B]
-            # vector (per-slot cache position); its RoPE position is
-            # pos - pad for a left-pad-prefilled slot.
-            assert S == 1, "the paged cache path decodes one token/slot"
-            positions = pos[:, None] + jnp.arange(S)[None, :]
-            if pad is not None:
-                positions = jnp.maximum(positions - pad[:, None], 0)
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
-            pk, pv = cache  # [n_blocks, P, Hkv, hd] — one layer's pool
-            # write-then-attend, exactly the dense cache path's
-            # dynamic_update_slice ordering: the token's own K/V is
-            # visible to its query. Idle/prefilling slots arrive
-            # scratch-redirected (write_block 0) — duplicate scratch
-            # writes race, but scratch is masked garbage by contract.
-            pk = pk.at[paged.write_block, paged.write_offset].set(
-                k[:, 0].astype(pk.dtype))
-            pv = pv.at[paged.write_block, paged.write_offset].set(
-                v[:, 0].astype(pv.dtype))
-            from ray_lightning_tpu.ops.attention import paged_attention
-
-            # the view's STATIC use_pallas (the serve engine's
-            # build-time decision) pins the dispatch; absent that,
-            # fall back to the flash-style ambient policy
-            up = (paged.use_pallas if paged.use_pallas is not None
-                  else (None if cfg.use_flash else False))
-            attn = paged_attention(
-                q[:, 0], pk, pv, paged.tables, paged.lengths, pad=pad,
-                use_pallas=up)[:, None]
-            new_cache = (pk, pv)
-        else:
-            positions = pos + jnp.arange(S)
-            if pad is not None:
-                # left-padded ragged batch: row b's first real token
-                # sits at column pad[b] but is RoPE position 0; clamp
-                # keeps the (discarded) pad rows' table reads in range
-                positions = jnp.maximum(
-                    positions[None, :] - pad[:, None], 0)
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
-            ck, cv = cache  # [B, S_max, Hkv, hd]
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                ck, k.astype(ck.dtype), pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cv, v.astype(cv.dtype), pos, axis=1)
-            if (S > 1 and isinstance(pos, int) and pos == 0
-                    and pad is None):
-                # prefill from empty context: plain causal attention over
-                # the chunk itself (flash path — never materialize the
-                # [S, S_max] masked score matrix against the zero tail).
-                attn = flash_attention_on_mesh(
-                    q, k, v, self.mesh, causal=True,
-                    use_pallas=None if cfg.use_flash else False)
-            else:
-                # single-token decode (or mid-sequence chunk, or a
-                # left-padded prefill): masked reference SDPA over the
-                # cache — S is tiny here.
-                kv_pos = jnp.arange(ck.shape[1])[None, None, None, :]
-                q_pos = (pos + jnp.arange(S))[None, None, :, None]
-                mask = kv_pos <= q_pos
-                if pad is not None:
-                    # pad columns are not context for anyone
-                    mask = mask & (kv_pos >= pad[:, None, None, None])
-                attn = dot_product_attention(
-                    q, ck, cv, causal=False, mask=mask)
-            new_cache = (ck, cv)
-        attn = attn.reshape(B, S, n_q * hd)
-        x = x + dense(d, name="wo")(attn)
-
-        mlp_norm_w = self.param("mlp_norm", nn.initializers.ones, (d,))
-        h = rms_norm(x, mlp_norm_w, cfg.norm_eps)
-        # fused gate+up: one [D, 2F] matmul
-        gate_up = dense(2 * cfg.hidden_dim, name="w_gate_up")(h)
-        gate, up = jnp.split(gate_up, 2, axis=-1)
-        x = x + dense(d, name="w_down")(nn.silu(gate) * up)
+        with jax.named_scope("mlp"):
+            mlp_norm_w = self.param("mlp_norm", nn.initializers.ones, (d,))
+            h = rms_norm(x, mlp_norm_w, cfg.norm_eps)
+            # fused gate+up: one [D, 2F] matmul
+            gate_up = dense(2 * cfg.hidden_dim, name="w_gate_up")(h)
+            gate, up = jnp.split(gate_up, 2, axis=-1)
+            x = x + dense(d, name="w_down")(nn.silu(gate) * up)
         return x, new_cache  # (carry, ys) pair so nn.scan drives the block
 
 
@@ -492,13 +500,19 @@ class Llama(nn.Module):
                 # cache collected as the scan output (out_axes=0). The
                 # paged view (block tables / lengths / write indices)
                 # is layer-invariant, so it broadcasts like pos/pad.
-                x, new_cache = scan(
-                    block,
-                    in_axes=(nn.broadcast, nn.broadcast, 0,
-                             nn.broadcast, nn.broadcast, nn.broadcast),
-                    out_axes=0,
-                )(cfg, self.mesh, name="layers")(x, cos, sin, cache,
-                                                 pos, pad, paged)
+                # `kv_pool`: taking a layer's pool out of the stack and
+                # writing it back is the scan's own work, so the scope sits
+                # around the scan; a block's `attn` / `mlp` ops lie deeper
+                # and a trace reader credits the innermost scope
+                with (jax.named_scope("kv_pool") if paged is not None
+                      else contextlib.nullcontext()):
+                    x, new_cache = scan(
+                        block,
+                        in_axes=(nn.broadcast, nn.broadcast, 0,
+                                 nn.broadcast, nn.broadcast, nn.broadcast),
+                        out_axes=0,
+                    )(cfg, self.mesh, name="layers")(x, cos, sin, cache,
+                                                     pos, pad, paged)
         else:
             caches = []
             for i in range(cfg.n_layers):
@@ -520,7 +534,8 @@ class Llama(nn.Module):
             # the loss projects these states tile-by-tile instead.
             return x
         if cfg.tie_embeddings:
-            logits = embed.attend(x.astype(jnp.float32))
+            with jax.named_scope("lm_head"):
+                logits = embed.attend(x.astype(jnp.float32))
         else:
             # vocab projection at activation dtype (bf16 operands hit
             # the MXU at full rate; ~3% step-time win) with an f32

@@ -55,6 +55,9 @@ def _inline_unroll_max() -> int:
         return 16
 
 
+# the scope every op of this module carries in a profiler trace: both
+# backward passes (autodiff's and `_ce_inline_bwd`) inherit it
+@jax.named_scope("fused_ce")
 def fused_cross_entropy(
     hidden: jnp.ndarray,
     lm_head: jnp.ndarray,

@@ -155,6 +155,7 @@ def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="rlt_flash_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return o, lse
@@ -310,6 +311,7 @@ def _bwd(scale, causal, q_offset, block_q, block_k, res, do):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="rlt_flash_bwd_dkdv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     if n_rep > 1:
@@ -339,6 +341,7 @@ def _bwd(scale, causal, q_offset, block_q, block_k, res, do):
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="rlt_flash_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

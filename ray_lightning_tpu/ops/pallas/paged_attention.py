@@ -196,6 +196,7 @@ def paged_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c, h, hd), q.dtype),
+        name="rlt_paged_decode",
         interpret=_interpret(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
       pad.astype(jnp.int32), q, pool_k, pool_v)

@@ -271,6 +271,7 @@ def paged_prefill_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, ch, h, hd), q.dtype),
+        name="rlt_paged_prefill",
         interpret=_interpret(),
     )(tables.astype(jnp.int32),
       jnp.asarray(pos, jnp.int32).reshape(1),

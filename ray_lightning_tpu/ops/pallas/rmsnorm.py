@@ -38,6 +38,7 @@ def _rmsnorm_fwd_2d(x2, w, eps, block_rows):
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2.dtype),
+        name="rlt_rmsnorm",
         interpret=_interpret(),
     )(x2, w)
 

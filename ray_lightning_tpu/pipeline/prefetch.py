@@ -48,6 +48,7 @@ from ray_lightning_tpu.telemetry.spans import (
     PH_DATA_WAIT,
     PH_H2D,
     THREAD_PRODUCER,
+    annotate,
 )
 
 
@@ -123,11 +124,13 @@ class DevicePrefetcher(Iterable[Any]):
 
     def _produce(self) -> None:
         try:
-            for item in self._source:
+            for batch, item in enumerate(self._source):
                 if self._stop.is_set():
                     return
-                with self._recorder.span(PH_H2D,
-                                         thread=THREAD_PRODUCER):
+                # `batch` (this stream's index of the batch being placed)
+                # joins the producer's span to the step that consumes it
+                with self._recorder.span(PH_H2D, thread=THREAD_PRODUCER,
+                                         meta={"batch": batch}):
                     placed = self._place(item)
                 # bounded put with a timeout poll so close() can always
                 # unblock the producer even if the consumer vanished
@@ -161,9 +164,12 @@ class DevicePrefetcher(Iterable[Any]):
         if self._closed:
             raise StopIteration
         hit = not self._q.empty()
-        t0 = time.perf_counter()
-        item = self._q.get()
-        waited = time.perf_counter() - t0
+        # the wait is on the profiler's clock hit or miss (`rlt.data_wait`);
+        # only a miss enters the ring below
+        with annotate(PH_DATA_WAIT):
+            t0 = time.perf_counter()
+            item = self._q.get()
+            waited = time.perf_counter() - t0
         if isinstance(item, _Stop):
             self.close()
             raise StopIteration

@@ -70,6 +70,7 @@ from ray_lightning_tpu.serve.scheduler import (
     Completion, Request, Scheduler, SLOConfig,
 )
 from ray_lightning_tpu.analysis.lockwatch import san_lock
+from ray_lightning_tpu.telemetry.spans import annotate
 from ray_lightning_tpu.utils import get_logger
 
 log = get_logger(__name__)
@@ -1434,7 +1435,8 @@ class ServeDriver:
                     dm.tick_end()
             self._flush_sends(sends)
             return []
-        self._route_pending()
+        with annotate("serve.route"):
+            self._route_pending()
         done: List[Completion] = []
         for r in sorted(self.replicas):
             rep = self.replicas[r]
@@ -1448,31 +1450,32 @@ class ServeDriver:
                     self._stop_replica(rep)
                     continue
             completions = rep.sched.tick()
-            for detail in rep.sched.last_preemption_details:
-                self.outputs[detail["rid"]] = []
-                _record_preemption(rep.recorder, detail, r)
-            if rep.state == "draining":
-                # a preemption during the drain tick: reroute now so
-                # the request is not parked behind closed admissions
-                self._requeue_from(rep)
-            for rid, tok in rep.sched.last_emissions:
-                self.outputs[rid].append(tok)
-                self._session_tokens += 1
-            for comp in completions:
-                _record_completion(rep.recorder, comp, r)
-                self.meta[comp.rid] = {
-                    "replica": r,
-                    "finish_reason": comp.finish_reason,
-                    "queue_wait_s": comp.queue_wait_s,
-                    "ttft_s": comp.ttft_s, "tpot_s": comp.tpot_s,
-                    "preempted": comp.preempted,
-                    "n_tokens": len(comp.tokens),
-                    "priority": comp.priority,
-                }
-                if len(rep.sched.completions) % \
-                        FLUSH_EVERY_N_COMPLETIONS == 0:
-                    rep.recorder.flush()
-            self._drain_sheds(r, rep.sched)
+            with annotate("serve.collect"):
+                for detail in rep.sched.last_preemption_details:
+                    self.outputs[detail["rid"]] = []
+                    _record_preemption(rep.recorder, detail, r)
+                if rep.state == "draining":
+                    # a preemption during the drain tick: reroute now so
+                    # the request is not parked behind closed admissions
+                    self._requeue_from(rep)
+                for rid, tok in rep.sched.last_emissions:
+                    self.outputs[rid].append(tok)
+                    self._session_tokens += 1
+                for comp in completions:
+                    _record_completion(rep.recorder, comp, r)
+                    self.meta[comp.rid] = {
+                        "replica": r,
+                        "finish_reason": comp.finish_reason,
+                        "queue_wait_s": comp.queue_wait_s,
+                        "ttft_s": comp.ttft_s, "tpot_s": comp.tpot_s,
+                        "preempted": comp.preempted,
+                        "n_tokens": len(comp.tokens),
+                        "priority": comp.priority,
+                    }
+                    if len(rep.sched.completions) % \
+                            FLUSH_EVERY_N_COMPLETIONS == 0:
+                        rep.recorder.flush()
+                self._drain_sheds(r, rep.sched)
             done.extend(completions)
         self._session_ticks += 1
         dm = self.driver_metrics

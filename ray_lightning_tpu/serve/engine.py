@@ -59,6 +59,7 @@ from ray_lightning_tpu.serve.kv_cache import (
     pool_partition_spec,
     validate_pool_tp,
 )
+from ray_lightning_tpu.telemetry.spans import annotate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -259,8 +260,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                           emitted, slot_pad):
         # one dense gathered view per step — the copy the fused lane
         # retires (charged by serve_memory_summary on this path only)
-        gk = pool_k[:, tables].reshape(L, C, G, HKV, HD)
-        gv = pool_v[:, tables].reshape(L, C, G, HKV, HD)
+        with jax.named_scope("kv_pool"):
+            gk = pool_k[:, tables].reshape(L, C, G, HKV, HD)
+            gv = pool_v[:, tables].reshape(L, C, G, HKV, HD)
         if slot_pad is None:
             logits2, k_tok, v_tok = jax.vmap(
                 _decode_one, in_axes=(None, 0, 1, 1, 0),
@@ -271,9 +273,10 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                 _decode_one_padded, in_axes=(None, 0, 1, 1, 0, 0),
                 out_axes=(0, 1, 1),
             )(params, emitted, gk, gv, pos, slot_pad)
-        bi, off = _write_index(tables, pos, decoding)
-        pool_k = pool_k.at[:, bi, off].set(k_tok)
-        pool_v = pool_v.at[:, bi, off].set(v_tok)
+        with jax.named_scope("kv_pool"):
+            bi, off = _write_index(tables, pos, decoding)
+            pool_k = pool_k.at[:, bi, off].set(k_tok)
+            pool_v = pool_v.at[:, bi, off].set(v_tok)
         return pool_k, pool_v, logits2
 
     def _decode_fused(params, pool_k, pool_v, tables, pos, decoding,
@@ -299,6 +302,7 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
 
     _decode = _decode_fused if fused else _decode_reference
 
+    @jax.named_scope("sample")
     def _sample(last_logits, decoding, temp, top_k, rngs):
         keys = jax.random.wrap_key_data(rngs)
         split = jax.vmap(jax.random.split)(keys)
@@ -370,8 +374,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                         cache=(pool_k, pool_v), pos=prefill_pos,
                         paged=view)
                 else:
-                    kc = pool_k[:, row].reshape(L, 1, G, HKV, HD)
-                    vc = pool_v[:, row].reshape(L, 1, G, HKV, HD)
+                    with jax.named_scope("kv_pool"):
+                        kc = pool_k[:, row].reshape(L, 1, G, HKV, HD)
+                        vc = pool_v[:, row].reshape(L, 1, G, HKV, HD)
                     logits, (nk, nv) = model.apply(
                         {"params": params}, prefill_tokens[None],
                         cache=(kc, vc), pos=prefill_pos)
@@ -383,10 +388,11 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                     # chunk: positions >= prompt_len hold garbage the
                     # decode lane overwrites before any mask ever
                     # exposes them
-                    wpos = prefill_pos + jnp.arange(CH)
-                    wbi = row[wpos // P]
-                    pool_k = pool_k.at[:, wbi, wpos % P].set(kw)
-                    pool_v = pool_v.at[:, wbi, wpos % P].set(vw)
+                    with jax.named_scope("kv_pool"):
+                        wpos = prefill_pos + jnp.arange(CH)
+                        wbi = row[wpos // P]
+                        pool_k = pool_k.at[:, wbi, wpos % P].set(kw)
+                        pool_v = pool_v.at[:, wbi, wpos % P].set(vw)
                 done_row = logits[0, prefill_last_row]
                 finished = prefill_last_row >= 0
                 last_logits = jnp.where(
@@ -459,8 +465,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                     cache=(pool_k, pool_v), pos=prefill_pos,
                     pad=prefill_pad, paged=view)
             else:
-                kc = pool_k[:, rows].reshape(L, B, G, HKV, HD)
-                vc = pool_v[:, rows].reshape(L, B, G, HKV, HD)
+                with jax.named_scope("kv_pool"):
+                    kc = pool_k[:, rows].reshape(L, B, G, HKV, HD)
+                    vc = pool_v[:, rows].reshape(L, B, G, HKV, HD)
                 logits, (nk, nv) = model.apply(
                     {"params": params}, prefill_tokens,
                     cache=(kc, vc), pos=prefill_pos, pad=prefill_pad)
@@ -472,10 +479,11 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                 # masked out of every attention forever (the model's
                 # pad contract), so like partial-tail garbage they can
                 # never reach an unmasked reduction
-                wbi = rows[:, wpos // P]
-                woff = jnp.broadcast_to(wpos % P, (B, CH))
-                pool_k = pool_k.at[:, wbi, woff].set(kw)
-                pool_v = pool_v.at[:, wbi, woff].set(vw)
+                with jax.named_scope("kv_pool"):
+                    wbi = rows[:, wpos // P]
+                    woff = jnp.broadcast_to(wpos % P, (B, CH))
+                    pool_k = pool_k.at[:, wbi, woff].set(kw)
+                    pool_v = pool_v.at[:, wbi, woff].set(vw)
             done = active & (prefill_last_row >= 0)
             done_rows = logits[:, prefill_last_row]      # [B, V]
             # scatter each finished row's logits into its slot via a
@@ -569,12 +577,13 @@ def build_spec_step(model, draft_model, cfg: EngineConfig):
         """One speculative tick. Donated: both pools + last_logits
         (positions 2-6). Runtime inputs as in the base step."""
         # ---- t0: the carried token, sampled exactly like the base ---
-        keys = jax.random.wrap_key_data(rngs)
-        split = jax.vmap(jax.random.split)(keys)
-        new_rngs = jnp.where(decoding[:, None],
-                             jax.random.key_data(split[:, 0]), rngs)
-        t0 = jax.vmap(_sample_one)(last_logits, split[:, 1], temp,
-                                   top_k)
+        with jax.named_scope("sample"):
+            keys = jax.random.wrap_key_data(rngs)
+            split = jax.vmap(jax.random.split)(keys)
+            new_rngs = jnp.where(decoding[:, None],
+                                 jax.random.key_data(split[:, 0]), rngs)
+            t0 = jax.vmap(_sample_one)(last_logits, split[:, 1], temp,
+                                       top_k)
 
         # ---- draft lane: K feedback trips over the draft pool --------
         def propose(carry, _):
@@ -1027,38 +1036,43 @@ class DecodeEngine:
             def put(x):
                 return _global_put(x, self._repl_sh)
         spec_mode = self.cfg.draft is not None
-        if spec_mode:
-            common = (
-                self.params, self.draft_params, self.pool_k,
-                self.pool_v, self.dpool_k, self.dpool_v,
-                self.last_logits,
-                put(tables), put(pos), put(decoding),
-                put(temp), put(top_k), put(rngs))
-        else:
-            common = (
-                self.params, self.pool_k, self.pool_v, self.last_logits,
-                put(tables), put(pos), put(decoding),
-                put(temp), put(top_k), put(rngs))
-        if self.cfg.prefill_batch == 1:
-            pslot, ptoks, ppos, plast = prefill
-            args = common + (put(pslot), put(ptoks),
-                             put(ppos), put(plast))
-        else:
-            if pad is None:
-                pad = np.zeros(self.cfg.capacity, np.int32)
-            pslot, ptoks, ppos, plast, ppad = prefill
-            args = common + (put(pad), put(pslot),
-                             put(ptoks), put(ppos),
-                             put(plast), put(ppad))
+        with annotate("serve.put"):
+            if spec_mode:
+                common = (
+                    self.params, self.draft_params, self.pool_k,
+                    self.pool_v, self.dpool_k, self.dpool_v,
+                    self.last_logits,
+                    put(tables), put(pos), put(decoding),
+                    put(temp), put(top_k), put(rngs))
+            else:
+                common = (
+                    self.params, self.pool_k, self.pool_v,
+                    self.last_logits,
+                    put(tables), put(pos), put(decoding),
+                    put(temp), put(top_k), put(rngs))
+            if self.cfg.prefill_batch == 1:
+                pslot, ptoks, ppos, plast = prefill
+                args = common + (put(pslot), put(ptoks),
+                                 put(ppos), put(plast))
+            else:
+                if pad is None:
+                    pad = np.zeros(self.cfg.capacity, np.int32)
+                pslot, ptoks, ppos, plast, ppad = prefill
+                args = common + (put(pad), put(pslot),
+                                 put(ptoks), put(ppos),
+                                 put(plast), put(ppad))
+        with annotate("serve.dispatch",
+                      **self._step_work(pos, decoding, prefill)):
+            out = self._step(*args)
         if spec_mode:
             (self.pool_k, self.pool_v, self.dpool_k, self.dpool_v,
-             self.last_logits, new_rngs, toks, n_emit) = \
-                self._step(*args)
-            toks = np.array(toks)
-            n_emit = np.array(n_emit)
+             self.last_logits, new_rngs, toks, n_emit) = out
+            with annotate("serve.fetch"):
+                toks = np.array(toks)
+                n_emit = np.array(n_emit)
         else:
             (self.pool_k, self.pool_v, self.last_logits, new_rngs,
-             emitted) = self._step(*args)
+             emitted) = out
         self.steps += 1
         m = self.metrics
         if m.enabled:
@@ -1082,16 +1096,51 @@ class DecodeEngine:
                         n_pf_rows * self.cfg.prefill_chunk)
             m.gauge("engine_steps", self.steps)
             m.gauge("compile_count", self.compile_count)
-        if spec_mode:
-            return toks, n_emit, np.array(new_rngs)
-        if self.mesh is not None:
-            # replicated outputs: any addressable shard IS the global
-            # value — np.array on a multi-process global array would
-            # raise (non-addressable devices)
-            emitted = np.array(emitted.addressable_data(0))
-            new_rngs = np.array(new_rngs.addressable_data(0))
-        else:
-            emitted = np.array(emitted)
-            new_rngs = np.array(new_rngs)
+        with annotate("serve.fetch"):
+            if spec_mode:
+                return toks, n_emit, np.array(new_rngs)
+            if self.mesh is not None:
+                # replicated outputs: any addressable shard IS the global
+                # value — np.array on a multi-process global array would
+                # raise (non-addressable devices)
+                emitted = np.array(emitted.addressable_data(0))
+                new_rngs = np.array(new_rngs.addressable_data(0))
+            else:
+                emitted = np.array(emitted)
+                new_rngs = np.array(new_rngs)
         return (emitted[:, None],
                 np.asarray(decoding).astype(np.int32), new_rngs)
+
+    def _step_work(self, pos, decoding, prefill) -> dict:
+        """What this step's attention is asked to do, as `rlt.serve.
+        dispatch` carries it into a profiler trace: from the host arrays
+        the tick already holds, no device value is read.
+
+          decode_slots  slots in the decode phase
+          kv_tokens     cache tokens their queries read: each reads its
+                        ``pos`` written tokens and the one this step
+                        writes (the kernel's ``lengths = pos + 1``)
+          prefill_rows  real prompt rows in this step's chunk (pad
+                        columns and the zero tail past a prompt's end
+                        are not work); 0 without a chunk
+          prefill_ctx   tokens already in those rows' caches before the
+                        chunk (summed over the group's rows)
+        """
+        dec = np.asarray(decoding)
+        if self.cfg.prefill_batch == 1:
+            pslot, _toks, start, last = prefill
+            active = np.asarray([pslot]) >= 0
+            pads = np.zeros(1, np.int64)
+        else:
+            pslots, _toks, start, last, pads = prefill
+            active = np.asarray(pslots) >= 0
+            pads = np.asarray(pads, np.int64)
+        start, last = int(start), int(last)
+        cols = last + 1 if last >= 0 else self.cfg.prefill_chunk
+        lead = np.clip(pads - start, 0, cols)   # pad columns in the chunk
+        return {
+            "decode_slots": int(dec.sum()),
+            "kv_tokens": int((np.asarray(pos)[dec] + 1).sum()),
+            "prefill_rows": int(((cols - lead) * active).sum()),
+            "prefill_ctx": int((np.maximum(start - pads, 0) * active).sum()),
+        }

@@ -28,6 +28,7 @@ from ray_lightning_tpu.serve.kv_cache import (
     prefix_block_hashes,
 )
 from ray_lightning_tpu.telemetry.metrics import NULL_FLIGHT, NULL_METRICS
+from ray_lightning_tpu.telemetry.spans import annotate
 
 
 #: traffic classes, best first — the index is the preemption rank
@@ -845,10 +846,29 @@ class Scheduler:
 
     def tick(self) -> List[Completion]:
         """Admit -> prefill-chunk pick -> engine step -> account.
-        Returns the requests that COMPLETED this tick."""
-        self.last_preemptions = []
-        self.last_preemption_details = []
-        self._admit()
+        Returns the requests that COMPLETED this tick. Each phase is an
+        `rlt.serve.*` event in a profiler trace (telemetry/spans.py
+        `annotate`; annotations only, the tick never enters the span
+        ring), nested under `rlt.serve.tick`."""
+        with annotate("serve.tick", tick=self._ticks):
+            self.last_preemptions = []
+            self.last_preemption_details = []
+            with annotate("serve.admit"):
+                self._admit()
+            with annotate("serve.grow"):
+                self._grow_decoding()
+            with annotate("serve.build"):
+                prefill, pf_group = self._build_prefill()
+            was_decoding = self.decoding.copy()
+            emitted, n_emit, self.rngs = self.engine.tick(
+                self.tables, self.pos, self.decoding, self.temp,
+                self.top_k, self.rngs, prefill,
+                pad=self.pad if self.cfg.prefill_batch > 1 else None)
+            with annotate("serve.account"):
+                return self._account(pf_group, was_decoding, emitted,
+                                     n_emit)
+
+    def _grow_decoding(self) -> None:
         # growth check before the step: every decoding slot must own
         # the block its write lands in. On a dry pool a grower may only
         # evict slots STRICTLY AFTER itself in policy order (decoding
@@ -894,6 +914,10 @@ class Scheduler:
                         f"request {me.req.rid} cannot grow with the "
                         "pool to itself — engine pool is smaller than "
                         "one request's span")
+
+    def _build_prefill(self):
+        """This tick's prefill arguments for the engine and the group
+        they advance (None: no chunk this tick)."""
         # one prefill chunk, FIFO over admitted-but-not-decoding groups
         prefill = idle_prefill(self.cfg)
         pf_group = self.prefill_groups[0] if self.prefill_groups else None
@@ -952,11 +976,13 @@ class Scheduler:
             last_row = (pf_group.width - 1 - start) if finished else -1
             prefill = (slots_arr, toks, np.int32(start),
                        np.int32(last_row), pads)
-        was_decoding = self.decoding.copy()
-        emitted, n_emit, self.rngs = self.engine.tick(
-            self.tables, self.pos, self.decoding, self.temp, self.top_k,
-            self.rngs, prefill,
-            pad=self.pad if self.cfg.prefill_batch > 1 else None)
+        return prefill, pf_group
+
+    def _account(self, pf_group, was_decoding, emitted,
+                 n_emit) -> List[Completion]:
+        """Prefill and decode accounting, retirement and gauges after the
+        engine's step."""
+        ch = self.cfg.prefill_chunk
         self._occupancy_sum += float(was_decoding.mean())
         self._ticks += 1
         # prefill accounting

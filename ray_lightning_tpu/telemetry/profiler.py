@@ -1,8 +1,9 @@
 """On-demand ``jax.profiler`` capture, driven from the fit loop.
 
-A production incident is never reproduced with ``profiler_dir`` set from
-the start — the capture has to be armable on a RUNNING job. Three
-triggers, all host-side and cadence-guarded:
+A production incident is never reproduced with a profiler armed from the
+start — the capture has to be armable on a RUNNING job. Three
+triggers, all host-side and cadence-guarded (a whole-fit trace is the
+first with ``start_step=1``):
 
     step window   ``ProfileConfig(start_step=500, num_steps=5)`` —
                   deterministic capture of a known-bad region;

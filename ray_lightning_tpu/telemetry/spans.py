@@ -13,14 +13,23 @@ attempt launch). This module gives those seams one cheap vocabulary:
 A span is a host-side ``(phase, start, dur, step, thread)`` record in a
 bounded ring (``collections.deque(maxlen=...)``) that is flushed to
 JSONL per rank under the run dir on a cadence the caller controls.
-Nothing here touches jax: no ``device_get``, no ``block_until_ready``,
-no array inspection — a span measures how long the HOST spent inside a
-region that was host-resident anyway, so telemetry=off and telemetry=on
-compile the byte-identical device program (test-pinned) and telemetry
-adds zero new host syncs.
+Nothing here touches a device: no ``device_get``, no
+``block_until_ready``, no array inspection — a span measures how long
+the HOST spent inside a region that was host-resident anyway, so
+telemetry=off and telemetry=on compile the byte-identical device program
+(test-pinned) and telemetry adds zero new host syncs.
 
-``NullRecorder`` is the off switch: the same surface with a shared
-reusable no-op context, so call sites never branch.
+``NullRecorder`` is the off switch for the RING: the same surface, so
+call sites never branch.
+
+The profiler's clock: every span, with the ring on or off, is also a
+``jax.profiler.TraceAnnotation`` named ``rlt.<phase>`` (`annotate`), so
+whenever a profiler session is running (the benchmark's ``--trace 1``,
+a `ProfileConfig` capture) the phase sits in the trace's host plane on
+the same clock as the device's ops, carrying its ``step``. Outside a
+session an annotation is a flag test and records nothing. The serving
+tick's phases (``rlt.serve.*``) go through `annotate` alone and never
+enter the ring.
 
 Clock alignment: each JSONL file opens with a header line carrying the
 pair ``(t0_wall, t0_perf)``; span ``t`` fields are perf_counter offsets
@@ -37,6 +46,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ray_lightning_tpu.analysis.lockwatch import san_lock
 
@@ -92,6 +103,27 @@ THREAD_PRODUCER = "producer"
 
 SPANS_VERSION = "rlt-spans-v1"
 
+#: every host span the program opens is named with this prefix in a
+#: profiler trace (docs/OBSERVABILITY.md "names in a trace")
+TRACE_PREFIX = "rlt."
+
+
+def annotate(phase: str, **counters: Any) -> TraceAnnotation:
+    """``with annotate("serve.tick", tick=n):`` — the phase as an event
+    named ``rlt.<phase>`` on the profiler's clock, its counters as the
+    event's stats. The one place the program opens a host span: the
+    recorders below call it for the trainer's phases, the serving loop
+    calls it directly. Counters that are None are left out."""
+    return TraceAnnotation(
+        TRACE_PREFIX + phase,
+        **{k: v for k, v in counters.items() if v is not None})
+
+
+def _span_annotation(phase: str, step: Optional[int],
+                     meta: Optional[dict]) -> TraceAnnotation:
+    """A recorder span's annotation: its `step` and its `meta` as stats."""
+    return annotate(phase, **{**(meta or {}), "step": step})
+
 
 class _SpanCtx:
     """One `with recorder.span(...)` region. Slots + a single perf_counter
@@ -105,7 +137,7 @@ class _SpanCtx:
     wall-clock second."""
 
     __slots__ = ("_rec", "phase", "step", "thread", "meta", "_t0",
-                 "child_s")
+                 "child_s", "_annotation")
 
     def __init__(self, rec: "TelemetryRecorder", phase: str,
                  step: Optional[int], thread: str, meta: Optional[dict]):
@@ -115,8 +147,12 @@ class _SpanCtx:
         self.thread = thread
         self.meta = meta
         self.child_s = 0.0
+        self._annotation = _span_annotation(phase, step, meta)
 
     def __enter__(self) -> "_SpanCtx":
+        # the annotation encloses the ring's interval: same phase, same
+        # step, which is the join between the ring's clock and the trace's
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         if self.thread == THREAD_MAIN:
             self._rec._stack.append(self)
@@ -138,6 +174,7 @@ class _SpanCtx:
         self._rec.record(self.phase, self._t0, dur,
                          step=self.step, thread=self.thread,
                          meta=self.meta, totals_s=totals_s)
+        self._annotation.__exit__(*exc)
         return None
 
 
@@ -280,22 +317,9 @@ class TelemetryRecorder:
         self.flush()
 
 
-class _NullCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
-
-
-_NULL_CTX = _NullCtx()
-
-
 class NullRecorder:
-    """telemetry=off: the same surface, every call a no-op. One shared
-    context object — ``span()`` allocates nothing."""
+    """telemetry=off: the same surface, nothing enters a ring. A span is
+    its profiler annotation alone."""
 
     directory = None
     rank = 0
@@ -304,7 +328,7 @@ class NullRecorder:
 
     def span(self, phase: str, step: Optional[int] = None,
              thread: str = THREAD_MAIN, meta: Optional[dict] = None):
-        return _NULL_CTX
+        return _span_annotation(phase, step, meta)
 
     def record(self, *a: Any, **kw: Any) -> None: ...
     def set_step(self, step: int) -> None: ...
