@@ -18,7 +18,6 @@ tests/utils.py:96-120) — this is net-new capability designed for the MXU:
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from functools import partial
@@ -231,7 +230,7 @@ class LlamaBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, cache=None, pos=None, pad=None,
-                 paged=None):
+                 paged=None, layer=None):
         """Training/prefill-from-zero when cache is None; with a
         ``cache=(k_cache, v_cache)`` ([B, S_max, Hkv, hd] each) and a
         (traced) ``pos``, runs the KV-cache decode path and returns the
@@ -244,8 +243,13 @@ class LlamaBlock(nn.Module):
 
         ``paged`` (an `ops.attention.PagedDecodeView`, serving engine
         only) switches the cache path to the block-paged pool: ``cache``
-        is then ONE layer's shared pool ``([n_blocks, P, Hkv, hd])``
-        pair, S must be 1 (one decode token per slot), ``pos`` is a
+        is then the WHOLE stacked pool ``([L, n_blocks, P, Hkv, hd])``
+        pair and ``layer`` this block's index into it (traced under the
+        layer scan, a python int otherwise) — a layer's pool is never
+        taken out of the stack: the write carries the layer in its
+        index, the kernel in its block ids, and the updated stack is
+        returned. S must
+        be 1 (one decode token per slot), ``pos`` is a
         per-slot [B] vector of cache positions, the new K/V token is
         scattered straight into the pool at the view's (already
         scratch-redirected) write index, and attention consumes the
@@ -334,17 +338,18 @@ class LlamaBlock(nn.Module):
                     positions = jnp.maximum(positions - pad[:, None], 0)
                 q = apply_rope(q, cos, sin, positions=positions)
                 k = apply_rope(k, cos, sin, positions=positions)
-                pk, pv = cache  # [n_blocks, P, Hkv, hd] — one layer's pool
+                pk, pv = cache  # [L, n_blocks, P, Hkv, hd] — the stack
                 # write-then-attend, the decode fused lane's ordering: the
                 # whole chunk's K/V is scattered into OWNED pool blocks
                 # (vacant group rows arrive scratch-redirected — block 0 is
                 # masked garbage by contract) BEFORE attention, so each
                 # query's causal window covers the in-chunk prefix too.
+                # `kv_pool`: all the pool handling the paged path has
                 with jax.named_scope("kv_pool"):
-                    pk = pk.at[paged.write_block, paged.write_offset].set(
-                        k.astype(pk.dtype))
-                    pv = pv.at[paged.write_block, paged.write_offset].set(
-                        v.astype(pv.dtype))
+                    pk = pk.at[layer, paged.write_block,
+                               paged.write_offset].set(k.astype(pk.dtype))
+                    pv = pv.at[layer, paged.write_block,
+                               paged.write_offset].set(v.astype(pv.dtype))
                 from ray_lightning_tpu.ops.attention import paged_prefill
 
                 # the view's STATIC use_pallas (the serve engine's
@@ -353,7 +358,7 @@ class LlamaBlock(nn.Module):
                 up = (paged.use_pallas if paged.use_pallas is not None
                       else (None if cfg.use_flash else False))
                 attn = paged_prefill(q, pk, pv, paged.tables, pos, pad=pad,
-                                     use_pallas=up)
+                                     use_pallas=up, layer=layer)
                 new_cache = (pk, pv)
             elif paged is not None:
                 # paged decode (serve/engine.py fused lane): one token per
@@ -367,17 +372,19 @@ class LlamaBlock(nn.Module):
                     positions = jnp.maximum(positions - pad[:, None], 0)
                 q = apply_rope(q, cos, sin, positions=positions)
                 k = apply_rope(k, cos, sin, positions=positions)
-                pk, pv = cache  # [n_blocks, P, Hkv, hd] — one layer's pool
+                pk, pv = cache  # [L, n_blocks, P, Hkv, hd] — the stack
                 # write-then-attend, exactly the dense cache path's
                 # dynamic_update_slice ordering: the token's own K/V is
                 # visible to its query. Idle/prefilling slots arrive
                 # scratch-redirected (write_block 0) — duplicate scratch
                 # writes race, but scratch is masked garbage by contract.
                 with jax.named_scope("kv_pool"):
-                    pk = pk.at[paged.write_block, paged.write_offset].set(
-                        k[:, 0].astype(pk.dtype))
-                    pv = pv.at[paged.write_block, paged.write_offset].set(
-                        v[:, 0].astype(pv.dtype))
+                    pk = pk.at[layer, paged.write_block,
+                               paged.write_offset].set(
+                                   k[:, 0].astype(pk.dtype))
+                    pv = pv.at[layer, paged.write_block,
+                               paged.write_offset].set(
+                                   v[:, 0].astype(pv.dtype))
                 from ray_lightning_tpu.ops.attention import paged_attention
 
                 # the view's STATIC use_pallas (the serve engine's
@@ -387,7 +394,7 @@ class LlamaBlock(nn.Module):
                       else (None if cfg.use_flash else False))
                 attn = paged_attention(
                     q[:, 0], pk, pv, paged.tables, paged.lengths, pad=pad,
-                    use_pallas=up)[:, None]
+                    use_pallas=up, layer=layer)[:, None]
                 new_cache = (pk, pv)
             else:
                 positions = pos + jnp.arange(S)
@@ -459,7 +466,13 @@ class Llama(nn.Module):
         path projects them chunk-wise (ops/fused_ce.py). ``paged``
         (serving engine) switches the cache path to the block-paged
         pool — cache leaves are then [L, n_blocks, P, Hkv, hd] and
-        ``pos`` is a per-slot vector; see `LlamaBlock.__call__`."""
+        ``pos`` is a per-slot vector; see `LlamaBlock.__call__`. The
+        stacked pool is then the layer scan's CARRY (the layer index
+        its xs): every block writes into and reads from the one stack
+        at its own layer, so the returned pool is the donated buffer
+        updated in place, and no layer's pool is ever sliced out or
+        copied. The dense cache (``paged=None``) rides the scan as
+        xs in / ys out, as it always has."""
         cfg = self.cfg
         # take from the f32 table and round the (token-sized) result,
         # rather than dtype=cfg.dtype (which rounds the TABLE before the
@@ -495,24 +508,47 @@ class Llama(nn.Module):
             if cache is None:
                 x, _ = scan(block, in_axes=nn.broadcast)(
                     cfg, self.mesh, name="layers")(x, cos, sin)
+            elif paged is not None:
+                # the stacked pool is the scan's CARRY beside x, and the
+                # layer index its only xs: xs in / ys out are different
+                # buffers, so a pool that rode them was sliced out of the
+                # stack, written back into a second stack and copied over
+                # the donated one, every layer of every tick. Carried, it
+                # stays where it is: each block scatters its tokens at
+                # [layer, block, offset] and the kernels read the layer's
+                # blocks out of the stack through their block tables
+                # (ops/pallas/paged_attention.py:stack_as_pool). The
+                # paged view (block tables / lengths / write indices) is
+                # layer-invariant and broadcasts like pos/pad.
+                def layer_body(blk, carry, layer, cos, sin, pos, pad,
+                               paged):
+                    h, pk, pv = carry
+                    h, (pk, pv) = blk(h, cos, sin, (pk, pv), pos, pad,
+                                      paged, layer)
+                    return (h, pk, pv), None
+
+                (x, *new_cache), _ = scan(
+                    layer_body, in_axes=(0,) + (nn.broadcast,) * 5,
+                )(block(cfg, self.mesh, name="layers"), (x, *cache),
+                  jnp.arange(cfg.n_layers), cos, sin, pos, pad, paged)
+                new_cache = tuple(new_cache)
             else:
-                # cache rides the scan: in over the layer axis, updated
-                # cache collected as the scan output (out_axes=0). The
-                # paged view (block tables / lengths / write indices)
-                # is layer-invariant, so it broadcasts like pos/pad.
-                # `kv_pool`: taking a layer's pool out of the stack and
-                # writing it back is the scan's own work, so the scope sits
-                # around the scan; a block's `attn` / `mlp` ops lie deeper
-                # and a trace reader credits the innermost scope
-                with (jax.named_scope("kv_pool") if paged is not None
-                      else contextlib.nullcontext()):
-                    x, new_cache = scan(
-                        block,
-                        in_axes=(nn.broadcast, nn.broadcast, 0,
-                                 nn.broadcast, nn.broadcast, nn.broadcast),
-                        out_axes=0,
-                    )(cfg, self.mesh, name="layers")(x, cos, sin, cache,
-                                                     pos, pad, paged)
+                # the dense cache rides the scan: in over the layer axis,
+                # updated cache collected as the scan output (out_axes=0)
+                x, new_cache = scan(
+                    block,
+                    in_axes=(nn.broadcast, nn.broadcast, 0,
+                             nn.broadcast, nn.broadcast, nn.broadcast),
+                    out_axes=0,
+                )(cfg, self.mesh, name="layers")(x, cos, sin, cache,
+                                                 pos, pad, paged)
+        elif paged is not None:
+            # the same rule unrolled: every block writes into and reads
+            # from the one stack at its static layer index
+            new_cache = cache
+            for i in range(cfg.n_layers):
+                x, new_cache = block(cfg, self.mesh, name=f"layer_{i}")(
+                    x, cos, sin, new_cache, pos, pad, paged, i)
         else:
             caches = []
             for i in range(cfg.n_layers):

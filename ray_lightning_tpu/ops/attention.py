@@ -217,6 +217,15 @@ class PagedDecodeView:
         return cls(*children, use_pallas=aux)
 
 
+def _gather_blocks(pool, tables, layer):
+    """The table-named blocks of one layer: ``pool[tables]`` of a 4-D
+    pool, ``pool[layer, tables]`` of the 5-D stack (one gather, the
+    layer's pool is never sliced out)."""
+    if pool.ndim == 4:
+        return pool[tables]
+    return pool[layer, tables]
+
+
 def paged_attention_reference(
     q: jnp.ndarray,
     pool_k: jnp.ndarray,
@@ -225,6 +234,7 @@ def paged_attention_reference(
     lengths: jnp.ndarray,
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
+    layer=0,
 ) -> jnp.ndarray:
     """XLA reference with the kernel's exact semantics: gather each
     slot's blocks into a dense [C, M*P, Hkv, hd] view (the copy the
@@ -232,12 +242,13 @@ def paged_attention_reference(
     run the shared masked-SDPA reference. Scratch-block garbage and
     table tails are masked to exact softmax zeros, so a longer table
     cannot perturb the visible reduction (the serving numerics
-    contract, docs/SERVING.md)."""
+    contract, docs/SERVING.md). A 5-D pool is the stack
+    ``[L, n_blocks, P, Hkv, hd]``, gathered at ``layer``."""
     c, h, hd = q.shape
-    _, p, hkv, _ = pool_k.shape
+    p, hkv = pool_k.shape[-3:-1]
     m = tables.shape[1]
-    k = pool_k[tables].reshape(c, m * p, hkv, hd)
-    v = pool_v[tables].reshape(c, m * p, hkv, hd)
+    k = _gather_blocks(pool_k, tables, layer).reshape(c, m * p, hkv, hd)
+    v = _gather_blocks(pool_v, tables, layer).reshape(c, m * p, hkv, hd)
     kv_pos = jnp.arange(m * p)[None, :]
     mask = kv_pos < lengths[:, None]
     if pad is not None:
@@ -292,6 +303,7 @@ def paged_prefill_reference(
     pos,
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
+    layer=0,
 ) -> jnp.ndarray:
     """XLA reference with the prefill kernel's exact semantics: gather
     each row's blocks into a dense [B, M*P, Hkv, hd] view (the copy the
@@ -300,12 +312,14 @@ def paged_prefill_reference(
     positions), and run the shared masked-SDPA reference. Scratch-block
     garbage, table tails and future in-chunk positions are masked to
     exact softmax zeros; a fully-masked query row (a pad column) emits
-    zeros (the serving numerics contract, docs/SERVING.md)."""
+    zeros (the serving numerics contract, docs/SERVING.md). A 5-D pool
+    is the stack ``[L, n_blocks, P, Hkv, hd]``, gathered at
+    ``layer``."""
     b, ch, h, hd = q.shape
-    _, p, hkv, _ = pool_k.shape
+    p, hkv = pool_k.shape[-3:-1]
     m = tables.shape[1]
-    k = pool_k[tables].reshape(b, m * p, hkv, hd)
-    v = pool_v[tables].reshape(b, m * p, hkv, hd)
+    k = _gather_blocks(pool_k, tables, layer).reshape(b, m * p, hkv, hd)
+    v = _gather_blocks(pool_v, tables, layer).reshape(b, m * p, hkv, hd)
     kv_pos = jnp.arange(m * p)[None, None, :]
     q_pos = (pos + jnp.arange(ch))[None, :, None]
     mask = kv_pos <= q_pos
@@ -346,9 +360,11 @@ def paged_prefill(
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
     use_pallas: bool | None = None,
+    layer=0,
 ) -> jnp.ndarray:
     """Chunked causal prefill attention over the block-paged KV pool:
-    q [B, CH, H, hd], pool [n_blocks, P, Hkv, hd], tables [B, M],
+    q [B, CH, H, hd], pool [n_blocks, P, Hkv, hd] (or the stack
+    [L, n_blocks, P, Hkv, hd] read at ``layer``), tables [B, M],
     pos scalar (chunk token j sits at cache position pos + j) ->
     [B, CH, H, hd]. Dispatches to the fused pallas kernel when on TPU
     (or forced, with interpret mode off-TPU) and the shapes tile;
@@ -360,10 +376,11 @@ def paged_prefill(
             paged_prefill_pallas,
         )
 
-        return paged_prefill_pallas(q, pool_k, pool_v, tables, pos,
-                                    pad=pad, scale=scale)
+        return paged_prefill_pallas(
+            q, pool_k, pool_v, tables, pos, pad=pad, scale=scale,
+            layer=layer)
     return paged_prefill_reference(q, pool_k, pool_v, tables, pos,
-                                   pad=pad, scale=scale)
+                                   pad=pad, scale=scale, layer=layer)
 
 
 def paged_attention_uses_pallas(q_shape, pool_shape,
@@ -394,10 +411,13 @@ def paged_attention(
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
     use_pallas: bool | None = None,
+    layer=0,
 ) -> jnp.ndarray:
     """Decode attention over the block-paged KV pool: q [C, H, hd],
-    pool [n_blocks, P, Hkv, hd], tables [C, M], lengths [C] ->
-    [C, H, hd]. Dispatches to the fused pallas kernel when on TPU (or
+    pool [n_blocks, P, Hkv, hd] (or the stack
+    [L, n_blocks, P, Hkv, hd] read at ``layer``), tables [C, M],
+    lengths [C] -> [C, H, hd].
+    Dispatches to the fused pallas kernel when on TPU (or
     forced, with interpret mode off-TPU) and the shapes tile; otherwise
     the gathering XLA reference path — identical semantics, but the
     dense per-slot view is materialized (and charged by the serve
@@ -407,7 +427,8 @@ def paged_attention(
             paged_attention_pallas,
         )
 
-        return paged_attention_pallas(q, pool_k, pool_v, tables,
-                                      lengths, pad=pad, scale=scale)
+        return paged_attention_pallas(
+            q, pool_k, pool_v, tables, lengths, pad=pad, scale=scale,
+            layer=layer)
     return paged_attention_reference(q, pool_k, pool_v, tables, lengths,
-                                     pad=pad, scale=scale)
+                                     pad=pad, scale=scale, layer=layer)

@@ -10,7 +10,9 @@ through the per-slot block tables**.
 Schedule (one layer's pool, all slots):
 
     q       [C, H, hd]           one query token per slot
-    pool_k  [n_blocks, P, Hkv, hd]  the shared block pool (k; v alike)
+    pool_k  [n_blocks, P, Hkv, hd]  the shared block pool (k; v alike),
+                                 or the stack [L, n_blocks, P, Hkv, hd]
+                                 with a layer index (`stack_as_pool`)
     tables  [C, M] int32         slot -> pool block ids (0 = scratch)
     lengths [C] int32            valid cache positions per slot
     pad     [C] int32            left-pad columns to mask (ragged
@@ -52,16 +54,17 @@ _NEG_INF = -1e30  # never true -inf: exp(-inf - -inf) = nan on empty rows
 def paged_shapes_supported(q_shape, pool_shape) -> bool:
     """Would the kernel accept these shapes on a real TPU?
 
-    q [C, H, hd], pool [n_blocks, P, Hkv, hd]: the head dim must be
-    lane-aligned (128, or 64 which still tiles acceptably — same rule
+    q [C, H, hd], pool [n_blocks, P, Hkv, hd] (or the stack, with a
+    leading layer axis: the last four dims are judged): the head dim
+    must be lane-aligned (128, or 64 which still tiles acceptably — same rule
     as flash), the pool block must be sublane-aligned (P % 8), and the
     GQA ratio must be whole. Callers that must know the dispatch
     outcome use `ops.attention.paged_attention_uses_pallas`, never this
     directly — one predicate, no drift."""
-    if len(q_shape) != 3 or len(pool_shape) != 4:
+    if len(q_shape) != 3 or len(pool_shape) not in (4, 5):
         return False
     _, h, hd = q_shape
-    _, p, hkv, hd2 = pool_shape
+    _, p, hkv, hd2 = pool_shape[-4:]
     if hd != hd2:
         return False
     if hd % 128 != 0 and hd not in (64,):
@@ -71,6 +74,24 @@ def paged_shapes_supported(q_shape, pool_shape) -> bool:
     if p % 8 != 0:
         return False
     return True
+
+
+def stack_as_pool(pool_k, pool_v, tables, layer):
+    """Serve one layer of the stacked pool ``[L, n_blocks, P, Hkv, hd]``
+    WITHOUT taking it out of the stack: the stack is relabelled as one
+    long pool ``[L * n_blocks, P, Hkv, hd]`` (the tiled dims are the
+    last two, so the reshape moves nothing) and the layer is folded into
+    the block ids, ``tables + layer * n_blocks`` (a [rows, M] int32
+    add). The kernel then reads block ``tables[c, m]`` of ``layer``
+    through the index_map it always had; a slot's scratch block 0 is
+    the layer's own block 0. A 4-D pool passes through (``layer``
+    must then be 0)."""
+    if pool_k.ndim == 4:
+        return pool_k, pool_v, tables
+    n_layers, n_blocks = pool_k.shape[:2]
+    flat = (n_layers * n_blocks, *pool_k.shape[2:])
+    return (pool_k.reshape(flat), pool_v.reshape(flat),
+            tables + jnp.asarray(layer, tables.dtype) * n_blocks)
 
 
 def _decode_kernel(tbl_ref, len_ref, pad_ref, q_ref, k_ref, v_ref, o_ref,
@@ -150,15 +171,20 @@ def paged_attention_pallas(
     lengths: jnp.ndarray,
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
+    layer=0,
 ) -> jnp.ndarray:
     """Decode attention over the paged pool: [C, H, hd] out.
 
+    ``pool_k`` / ``pool_v`` are one layer's pool, or the stacked pool
+    ``[L, n_blocks, P, Hkv, hd]`` read at ``layer`` (python int or
+    traced scalar; `stack_as_pool`).
     ``tables`` names each slot's pool blocks (block 0 = reserved
     scratch — readable garbage, always masked by ``lengths``/``pad``);
     ``lengths[c]`` is the number of valid cache positions (including
     the just-written query token); ``pad[c]`` masks a left-padded
     slot's pad columns (positions < pad never attend)."""
     c, h, hd = q.shape
+    pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
     n_blocks, p, hkv, _ = pool_k.shape
     m = tables.shape[1]
     n_rep = h // hkv

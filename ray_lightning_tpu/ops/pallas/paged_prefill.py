@@ -16,7 +16,9 @@ Schedule (one layer's pool, the head group's chunk):
 
     q       [B, CH, H, hd]        the group's query chunk (B = group
                                   rows incl. vacant scratch rows)
-    pool_k  [n_blocks, P, Hkv, hd]  the shared block pool (k; v alike)
+    pool_k  [n_blocks, P, Hkv, hd]  the shared block pool (k; v alike),
+                                  or the stack [L, n_blocks, P, Hkv, hd]
+                                  with a layer index (`stack_as_pool`)
     tables  [B, M] int32          row -> pool block ids (0 = scratch)
     pos     [1] int32             the group's shared cache write offset
                                   (chunk token j sits at pos + j)
@@ -62,6 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_lightning_tpu.ops.dispatch import interpret_mode as _interpret
+from ray_lightning_tpu.ops.pallas.paged_attention import stack_as_pool
 
 _NEG_INF = -1e30  # never true -inf: exp(-inf - -inf) = nan on empty rows
 
@@ -96,17 +99,18 @@ def _fit_q_block(ch: int, h: int, hd: int, cap: int = 128) -> int:
 def paged_prefill_shapes_supported(q_shape, pool_shape) -> bool:
     """Would the prefill kernel accept these shapes on a real TPU?
 
-    q [B, CH, H, hd], pool [n_blocks, P, Hkv, hd]: the head dim must be
-    lane-aligned (128, or 64 which still tiles acceptably — the decode
+    q [B, CH, H, hd], pool [n_blocks, P, Hkv, hd] (or the stack, with
+    a leading layer axis: the last four dims are judged): the head dim
+    must be lane-aligned (128, or 64 which still tiles acceptably — the decode
     kernel's rule), the pool block must be sublane-aligned (P % 8), the
     GQA ratio must be whole, and the flattened score panel rows
     (q-tile x heads) must be sublane-aligned. Callers that must know
     the dispatch outcome use `ops.attention.paged_prefill_uses_pallas`,
     never this directly — one predicate, no drift."""
-    if len(q_shape) != 4 or len(pool_shape) != 4:
+    if len(q_shape) != 4 or len(pool_shape) not in (4, 5):
         return False
     _, ch, h, hd = q_shape
-    _, p, hkv, hd2 = pool_shape
+    _, p, hkv, hd2 = pool_shape[-4:]
     if hd != hd2:
         return False
     if hd % 128 != 0 and hd not in (64,):
@@ -216,10 +220,14 @@ def paged_prefill_pallas(
     pos,
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
+    layer=0,
 ) -> jnp.ndarray:
     """Chunked causal prefill attention over the paged pool:
     [B, CH, H, hd] out.
 
+    ``pool_k`` / ``pool_v`` are one layer's pool, or the stacked pool
+    ``[L, n_blocks, P, Hkv, hd]`` read at ``layer`` (python int or
+    traced scalar; `paged_attention.stack_as_pool`).
     ``tables`` names each group row's pool blocks (block 0 = reserved
     scratch — readable garbage, always masked); chunk token ``j`` sits
     at cache position ``pos + j`` and attends to
@@ -230,6 +238,7 @@ def paged_prefill_pallas(
     itself a pad column sees nothing and emits zeros (discarded by the
     engine's active-row scatter)."""
     b, ch, h, hd = q.shape
+    pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
     n_blocks, p, hkv, _ = pool_k.shape
     m = tables.shape[1]
     n_rep = h // hkv

@@ -200,6 +200,14 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         never materialized. Pinned to the reference lane within the
         flash kernel's tolerance discipline (tests/test_paged_attention).
 
+    On the fused lanes the donated stack ``[L, n_blocks, P, Hkv, hd]``
+    goes into `Llama.__call__` whole and comes back whole: the model
+    carries it through its layer scan, writes a tick's K/V rows at
+    ``[layer, block, offset]`` and hands the kernels the stack with the
+    layer index, so the step moves token rows and table-named tiles,
+    never a layer's pool (docs/SERVING.md "How the pool passes through
+    the step"; tests/test_tpu_aot_compile.py pins the v5e compile).
+
     ``fused_prefill`` selects the PREFILL lane the same way
     (independently — the two kernels have separate shape gates):
 

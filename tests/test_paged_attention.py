@@ -26,6 +26,7 @@ from ray_lightning_tpu.ops.pallas.paged_attention import (
 )
 from ray_lightning_tpu.serve.engine import DecodeEngine, EngineConfig
 from ray_lightning_tpu.serve.scheduler import Request, Scheduler
+from tests.utils import POOL_FORMS, pool_form
 
 
 # ---- op-level parity matrix ------------------------------------------------
@@ -40,21 +41,26 @@ def _rand_case(rng, C, H, hd, Hkv, P, M, N, dtype=jnp.float32):
     return q, pk, pv, tables, lengths
 
 
+@pytest.mark.parametrize("form", POOL_FORMS)
 @pytest.mark.parametrize("C,H,hd,Hkv,P,M,N", [
     (4, 4, 64, 2, 8, 3, 10),     # GQA 2:1
     (3, 8, 64, 8, 16, 2, 7),     # MHA, 16-token blocks
     (2, 4, 128, 1, 8, 4, 6),     # MQA, lane-wide head dim
     (5, 6, 64, 2, 8, 1, 4),      # single-block table
 ])
-def test_kernel_matches_reference_matrix(C, H, hd, Hkv, P, M, N):
+def test_kernel_matches_reference_matrix(C, H, hd, Hkv, P, M, N, form):
     """The parity matrix: block_size x gathered_len x GQA ratio x
-    ragged per-slot lengths, interpret mode on CPU."""
+    ragged per-slot lengths, interpret mode on CPU; over the 4-D pool
+    and over the stacked pool read at a (traced) layer index, by the
+    kernel and by the XLA reference alike."""
     rng = np.random.default_rng(C * 100 + P)
     q, pk, pv, tables, lengths = _rand_case(rng, C, H, hd, Hkv, P, M, N)
     ref = paged_attention_reference(q, pk, pv, tables, lengths)
-    got = paged_attention_pallas(q, pk, pv, tables, lengths)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    fk, fv, at = pool_form(pk, pv, form)
+    for fn in (paged_attention_pallas, paged_attention_reference):
+        got = jax.jit(fn)(q, fk, fv, tables, lengths, **at)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_kernel_pad_masking_matches_reference():
@@ -232,6 +238,78 @@ def test_fused_engine_selected_and_streams_match(kernel_tiny):
         out_fused = _drain(Scheduler(eng), _mixed_requests(prompts))
     for rid in refs:
         assert out_fused[rid].tokens == out_ref[rid].tokens, rid
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+def _shape(var):
+    return tuple(getattr(var.aval, "shape", ()))
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+@pytest.mark.parametrize("prefill_batch", [1, 2])
+def test_fused_step_carries_the_stacked_pool(kernel_tiny, prefill_batch,
+                                             scan_layers):
+    """ISSUE 25: on the fused lanes the stacked pool is the layer
+    scan's CARRY. No scan has an xs or ys leaf of the pool's shape
+    (that was a slice out of the stack and a write into a second one,
+    every layer), nothing anywhere has the shape of one layer's pool
+    (scan or unrolled: nobody takes a layer out of the stack), and the
+    served streams still equal the reference lane's."""
+    import dataclasses
+
+    from ray_lightning_tpu.serve.audit import trace_decode_step
+
+    cfg, model, params, prompts = kernel_tiny
+    if not scan_layers:
+        cfg = dataclasses.replace(cfg, scan_layers=False)
+        model = Llama(cfg)
+        params = {**{k: v for k, v in params.items() if k != "layers"},
+                  **{f"layer_{i}": jax.tree.map(lambda x, i=i: x[i],
+                                                params["layers"])
+                     for i in range(cfg.n_layers)}}
+    ecfg = EngineConfig(capacity=4, block_size=8, blocks_per_slot=4,
+                        prefill_chunk=4, prefill_batch=prefill_batch)
+    with dispatch.force_pallas():
+        closed, meta = trace_decode_step(cfg, ecfg, fused=True)
+    assert meta["fused"] and meta["fused_prefill"]
+    pool_shape = _shape(closed.jaxpr.invars[
+        len(jax.tree.leaves(meta["args"][0]))])
+    assert pool_shape[0] == cfg.n_layers and len(pool_shape) == 5
+    carried = 0
+    for eqn in _walk_eqns(closed.jaxpr):
+        assert pool_shape[1:] not in [_shape(v) for v in eqn.outvars], (
+            f"{eqn.primitive.name} takes a layer's pool out of the stack")
+        if eqn.primitive.name != "scan":
+            continue
+        n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+        xs = eqn.invars[n_fixed:]
+        ys = eqn.outvars[eqn.params["num_carry"]:]
+        assert pool_shape not in [_shape(v) for v in (*xs, *ys)]
+        carried += [_shape(v) for v in eqn.outvars[
+            :eqn.params["num_carry"]]].count(pool_shape)
+    # k and v, in the decode pass and in the prefill branch
+    assert carried == (4 if scan_layers else 0)
+
+    reqs = _mixed_requests(prompts[:4], max_new=4)
+    out_ref = _drain(Scheduler(DecodeEngine(model, params, ecfg,
+                                            use_pallas=False)), reqs)
+    with dispatch.force_pallas():
+        eng = DecodeEngine(model, params, ecfg)
+        assert eng.fused and eng.fused_prefill
+        out_fused = _drain(Scheduler(eng),
+                           _mixed_requests(prompts[:4], max_new=4))
+    for rid, comp in out_ref.items():
+        assert out_fused[rid].tokens == comp.tokens, rid
 
 
 def test_fused_engine_churn_compile_count_pinned(kernel_tiny):

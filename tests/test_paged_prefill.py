@@ -32,6 +32,7 @@ from ray_lightning_tpu.ops.pallas.paged_prefill import (
 )
 from ray_lightning_tpu.serve.engine import DecodeEngine, EngineConfig
 from ray_lightning_tpu.serve.scheduler import Request, Scheduler
+from tests.utils import POOL_FORMS, pool_form
 
 
 # ---- op-level parity matrix ------------------------------------------------
@@ -45,6 +46,7 @@ def _rand_case(rng, B, CH, H, hd, Hkv, P, M, N, dtype=jnp.float32):
     return q, pk, pv, tables
 
 
+@pytest.mark.parametrize("form", POOL_FORMS)
 @pytest.mark.parametrize("B,CH,H,hd,Hkv,P,M,N,pos", [
     (2, 16, 4, 64, 2, 8, 4, 10, 8),    # GQA 2:1, mid-prompt chunk
     (1, 8, 8, 64, 8, 16, 2, 7, 0),     # MHA, 16-token blocks, chunk 0
@@ -52,15 +54,19 @@ def _rand_case(rng, B, CH, H, hd, Hkv, P, M, N, dtype=jnp.float32):
     (2, 12, 4, 64, 2, 8, 4, 9, 16),    # chunk 12: not a power of two
 ])
 def test_kernel_matches_reference_matrix(B, CH, H, hd, Hkv, P, M, N,
-                                         pos):
+                                         pos, form):
     """The parity matrix: block_size x chunk width x GQA ratio, with
-    causal in-chunk masking, interpret mode on CPU."""
+    causal in-chunk masking, interpret mode on CPU; over the 4-D pool
+    and over the stacked pool read at a (traced) layer index, by the
+    kernel and by the XLA reference alike."""
     rng = np.random.default_rng(B * 100 + CH)
     q, pk, pv, tables = _rand_case(rng, B, CH, H, hd, Hkv, P, M, N)
     ref = paged_prefill_reference(q, pk, pv, tables, pos)
-    got = paged_prefill_pallas(q, pk, pv, tables, pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    fk, fv, at = pool_form(pk, pv, form)
+    for fn in (paged_prefill_pallas, paged_prefill_reference):
+        got = jax.jit(fn)(q, fk, fv, tables, pos, **at)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_kernel_ragged_pad_masking_matches_reference():

@@ -65,9 +65,22 @@ BLOCKS = [8, 16, 32]
 SPAN = 1024                               # tokens per slot, held constant
 
 
+FORMS = ["pool", "stack"]                 # 4-D pool; 5-D stack + layer
+
+
+def _pool_operands(form, pool_shape, s):
+    """The K/V operands and layer kwargs of one form: the 4-D pool, or
+    a 3-layer stack read at a TRACED layer index."""
+    if form == "pool":
+        return _sds(pool_shape, jnp.bfloat16, s), {}
+    return (_sds((3, *pool_shape), jnp.bfloat16, s),
+            {"layer": _sds((), jnp.int32, s)})
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("heads,hd,p",
                          itertools.product(HEADS, HEAD_DIMS, BLOCKS))
-def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p):
+def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p, form):
     from ray_lightning_tpu.ops.pallas.paged_attention import (
         paged_attention_pallas, paged_shapes_supported,
     )
@@ -80,17 +93,18 @@ def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p):
                     "reference lane")
     s = SingleDeviceSharding(v5e[0])
     bf, i32 = jnp.bfloat16, jnp.int32
+    pool, at = _pool_operands(form, (nb, p, hkv, hd), s)
     compiled = jax.jit(paged_attention_pallas).lower(
-        _sds((c, h, hd), bf, s), _sds((nb, p, hkv, hd), bf, s),
-        _sds((nb, p, hkv, hd), bf, s), _sds((c, m), i32, s),
-        _sds((c,), i32, s), _sds((c,), i32, s)).compile()
+        _sds((c, h, hd), bf, s), pool, pool, _sds((c, m), i32, s),
+        _sds((c,), i32, s), _sds((c,), i32, s), **at).compile()
     assert _n_mosaic(compiled) == 1
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize(
     "heads,hd,p,ch",
     itertools.product(HEADS, HEAD_DIMS, BLOCKS, [64, 128]))
-def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch):
+def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch, form):
     from ray_lightning_tpu.ops.pallas.paged_prefill import (
         paged_prefill_pallas, paged_prefill_shapes_supported,
     )
@@ -104,10 +118,10 @@ def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch):
                     "reference lane")
     s = SingleDeviceSharding(v5e[0])
     bf, i32 = jnp.bfloat16, jnp.int32
+    pool, at = _pool_operands(form, (nb, p, hkv, hd), s)
     compiled = jax.jit(paged_prefill_pallas).lower(
-        _sds((b, ch, h, hd), bf, s), _sds((nb, p, hkv, hd), bf, s),
-        _sds((nb, p, hkv, hd), bf, s), _sds((b, m), i32, s),
-        _sds((), i32, s), _sds((b,), i32, s)).compile()
+        _sds((b, ch, h, hd), bf, s), pool, pool, _sds((b, m), i32, s),
+        _sds((), i32, s), _sds((b,), i32, s), **at).compile()
     assert _n_mosaic(compiled) == 1
 
 
@@ -188,10 +202,13 @@ def test_train_step_compiles(v5e, as_on_tpu, plan):
 
 # ---- the serving step -------------------------------------------------------
 
-def _serve_step_lowered(v5e, tp: int):
+def _serve_step_lowered(v5e, tp: int, param_dtype=None, **engine):
     """Lower `build_step` as `DecodeEngine` jits it, params and pool
     abstract. tp == 1: one device, both fused lanes. tp > 1: a tensor
-    mesh, where the engine takes the reference lanes."""
+    mesh, where the engine takes the reference lanes. ``engine``
+    overrides fields of the smoke's `EngineConfig`; ``param_dtype``
+    casts the (float32-initialised) weights, as a served checkpoint
+    is."""
     import chip_smoke
     from ray_lightning_tpu.models.llama import Llama, LlamaConfig
     from ray_lightning_tpu.ops.attention import (
@@ -205,7 +222,7 @@ def _serve_step_lowered(v5e, tp: int):
 
     size = chip_smoke.SmokeSize.full()
     cfg = LlamaConfig(**size.model)
-    ecfg = EngineConfig(**size.engine)
+    ecfg = EngineConfig(**{**size.engine, **engine})
     model = Llama(cfg)
     spec = ecfg.pool_spec
     pool_shape = (cfg.n_layers, spec.n_blocks, spec.block_size,
@@ -213,14 +230,17 @@ def _serve_step_lowered(v5e, tp: int):
     a_params = jax.eval_shape(
         model.init, jax.random.key(0),
         jnp.zeros((1, 8), jnp.int32))["params"]
+    if param_dtype is not None:
+        a_params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, param_dtype), a_params)
     if tp == 1:
         repl = pool_sh = SingleDeviceSharding(v5e[0])
         param_sh = jax.tree.map(lambda _: repl, a_params)
         fused = paged_attention_uses_pallas(
             (ecfg.capacity, cfg.n_heads, cfg.head_dim), pool_shape[1:])
         fused_prefill = paged_prefill_uses_pallas(
-            (1, ecfg.prefill_chunk, cfg.n_heads, cfg.head_dim),
-            pool_shape[1:])
+            (ecfg.prefill_batch, ecfg.prefill_chunk, cfg.n_heads,
+             cfg.head_dim), pool_shape[1:])
         assert fused and fused_prefill
     else:
         mesh = make_mesh(tensor=tp, devices=v5e[:tp])
@@ -234,7 +254,10 @@ def _serve_step_lowered(v5e, tp: int):
     runtime = (
         np.zeros((C, spec.blocks_per_slot), np.int32), np.zeros(C, np.int32),
         np.zeros(C, bool), np.zeros(C, np.float32), np.zeros(C, np.int32),
-        np.zeros((C, 2), np.uint32), *idle_prefill(ecfg))
+        np.zeros((C, 2), np.uint32),
+        # the batched-prefill step also takes the per-slot left pad
+        *([np.zeros(C, np.int32)] if ecfg.prefill_batch > 1 else []),
+        *idle_prefill(ecfg))
     step = jax.jit(
         build_step(model, ecfg, fused=fused, fused_prefill=fused_prefill),
         donate_argnums=(1, 2, 3))
@@ -249,6 +272,85 @@ def test_serving_step_compiles_on_one_chip(v5e, as_on_tpu):
     compiled = _serve_step_lowered(v5e, tp=1).compile()
     # one decode + one prefill kernel per scanned layer body
     assert _n_mosaic(compiled) >= 2
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+              "u64": 8}
+#: results that move nothing: a program's own arguments, tuple plumbing,
+#: the loops and the branch that CARRY the pool, and a relabelled buffer
+_MOVES_NOTHING = {"parameter", "tuple", "get-tuple-element", "while",
+                  "conditional", "bitcast"}
+
+
+def _materialised_results(hlo: str, floor: int):
+    """(opcode, name) of every instruction of an optimized HLO module
+    that is not inside a fusion and whose result holds an array of
+    ``floor`` bytes or more. A fusion is named by its root
+    (`fusion:scatter`): what it writes is what its root writes."""
+    import re
+
+    comp_of, root_of, fused, rows = {}, {}, set(), []
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s+(ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(",
+                     line)
+        if not m:
+            continue
+        root, name, shape, opcode = m.groups()
+        if root:
+            root_of[comp] = opcode
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        if opcode == "fusion" and called:
+            fused.add(called.group(1))
+            comp_of[name] = called.group(1)
+        size = max((_HLO_BYTES.get(dt, 0) * int(np.prod(
+            [int(d) for d in dims.split(",")]))
+            for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]+)\]", shape)),
+            default=0)
+        if size >= floor:
+            rows.append((comp, opcode, name))
+    return [(f"fusion:{root_of.get(comp_of[name])}" if opcode == "fusion"
+             else opcode, name)
+            for comp, opcode, name in rows if comp not in fused]
+
+
+@pytest.mark.parametrize("prefill_batch", [1, 2])
+def test_serving_step_moves_no_layer_of_the_pool(v5e, as_on_tpu,
+                                                 prefill_batch):
+    """ISSUE 25: the stacked pool is carried through the layer scan and
+    the kernels index the layer, so the compiled fused step holds no
+    instruction that moves a layer's K pool (67 MB here) or more: the
+    only results of that size are arguments, tuples, the loops and the
+    branch that carry the pool, relabelled buffers, and the scatters
+    that write a tick's token rows into the carried stack. That those
+    are in place is what the second assertion says: the compiler plans
+    less than ONE K pool of temporaries (it planned a whole second
+    K + V pool while the pool rode the scan as xs / ys)."""
+    import chip_smoke
+
+    capacity = 32
+    compiled = _serve_step_lowered(
+        v5e, tp=1, param_dtype=jnp.bfloat16, capacity=capacity,
+        prefill_batch=prefill_batch).compile()
+    assert _n_mosaic(compiled) >= 2
+    size = chip_smoke.SmokeSize.full()
+    m, e = size.model, size.engine
+    layer_bytes = ((1 + capacity * e["blocks_per_slot"]) * e["block_size"]
+                   * m["n_kv_heads"] * (m["dim"] // m["n_heads"]) * 2)
+    assert layer_bytes >= 64 * 1000**2
+    moved = [row for row in _materialised_results(compiled.as_text(),
+                                                  layer_bytes)
+             if row[0] not in _MOVES_NOTHING
+             and row[0] not in ("scatter", "fusion:scatter",
+                                "fusion:bitcast")]
+    assert not moved, moved
+    k_pool = m["n_layers"] * layer_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < k_pool
 
 
 def test_serving_step_lowers_under_tensor_parallel(v5e, as_on_tpu):

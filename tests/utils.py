@@ -263,3 +263,19 @@ def predict_test(trainer: Trainer, module: TpuModule, data=None):
     acc = float((y_all == data["y"][:n]).mean())
     assert acc >= 0.5, f"accuracy {acc} < 0.5"
     return acc
+
+
+#: the forms in which the paged kernels may be handed the KV pool
+POOL_FORMS = ["pool-4d", "stack-layer-0", "stack-last-layer"]
+
+
+def pool_form(pk, pv, form, n_layers=3):
+    """The pool as the kernels may be handed it: the 4-D pool itself,
+    or one layer of a 5-D stack whose OTHER layers are NaN, so that a
+    wrong layer index cannot pass. Returns (k, v, layer kwargs)."""
+    if form == "pool-4d":
+        return pk, pv, {}
+    layer = 0 if form == "stack-layer-0" else n_layers - 1
+    nan = jnp.full((n_layers,) + pk.shape, jnp.nan, pk.dtype)
+    return (nan.at[layer].set(pk), nan.at[layer].set(pv),
+            {"layer": jnp.int32(layer)})
