@@ -36,6 +36,28 @@ def load_module(path: str, name: str):
     return module
 
 
+def checkout_of(path: str) -> str:
+    """The checkout a file of `benchmarks/<directory>/` lies in."""
+    return os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(path))))
+
+
+#: the directories whose files are found by a configuration's `"model"`
+MODEL_PARTS = ("models", "reference", "tables")
+
+
+def load_model_file(root: str, part: str, model: str):
+    """`<root>/benchmarks/<part>/<model>.py` of the architecture a
+    configuration names: its adapter (`models`), its plain reference
+    (`reference`) or its tables of leaves and counts (`tables`). No file of
+    the harness names a model; a new one enters as these three files."""
+    if part not in MODEL_PARTS:
+        raise BenchError(f"a model has no part {part!r}; it has {MODEL_PARTS}")
+    return load_module(
+        os.path.join(root, "benchmarks", part, model + ".py"),
+        f"benchmarks_{part}_{model}")
+
+
 def say(tag: str, **fields: Any) -> None:
     print(f"[{tag}] " + " ".join(f"{k}={v}" for k, v in fields.items()),
           flush=True)
@@ -156,6 +178,8 @@ class RunRecord:
     seconds: float
     chips: int
     peaks: dict
+    #: the checkout the run's files were found in (`run.py`'s `root`)
+    root: str = ""
     setup_s: float = 0.0
     attempted: int = 0
     failed: int = 0
@@ -171,6 +195,11 @@ class RunRecord:
     memory_peak_bytes: int = 0
     compiles_in_window: int = 0
     reference_s: float = 0.0
+
+    def model_tables(self):
+        """`benchmarks/tables/<model>.py` of the run's configuration: the
+        counts a reader of operations or bytes needs."""
+        return load_model_file(self.root, "tables", self.config["model"])
 
 
 def percentile(values, q: float) -> float:

@@ -45,20 +45,30 @@ from benchmarks.harness import shapes, trace
 from benchmarks.harness.common import BenchError, say
 from benchmarks.harness.trace import Interval, Named
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+BDIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HOST_PREFIX = "rlt."
 KERNEL_PREFIX = "rlt_"
-#: the scopes the program opens, innermost wins (a block's `attn` lies
-#: inside the serving scan's `kv_pool`)
-SCOPES = ("fused_ce", "optimizer", "kv_pool", "sample", "lm_head", "attn",
-          "mlp")
 UNSCOPED = "unscoped"
 METADATA_PLANE = "/host:metadata"
 DISPATCH = {"serve": "rlt.serve.dispatch", "train": "rlt.dispatch"}
 ENGINE_PHASES = ("rlt.serve.put", "rlt.serve.dispatch", "rlt.serve.fetch")
 
 _COMPONENT = re.compile(r"[A-Za-z_][A-Za-z0-9_.]*")
+
+
+def scope_names(bdir: str = BDIR) -> Tuple[str, ...]:
+    """The `jax.named_scope`s the program opens, as data: one file a scope,
+    `<bdir>/scopes/<scope>.json` (where it is opened and what reads it). A
+    PR that opens a new scope in the program adds its file; a name the
+    program never opens matches no name stack and changes no share."""
+    names = sorted(n[:-len(".json")]
+                   for n in os.listdir(os.path.join(bdir, "scopes"))
+                   if n.endswith(".json"))
+    bad = [n for n in names if not _COMPONENT.fullmatch(n)]
+    if bad:
+        raise BenchError(f"benchmarks/scopes/ names {bad}, which no component "
+                         "of a name stack can equal")
+    return tuple(names)
 
 
 # ---- events -----------------------------------------------------------------
@@ -77,7 +87,7 @@ class HostEvent:
 class Op:
     """One device op event under the program's names: `kernel` is the
     Pallas kernel's `name=` (None for any other op), `scope` the innermost
-    of `SCOPES` on its name stack (`UNSCOPED` if none)."""
+    of `scope_names()` on its name stack (`UNSCOPED` if none)."""
     name: str                  # trace.short_name of the instruction
     start: float
     end: float
@@ -109,10 +119,16 @@ class ProgramTrace:
 # ---- names ------------------------------------------------------------------
 
 
-def innermost_scope(path: str, scopes: Sequence[str] = SCOPES) -> str:
-    """The last of `scopes` among the components of a name stack
+def innermost_scope(path: str, scopes: Optional[Sequence[str]] = None
+                    ) -> str:
+    """The last of `scopes` (default: `scope_names()`) among the components
+    of a name stack
     (`jit(step)/transpose(jvp(fused_ce))/while/body/dot_general`): a
-    transform wraps a component in parentheses, it does not rename it."""
+    transform wraps a component in parentheses, it does not rename it.
+    Innermost wins: a block's `attn` lies inside the serving scan's
+    `kv_pool`."""
+    if scopes is None:
+        scopes = scope_names()
     best, at = UNSCOPED, -1
     for m in _COMPONENT.finditer(path):
         if m.group(0) in scopes and m.start() > at:
@@ -133,7 +149,8 @@ def instruction_of(event_name: str) -> str:
     return head[1:] if head.startswith("%") else head
 
 
-def resolve(event_name: str, hlo: Dict[str, Tuple[str, str]]
+def resolve(event_name: str, hlo: Dict[str, Tuple[str, str]],
+            scopes: Optional[Sequence[str]] = None
             ) -> Tuple[str, Optional[str], str]:
     """(short name, kernel, scope) of one distinct op event; `hlo` maps an
     instruction's name to its (opcode, op_name)."""
@@ -145,7 +162,8 @@ def resolve(event_name: str, hlo: Dict[str, Tuple[str, str]]
     if opcode == "custom-call" or (
             not opcode and "custom-call(" in event_name):
         kernel = kernel_in(path) or kernel_in(instr)
-    return trace.short_name(event_name), kernel, innermost_scope(path)
+    return (trace.short_name(event_name), kernel,
+            innermost_scope(path, scopes))
 
 
 # ---- the HLO the trace carries ----------------------------------------------
@@ -251,12 +269,15 @@ def find_xplane(root: str, cell: str) -> Optional[str]:
 def from_events(device_events: Dict[int, Dict[str, List[Named]]],
                 host_events: Sequence[tuple],
                 programs: Dict[str, Dict[str, Tuple[str, str]]],
-                chips: int) -> ProgramTrace:
+                chips: int, scopes: Optional[Sequence[str]] = None
+                ) -> ProgramTrace:
     """A `ProgramTrace` from plain lists: per chip `{"ops": [(instruction
     text, start, end)], "modules": [(name, start, end)]}`, host events
     `(name, start, end, thread, stats)`, and the HLO tables. An op's names
     are resolved once per distinct instruction text: its metadata is the
     same at every execution."""
+    if scopes is None:
+        scopes = scope_names()
     devices: Dict[int, Device] = {}
     for idx, raw in device_events.items():
         modules = list(raw.get("modules", ()))
@@ -268,7 +289,7 @@ def from_events(device_events: Dict[int, Dict[str, List[Named]]],
         for text, s, e in raw.get("ops", ()):
             got = names.get(text)
             if got is None:
-                got = names[text] = resolve(text, hlo)
+                got = names[text] = resolve(text, hlo, scopes)
             ops.append(Op(got[0], s, e, got[1], got[2]))
         devices[idx] = Device(ops=ops, modules=modules)
     used = [devices[i] for i in sorted(devices) if devices[i].ops][:chips]
@@ -276,7 +297,8 @@ def from_events(device_events: Dict[int, Dict[str, List[Named]]],
     return ProgramTrace(devices=used, host=sorted(host, key=lambda e: e.start))
 
 
-def load_xplane(path: str, chips: int) -> ProgramTrace:
+def load_xplane(path: str, chips: int,
+                scopes: Optional[Sequence[str]] = None) -> ProgramTrace:
     """Host events named `rlt.*` with their stats, device op events under
     the program's names."""
     import jax
@@ -307,7 +329,7 @@ def load_xplane(path: str, chips: int) -> ProgramTrace:
                             ev.name, ev.start_ns * 1e-9,
                             (ev.start_ns + ev.duration_ns) * 1e-9, thread,
                             dict(ev.stats)))
-    pt = from_events(device_events, host_events, programs, chips)
+    pt = from_events(device_events, host_events, programs, chips, scopes)
     pt.load_s = time.perf_counter() - t0
     return pt
 
@@ -503,6 +525,10 @@ def build_tables(pt: ProgramTrace, kind: str) -> Tables:
         kernels={k: (v[0], int(v[1])) for k, v in kernels.items()})
 
 
+def _run_scopes(run) -> Tuple[str, ...]:
+    return scope_names(os.path.join(run.root, "benchmarks"))
+
+
 def tables(run) -> Optional[Tables]:
     """The run's tables, or None where there is nothing to read: the run
     was not traced, or the program is older than its names. Built by the
@@ -515,10 +541,10 @@ def tables(run) -> Optional[Tables]:
 
 
 def _read_tables(run) -> Optional[Tables]:
-    path = find_xplane(ROOT, run.cell["name"])
+    path = find_xplane(run.root, run.cell["name"])
     if path is None:
         return None
-    pt = load_xplane(path, run.chips)
+    pt = load_xplane(path, run.chips, _run_scopes(run))
     t0 = time.perf_counter()
     if not pt.host and not pt.kernels:
         say("program", names="none", load_s=round(pt.load_s, 2),
@@ -609,25 +635,21 @@ def scope_share_pct(run, scope: str) -> Optional[float]:
         return None
     if set(tb.scopes) <= {UNSCOPED}:
         raise BenchError("no device op carries one of the program's scopes "
-                         f"{SCOPES}: the op events' name stack was not found")
+                         f"{_run_scopes(run)}: the op events' name stack was "
+                         "not found")
     return 100.0 * tb.scopes.get(scope, 0.0) / tb.step_device_s
 
 
-def _attention_dims(hp: dict) -> dict:
-    return {"heads": hp["num_attention_heads"],
-            "kv_heads": hp["num_key_value_heads"],
-            "head_dim": hp["head_dim"]}
-
-
-def _counter(stats: Dict[str, object], name: str) -> int:
+def counter(stats: Dict[str, object], name: str) -> int:
+    """A counter of one `rlt.serve.dispatch` event, by name."""
     if name not in stats:
         raise BenchError(f"rlt.serve.dispatch carries no counter {name!r}; "
                          f"it has {sorted(stats)}")
     return int(stats[name])
 
 
-def _paired_kernel_seconds(tb: Tables, kernel: str, keep
-                           ) -> Tuple[float, list]:
+def paired_kernel_seconds(tb: Tables, kernel: str, keep
+                          ) -> Tuple[float, list]:
     """Device seconds of `kernel` inside the paired executions `keep`
     accepts, and those executions' dispatch counters."""
     picked = [(run, ev) for run, ev in tb.pairs if keep(ev.stats)]
@@ -641,14 +663,15 @@ def paged_decode_roofline_pct(run) -> Optional[float]:
     if tb is None:
         return None
     need_kernels(tb, ["rlt_paged_decode"])
-    seconds, stats = _paired_kernel_seconds(
-        tb, "rlt_paged_decode", lambda s: _counter(s, "decode_slots") > 0)
-    dims, layers = _attention_dims(run.hp), run.hp["num_hidden_layers"]
+    seconds, stats = paired_kernel_seconds(
+        tb, "rlt_paged_decode", lambda s: counter(s, "decode_slots") > 0)
+    model = run.model_tables()
+    dims, layers = model.attention_dims(run.hp), model.attention_layers(run.hp)
     work = []
     for s in stats:
         # `paged_decode` sums the contexts and counts the slots
-        contexts = ([_counter(s, "kv_tokens")]
-                    + [0] * (_counter(s, "decode_slots") - 1))
+        contexts = ([counter(s, "kv_tokens")]
+                    + [0] * (counter(s, "decode_slots") - 1))
         one = shapes.paged_decode(contexts, **dims)
         work.append({k: layers * v for k, v in one.items()})
     return roofline_pct(work, seconds, run.peaks)
@@ -659,13 +682,14 @@ def paged_prefill_roofline_pct(run) -> Optional[float]:
     if tb is None:
         return None
     need_kernels(tb, ["rlt_paged_prefill"])
-    seconds, stats = _paired_kernel_seconds(
-        tb, "rlt_paged_prefill", lambda s: _counter(s, "prefill_rows") > 0)
-    dims, layers = _attention_dims(run.hp), run.hp["num_hidden_layers"]
+    seconds, stats = paired_kernel_seconds(
+        tb, "rlt_paged_prefill", lambda s: counter(s, "prefill_rows") > 0)
+    model = run.model_tables()
+    dims, layers = model.attention_dims(run.hp), model.attention_layers(run.hp)
     work = []
     for s in stats:
-        one = shapes.paged_prefill(_counter(s, "prefill_rows"),
-                                   _counter(s, "prefill_ctx"), **dims)
+        one = shapes.paged_prefill(counter(s, "prefill_rows"),
+                                   counter(s, "prefill_ctx"), **dims)
         work.append({k: layers * v for k, v in one.items()})
     return roofline_pct(work, seconds, run.peaks)
 
@@ -682,10 +706,11 @@ def flash_roofline_pct(run) -> Optional[float]:
         return None
     need_kernels(tb, FLASH_KERNELS)
     seconds = sum(tb.kernels[k][0] for k in FLASH_KERNELS)
+    model = run.model_tables()
     one = shapes.flash_fwd_bwd(
         run.traffic["batch"] / run.chips, run.stamps["seq"],
-        **_attention_dims(run.hp))
-    layers = run.hp["num_hidden_layers"]
+        **model.attention_dims(run.hp))
+    layers = model.attention_layers(run.hp)
     work = [{k: layers * v for k, v in one.items()}] * len(tb.runs[0])
     return roofline_pct(work, seconds, run.peaks)
 

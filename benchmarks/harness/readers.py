@@ -7,7 +7,7 @@ from __future__ import annotations
 import statistics
 from typing import Optional
 
-from benchmarks.harness import shapes, trace
+from benchmarks.harness import trace
 
 
 def device_idle_share_pct(run) -> Optional[float]:
@@ -65,11 +65,12 @@ def queue_wait_p95_ms(run) -> Optional[float]:
 
 
 def mfu_pct(run) -> Optional[float]:
-    """Tokens/s x FLOPs/token (recompute not counted) over chips x peak."""
+    """Tokens/s x the model's own FLOPs/token (`tables/<model>.py`;
+    recompute not counted) over chips x peak."""
     tps = run.end_to_end.get("train_tokens_per_s")
     if not tps:
         return None
-    fpt = shapes.train_flops_per_token(run.hp, run.stamps["seq"])
+    fpt = run.model_tables().train_flops_per_token(run.hp, run.stamps["seq"])
     return 100.0 * tps * fpt / (run.chips * run.peaks["bf16_flops_per_s"])
 
 

@@ -156,7 +156,8 @@ def run(ctx: dict, loop: str) -> RunRecord:
     hp = adapter.hyperparams(ctx["config"], "serve")
     rec = RunRecord(kind=traffic["kind"], cell=ctx["cell"],
                     config=ctx["config"], traffic=traffic, hp=hp,
-                    seconds=seconds, chips=ctx["chips"], peaks=ctx["peaks"])
+                    seconds=seconds, chips=ctx["chips"], peaks=ctx["peaks"],
+                    root=ctx["root"])
     vocab = hp["vocab_size"]
     if loop == "open":
         plan = traffic_gen.open_loop(traffic, vocab, ctx["seed"], seconds)
@@ -273,7 +274,9 @@ def run(ctx: dict, loop: str) -> RunRecord:
     sample = pick_sample(finished, int(traffic["check"]["n_requests"]),
                          ctx["seed"])
     rows_cap = -(-max(r.plan.max_new_tokens for r in reqs) // 128) * 128
-    check = check_tokens(hp, ctx["seed"], sample, outputs,
+    ref = common.load_model_file(ctx["root"], "reference",
+                                 ctx["config"]["model"])
+    check = check_tokens(ref, hp, ctx["seed"], sample, outputs,
                          control=ctx.get("control"), rows_cap=rows_cap)
     rec.reference_s = time.perf_counter() - t_ref
     limit = float(traffic["check"]["gap_limit"])
@@ -334,22 +337,25 @@ def _bucket(n: int) -> int:
     return max(512, 1 << (int(n) - 1).bit_length())
 
 
-def reference_logits(hp: dict, seed: int, sequences, rows_cap: int,
+def reference_logits(ref, hp: dict, seed: int, sequences, rows_cap: int,
                      quant=None):
-    """For each (tokens, first_row, n_rows): the reference's logits
-    [n_rows, V] at rows first_row.. of its full forward pass over `tokens`.
-    Layer by layer, one layer's float32 weights resident at a time; rows past
-    a sequence's end are padding the causal mask keeps out of sight."""
+    """For each (tokens, first_row, n_rows): the logits [n_rows, V] of the
+    plain reference `ref` at rows first_row.. of its full forward pass over
+    `tokens`. Layer by layer, one layer's float32 weights resident at a
+    time, one jitted maker and one jitted `layer` a kind of layer; rows
+    past a sequence's end are padding the causal mask keeps out of sight."""
     import jax
     import jax.numpy as jnp
 
-    ref = common.load_module(
-        __file__.replace("harness/serving.py", "reference/dense_decoder.py"),
-        "benchmarks_reference_dense_decoder")
+    tables = ref.tables
+    kinds = tables.layer_kinds(hp)
     s32 = weights.seed_u32(seed)
-    make_layer = jax.jit(lambda s, l: weights.layer_weights(hp, s, l, True))
-    make_globals = jax.jit(lambda s: weights.global_weights(hp, s, True))
-    layer_fn = jax.jit(lambda w, x: ref.layer(hp, w, x, quant))
+    make_layer = {k: jax.jit(lambda s, l, k=k: weights.leaves(
+        hp, tables.layer_table(hp, k), s, l, True)) for k in set(kinds)}
+    make_globals = jax.jit(lambda s: weights.leaves(
+        hp, tables.global_table(hp), s, 0, True))
+    layer_fn = {k: jax.jit(lambda w, x, k=k: ref.layer(hp, k, w, x, quant))
+                for k in set(kinds)}
 
     def head(g, x, first):
         rows = jnp.take(x, first + jnp.arange(rows_cap), axis=0, mode="clip")
@@ -362,15 +368,15 @@ def reference_logits(hp: dict, seed: int, sequences, rows_cap: int,
         padded = np.zeros(_bucket(len(tokens)), np.int32)
         padded[: len(tokens)] = tokens
         xs.append(ref.embed(g, jnp.asarray(padded)))
-    for layer in range(hp["num_hidden_layers"]):
-        w = make_layer(s32, jnp.uint32(layer))
-        xs = [layer_fn(w, x) for x in xs]
+    for layer, kind in enumerate(kinds):
+        w = make_layer[kind](s32, jnp.uint32(layer))
+        xs = [layer_fn[kind](w, x) for x in xs]
     return [head_fn(g, x, jnp.int32(first))[:n]
             for x, (_t, first, n) in zip(xs, sequences)]
 
 
-def check_tokens(hp: dict, seed: int, sample: List[_Req], outputs: dict,
-                 control=None, rows_cap: int = 512) -> dict:
+def check_tokens(ref, hp: dict, seed: int, sample: List[_Req],
+                 outputs: dict, control=None, rows_cap: int = 512) -> dict:
     """The widest gap by which a served greedy token's reference logit lies
     below the reference's best, over every served token of the sample. With
     `control` (a `quant` function) it reads beside it the gap of the token
@@ -385,7 +391,7 @@ def check_tokens(hp: dict, seed: int, sample: List[_Req], outputs: dict,
         tokens = np.concatenate([r.plan.prompt, out])
         seqs.append((tokens, r.plan.prompt.size - 1, out.size))
         served.append(out)
-    logits = reference_logits(hp, seed, seqs, rows_cap)
+    logits = reference_logits(ref, hp, seed, seqs, rows_cap)
 
     def widest(tokens_by_request):
         per_request = []
@@ -400,7 +406,7 @@ def check_tokens(hp: dict, seed: int, sample: List[_Req], outputs: dict,
            "tokens": int(sum(len(t) for t in served)),
            "longest": int(max(len(s[0]) for s in seqs))}
     if control is not None:
-        low = reference_logits(hp, seed, seqs, rows_cap, quant=control)
+        low = reference_logits(ref, hp, seed, seqs, rows_cap, quant=control)
         per_control = widest([np.asarray(jnp.argmax(l, axis=-1))
                               for l in low])
         out.update(control_gap=max(per_control), control_per_request=per_control)

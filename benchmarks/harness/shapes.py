@@ -1,30 +1,14 @@
-"""Operations and bytes from shapes: what the algorithm needs, whatever the
-program does. Kept with the benchmark so that no PR that claims a gain can
-change them.
+"""Operations and bytes of one kernel call from its shapes: what the
+algorithm needs, whatever the program does. Kept with the benchmark so that
+no PR that claims a gain can change them.
 
-`train_flops_per_token` is copied from `bench.py:_flops_per_token`.
+What depends on an architecture (its matmul parameters and FLOPs a token,
+the dims and the number of its attention layers) is in
+`benchmarks/tables/<model>.py`. A new kernel's work function goes into a new
+file (its reader's, or a new one beside this) and ends in
+`roofline_seconds`.
 """
 from __future__ import annotations
-
-
-def matmul_params(hp: dict) -> int:
-    """Parameters that take part in a matrix product per token: every
-    projection of every layer and the output head (the embedding is a
-    gather)."""
-    d, hd = hp["hidden_size"], hp["head_dim"]
-    h, kv, f = (hp["num_attention_heads"], hp["num_key_value_heads"],
-                hp["intermediate_size"])
-    per_layer = d * (h + 2 * kv) * hd + h * hd * d + 3 * d * f
-    return hp["num_hidden_layers"] * per_layer + d * hp["vocab_size"]
-
-
-def train_flops_per_token(hp: dict, seq: int) -> float:
-    """Model FLOPs per trained token: 6 x matmul parameters plus causal
-    attention (QK^T and AV at an average context of S/2; forward x2,
-    backward x4). Recomputed operations are not counted."""
-    attn = (6 * hp["num_hidden_layers"] * hp["num_attention_heads"]
-            * hp["head_dim"] * seq)
-    return 6.0 * matmul_params(hp) + attn
 
 
 def flash_fwd_bwd(batch: int, seq: int, heads: int, kv_heads: int,

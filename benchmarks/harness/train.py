@@ -22,7 +22,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from benchmarks.harness import common, trace, traffic_gen, weights
+from benchmarks.harness import adamw, common, trace, traffic_gen, weights
 from benchmarks.harness.common import RunRecord, say
 
 B1 = 0.9          # Adam's first-moment decay, as LlamaModule configures it
@@ -37,10 +37,13 @@ def _strategy(spec: dict):
 
 def _norms(tree) -> Dict[str, float]:
     """{leaf name: l2 norm} of a canonical {"layers", "globals"} tree of
-    device scalars."""
+    device scalars; a leaf under a kind of layer reads `<kind>.<leaf>`."""
     import jax
 
-    flat = {**tree["layers"], **tree["globals"]}
+    flat = {}
+    for group in ("layers", "globals"):
+        for path, v in jax.tree_util.tree_flatten_with_path(tree[group])[0]:
+            flat[".".join(str(k.key) for k in path)] = v
     return {k: float(v) for k, v in jax.device_get(flat).items()}
 
 
@@ -57,20 +60,16 @@ def _canonical_norms_fn(adapter, hp):
     return jax.jit(norms)
 
 
-def _canonical_delta_fn(adapter, hp):
+def _canonical_delta_fn(adapter, ref, hp):
     """Norm of (parameters now - parameters as made from the seed), by
-    canonical leaf; the seeded values are regenerated leaf by leaf inside the
-    reduction, never held."""
+    canonical leaf; the seeded values are regenerated from the reference's
+    tables leaf by leaf inside the reduction, never held."""
     import jax
     import jax.numpy as jnp
 
-    n = hp["num_hidden_layers"]
-
     def delta(tree, s32):
         canon = adapter.canonical_from_program(hp, tree)
-        first = {"layers": weights.layer_weights(
-                     hp, s32, jnp.arange(n, dtype=jnp.uint32), False),
-                 "globals": weights.global_weights(hp, s32, False)}
+        first = weights.canonical(hp, ref.tables, s32, False)
         return jax.tree.map(
             lambda a, b: jnp.sqrt(jnp.sum(jnp.square(
                 a.astype(jnp.float32) - b))), canon, first)
@@ -81,6 +80,30 @@ def _canonical_delta_fn(adapter, hp):
 # ---- the plain reference's three steps --------------------------------------
 
 
+def batch_loss_and_grads(ref, hp: dict, params: dict, batch, quant=None,
+                         constrain=None):
+    """Mean of the reference's `sequence_loss` over all tokens of batch
+    [G, R, S + 1], and its gradients. The G groups run one after another;
+    the R rows of a group run side by side (one a chip, where `constrain`
+    pins the row axis to the chips). A row's forward pass is recomputed in
+    its backward pass, so one row's logits are live at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    n_tokens = batch.shape[0] * batch.shape[1] * (batch.shape[2] - 1)
+    row_loss = jax.checkpoint(
+        lambda p, row: ref.sequence_loss(hp, p, row, quant))
+
+    def total(p):
+        loss = jnp.float32(0.0)
+        for g in range(batch.shape[0]):
+            rows = batch[g] if constrain is None else constrain(batch[g])
+            loss = loss + jnp.sum(jax.vmap(row_loss, (None, 0))(p, rows))
+        return loss / n_tokens
+
+    return jax.value_and_grad(total)(params)
+
+
 class ReferencePrograms:
     """The plain reference's jitted pieces for one set of devices: seeded
     parameters (sharded over the devices on each leaf's widest axis), loss
@@ -88,24 +111,16 @@ class ReferencePrograms:
     run side by side, one a chip), AdamW on one leaf, and the parameters'
     change since the seed."""
 
-    def __init__(self, hp: dict, traffic: dict, devices, quant=None):
+    def __init__(self, ref, hp: dict, traffic: dict, devices, quant=None):
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-        ref = common.load_module(
-            __file__.replace("harness/train.py",
-                             "reference/dense_decoder.py"),
-            "benchmarks_reference_dense_decoder")
         n_dev = self.n_dev = len(devices)
         mesh = Mesh(np.asarray(devices), ("x",))
-        n_layers = hp["num_hidden_layers"]
 
         def make(s32):
-            return {"layers": weights.layer_weights(
-                        hp, s32, jnp.arange(n_layers, dtype=jnp.uint32),
-                        False),
-                    "globals": weights.global_weights(hp, s32, False)}
+            return weights.canonical(hp, ref.tables, s32, False)
 
         def shard(x):
             axes = [None] * x.ndim
@@ -126,8 +141,8 @@ class ReferencePrograms:
             lambda x: jnp.sqrt(jnp.sum(jnp.square(x))), t)
 
         def grads_of(params, batch):
-            loss, grads = ref.batch_loss_and_grads(
-                hp, params, batch, quant,
+            loss, grads = batch_loss_and_grads(
+                ref, hp, params, batch, quant,
                 constrain=lambda r: jax.lax.with_sharding_constraint(
                     r, rows_sh))
             return loss, grads, l2(grads)
@@ -136,24 +151,24 @@ class ReferencePrograms:
         self.grads_of = jax.jit(
             grads_of, out_shardings=(self.repl, self.p_sh, None))
         self.update = jax.jit(
-            lambda p, g, m, v, count: ref.adamw_leaf(
+            lambda p, g, m, v, count: adamw.adamw_leaf(
                 p, g, m, v, count,
-                ref.warmup_cosine_lr(count, lr, warm, total),
+                adamw.warmup_cosine_lr(count, lr, warm, total),
                 b1=B1, b2=0.95, weight_decay=wd),
             donate_argnums=(0, 2, 3))
         self.delta = jax.jit(
             lambda p, s: l2(jax.tree.map(jnp.subtract, p, make(s))))
 
 
-def reference_three_steps(hp: dict, seed: int, batches: np.ndarray,
+def reference_three_steps(ref, hp: dict, seed: int, batches: np.ndarray,
                           traffic: dict, devices, quant=None) -> dict:
     """Losses of the first three steps, per-leaf norms of the first gradient
     and of the parameters' change after the three, by the plain reference
-    (float32, `highest`), on the same seeded weights and rows."""
+    `ref` (float32, `highest`), on the same seeded weights and rows."""
     import jax
     import jax.numpy as jnp
 
-    prog = ReferencePrograms(hp, traffic, devices, quant)
+    prog = ReferencePrograms(ref, hp, traffic, devices, quant)
     s32 = weights.seed_u32(seed)
     leaves, treedef = jax.tree.flatten(prog.make(s32))
     shardings = jax.tree.leaves(prog.p_sh)
@@ -221,7 +236,7 @@ def compare(program: dict, reference: dict) -> dict:
 # ---- the window -------------------------------------------------------------
 
 
-def _window_callback(ctx, rec: RunRecord, adapter, hp):
+def _window_callback(ctx, rec: RunRecord, adapter, ref, hp):
     from ray_lightning_tpu.core.callbacks import Callback
 
     traffic = ctx["traffic"]
@@ -230,7 +245,7 @@ def _window_callback(ctx, rec: RunRecord, adapter, hp):
     trace_steps = int(traffic.get("trace_steps", 3))
     seconds = rec.seconds
     norms_fn = _canonical_norms_fn(adapter, hp)
-    delta_fn = _canonical_delta_fn(adapter, hp)
+    delta_fn = _canonical_delta_fn(adapter, ref, hp)
     s32 = weights.seed_u32(ctx["seed"])
     compiles = ctx["compile_counter"]
 
@@ -331,9 +346,11 @@ def run(ctx: dict) -> RunRecord:
 
     adapter, traffic = ctx["adapter"], ctx["traffic"]
     hp = adapter.hyperparams(ctx["config"], "train")
+    ref = common.load_model_file(ctx["root"], "reference",
+                                 ctx["config"]["model"])
     rec = RunRecord(kind="train", cell=ctx["cell"], config=ctx["config"],
                     traffic=traffic, hp=hp, seconds=float(ctx["seconds"]),
-                    chips=ctx["chips"], peaks=ctx["peaks"])
+                    chips=ctx["chips"], peaks=ctx["peaks"], root=ctx["root"])
     batch, seq = int(traffic["batch"]), int(traffic["seq"])
     tokens = traffic_gen.train_tokens(hp["vocab_size"], ctx["seed"],
                                       int(traffic["rows"]), seq)
@@ -342,7 +359,7 @@ def run(ctx: dict) -> RunRecord:
     _cfg, module = adapter.training_module(ctx["config"], hp, ctx["seed"],
                                            strategy, traffic)
     loader = DataLoader({"tokens": tokens}, batch_size=batch, prefetch=True)
-    window = _window_callback(ctx, rec, adapter, hp)
+    window = _window_callback(ctx, rec, adapter, ref, hp)
     trainer = Trainer(
         strategy=strategy, max_epochs=10 ** 6,
         log_every_n_steps=int(traffic["log_every_n_steps"]),
@@ -390,7 +407,7 @@ def run(ctx: dict) -> RunRecord:
     gc.collect()
     t_ref = time.perf_counter()
     first = tokens[: 3 * batch].reshape(3, batch, seq + 1)
-    reference = reference_three_steps(hp, ctx["seed"], first, traffic,
+    reference = reference_three_steps(ref, hp, ctx["seed"], first, traffic,
                                       ctx["devices"])
     reference_s = rec.reference_s = time.perf_counter() - t_ref
     print("[reference] " + json.dumps(reference), flush=True)

@@ -1,15 +1,19 @@
-"""Seeded weights of a dense decoder, made on the device by integer hashing.
+"""Seeded weights, made on the device by integer hashing.
 
-One canonical layout (the published one: separate q/k/v/o and gate/up/down
-projections, each stored [in, out]) serves the program's adapter and the
-plain reference alike, so neither takes anything the other has made. A
-value depends only on (seed, leaf, layer, position in the leaf): a stacked
-[L, ...] leaf and the same layer made alone agree bit for bit, which lets
-the reference build one layer at a time.
+The hash is the harness's; which leaves a model has is the model's: a table
+`{leaf: {"id": n, "shape": (..)} | {"fill": c, "shape": (..)}}` from
+`benchmarks/tables/<model>.py` (`layer_table(hp, kind)`, `global_table(hp)`,
+`layer_kinds(hp)`). One canonical layout serves the program's adapter and
+the plain reference alike, so neither takes anything the other has made. A
+value depends only on (seed, leaf id, layer, position in the leaf): a
+stacked [L, ...] leaf and the same layer made alone agree bit for bit, which
+lets the reference build one layer at a time. A leaf may have any rank (a
+matrix, a vector, experts stacked in front) as long as one layer of it has
+fewer than 2**32 elements.
 
-Values are uniform on (-a, a) with a = std * sqrt(3). `round_bf16` rounds
-them to bfloat16-representable numbers (the serving checkpoint's type);
-the reference then upcasts the same numbers exactly.
+Hashed values are uniform on (-a, a) with a = std * sqrt(3). `round_bf16`
+rounds them to bfloat16-representable numbers (the serving checkpoint's
+type); the reference then upcasts the same numbers exactly.
 """
 from __future__ import annotations
 
@@ -58,36 +62,36 @@ def _leaf_key(seed, leaf_id: int, layer):
                    + (layer + jnp.uint32(1)) * jnp.uint32(0x7FEB352D))
 
 
-def layer_shapes(hp: dict) -> Dict[str, Tuple[int, int]]:
-    d, hd = hp["hidden_size"], hp["head_dim"]
-    h, kv, f = (hp["num_attention_heads"], hp["num_key_value_heads"],
-                hp["intermediate_size"])
-    return {"q_proj": (d, h * hd), "k_proj": (d, kv * hd),
-            "v_proj": (d, kv * hd), "o_proj": (h * hd, d),
-            "gate_proj": (d, f), "up_proj": (d, f), "down_proj": (f, d)}
-
-
-def global_shapes(hp: dict) -> Dict[str, Tuple[int, int]]:
-    d, v = hp["hidden_size"], hp["vocab_size"]
-    return {"embed_tokens": (v, d), "lm_head": (d, v)}
-
-
-def layer_weights(hp: dict, seed, layer, round_bf16: bool):
-    """Canonical weights of layer(s) `layer` (a scalar, or an [L] vector for
-    the stacked leaves). Norm gains are ones."""
+def leaves(hp: dict, table: Dict[str, dict], seed, layer, round_bf16: bool):
+    """The leaves of one table for layer(s) `layer`: a scalar, or an [L]
+    vector of layer indices for stacked leaves (globals take layer 0). A
+    `fill` leaf is that constant (norm gains are ones)."""
     std = float(hp.get("initializer_std", 0.02))
     layer = jnp.asarray(layer, jnp.uint32)
-    out = {name: _uniform(_leaf_key(seed, i, layer), shape, std, round_bf16)
-           for i, (name, shape) in enumerate(layer_shapes(hp).items())}
-    ones = jnp.ones(layer.shape + (hp["hidden_size"],), jnp.float32)
-    out["input_layernorm"] = ones
-    out["post_attention_layernorm"] = ones
+    out = {}
+    for name, spec in table.items():
+        shape = tuple(spec["shape"])
+        if "fill" in spec:
+            out[name] = jnp.full(layer.shape + shape, spec["fill"],
+                                 jnp.float32)
+        else:
+            out[name] = _uniform(_leaf_key(seed, spec["id"], layer), shape,
+                                 std, round_bf16)
     return out
 
 
-def global_weights(hp: dict, seed, round_bf16: bool):
-    std = float(hp.get("initializer_std", 0.02))
-    out = {name: _uniform(_leaf_key(seed, 100 + i, 0), shape, std, round_bf16)
-           for i, (name, shape) in enumerate(global_shapes(hp).items())}
-    out["norm"] = jnp.ones((hp["hidden_size"],), jnp.float32)
-    return out
+def canonical(hp: dict, tables, seed, round_bf16: bool):
+    """The whole canonical tree {"layers": .., "globals": ..}: each kind's
+    leaves stacked over the layers of that kind, in layer order. A model of
+    one kind has no kind level, {"layers": {leaf: [L, ...]}}; one of more
+    has {"layers": {kind: {leaf: [n_kind, ...]}}}."""
+    kinds = tables.layer_kinds(hp)
+    stacks = {}
+    for kind in dict.fromkeys(kinds):
+        ids = [i for i, k in enumerate(kinds) if k == kind]
+        stacks[kind] = leaves(hp, tables.layer_table(hp, kind), seed,
+                              jnp.asarray(ids, jnp.uint32), round_bf16)
+    layers = next(iter(stacks.values())) if len(stacks) == 1 else stacks
+    return {"layers": layers,
+            "globals": leaves(hp, tables.global_table(hp), seed, 0,
+                              round_bf16)}

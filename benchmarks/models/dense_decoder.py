@@ -5,7 +5,8 @@ the seeded canonical weights -> the program's parameter tree.
 The only file of the benchmark that knows the program's model layout
 (`models/llama.py`: fused `wqkv` and `w_gate_up`, layers stacked for
 `lax.scan`). The reference it is compared with is the file of the same name
-under `benchmarks/reference/`.
+under `benchmarks/reference/`; the canonical leaves both are made from are
+the table of the same name under `benchmarks/tables/`.
 """
 from __future__ import annotations
 
@@ -14,7 +15,10 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from benchmarks.harness import weights
+from benchmarks.harness import common, weights
+
+tables = common.load_model_file(common.checkout_of(__file__), "tables",
+                                "dense_decoder")
 
 
 def hyperparams(config: dict, kind: str) -> dict:
@@ -55,10 +59,14 @@ def llama_config(config: dict, hp: dict, kind: str):
 def program_tree(hp: dict, seed, dtype, round_bf16: bool) -> Dict[str, Any]:
     """The program's parameter tree from the canonical seeded weights.
     Traceable: call it under `jax.jit` so the whole tree is one program."""
-    n = hp["num_hidden_layers"]
-    lw = weights.layer_weights(hp, seed, jnp.arange(n, dtype=jnp.uint32),
-                               round_bf16)
-    g = weights.global_weights(hp, seed, round_bf16)
+    return tree_from_canonical(
+        weights.canonical(hp, tables, seed, round_bf16), dtype)
+
+
+def tree_from_canonical(canon: dict, dtype) -> Dict[str, Any]:
+    """`models/llama.py`'s tree from {"layers": {leaf: [L, ...]},
+    "globals": ..} in the published layout."""
+    lw, g = canon["layers"], canon["globals"]
     cast = lambda x: x.astype(dtype)
     return {
         "tok_embed": {"embedding": cast(g["embed_tokens"])},

@@ -5,7 +5,9 @@ Written from the published equations in `jax.numpy`, float32, every matrix
 product at `Precision.HIGHEST`. No kernel, no KV cache, no batching; it
 imports nothing of `ray_lightning_tpu`. Weights arrive in the published
 layout (separate q/k/v/o, gate/up/down, each [in, out]) from the benchmark's
-seeded generator.
+seeded generator, which reads the leaves from `tables` (the file of this
+name under `benchmarks/tables/`, the adapter's table too); every layer is of
+the one kind that table has.
 
 Departures from "one forward pass over everything", all to fit the chip's
 memory and none changing the arithmetic: attention is computed in blocks of
@@ -13,7 +15,8 @@ query rows; the serving check calls `layer` once a layer so that one layer's
 float32 weights are resident at a time; the training reference recomputes a
 layer's activations (and, inside it, each attention block's scores) in its
 backward pass (`jax.checkpoint`) and walks the stacked layers with
-`lax.scan`.
+`lax.scan` (`harness/train.py` takes the gradients of `sequence_loss` over a
+batch, a row's forward pass recomputed in its backward pass).
 
 `quant` is the control's hook: a function applied to BOTH operands of every
 matrix product. `None` is the reference; `fp8_operands` rounds each operand
@@ -27,6 +30,12 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from benchmarks.harness import common
+
+#: the leaves (kinds of layer, ids, shapes, constants) the runners make the
+#: reference's weights from
+tables = common.load_model_file(common.checkout_of(__file__), "tables",
+                                "dense_decoder")
 HIGHEST = jax.lax.Precision.HIGHEST
 Quant = Optional[Callable[[jnp.ndarray], jnp.ndarray]]
 
@@ -88,8 +97,9 @@ def attention(q, k, v, quant: Quant, q_block: int = 512):
     return jnp.concatenate(out, axis=1).transpose(1, 0, 2)  # [S, H, hd]
 
 
-def layer(hp: dict, w: dict, x, quant: Quant = None):
-    """One decoder block on one sequence x [S, D]."""
+def layer(hp: dict, kind: str, w: dict, x, quant: Quant = None):
+    """One decoder block on one sequence x [S, D]; `kind` is the one kind
+    `tables.layer_kinds` names."""
     s = x.shape[0]
     h, kv, hd = (hp["num_attention_heads"], hp["num_key_value_heads"],
                  hp["head_dim"])
@@ -117,7 +127,7 @@ def head_logits(hp: dict, g: dict, x, quant: Quant = None):
                quant)
 
 
-# ---- training: loss, gradients, AdamW ---------------------------------------
+# ---- training: loss and gradients --------------------------------------------
 
 
 def sequence_loss(hp: dict, params: dict, tokens, quant: Quant = None):
@@ -128,55 +138,10 @@ def sequence_loss(hp: dict, params: dict, tokens, quant: Quant = None):
 
     @jax.checkpoint
     def body(x, w):
-        return layer(hp, w, x, quant), None
+        return layer(hp, tables.KIND, w, x, quant), None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
     logits = head_logits(hp, params["globals"], x, quant)
     logz = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
     return jnp.sum(logz - picked)
-
-
-def batch_loss_and_grads(hp: dict, params: dict, batch, quant: Quant = None,
-                         constrain=None):
-    """Mean loss over all tokens of batch [G, R, S + 1] and its gradients.
-    The G groups run one after another; the R rows of a group run side by
-    side (one a chip, where `constrain` pins the row axis to the chips). A
-    row's forward pass is recomputed in its backward pass, so one row's
-    logits are live at a time."""
-    n_tokens = batch.shape[0] * batch.shape[1] * (batch.shape[2] - 1)
-    row_loss = jax.checkpoint(
-        lambda p, row: sequence_loss(hp, p, row, quant))
-
-    def total(p):
-        loss = jnp.float32(0.0)
-        for g in range(batch.shape[0]):
-            rows = batch[g] if constrain is None else constrain(batch[g])
-            loss = loss + jnp.sum(jax.vmap(row_loss, (None, 0))(p, rows))
-        return loss / n_tokens
-
-    return jax.value_and_grad(total)(params)
-
-
-def adamw_leaf(p, g, m, v, count, lr, b1=0.9, b2=0.95, eps=1e-8,
-               weight_decay=0.1):
-    """AdamW as published (decoupled decay, bias-corrected moments) on one
-    leaf; `count` is the number of updates already made."""
-    t = count + 1
-    m = b1 * m + (1 - b1) * g
-    v = b2 * v + (1 - b2) * g * g
-    c1, c2 = 1 - b1 ** t, 1 - b2 ** t
-    p = p - lr * ((m / c1) / (jnp.sqrt(v / c2) + eps) + weight_decay * p)
-    return p, m, v
-
-
-def warmup_cosine_lr(count, peak, warmup_steps, total_steps):
-    """Linear warm-up from 0 to `peak`, then cosine decay to peak / 10 at
-    `total_steps`."""
-    count = jnp.asarray(count, jnp.float32)
-    warm = peak * count / max(warmup_steps, 1)
-    frac = jnp.clip((count - warmup_steps)
-                    / max(total_steps - warmup_steps, 1), 0.0, 1.0)
-    cos = 0.5 * (1 + jnp.cos(jnp.pi * frac))
-    decayed = peak * (0.1 + 0.9 * cos)
-    return jnp.where(count < warmup_steps, warm, decayed)

@@ -5,7 +5,9 @@
 Everything that belongs to one cell is found by name, as data:
   workload      -> its entry in BENCHMARK.json (config, traffic, chips)
   config        -> benchmarks/configs/<config>.json, whose "model" names the
-                   adapter benchmarks/models/<model>.py
+                   adapter benchmarks/models/<model>.py, the plain reference
+                   benchmarks/reference/<model>.py and the tables of leaves
+                   and counts benchmarks/tables/<model>.py
   traffic       -> benchmarks/traffic/<traffic>.json, whose "kind" names the
                    runner benchmarks/harness/<kind>.py
   per-layer     -> benchmarks/layer_metrics/<metric>.py, one reader each
@@ -67,9 +69,7 @@ def _run(args, root: str, common) -> int:
     devices, peaks = common.require_chips(root, int(cell["chips"]))
     t_chips = time.perf_counter()
     cache_dir = common.place_compile_cache(root)
-    adapter = common.load_module(
-        os.path.join(bdir, "models", config["model"] + ".py"),
-        "benchmarks_model_" + config["model"])
+    adapter = common.load_model_file(root, "models", config["model"])
     runner = common.load_module(
         os.path.join(bdir, "harness", traffic["kind"] + ".py"),
         "benchmarks_runner_" + traffic["kind"])

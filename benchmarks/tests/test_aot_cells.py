@@ -173,11 +173,12 @@ def test_reference_gradient_step_fits(v5e, as_on_tpu, config, traffic,
     Mistral's size)."""
     from benchmarks.harness import train
     from benchmarks.models import dense_decoder as adapter
+    from benchmarks.reference import dense_decoder as ref
 
     cfg_file, tr = _load(f"configs/{config}.json"), _load(
         f"traffic/{traffic}.json")
     hp = adapter.hyperparams(cfg_file, "train")
-    prog = train.ReferencePrograms(hp, tr, v5e[:chips])
+    prog = train.ReferencePrograms(ref, hp, tr, v5e[:chips])
     a_params = jax.tree.map(_sds, prog.shapes, prog.p_sh)
     batch = jax.ShapeDtypeStruct(
         (tr["batch"] // chips, chips, tr["seq"] + 1), jnp.int32,

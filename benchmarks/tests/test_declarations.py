@@ -44,10 +44,9 @@ def test_every_cell_is_files(cell):
     config = common.load_json(os.path.join(ROOT, entry["file"]))
     assert config["source"] == entry["source"]
     assert config["reduced"] == entry["reduced"]
-    assert os.path.isfile(os.path.join(BDIR, "models",
-                                       config["model"] + ".py"))
-    assert os.path.isfile(os.path.join(BDIR, "reference",
-                                       config["model"] + ".py"))
+    for part in common.MODEL_PARTS:
+        assert os.path.isfile(os.path.join(BDIR, part,
+                                           config["model"] + ".py")), part
     traffic = common.load_json(os.path.join(BDIR, "traffic",
                                             cell["traffic"] + ".json"))
     assert os.path.isfile(os.path.join(BDIR, "harness",
@@ -81,6 +80,56 @@ def test_the_reference_imports_nothing_of_the_program():
             with open(os.path.join(BDIR, "reference", name)) as fh:
                 assert "ray_lightning_tpu" not in fh.read().replace(
                     "`ray_lightning_tpu`", "")
+
+
+def test_the_harness_names_no_model():
+    """What knows one architecture is found by a configuration's `"model"`:
+    no file of `harness/`, `tools/` or `run.py` holds the name of a file in
+    `models/`, so a second architecture enters with new files alone."""
+    models = [n[:-3] for n in os.listdir(os.path.join(BDIR, "models"))
+              if n.endswith(".py")]
+    assert models
+    files = [os.path.join(BDIR, "run.py")]
+    for folder in ("harness", "tools"):
+        files += [os.path.join(BDIR, folder, n)
+                  for n in os.listdir(os.path.join(BDIR, folder))
+                  if n.endswith(".py")]
+    for path in files:
+        with open(path) as fh:
+            text = fh.read()
+        assert not [m for m in models if m in text], path
+
+
+def test_a_scope_enters_as_a_file(tmp_path):
+    """The scopes the trace reducer knows are `scopes/<scope>.json`: a new
+    file is a new scope, and one the program never opens changes nothing."""
+    import shutil
+
+    from benchmarks.harness import program_trace as pt
+
+    today = pt.scope_names()
+    assert set(today) == {"fused_ce", "optimizer", "kv_pool", "sample",
+                          "lm_head", "attn", "mlp"}
+    for name in today:
+        body = common.load_json(os.path.join(BDIR, "scopes", name + ".json"))
+        assert body["scope"] == name and body["opened_in"]
+    shutil.copytree(os.path.join(BDIR, "scopes"), tmp_path / "scopes")
+    (tmp_path / "scopes" / "router.json").write_text(
+        '{"scope": "router", "opened_in": "a later PR"}')
+    more = pt.scope_names(str(tmp_path))
+    assert set(more) == set(today) | {"router"}
+    path = "jit(step)/Llama/layers/while/body/mlp/router/top_k"
+    assert pt.innermost_scope(path) == "mlp"
+    assert pt.innermost_scope(path, more) == "router"
+    # through `resolve`, as a trace's op events are read
+    hlo = {"fusion.7": ("fusion", path)}
+    assert pt.resolve("%fusion.7 = f32[8]{0} fusion(...)", hlo, more)[2] == \
+        "router"
+    assert pt.innermost_scope("jit(step)/Llama/attn/wo/dot_general",
+                              more) == "attn"
+    (tmp_path / "scopes" / "not a name.json").write_text("{}")
+    with pytest.raises(common.BenchError):
+        pt.scope_names(str(tmp_path))
 
 
 def test_peaks_table_names_its_source():

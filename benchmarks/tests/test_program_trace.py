@@ -1,5 +1,6 @@
 """The reductions over the program's own names (`program_trace.py`) on
 hand-built events, and a synthetic trace of the four-chip cell's size."""
+import os
 import random
 import time
 
@@ -10,6 +11,8 @@ from benchmarks.harness import shapes
 from benchmarks.harness.common import BenchError, RunRecord
 from benchmarks.harness.program_trace import HostEvent, Op
 
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 HP = {"num_attention_heads": 16, "num_key_value_heads": 8, "head_dim": 128,
       "num_hidden_layers": 24}
@@ -202,8 +205,10 @@ def _serve_tables(kernel="rlt_paged_decode"):
 
 
 def _run(tables, kind="serve_open", **kw):
-    run = RunRecord(kind=kind, cell={"name": "cell"}, config={}, traffic={},
-                    hp=dict(HP), seconds=30.0, chips=1, peaks=PEAKS, **kw)
+    run = RunRecord(kind=kind, cell={"name": "cell"},
+                    config={"model": "dense_decoder"}, traffic={},
+                    hp=dict(HP), seconds=30.0, chips=1, peaks=PEAKS,
+                    root=ROOT, **kw)
     run.trace = object()
     run.stamps[pt._STAMP] = tables
     return run

@@ -14,28 +14,29 @@ from benchmarks.tests import tiny
 
 HP = adapter.hyperparams(tiny.TINY_CONFIG, "serve")
 SEED = 2 ** 31 + 5
+KIND = ref.tables.KIND
 
 
 def _canonical(round_bf16):
-    s = weights.seed_u32(SEED)
-    n = HP["num_hidden_layers"]
-    return {"layers": weights.layer_weights(
-                HP, s, jnp.arange(n, dtype=jnp.uint32), round_bf16),
-            "globals": weights.global_weights(HP, s, round_bf16)}
+    return weights.canonical(HP, ref.tables, weights.seed_u32(SEED),
+                             round_bf16)
+
+
+def _one_layer(seed, layer):
+    return weights.leaves(HP, ref.tables.layer_table(HP, KIND),
+                          weights.seed_u32(seed), jnp.uint32(layer), True)
 
 
 def test_stacked_and_single_layer_weights_agree_bit_for_bit():
     stacked = _canonical(True)["layers"]
     for layer in range(HP["num_hidden_layers"]):
-        one = weights.layer_weights(HP, weights.seed_u32(SEED),
-                                    jnp.uint32(layer), True)
+        one = _one_layer(SEED, layer)
         for k in one:
             assert np.array_equal(np.asarray(one[k]),
                                   np.asarray(stacked[k][layer])), k
     w = np.asarray(stacked["q_proj"])
     assert abs(w.std() - 0.05) < 0.005 and abs(w.mean()) < 3e-3
-    other = weights.layer_weights(HP, weights.seed_u32(SEED + 1),
-                                  jnp.uint32(0), True)
+    other = _one_layer(SEED + 1, 0)
     assert not np.array_equal(np.asarray(other["q_proj"]), w[0])
 
 
@@ -53,7 +54,7 @@ def test_reference_forward_matches_the_program():
     x = ref.embed(canon["globals"], jnp.asarray(tokens[0]))
     for layer in range(HP["num_hidden_layers"]):
         w = jax.tree.map(lambda a: a[layer], canon["layers"])
-        x = ref.layer(HP, w, x)
+        x = ref.layer(HP, KIND, w, x)
     got = ref.head_logits(HP, canon["globals"], x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
@@ -76,8 +77,8 @@ def three_steps():
     rows = np.random.default_rng(3).integers(0, 256, (3, 2, 33)).astype(
         np.int32)
     dev = jax.devices()[:1]
-    sound = train.reference_three_steps(hp, SEED, rows, traffic, dev)
-    low = train.reference_three_steps(hp, SEED, rows, traffic, dev,
+    sound = train.reference_three_steps(ref, hp, SEED, rows, traffic, dev)
+    low = train.reference_three_steps(ref, hp, SEED, rows, traffic, dev,
                                       quant=ref.fp8_operands)
     return traffic, sound, low
 
@@ -105,7 +106,7 @@ def test_a_part_of_the_batch_left_out_moves_the_loss(three_steps):
     hp = adapter.hyperparams(tiny.TINY_CONFIG, "train")
     rows = np.random.default_rng(3).integers(0, 256, (3, 2, 33)).astype(
         np.int32)
-    half = train.reference_three_steps(hp, SEED, rows[:, :1], traffic,
+    half = train.reference_three_steps(ref, hp, SEED, rows[:, :1], traffic,
                                        jax.devices()[:1])
     assert train.compare(half, sound)["loss_gap"] > 1e-3
 
@@ -121,8 +122,8 @@ def _greedy_sample():
         for _ in range(6):
             x = ref.embed(canon["globals"], jnp.asarray(toks, jnp.int32))
             for layer in range(HP["num_hidden_layers"]):
-                x = ref.layer(HP, jax.tree.map(lambda a: a[layer],
-                                               canon["layers"]), x)
+                x = ref.layer(HP, KIND, jax.tree.map(
+                    lambda a: a[layer], canon["layers"]), x)
             toks.append(int(jnp.argmax(
                 ref.head_logits(HP, canon["globals"], x[-1:])[0])))
         plan = serving.traffic_gen.PlannedRequest(
@@ -138,12 +139,12 @@ def test_serving_check_passes_sound_tokens_and_fails_an_altered_one():
     sample = _greedy_sample()
     reqs = [r for r, _ in sample]
     outputs = {r.plan.rid: toks for r, toks in sample}
-    sound = serving.check_tokens(HP, SEED, reqs, outputs)
+    sound = serving.check_tokens(ref, HP, SEED, reqs, outputs)
     assert sound["widest_gap"] <= 1e-5 and sound["tokens"] == 18
     broken = dict(outputs)
     broken["r1"] = list(broken["r1"])
     broken["r1"][2] = (broken["r1"][2] + 1) % 256
-    assert serving.check_tokens(HP, SEED, reqs, broken)["widest_gap"] > \
+    assert serving.check_tokens(ref, HP, SEED, reqs, broken)["widest_gap"] > \
         tiny.TRAFFIC["tiny_open"]["check"]["gap_limit"]
 
 
@@ -151,7 +152,7 @@ def test_serving_control_reads_a_wider_gap_than_sound_tokens():
     sample = _greedy_sample()
     reqs = [r for r, _ in sample]
     outputs = {r.plan.rid: toks for r, toks in sample}
-    chk = serving.check_tokens(HP, SEED, reqs, outputs,
+    chk = serving.check_tokens(ref, HP, SEED, reqs, outputs,
                                control=ref.fp8_operands)
     assert chk["control_gap"] > 3 * max(chk["widest_gap"], 1e-3)
 

@@ -2,6 +2,7 @@
 import pytest
 
 from benchmarks.harness import shapes
+from benchmarks.tables import dense_decoder as tables
 
 MISTRAL_2L = {"hidden_size": 4096, "head_dim": 128, "num_attention_heads": 32,
               "num_key_value_heads": 8, "intermediate_size": 14336,
@@ -11,9 +12,9 @@ PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 def test_train_flops_per_token():
     # a layer: 4096*6144 + 4096*4096 + 3*4096*14336 = 218,103,808
-    assert shapes.matmul_params(MISTRAL_2L) == 2 * 218103808 + 4096 * 32768
+    assert tables.matmul_params(MISTRAL_2L) == 2 * 218103808 + 4096 * 32768
     # 6 * 570,425,344 + 6*2*32*128*4096
-    assert shapes.train_flops_per_token(MISTRAL_2L, 4096) == pytest.approx(
+    assert tables.train_flops_per_token(MISTRAL_2L, 4096) == pytest.approx(
         6 * 570425344 + 201326592)
 
 

@@ -1,7 +1,14 @@
 """A throw-away benchmark in a temporary directory: a tiny configuration,
-traffic mixes and a metric added as NEW files and NEW entries, beside a copy
-of the benchmark's own files, none of which is edited. `run.py` is then
-driven end to end on the CPU with the device gate lifted by the test."""
+traffic mixes, a metric and a second architecture added as NEW files and NEW
+entries, beside a copy of the benchmark's own files, none of which is
+edited. `run.py` is then driven end to end on the CPU with the device gate
+lifted by the test.
+
+The second architecture, `two_kind` (`tests/two_kind/`: adapter, reference
+and tables), has layers of two kinds with the dense equations and other leaf
+ids. Its broken twin `two_kind_swapped` is the same adapter beside a
+reference that reads a table with two leaf ids swapped: its cells must come
+out `correct: false`."""
 import json
 import os
 import shutil
@@ -19,6 +26,9 @@ TINY_CONFIG = {
     "execution": {"remat": False, "scan_layers": True, "use_flash": True,
                   "fused_ce": True, "ce_chunk_tokens": 32},
 }
+TINY2_CONFIG = dict(TINY_CONFIG, name="tiny2", model="two_kind",
+                    num_hidden_layers=3)
+TINY2X_CONFIG = dict(TINY2_CONFIG, name="tiny2x", model="two_kind_swapped")
 ENGINE = {"capacity": 4, "block_size": 4, "blocks_per_slot": 16,
           "n_blocks": 48, "prefill_chunk": 8, "prefill_batch": 1}
 SAMPLING = {"greedy_every": 2, "temperature": 0.8, "top_k": 8,
@@ -71,14 +81,43 @@ def reduce(run):
 '''
 
 
+def _add_two_kind(bdir: str) -> None:
+    """The second architecture and its broken twin, as files only."""
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "two_kind")
+    text = {}
+    for part in ("adapter", "reference", "tables"):
+        with open(os.path.join(here, part + ".py")) as fh:
+            text[part] = fh.read()
+    swapped_ids = text["tables"].replace('"q_proj": 0, "k_proj": 1',
+                                         '"q_proj": 1, "k_proj": 0')
+    twin_reference = text["reference"].replace(
+        'MODEL = "two_kind"', 'MODEL = "two_kind_swapped"')
+    assert swapped_ids != text["tables"]
+    assert twin_reference != text["reference"]
+    files = {"models/two_kind.py": text["adapter"],
+             "reference/two_kind.py": text["reference"],
+             "tables/two_kind.py": text["tables"],
+             "models/two_kind_swapped.py": text["adapter"],
+             "reference/two_kind_swapped.py": twin_reference,
+             "tables/two_kind_swapped.py": swapped_ids}
+    for rel, body in files.items():
+        with open(os.path.join(bdir, rel), "w") as fh:
+            fh.write(body)
+
+
 def build(tmp: str) -> str:
     """Copy the benchmark into `tmp` and ADD the throw-away cells."""
     shutil.copytree(os.path.join(ROOT, "benchmarks"),
                     os.path.join(tmp, "benchmarks"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     bdir = os.path.join(tmp, "benchmarks")
-    with open(os.path.join(bdir, "configs", "tiny.json"), "w") as fh:
-        json.dump(TINY_CONFIG, fh)
+    _add_two_kind(bdir)
+    configs = {"tiny": TINY_CONFIG, "tiny2": TINY2_CONFIG,
+               "tiny2x": TINY2X_CONFIG}
+    for name, body in configs.items():
+        with open(os.path.join(bdir, "configs", name + ".json"), "w") as fh:
+            json.dump(body, fh)
     for name, body in TRAFFIC.items():
         with open(os.path.join(bdir, "traffic", name + ".json"), "w") as fh:
             json.dump(body, fh)
@@ -88,15 +127,22 @@ def build(tmp: str) -> str:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     cells = {"tiny.open": ("tiny_open", 1), "tiny.closed": ("tiny_closed", 1),
-             "tiny.train": ("tiny_train", 1), "tiny.fsdp": ("tiny_fsdp", 4)}
-    bench["configs"].append({"name": "tiny", "source": "test",
-                             "file": "benchmarks/configs/tiny.json",
-                             "reduced": [], "why": "test"})
+             "tiny.train": ("tiny_train", 1), "tiny.fsdp": ("tiny_fsdp", 4),
+             "tiny2.open": ("tiny_open", 1), "tiny2.closed": ("tiny_closed", 1),
+             "tiny2.train": ("tiny_train", 1),
+             "tiny2x.open": ("tiny_open", 1), "tiny2x.train": ("tiny_train", 1)}
+    for config in configs:
+        bench["configs"].append({"name": config, "source": "test",
+                                 "file": f"benchmarks/configs/{config}.json",
+                                 "reduced": [], "why": "test"})
     for name, (traffic, chips) in cells.items():
-        bench["workloads"].append({"name": name, "config": "tiny",
+        bench["workloads"].append({"name": name,
+                                   "config": name.split(".")[0],
                                    "traffic": traffic, "chips": chips,
                                    "why": "test"})
-    serve, train = ["tiny.open", "tiny.closed"], ["tiny.train", "tiny.fsdp"]
+    serve = [c for c, (traffic, _) in cells.items()
+             if TRAFFIC[traffic]["kind"] != "train"]
+    train = [c for c in cells if c not in serve]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" not in m:
             continue

@@ -42,12 +42,8 @@ def main() -> int:
         ROOT, "benchmarks", "traffic", cell["traffic"] + ".json"))
     devices, peaks = common.require_chips(ROOT, int(cell["chips"]))
     common.place_compile_cache(ROOT)
-    adapter = common.load_module(os.path.join(
-        ROOT, "benchmarks", "models", config["model"] + ".py"),
-        "benchmarks_model_" + config["model"])
-    ref = common.load_module(os.path.join(
-        ROOT, "benchmarks", "reference", config["model"] + ".py"),
-        "benchmarks_reference_control")
+    adapter = common.load_model_file(ROOT, "models", config["model"])
+    ref = common.load_model_file(ROOT, "reference", config["model"])
     counter = common.CompileCounter()
     known = {}
     if args.sound_from:
@@ -68,9 +64,10 @@ def main() -> int:
                 hp["vocab_size"], seed, 3 * batch, seq).reshape(
                     3, batch, seq + 1)
             sound = known.get(seed) or train.reference_three_steps(
-                hp, seed, first, traffic, devices)
-            low = train.reference_three_steps(hp, seed, first, traffic,
-                                              devices, quant=ref.fp8_operands)
+                ref, hp, seed, first, traffic, devices)
+            low = train.reference_three_steps(
+                ref, hp, seed, first, traffic, devices,
+                quant=ref.fp8_operands)
             row = {"seed": seed, "control": train.compare(low, sound),
                    "limits": traffic["check"]}
         else:

@@ -5,11 +5,14 @@ keep what they print. For the builder's chip calls:
     chiprun --chips 1 -- python3 benchmarks/tools/session.py plan.json
 
 plan.json: {"name": "...", "runs": [{"label", "workload", "seed", "seconds",
-"trace", "overrides": {"<traffic>": {"dotted.key": value}}, "describe": bool}]}
+"trace", "overrides": {"<traffic>": {"dotted.key": value}}, "describe": bool,
+"root": "_parent"}]}
 A run with `overrides` executes from a copy of the benchmark under
 `.bench_tmp/` whose traffic file has those keys replaced (a knee sweep is
-the same cell at other rates: data, not code). Output goes to
-`chiprun_out/<name>/`.
+the same cell at other rates: data, not code). A run with `root` executes
+another checkout unpacked inside this one (the parent commit from
+`git archive`, in a directory `.gitignore` lists), so that parent and change
+share one machine. Output goes to `chiprun_out/<name>/`.
 """
 import json
 import os
@@ -53,20 +56,24 @@ def main():
         plan = json.load(fh)
     out_dir = os.path.join(ROOT, "chiprun_out", plan["name"])
     os.makedirs(out_dir, exist_ok=True)
-    env = dict(os.environ, PYTHONPATH=ROOT)
+    env = dict(os.environ)
     env.setdefault("JAX_COMPILATION_CACHE_DIR",
                    os.path.join(ROOT, ".jax_cache"))
     summary = []
     for run in plan["runs"]:
+        # the program is imported from `home`: this checkout, or the other
+        # one; an override copy holds the benchmark's files only
+        home = os.path.join(ROOT, run["root"]) if run.get("root") else ROOT
         root = _override_root(run["label"], run["overrides"]) \
-            if run.get("overrides") else ROOT
+            if run.get("overrides") else home
         cmd = [sys.executable, os.path.join(root, "benchmarks", "run.py"),
                "--workload", run["workload"], "--seed", str(run["seed"]),
                "--seconds", str(run["seconds"]),
                "--trace", str(run.get("trace", 0))]
         t0 = time.time()
-        proc = subprocess.run(cmd, cwd=root, env=env, capture_output=True,
-                              text=True, timeout=run.get("timeout", 1500))
+        proc = subprocess.run(cmd, cwd=root, env=dict(env, PYTHONPATH=home),
+                              capture_output=True, text=True,
+                              timeout=run.get("timeout", 1500))
         wall = time.time() - t0
         with open(os.path.join(out_dir, run["label"] + ".out"), "w") as fh:
             fh.write(proc.stdout)
