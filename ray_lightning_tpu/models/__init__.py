@@ -23,6 +23,7 @@ from ray_lightning_tpu.models.llama import (
     generate,
     init_cache,
 )
+from ray_lightning_tpu.models.mla_moe import MlaMoe, MlaMoeConfig
 from ray_lightning_tpu.models.mlp import MLP, MLPClassifier, MNISTClassifier
 from ray_lightning_tpu.models.moe import (
     MoEClassifierModule,
@@ -50,6 +51,8 @@ __all__ = [
     "generate",
     "init_cache",
     "llama_params_from_hf",
+    "MlaMoe",
+    "MlaMoeConfig",
     "MLP",
     "MLPClassifier",
     "MNISTClassifier",

@@ -142,6 +142,13 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    def pool_leaf_shapes(self, n_blocks: int, block_size: int):
+        """The serving engine's paged pool for this model: a K and a V
+        leaf (`serve/kv_cache.py:init_pool`)."""
+        leaf = (self.n_layers, n_blocks, block_size, self.n_kv_heads,
+                self.head_dim)
+        return (leaf, leaf)
+
     @classmethod
     def llama3_8b(cls, **kw) -> "LlamaConfig":
         return cls(**{**dict(
@@ -450,6 +457,33 @@ class Llama(nn.Module):
 
     cfg: LlamaConfig
     mesh: Optional[Any] = None  # set by the strategy for seq/tensor islands
+
+    # ---- what the serving engine asks of a decoder (serve/engine.py) ----
+    #: device-side counts a paged call returns beside the pool: none
+    tick_counters = ()
+    #: serving modes the engine has to refuse for this decoder: none
+    serving_unsupported = ()
+
+    def serving_param_specs(self):
+        return llama_param_specs(self.cfg)
+
+    def paged_lanes(self, capacity: int, prefill_batch: int,
+                    prefill_chunk: int, pool_block, use_pallas):
+        """(decode, prefill): would the paged lanes take the kernels at
+        these shapes? The predicates the ops' own dispatch uses;
+        ``pool_block`` = (n_blocks, block_size)."""
+        from ray_lightning_tpu.ops.attention import (
+            paged_attention_uses_pallas,
+            paged_prefill_uses_pallas,
+        )
+
+        cfg = self.cfg
+        pool = (*pool_block, cfg.n_kv_heads, cfg.head_dim)
+        return (paged_attention_uses_pallas(
+                    (capacity, cfg.n_heads, cfg.head_dim), pool, use_pallas),
+                paged_prefill_uses_pallas(
+                    (prefill_batch, prefill_chunk, cfg.n_heads,
+                     cfg.head_dim), pool, use_pallas))
 
     @nn.compact
     def __call__(self, tokens: jnp.ndarray, cache=None, pos=None,

@@ -1,0 +1,65 @@
+"""The one place the serving stack names a model class.
+
+`serve/engine.py`, `serve/driver.py` and `serve/kv_cache.py` import
+nothing else under `models/`: a serving configuration's TYPE picks its
+decoder here, and everything the engine needs it asks of the decoder
+itself:
+
+    model.cfg                       vocab_size, max_seq_len, dtype, use_flash,
+                                    pool_leaf_shapes(n_blocks, P): the
+                                    paged pool's leaves
+    model.paged_lanes(...)          would the paged lanes take the kernels
+    model.serving_param_specs()     per-leaf placement of a sharded replica
+    model.tick_counters             device-side counts of a paged call
+    model.serving_unsupported       what the engine has to refuse
+    model(tokens, cache=, pos=, pad=, paged=)
+
+A new decoder enters with a config dataclass, a flax module with those
+members, and one row below.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib
+
+import numpy as np
+
+#: config class name -> (module, config class, decoder class)
+_DECODERS = {
+    "LlamaConfig": ("ray_lightning_tpu.models.llama", "LlamaConfig", "Llama"),
+    "MlaMoeConfig": ("ray_lightning_tpu.models.mla_moe", "MlaMoeConfig",
+                     "MlaMoe"),
+}
+
+
+def _row(config_type: str):
+    if config_type not in _DECODERS:
+        raise ValueError(
+            f"no serving decoder for a configuration of type "
+            f"{config_type!r}; models/serving.py has {sorted(_DECODERS)}")
+    module, config, decoder = _DECODERS[config_type]
+    mod = importlib.import_module(module)
+    return getattr(mod, config), getattr(mod, decoder)
+
+
+def serving_model(cfg):
+    """The flax decoder the configuration ``cfg`` names by its type."""
+    return _row(type(cfg).__name__)[1](cfg)
+
+
+def config_to_wire(cfg) -> dict:
+    """``cfg`` as plain values a replica process can be sent: its fields,
+    the dtype by name, and the type that `config_from_wire` keys on."""
+    kw = dataclasses.asdict(cfg)
+    kw["dtype"] = np.dtype(cfg.dtype).name
+    kw["config_type"] = type(cfg).__name__
+    return kw
+
+
+def config_from_wire(kw: dict):
+    import jax.numpy as jnp
+
+    kw = dict(kw)
+    config_cls, _ = _row(kw.pop("config_type", "LlamaConfig"))
+    dtype = kw.pop("dtype", "float32")
+    return config_cls(**kw, dtype=jnp.dtype(dtype))

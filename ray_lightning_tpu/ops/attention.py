@@ -432,3 +432,103 @@ def paged_attention(
             layer=layer)
     return paged_attention_reference(q, pool_k, pool_v, tables, lengths,
                                      pad=pad, scale=scale, layer=layer)
+
+
+# ---- latent attention (MLA) over the latent paged pool -----------------------
+#
+# One cached row a token a layer, ``[c_kv | k_r]``; every query head reads
+# the same row (scores over all ``Dk`` columns, value = the first
+# ``value_dim``). The views are the paged lanes' own (`PagedDecodeView`,
+# `PagedPrefillView`); the pool is ONE leaf ``[n_blocks, P, Dk]`` or the
+# stack ``[L, n_blocks, P, Dk]`` read at ``layer``.
+
+
+def _gather_latent(pool, tables, layer):
+    """A row's blocks as one dense ``[B, M * P, Dk]`` view (the copy the
+    kernels exist to retire)."""
+    rows = pool[tables] if pool.ndim == 3 else pool[layer, tables]
+    b, m, p, dk = rows.shape
+    return rows.reshape(b, m * p, dk)
+
+
+def _latent_sdpa(q, k, visible, value_dim, scale):
+    """q [B, R, H, Dk], k [B, T, Dk], visible [B, R, T] -> [B, R, H, Dv];
+    float32 scores and softmax, a fully masked query emits zeros."""
+    s = jnp.einsum("brhd,btd->brht", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(visible[:, :, None, :], s, -jnp.inf)
+    top = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.where(visible[:, :, None, :],
+                  jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0)), 0.0)
+    den = jnp.sum(e, axis=-1, keepdims=True)
+    p = e / jnp.where(den == 0.0, 1.0, den)
+    return jnp.einsum("brht,btv->brhv", p.astype(k.dtype),
+                      k[..., :value_dim],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def mla_decode_reference(q, pool, tables, lengths, value_dim: int,
+                         scale: float, layer=0):
+    """`jax.numpy` twin of `mla_decode_pallas`: q [C, H, Dk] -> [C, H, Dv],
+    slot c sees ``kv_pos < lengths[c]``."""
+    k = _gather_latent(pool, tables, layer)
+    visible = jnp.arange(k.shape[1])[None, None, :] < lengths[:, None, None]
+    return _latent_sdpa(q[:, None], k, visible, value_dim, scale)[:, 0]
+
+
+def mla_prefill_reference(q, pool, tables, pos, value_dim: int,
+                          scale: float, layer=0):
+    """`jax.numpy` twin of `mla_prefill_pallas`: q [B, CH, H, Dk], query j
+    sees ``kv_pos <= pos + j``."""
+    k = _gather_latent(pool, tables, layer)
+    q_pos = jnp.asarray(pos, jnp.int32) + jnp.arange(q.shape[1])
+    visible = jnp.arange(k.shape[1])[None, None, :] <= q_pos[None, :, None]
+    visible = jnp.broadcast_to(visible, (q.shape[0],) + visible.shape[1:])
+    return _latent_sdpa(q, k, visible, value_dim, scale)
+
+
+def mla_uses_pallas(q_shape, pool_shape, value_dim: int,
+                    use_pallas: bool | None = None) -> bool:
+    """Would `mla_decode` / `mla_prefill` take the pallas kernel for these
+    shapes? The one predicate the dispatch and the serving engine's
+    build-time lane decision share."""
+    from ray_lightning_tpu.ops import dispatch
+
+    if not dispatch.use_pallas(use_pallas):
+        return False
+    from ray_lightning_tpu.ops.pallas.mla_attention import (
+        mla_shapes_supported,
+    )
+
+    return mla_shapes_supported(q_shape, pool_shape, value_dim)
+
+
+def mla_decode(q, pool, tables, lengths, value_dim: int, scale: float,
+               use_pallas: bool | None = None, layer=0):
+    """Decode attention over the latent pool, q [C, H, Dk] -> [C, H, Dv]:
+    the fused kernel on TPU (or forced, interpreted elsewhere) when the
+    shapes tile, else the gathering reference."""
+    if mla_uses_pallas(q.shape, pool.shape, value_dim, use_pallas):
+        from ray_lightning_tpu.ops.pallas.mla_attention import (
+            mla_decode_pallas,
+        )
+
+        return mla_decode_pallas(q, pool, tables, lengths, value_dim,
+                                 scale, layer=layer)
+    return mla_decode_reference(q, pool, tables, lengths, value_dim, scale,
+                                layer=layer)
+
+
+def mla_prefill(q, pool, tables, pos, value_dim: int, scale: float,
+                use_pallas: bool | None = None, layer=0):
+    """Chunked causal prefill attention over the latent pool,
+    q [B, CH, H, Dk] -> [B, CH, H, Dv]; dispatch as `mla_decode`."""
+    if mla_uses_pallas(q.shape, pool.shape, value_dim, use_pallas):
+        from ray_lightning_tpu.ops.pallas.mla_attention import (
+            mla_prefill_pallas,
+        )
+
+        return mla_prefill_pallas(q, pool, tables, pos, value_dim, scale,
+                                  layer=layer)
+    return mla_prefill_reference(q, pool, tables, pos, value_dim, scale,
+                                 layer=layer)

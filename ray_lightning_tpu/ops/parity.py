@@ -176,3 +176,101 @@ def kernel_parity_errors(
         paged_prefill_reference(qc, pool_k, pool_v, tables[:rows], pos,
                                 pad=pad[:rows]))
     return errors
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentGeometry:
+    """The serving shapes the latent-attention kernels and the expert
+    product are checked at. The defaults are the published dims of the
+    DeepSeek-V3 family (128 heads, a 576-value row stored at 640, value
+    512; experts 7168 x 2048) at the benchmark cell's engine shape; off
+    the TPU pass a small one."""
+
+    capacity: int = 8
+    n_heads: int = 128
+    row_dim: int = 640
+    value_dim: int = 512
+    block_size: int = 128
+    blocks_per_slot: int = 16
+    prefill_chunk: int = 1024
+    layers: int = 2
+    experts: int = 16
+    hidden: int = 7168
+    width: int = 2048
+    rows: int = 512
+
+
+def latent_parity_errors(
+        g: LatentGeometry = LatentGeometry()) -> Dict[str, float]:
+    """name -> scale-relative error of `rlt_mla_decode`, `rlt_mla_prefill`
+    (ragged lengths, a stacked pool read at a traced layer) and the
+    grouped product (uneven groups, one empty, rows of no group) against
+    their `jax.numpy` twins, in the execution environment."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.ops.attention import (
+        mla_decode_reference,
+        mla_prefill_reference,
+    )
+    from ray_lightning_tpu.ops.grouped_matmul import grouped_matmul
+    from ray_lightning_tpu.ops.pallas.mla_attention import (
+        mla_decode_pallas,
+        mla_prefill_pallas,
+    )
+
+    rng = np.random.default_rng(11)
+    dt = jnp.bfloat16
+    C, M, P = g.capacity, g.blocks_per_slot, g.block_size
+    n_blocks = 1 + C * M
+    pool = jnp.asarray(rng.standard_normal(
+        (g.layers, n_blocks, P, g.row_dim), dtype=np.float32), dt)
+    tables = jnp.asarray(rng.integers(1, n_blocks, (C, M)).astype(np.int32))
+    scale = 0.1
+    layer = jnp.int32(g.layers - 1)
+    errors: Dict[str, float] = {}
+
+    q1 = jnp.asarray(rng.standard_normal(
+        (C, g.n_heads, g.row_dim), dtype=np.float32), dt)
+    lengths = jnp.asarray(rng.integers(1, M * P + 1, (C,)).astype(np.int32))
+    errors["mla_decode"] = _rel_err(
+        jax.jit(lambda *a: mla_decode_pallas(
+            *a[:4], g.value_dim, scale, layer=a[4]))(
+                q1, pool, tables, lengths, layer),
+        jax.jit(lambda *a: mla_decode_reference(
+            *a[:4], g.value_dim, scale, layer=a[4]))(
+                q1, pool, tables, lengths, layer))
+
+    qc = jnp.asarray(rng.standard_normal(
+        (1, g.prefill_chunk, g.n_heads, g.row_dim), dtype=np.float32), dt)
+    for name, pos in (("mla_prefill_first", 0),
+                      ("mla_prefill_last", M * P - g.prefill_chunk)):
+        args = (qc, pool, tables[:1], jnp.int32(pos), layer)
+        errors[name] = _rel_err(
+            jax.jit(lambda *a: mla_prefill_pallas(
+                *a[:4], g.value_dim, scale, layer=a[4]))(*args),
+            jax.jit(lambda *a: mla_prefill_reference(
+                *a[:4], g.value_dim, scale, layer=a[4]))(*args))
+
+    lhs = jnp.asarray(rng.standard_normal(
+        (g.rows, g.hidden), dtype=np.float32), dt)
+    rhs = jnp.asarray(rng.standard_normal(
+        (g.experts, g.hidden, g.width), dtype=np.float32)
+        * g.hidden ** -0.5, dt)
+    sizes = rng.multinomial(g.rows * 3 // 4,
+                            np.ones(g.experts - 1) / (g.experts - 1))
+    sizes = jnp.asarray(np.concatenate([[0], sizes]).astype(np.int32))
+    errors["grouped_matmul"] = _rel_err(
+        jax.jit(lambda *a: grouped_matmul(*a, use_pallas=True))(
+            lhs, rhs, sizes),
+        jax.jit(lambda *a: grouped_matmul(*a, use_pallas=False))(
+            lhs, rhs, sizes))
+    return errors
+
+
+if __name__ == "__main__":
+    import json
+
+    errs = latent_parity_errors()
+    print(json.dumps({"latent_parity": errs, "tolerance": TOLERANCE,
+                      "ok": all(e <= TOLERANCE for e in errs.values())}))

@@ -154,7 +154,7 @@ class ReplicaGroupConfig:
     flight_ring: int = 256
     #: flight ring persist cadence in recorded events
     flight_persist_every: int = 16
-    #: draft model config (models.llama.LlamaConfig) for speculative
+    #: draft model config (a `LlamaConfig`) for speculative
     #: decoding — arms together with ``engine.draft``; inline replicas
     #: only (the process respawn path reloads ONE params .npz and the
     #: wire carries no draft weights)
@@ -416,15 +416,13 @@ def _replica_worker_main(model_cfg_kw: dict, params_path: str,
     armed), announce live, then serve — streaming every token over the
     side channel so the driver holds partial streams when this process
     dies mid-request."""
-    import jax.numpy as jnp
-
-    from ray_lightning_tpu.models.llama import Llama, LlamaConfig
+    from ray_lightning_tpu.models.serving import (
+        config_from_wire, serving_model,
+    )
     from ray_lightning_tpu.runtime import session
 
     enable_persistent_cache(compile_cache_dir)
-    dtype = model_cfg_kw.pop("dtype", "float32")
-    cfg = LlamaConfig(**model_cfg_kw, dtype=jnp.dtype(dtype))
-    model = Llama(cfg)
+    model = serving_model(config_from_wire(model_cfg_kw))
     params = load_params_npz(params_path)
     t0 = time.perf_counter()
     engine = DecodeEngine(model, params, EngineConfig(**engine_kw))
@@ -498,9 +496,9 @@ def _replica_session_main(model_cfg_kw: dict, params_path: str,
     Results are BATCHED one side-channel item per tick (tokens,
     preemptions, completions, the command ack, evictions together) —
     the channel's documented discipline, lint-enforced as RLT504."""
-    import jax.numpy as jnp
-
-    from ray_lightning_tpu.models.llama import Llama, LlamaConfig
+    from ray_lightning_tpu.models.serving import (
+        config_from_wire, serving_model,
+    )
     from ray_lightning_tpu.runtime import session
     from ray_lightning_tpu.serve.channel import (
         ChannelReader, CursorReader, CursorWriter, request_from_wire,
@@ -508,9 +506,7 @@ def _replica_session_main(model_cfg_kw: dict, params_path: str,
     )
 
     enable_persistent_cache(compile_cache_dir)
-    dtype = model_cfg_kw.pop("dtype", "float32")
-    cfg = LlamaConfig(**model_cfg_kw, dtype=jnp.dtype(dtype))
-    model = Llama(cfg)
+    model = serving_model(config_from_wire(model_cfg_kw))
     params = load_params_npz(params_path)
     mesh = None
     if tp > 1:
@@ -806,7 +802,8 @@ class _ProcessReplica:
 class ServeDriver:
     """Multiplex request streams over ``cfg.n_replicas`` replicas.
 
-    ``model_cfg`` is a `models.llama.LlamaConfig`; ``params`` is the
+    ``model_cfg`` is the configuration of a decoder `models/serving.py`
+    knows (`LlamaConfig`, `MlaMoeConfig`); ``params`` is the
     weights pytree (inline) or a ``.npz`` path from `save_params_npz`
     (required for process replicas — the weight-reload path IS the
     respawn story). Requests are assigned round-robin at submission;
@@ -859,14 +856,14 @@ class ServeDriver:
 
     def _run_inline(self, requests: Sequence[Request],
                     fault: Optional[dict]) -> ServeResult:
-        from ray_lightning_tpu.models.llama import Llama
+        from ray_lightning_tpu.models.serving import serving_model
 
         enable_persistent_cache(self.cfg.compile_cache_dir)
         params = self.params
         if self.params_path is not None:
             params = load_params_npz(self.params_path)
-        model = Llama(self.model_cfg)
-        draft_model = (Llama(self.cfg.draft_model_cfg)
+        model = serving_model(self.model_cfg)
+        draft_model = (serving_model(self.cfg.draft_model_cfg)
                        if self.cfg.draft_model_cfg is not None else None)
         outputs: Dict[str, List[int]] = {}
         meta: Dict[str, dict] = {}
@@ -970,8 +967,9 @@ class ServeDriver:
         from ray_lightning_tpu.resilience.policy import classify_failure
         from ray_lightning_tpu.runtime.group import WorkerGroup
 
-        cfgkw = dataclasses.asdict(self.model_cfg)
-        cfgkw["dtype"] = np.dtype(self.model_cfg.dtype).name
+        from ray_lightning_tpu.models.serving import config_to_wire
+
+        cfgkw = config_to_wire(self.model_cfg)
         enginekw = dataclasses.asdict(self.cfg.engine)
         n = self.cfg.n_replicas
         assign: List[List[Request]] = [[] for _ in range(n)]
@@ -1206,11 +1204,11 @@ class ServeDriver:
                              "recovery")
         enable_persistent_cache(self.cfg.compile_cache_dir)
         if self.cfg.backend == "inline":
-            from ray_lightning_tpu.models.llama import Llama
+            from ray_lightning_tpu.models.serving import serving_model
 
-            self._model = Llama(self.model_cfg)
+            self._model = serving_model(self.model_cfg)
             self._draft_model = (
-                Llama(self.cfg.draft_model_cfg)
+                serving_model(self.cfg.draft_model_cfg)
                 if self.cfg.draft_model_cfg is not None else None)
         else:
             _require_chip_free_parent(self.cfg)
@@ -1789,8 +1787,9 @@ class ServeDriver:
         from ray_lightning_tpu.runtime.launch import _spmd_main
         from ray_lightning_tpu.serve.channel import request_to_wire
 
-        cfgkw = dataclasses.asdict(self.model_cfg)
-        cfgkw["dtype"] = np.dtype(self.model_cfg.dtype).name
+        from ray_lightning_tpu.models.serving import config_to_wire
+
+        cfgkw = config_to_wire(self.model_cfg)
         enginekw = dataclasses.asdict(self.cfg.engine)
         tp = self.cfg.tp
         fault = getattr(self, "_session_fault", None)

@@ -183,10 +183,20 @@ def _sample_one(logits, key, temp, top_k):
 
 def build_step(model, cfg: EngineConfig, fused: bool = False,
                fused_prefill: bool = False):
-    """The jitted continuous-batching step for ``model`` (a
-    `models.llama.Llama` instance) under ``cfg``. Returned uncompiled —
+    """The jitted continuous-batching step for ``model`` (a decoder of
+    `models.serving.serving_model`) under ``cfg``. Returned uncompiled —
     `DecodeEngine` jits it with the pool/logits donated; `serve.audit`
     traces it abstractly.
+
+    The model declares its pool: `model.cfg.pool_leaf_shapes(n_blocks,
+    block_size)` names the leaves (Llama: K and V ``[L, n_blocks, P,
+    Hkv, hd]``; a latent-attention decoder: one leaf ``[L, n_blocks, P,
+    row]``), and the step takes and returns them in that order between
+    ``params`` and ``last_logits``. On the fused lanes the model is
+    handed the leaves whole (``cache=pool``) beside a paged view and
+    hands them back. A model with `tick_counters` returns their values
+    from a paged call too; the step joins the two lanes' as each
+    counter says (sum / max) and returns them after ``emitted``.
 
     ``fused`` selects the decode lane at BUILD time (the dispatch
     decision is static, like a kernel choice — it can never retrace):
@@ -223,10 +233,27 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     """
     mcfg = model.cfg
     spec = cfg.pool_spec
-    L, HKV, HD = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
     C, P, G, CH = cfg.capacity, spec.block_size, spec.gathered_len, \
         cfg.prefill_chunk
     B = cfg.prefill_batch
+    n_pool = len(mcfg.pool_leaf_shapes(spec.n_blocks, spec.block_size))
+    counters = tuple(model.tick_counters)
+    if not (fused and fused_prefill):
+        # the reference lanes gather a dense K/V view: Llama's cache path
+        L, HKV, HD = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
+
+    def _paged_apply(params, tokens, pool, pos, pad, view):
+        # the model's paged call: (logits, pool) and, where the model
+        # counts, its counters' values
+        out = model.apply({"params": params}, tokens, cache=pool, pos=pos,
+                          pad=pad, paged=view)
+        return out[0], tuple(out[1]), (out[2] if counters else None)
+
+    def _join(a, b):
+        # a tick's two lanes' counts, each as its counter says
+        return jnp.stack([a[i] + b[i] if how == "sum"
+                          else jnp.maximum(a[i], b[i])
+                          for i, (_, how) in enumerate(counters)])
 
     def _decode_one(params, tok, kc, vc, pos):
         # the model's OWN single-token cache path ([1, 1] batch), new
@@ -264,10 +291,11 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         off = jnp.where(decoding, pos % P, 0)
         return bi, off
 
-    def _decode_reference(params, pool_k, pool_v, tables, pos, decoding,
+    def _decode_reference(params, pool, tables, pos, decoding,
                           emitted, slot_pad):
         # one dense gathered view per step — the copy the fused lane
         # retires (charged by serve_memory_summary on this path only)
+        pool_k, pool_v = pool
         with jax.named_scope("kv_pool"):
             gk = pool_k[:, tables].reshape(L, C, G, HKV, HD)
             gv = pool_v[:, tables].reshape(L, C, G, HKV, HD)
@@ -285,9 +313,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             bi, off = _write_index(tables, pos, decoding)
             pool_k = pool_k.at[:, bi, off].set(k_tok)
             pool_v = pool_v.at[:, bi, off].set(v_tok)
-        return pool_k, pool_v, logits2
+        return (pool_k, pool_v), logits2, None
 
-    def _decode_fused(params, pool_k, pool_v, tables, pos, decoding,
+    def _decode_fused(params, pool, tables, pos, decoding,
                       emitted, slot_pad):
         # the fused lane: the pool IS the cache — the model's paged
         # branch scatters the new K/V at the (scratch-redirected) write
@@ -303,10 +331,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         view = PagedDecodeView(tables=tables, lengths=pos + 1,
                                write_block=bi, write_offset=off,
                                use_pallas=True)
-        logits2, (pool_k, pool_v) = model.apply(
-            {"params": params}, emitted[:, None],
-            cache=(pool_k, pool_v), pos=pos, pad=slot_pad, paged=view)
-        return pool_k, pool_v, logits2[:, 0]
+        logits2, pool, counts = _paged_apply(
+            params, emitted[:, None], pool, pos, slot_pad, view)
+        return pool, logits2[:, 0], counts
 
     _decode = _decode_fused if fused else _decode_reference
 
@@ -322,12 +349,28 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         emitted = jax.vmap(_sample_one)(last_logits, sub, temp, top_k)
         return emitted, new_rngs
 
+    def _prefill_reference(params, pool, rows, n, tokens, prefill_pos,
+                           pad):
+        # gather the blocks of the group's ``n`` rows into a dense
+        # [L, n, G, Hkv, hd] view and run the model's chunked cache path
+        # over it (B = 1 takes the historical single-slot program, no
+        # pad anywhere)
+        pool_k, pool_v = pool
+        with jax.named_scope("kv_pool"):
+            kc = pool_k[:, rows].reshape(L, n, G, HKV, HD)
+            vc = pool_v[:, rows].reshape(L, n, G, HKV, HD)
+        logits, (nk, nv) = model.apply(
+            {"params": params}, tokens, cache=(kc, vc), pos=prefill_pos,
+            pad=pad)
+        return logits, nk, nv
+
     if B == 1:
-        def step(params, pool_k, pool_v, last_logits, tables, pos,
-                 decoding, temp, top_k, rngs, prefill_slot,
-                 prefill_tokens, prefill_pos, prefill_last_row):
-            """One engine tick. Donated: pool_k, pool_v, last_logits
-            (positions 1-3 of the signature; `DecodeEngine` owns them).
+        def step(params, *args):
+            """One engine tick: ``step(params, *pool, last_logits,
+            tables, pos, decoding, temp, top_k, rngs, prefill_slot,
+            prefill_tokens, prefill_pos, prefill_last_row)``. Donated:
+            the pool's leaves and last_logits (`DecodeEngine` owns
+            them).
 
             Host-owned runtime inputs (plain numpy per call):
               tables   [C, M] i32   slot -> pool block ids (0 = scratch)
@@ -341,22 +384,26 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                                     within this chunk (-1: prompt
                                     continues)
 
-            Returns (pool_k, pool_v, last_logits, rngs', emitted [C]
-            i32). ``emitted[s]`` is meaningful only where
-            ``decoding[s]`` — the scheduler masks by its own phase
-            bookkeeping.
+            Returns (*pool, last_logits, rngs', emitted [C] i32) and,
+            for a model that counts, counts [n] i32 after them.
+            ``emitted[s]`` is meaningful only where ``decoding[s]`` —
+            the scheduler masks by its own phase bookkeeping.
             """
+            pool = tuple(args[:n_pool])
+            (last_logits, tables, pos, decoding, temp, top_k, rngs,
+             prefill_slot, prefill_tokens, prefill_pos,
+             prefill_last_row) = args[n_pool:]
             # ---- decode lane: sample, then advance every slot --------
             emitted, new_rngs = _sample(last_logits, decoding, temp,
                                         top_k, rngs)
-            pool_k, pool_v, logits2 = _decode(
-                params, pool_k, pool_v, tables, pos, decoding, emitted,
-                None)
+            pool, logits2, counts = _decode(
+                params, pool, tables, pos, decoding, emitted, None)
             last_logits = jnp.where(decoding[:, None], logits2,
                                     last_logits)
 
             # ---- prefill lane: one chunk for one admitting slot ------
-            def do_prefill(pool_k, pool_v, last_logits):
+            def do_prefill(*carried):
+                pool, last_logits = carried[:n_pool], carried[n_pool]
                 slot = jnp.maximum(prefill_slot, 0)
                 row = tables[slot]
                 if fused_prefill:
@@ -377,17 +424,14 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                     view = PagedPrefillView(
                         tables=row[None], write_block=row[wpos // P][None],
                         write_offset=(wpos % P)[None], use_pallas=True)
-                    logits, (pool_k, pool_v) = model.apply(
-                        {"params": params}, prefill_tokens[None],
-                        cache=(pool_k, pool_v), pos=prefill_pos,
-                        paged=view)
+                    logits, pool, pf_counts = _paged_apply(
+                        params, prefill_tokens[None], pool, prefill_pos,
+                        None, view)
                 else:
-                    with jax.named_scope("kv_pool"):
-                        kc = pool_k[:, row].reshape(L, 1, G, HKV, HD)
-                        vc = pool_v[:, row].reshape(L, 1, G, HKV, HD)
-                    logits, (nk, nv) = model.apply(
-                        {"params": params}, prefill_tokens[None],
-                        cache=(kc, vc), pos=prefill_pos)
+                    pool_k, pool_v = pool
+                    logits, nk, nv = _prefill_reference(
+                        params, pool, row, 1, prefill_tokens[None],
+                        prefill_pos, None)
                     kw = jax.lax.dynamic_slice_in_dim(
                         nk[:, 0], prefill_pos, CH, axis=1)
                     vw = jax.lax.dynamic_slice_in_dim(
@@ -399,28 +443,32 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                     with jax.named_scope("kv_pool"):
                         wpos = prefill_pos + jnp.arange(CH)
                         wbi = row[wpos // P]
-                        pool_k = pool_k.at[:, wbi, wpos % P].set(kw)
-                        pool_v = pool_v.at[:, wbi, wpos % P].set(vw)
+                        pool = (pool_k.at[:, wbi, wpos % P].set(kw),
+                                pool_v.at[:, wbi, wpos % P].set(vw))
                 done_row = logits[0, prefill_last_row]
                 finished = prefill_last_row >= 0
                 last_logits = jnp.where(
                     (jnp.arange(C) == slot)[:, None] & finished,
                     done_row[None, :], last_logits)
-                return pool_k, pool_v, last_logits
+                if counters:
+                    return (*pool, last_logits,
+                            _join(carried[-1], pf_counts))
+                return (*pool, last_logits)
 
-            pool_k, pool_v, last_logits = jax.lax.cond(
-                prefill_slot >= 0, do_prefill,
-                lambda a, b, c: (a, b, c), pool_k, pool_v, last_logits)
-            return pool_k, pool_v, last_logits, new_rngs, emitted
+            carried = (*pool, last_logits) + ((counts,) if counters else ())
+            carried = jax.lax.cond(
+                prefill_slot >= 0, do_prefill, lambda *a: a, *carried)
+            return (*carried[:n_pool + 1], new_rngs, emitted,
+                    *carried[n_pool + 1:])
 
         return step
 
-    def step(params, pool_k, pool_v, last_logits, tables, pos,
-             decoding, temp, top_k, rngs, slot_pad, prefill_slots,
-             prefill_tokens, prefill_pos, prefill_last_row,
-             prefill_pad):
-        """The batched-prefill twin (prefill_batch > 1). Extra runtime
-        inputs over the single-slot step:
+    def step(params, *args):
+        """The batched-prefill twin (prefill_batch > 1): ``step(params,
+        *pool, last_logits, tables, pos, decoding, temp, top_k, rngs,
+        slot_pad, prefill_slots, prefill_tokens, prefill_pos,
+        prefill_last_row, prefill_pad)``. Extra runtime inputs over the
+        single-slot step:
 
           slot_pad [C] i32      per-slot left pad (0 once unpadded) —
                                 the decode lanes mask pad columns and
@@ -437,15 +485,19 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                                 right-alignment makes it shared)
           prefill_pad [B] i32   per-row left pad within the group
         """
+        pool = tuple(args[:n_pool])
+        (last_logits, tables, pos, decoding, temp, top_k, rngs, slot_pad,
+         prefill_slots, prefill_tokens, prefill_pos, prefill_last_row,
+         prefill_pad) = args[n_pool:]
         emitted, new_rngs = _sample(last_logits, decoding, temp, top_k,
                                     rngs)
-        pool_k, pool_v, logits2 = _decode(
-            params, pool_k, pool_v, tables, pos, decoding, emitted,
-            slot_pad)
+        pool, logits2, _ = _decode(
+            params, pool, tables, pos, decoding, emitted, slot_pad)
         last_logits = jnp.where(decoding[:, None], logits2, last_logits)
 
         # ---- prefill lane: one chunk for the head FIFO group ---------
-        def do_prefill(pool_k, pool_v, last_logits):
+        def do_prefill(*carried):
+            pool, last_logits = carried[:n_pool], carried[n_pool]
             slots = jnp.maximum(prefill_slots, 0)
             active = prefill_slots >= 0
             rows = jnp.where(active[:, None], tables[slots], 0)
@@ -468,17 +520,14 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                     tables=rows, write_block=rows[:, wpos // P],
                     write_offset=jnp.broadcast_to(wpos % P, (B, CH)),
                     use_pallas=True)
-                logits, (pool_k, pool_v) = model.apply(
-                    {"params": params}, prefill_tokens,
-                    cache=(pool_k, pool_v), pos=prefill_pos,
-                    pad=prefill_pad, paged=view)
+                logits, pool, _ = _paged_apply(
+                    params, prefill_tokens, pool, prefill_pos,
+                    prefill_pad, view)
             else:
-                with jax.named_scope("kv_pool"):
-                    kc = pool_k[:, rows].reshape(L, B, G, HKV, HD)
-                    vc = pool_v[:, rows].reshape(L, B, G, HKV, HD)
-                logits, (nk, nv) = model.apply(
-                    {"params": params}, prefill_tokens,
-                    cache=(kc, vc), pos=prefill_pos, pad=prefill_pad)
+                pool_k, pool_v = pool
+                logits, nk, nv = _prefill_reference(
+                    params, pool, rows, B, prefill_tokens, prefill_pos,
+                    prefill_pad)
                 kw = jax.lax.dynamic_slice_in_dim(nk, prefill_pos, CH,
                                                   axis=2)
                 vw = jax.lax.dynamic_slice_in_dim(nv, prefill_pos, CH,
@@ -490,8 +539,8 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                 with jax.named_scope("kv_pool"):
                     wbi = rows[:, wpos // P]
                     woff = jnp.broadcast_to(wpos % P, (B, CH))
-                    pool_k = pool_k.at[:, wbi, woff].set(kw)
-                    pool_v = pool_v.at[:, wbi, woff].set(vw)
+                    pool = (pool_k.at[:, wbi, woff].set(kw),
+                            pool_v.at[:, wbi, woff].set(vw))
             done = active & (prefill_last_row >= 0)
             done_rows = logits[:, prefill_last_row]      # [B, V]
             # scatter each finished row's logits into its slot via a
@@ -502,12 +551,12 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             contrib = sel.astype(done_rows.dtype) @ done_rows
             last_logits = jnp.where(sel.any(axis=1)[:, None], contrib,
                                     last_logits)
-            return pool_k, pool_v, last_logits
+            return (*pool, last_logits)
 
-        pool_k, pool_v, last_logits = jax.lax.cond(
-            jnp.any(prefill_slots >= 0), do_prefill,
-            lambda a, b, c: (a, b, c), pool_k, pool_v, last_logits)
-        return pool_k, pool_v, last_logits, new_rngs, emitted
+        carried = jax.lax.cond(
+            jnp.any(prefill_slots >= 0), do_prefill, lambda *a: a,
+            *pool, last_logits)
+        return (*carried, new_rngs, emitted)
 
     return step
 
@@ -692,12 +741,11 @@ def build_spec_step(model, draft_model, cfg: EngineConfig):
     return step
 
 
-def _copy_pool_block(pk, pv, src, dst):
-    """Copy one block's K/V within a (donated) pool pair — the
+def _copy_pool_block(pool, src, dst):
+    """Copy one block within every leaf of a (donated) pool — the
     copy-on-write fork primitive. Jitted separately from the step so
     the engine's `compile_count` pin (== 1) is undisturbed."""
-    return (pk.at[:, dst].set(pk[:, src]),
-            pv.at[:, dst].set(pv[:, src]))
+    return tuple(leaf.at[:, dst].set(leaf[:, src]) for leaf in pool)
 
 
 def idle_prefill(cfg: EngineConfig):
@@ -733,9 +781,9 @@ def _global_put(x, sharding):
 def serving_param_specs(model, params, axis_names):
     """Per-leaf ``(path, PartitionSpec)`` list (tree_leaves order) for
     a replica's weights: the model's published per-leaf specs
-    (`model.param_specs`, e.g. `models.llama.llama_param_specs` —
-    wqkv/gate_up column-split, wo/w_down row-split, embeddings
-    vocab-split) looked up by exact leaf path, every unknown leaf
+    (`model.serving_param_specs()`; Llama's: wqkv/gate_up column-split,
+    wo/w_down row-split, embeddings vocab-split) looked up by exact
+    leaf path, every unknown leaf
     REPLICATED. Specs naming axes outside ``axis_names`` fall back to
     replicated too — serving meshes are tensor-only. Shared by the
     engine's device placement and `serve.audit`'s collective pricing,
@@ -746,12 +794,9 @@ def serving_param_specs(model, params, axis_names):
 
     if hasattr(model, "param_specs"):       # the trainer-side wrapper
         specs = model.param_specs(params)
-    elif hasattr(model, "cfg"):
-        # the flax module the engine serves: the published llama
-        # placement keyed off its config
-        from ray_lightning_tpu.models.llama import llama_param_specs
-
-        specs = llama_param_specs(model.cfg)
+    elif hasattr(model, "serving_param_specs"):
+        # the flax decoder the engine serves publishes its own placement
+        specs = model.serving_param_specs()
     else:
         specs = {}
     axes = set(axis_names)
@@ -779,11 +824,32 @@ def serving_param_shardings(model, params, mesh):
         jax.tree_util.tree_structure(params), flat)
 
 
+def _refusal(model, cfg: EngineConfig, mesh) -> Optional[str]:
+    """Why ``model`` cannot be served under ``cfg``/``mesh``, by what it
+    declares in `serving_unsupported`; None where it can."""
+    name = type(model).__name__
+    no = set(model.serving_unsupported)
+    if "speculative" in no and cfg.draft is not None:
+        return (f"{name} cannot be a speculative-decoding target: the "
+                "verify chunk rides the dense reference cache path, which "
+                "this decoder does not have (set draft=None)")
+    if "prefill_batch" in no and cfg.prefill_batch > 1:
+        return (f"{name} prefills one slot a tick: its cache path has no "
+                f"left-padded group form (prefill_batch "
+                f"{cfg.prefill_batch}; set prefill_batch=1)")
+    if "tensor_parallel" in no and mesh is not None and mesh.size > 1:
+        return (f"{name} has no tensor-parallel replica: it publishes no "
+                "parameter placement and its paged kernels have no manual "
+                "region (serve it with mesh=None, one chip a replica)")
+    return None
+
+
 class DecodeEngine:
     """One replica's compiled step + its device-resident buffers.
 
-    Owns ``pool_k/pool_v/last_logits`` (donated through every step —
-    callers must never hold references to them) and the compile-count
+    Owns ``pool`` (the leaves the model declares: `pool_k`/`pool_v`
+    name a K/V pair's) and ``last_logits`` (donated through every step
+    — callers must never hold references to them) and the compile-count
     pin. The host-side request state lives in `serve.scheduler`.
     """
 
@@ -810,12 +876,10 @@ class DecodeEngine:
         # shape, the decode lane is the fused paged-attention kernel
         # and the dense gathered view is never built; otherwise the
         # reference lane, the bitwise anchor against generate().
-        from ray_lightning_tpu.ops.attention import (
-            paged_attention_uses_pallas,
-            paged_prefill_uses_pallas,
-        )
-
         spec = cfg.pool_spec
+        refused = _refusal(model, cfg, mesh)
+        if refused:
+            raise ValueError(refused)
         if use_pallas is None and not model.cfg.use_flash:
             use_pallas = False  # reference-forced model config
         if mesh is not None and mesh.size > 1:
@@ -823,19 +887,22 @@ class DecodeEngine:
             # have no manual region yet: a sharded replica takes the
             # reference lanes, and attention_path/prefill_path say so
             use_pallas = False
-        pool_shape = (spec.n_blocks, spec.block_size,
-                      model.cfg.n_kv_heads, model.cfg.head_dim)
-        self.fused = paged_attention_uses_pallas(
-            (cfg.capacity, model.cfg.n_heads, model.cfg.head_dim),
-            pool_shape, use_pallas)
-        # the PREFILL lane's dispatch is decided the same way, once,
-        # here — the two kernels have separate shape gates (the prefill
-        # kernel additionally tiles the chunk width), so the decisions
-        # are independent but share the use_pallas resolution
-        self.fused_prefill = paged_prefill_uses_pallas(
-            (cfg.prefill_batch, cfg.prefill_chunk, model.cfg.n_heads,
-             model.cfg.head_dim),
-            pool_shape, use_pallas)
+        # the model's own predicates (the ones its ops dispatch on): the
+        # two lanes have separate shape gates (the prefill kernel also
+        # tiles the chunk width), so the decisions are independent but
+        # share the use_pallas resolution
+        self.fused, self.fused_prefill = model.paged_lanes(
+            cfg.capacity, cfg.prefill_batch, cfg.prefill_chunk,
+            (spec.n_blocks, spec.block_size), use_pallas)
+        if "reference_lanes" in model.serving_unsupported and not (
+                self.fused and self.fused_prefill):
+            raise ValueError(
+                f"{type(model).__name__} has no reference (gathered-"
+                "view) lanes: it serves through its paged kernels only "
+                f"(decode {self.fused}, prefill {self.fused_prefill} at "
+                f"these shapes). Run on a TPU, or pass use_pallas=True "
+                "(RLT_PALLAS=1) for interpret mode, with a block_size "
+                "and head count the kernels tile")
         self.draft_model = draft_model
         self.dpool_k = self.dpool_v = self.draft_params = None
         if cfg.draft is not None:
@@ -875,6 +942,12 @@ class DecodeEngine:
         #: (which lives on every rank, lockstep) is tp-oblivious.
         self.mesh = mesh
         self.tp = 1 if mesh is None else int(mesh.shape.get("tensor", 1))
+        n_pool = len(model.cfg.pool_leaf_shapes(spec.n_blocks,
+                                                spec.block_size))
+        #: names of the device-side counts the step returns, fetched
+        #: with the tick's tokens into `last_counters`
+        self._counter_names = tuple(n for n, _ in model.tick_counters)
+        self.last_counters: dict = {}
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -891,12 +964,10 @@ class DecodeEngine:
             self._step = jax.jit(
                 build_step(model, cfg, fused=self.fused,
                            fused_prefill=self.fused_prefill),
-                donate_argnums=(1, 2, 3),
-                out_shardings=(pool_sh, pool_sh, self._repl_sh,
-                               self._repl_sh, self._repl_sh))
-            pool_k, pool_v = init_pool(model.cfg, self.spec)
-            self.pool_k = _global_put(pool_k, pool_sh)
-            self.pool_v = _global_put(pool_v, pool_sh)
+                donate_argnums=tuple(range(1, n_pool + 2)),
+                out_shardings=(pool_sh,) * n_pool + (self._repl_sh,) * 3)
+            self.pool = tuple(_global_put(leaf, pool_sh)
+                              for leaf in init_pool(model.cfg, self.spec))
             self.last_logits = _global_put(
                 jnp.zeros((cfg.capacity, model.cfg.vocab_size),
                           jnp.float32), self._repl_sh)
@@ -925,7 +996,7 @@ class DecodeEngine:
                 self._step = jax.jit(
                     build_step(model, cfg, fused=self.fused,
                                fused_prefill=self.fused_prefill),
-                    donate_argnums=(1, 2, 3))
+                    donate_argnums=tuple(range(1, n_pool + 2)))
             # COMMIT the device-resident buffers to the same device as
             # the weights: a fresh jnp.zeros is uncommitted, but the
             # step's outputs are committed, so an uncommitted
@@ -933,9 +1004,8 @@ class DecodeEngine:
             # the moment the donated outputs cycle back in (same
             # phantom-recompile class as the params placement above;
             # the churn pin covers both)
-            pool_k, pool_v = init_pool(model.cfg, self.spec)
-            self.pool_k = jax.device_put(pool_k, device)
-            self.pool_v = jax.device_put(pool_v, device)
+            self.pool = tuple(jax.device_put(leaf, device)
+                              for leaf in init_pool(model.cfg, self.spec))
             self.last_logits = jax.device_put(
                 jnp.zeros((cfg.capacity, model.cfg.vocab_size),
                           jnp.float32),
@@ -947,7 +1017,7 @@ class DecodeEngine:
                 self.dpool_v = jax.device_put(dpv, device)
         # the copy-on-write fork primitive (scheduler-driven): its own
         # tiny jit so the step's compile_count pin is undisturbed
-        self._copy = jax.jit(_copy_pool_block, donate_argnums=(0, 1))
+        self._copy = jax.jit(_copy_pool_block, donate_argnums=(0,))
         self.steps = 0
         # live metrics (telemetry/metrics.py): per-tick prefill/decode
         # token counts + the compile counter. The registry NEVER enters
@@ -959,6 +1029,19 @@ class DecodeEngine:
         from ray_lightning_tpu.telemetry.metrics import NULL_METRICS
 
         self.metrics = metrics if metrics is not None else NULL_METRICS
+
+    @property
+    def pool_k(self):
+        """The K leaf of a K/V pool (the model's first leaf)."""
+        return self.pool[0]
+
+    @property
+    def pool_v(self):
+        """The V leaf of a K/V pool; a one-leaf (latent) pool has none."""
+        if len(self.pool) < 2:
+            raise AttributeError(
+                f"{type(self.model).__name__}'s pool has one leaf")
+        return self.pool[1]
 
     # ---- compile accounting ---------------------------------------------
 
@@ -1013,11 +1096,10 @@ class DecodeEngine:
         fresh block populated by this copy, so a shared block is never
         written by a non-exclusive owner."""
         s, d = jnp.int32(src), jnp.int32(dst)
-        self.pool_k, self.pool_v = self._copy(self.pool_k, self.pool_v,
-                                              s, d)
+        self.pool = self._copy(self.pool, s, d)
         if self.dpool_k is not None:
             self.dpool_k, self.dpool_v = self._copy(
-                self.dpool_k, self.dpool_v, s, d)
+                (self.dpool_k, self.dpool_v), s, d)
 
     # ---- the tick --------------------------------------------------------
 
@@ -1047,15 +1129,14 @@ class DecodeEngine:
         with annotate("serve.put"):
             if spec_mode:
                 common = (
-                    self.params, self.draft_params, self.pool_k,
-                    self.pool_v, self.dpool_k, self.dpool_v,
+                    self.params, self.draft_params, *self.pool,
+                    self.dpool_k, self.dpool_v,
                     self.last_logits,
                     put(tables), put(pos), put(decoding),
                     put(temp), put(top_k), put(rngs))
             else:
                 common = (
-                    self.params, self.pool_k, self.pool_v,
-                    self.last_logits,
+                    self.params, *self.pool, self.last_logits,
                     put(tables), put(pos), put(decoding),
                     put(temp), put(top_k), put(rngs))
             if self.cfg.prefill_batch == 1:
@@ -1072,15 +1153,19 @@ class DecodeEngine:
         with annotate("serve.dispatch",
                       **self._step_work(pos, decoding, prefill)):
             out = self._step(*args)
+        counts = ()
         if spec_mode:
-            (self.pool_k, self.pool_v, self.dpool_k, self.dpool_v,
+            (*pool, self.dpool_k, self.dpool_v,
              self.last_logits, new_rngs, toks, n_emit) = out
+            self.pool = tuple(pool)
             with annotate("serve.fetch"):
                 toks = np.array(toks)
                 n_emit = np.array(n_emit)
         else:
-            (self.pool_k, self.pool_v, self.last_logits, new_rngs,
-             emitted) = out
+            n_pool = len(self.pool)
+            self.pool = tuple(out[:n_pool])
+            self.last_logits, new_rngs, emitted = out[n_pool:n_pool + 3]
+            counts = out[n_pool + 3:]
         self.steps += 1
         m = self.metrics
         if m.enabled:
@@ -1116,6 +1201,11 @@ class DecodeEngine:
             else:
                 emitted = np.array(emitted)
                 new_rngs = np.array(new_rngs)
+            if counts:
+                # the model's device-side counts ride the same fetch
+                self.last_counters = dict(zip(
+                    self._counter_names,
+                    (int(v) for v in np.array(counts[0]))))
         return (emitted[:, None],
                 np.asarray(decoding).astype(np.int32), new_rngs)
 

@@ -78,13 +78,15 @@ class PagedPoolSpec:
 
 
 def init_pool(cfg, spec: PagedPoolSpec):
-    """Zeroed (pool_k, pool_v), leaves
-    ``[n_layers, n_blocks, block_size, n_kv_heads, head_dim]`` in the
-    model's activation dtype — the same per-position layout as
-    `models.llama.init_cache`, block-chunked over the sequence axis."""
-    shape = (cfg.n_layers, spec.n_blocks, spec.block_size,
-             cfg.n_kv_heads, cfg.head_dim)
-    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+    """The zeroed pool: one leaf a shape the model's config declares
+    (`cfg.pool_leaf_shapes(n_blocks, block_size)`), in the model's
+    activation dtype. A K/V decoder declares two, ``(pool_k, pool_v)``,
+    each ``[n_layers, n_blocks, block_size, n_kv_heads, head_dim]`` —
+    the same per-position layout as `models.llama.init_cache`,
+    block-chunked over the sequence axis; a latent-attention decoder
+    one, ``[n_layers, n_blocks, block_size, row]``."""
+    return tuple(jnp.zeros(shape, cfg.dtype) for shape in
+                 cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size))
 
 
 def validate_pool_tp(cfg, tp: int) -> None:
@@ -123,10 +125,11 @@ def pool_shard_bytes(cfg, spec: PagedPoolSpec, tp: int = 1) -> int:
 
 
 def pool_bytes(cfg, spec: PagedPoolSpec) -> int:
-    """HBM held by the pool itself (k + v)."""
-    per = (cfg.n_layers * spec.n_blocks * spec.block_size
-           * cfg.n_kv_heads * cfg.head_dim)
-    return 2 * per * jnp.dtype(cfg.dtype).itemsize
+    """HBM held by the pool itself (every leaf the model declares)."""
+    import math
+
+    return sum(math.prod(shape) for shape in cfg.pool_leaf_shapes(
+        spec.n_blocks, spec.block_size)) * jnp.dtype(cfg.dtype).itemsize
 
 
 def gathered_view_bytes(cfg, spec: PagedPoolSpec, capacity: int) -> int:

@@ -864,7 +864,9 @@ class Scheduler:
                 self.tables, self.pos, self.decoding, self.temp,
                 self.top_k, self.rngs, prefill,
                 pad=self.pad if self.cfg.prefill_batch > 1 else None)
-            with annotate("serve.account"):
+            # the model's device-side counts of this tick (fetched with
+            # its tokens; none for a decoder that counts nothing)
+            with annotate("serve.account", **self.engine.last_counters):
                 return self._account(pf_group, was_decoding, emitted,
                                      n_emit)
 

@@ -141,6 +141,38 @@ def test_flash_fwd_bwd_compiles(v5e, as_on_tpu, seq):
     assert _n_mosaic(compiled) == 3      # fwd, dkv, dq
 
 
+@pytest.mark.parametrize("block", [16, 64, 128])
+@pytest.mark.parametrize("lane", ["decode", "prefill"])
+def test_latent_attention_kernels_compile_at_the_published_dims(
+        v5e, as_on_tpu, lane, block):
+    """`rlt_mla_decode` / `rlt_mla_prefill` at 128 heads, a 640-wide row
+    (576 values) and value 512, over a stacked pool read at a traced
+    layer, with no copy of the pool in front of the kernel."""
+    from ray_lightning_tpu.ops.pallas import mla_attention as mla
+
+    s = SingleDeviceSharding(v5e[0])
+    span, slots = 8192, 24
+    pool = _sds((6, 1 + 3072 * 64 // block, block, 640), jnp.bfloat16, s)
+    tables = _sds((slots if lane == "decode" else 1, span // block),
+                  jnp.int32, s)
+    layer = _sds((), jnp.int32, s)
+    assert mla.mla_shapes_supported((slots, 128, 640), pool.shape, 512)
+    if lane == "decode":
+        fn = lambda q, pool, t, n, layer: mla.mla_decode_pallas(
+            q, pool, t, n, 512, 0.1, layer=layer)
+        args = (_sds((slots, 128, 640), jnp.bfloat16, s), pool, tables,
+                _sds((slots,), jnp.int32, s), layer)
+    else:
+        fn = lambda q, pool, t, pos, layer: mla.mla_prefill_pallas(
+            q, pool, t, pos, 512, 0.1, layer=layer)
+        args = (_sds((1, 1024, 128, 640), jnp.bfloat16, s), pool, tables,
+                _sds((), jnp.int32, s), layer)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _n_mosaic(compiled) == 1
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
 # ---- the 0.5B train step, through the Trainer's own step builder -----------
 
 def _train_step_compiled(strategy, batch=8, seq=2048):
