@@ -467,6 +467,18 @@ class Llama(nn.Module):
     def serving_param_specs(self):
         return llama_param_specs(self.cfg)
 
+    def decode_tile_tokens(self, block_size: int, blocks_per_slot: int):
+        """Tokens of the paged decode kernel's KV tile, by the kernel's
+        own rule. A decoder that states one has a kernel whose time is
+        its live tiles': a slot handed a length of 0 costs none and
+        reads zeros, so the engine hands that to every slot that is not
+        decoding (`serve/engine.py:_decode_fused`)."""
+        from ray_lightning_tpu.ops.pallas.paged_attention import (
+            decode_tile_tokens,
+        )
+
+        return decode_tile_tokens(block_size, blocks_per_slot)
+
     def paged_lanes(self, capacity: int, prefill_batch: int,
                     prefill_chunk: int, pool_block, use_pallas):
         """(decode, prefill): would the paged lanes take the kernels at

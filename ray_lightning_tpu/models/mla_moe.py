@@ -458,6 +458,12 @@ class MlaMoe(nn.Module):
         """No published placement: a replica holds its share whole."""
         return {}
 
+    def decode_tile_tokens(self, block_size: int, blocks_per_slot: int):
+        """None: `rlt_mla_decode` states no tile to the engine (its
+        index map reads table entry ``(length - 1) // P``, so no slot
+        may be handed a length of 0)."""
+        return None
+
     def paged_lanes(self, capacity: int, prefill_batch: int,
                     prefill_chunk: int, pool_block, use_pallas):
         """(decode, prefill): would the paged lanes take the kernels at

@@ -11,6 +11,7 @@ itself:
     model.paged_lanes(...)          would the paged lanes take the kernels
     model.serving_param_specs()     per-leaf placement of a sharded replica
     model.tick_counters             device-side counts of a paged call
+    model.decode_tile_tokens(P, M)  the decode kernel's KV tile, or None
     model.serving_unsupported       what the engine has to refuse
     model(tokens, cache=, pos=, pad=, paged=)
 

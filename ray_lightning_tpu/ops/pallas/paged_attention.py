@@ -18,18 +18,37 @@ Schedule (one layer's pool, all slots):
     pad     [C] int32            left-pad columns to mask (ragged
                                  batched prefill; 0 = none)
 
-grid = (C, M): for slot c the kernel streams that slot's M table-named
-KV tiles through VMEM — the BlockSpec index_map reads the
-scalar-prefetched table (`pltpu.PrefetchScalarGridSpec`), so the DMA
-engine fetches pool block `tables[c, m]` while compute runs, and no
-gathered copy ever exists in HBM. Per tile: one [H, P] score panel,
-online-softmax statistics (running max / sum / accumulator in f32 VMEM
-scratch, exactly the flash-attention discipline of
-`ops/pallas/flash.py`), masked by `pad <= kv_pos < length` BEFORE the
-max so scratch-block garbage (block 0, and table tails past a slot's
-length) contributes exactly zero. Tiles entirely past `length` are
-skipped (predicated body). GQA reads KV heads in place via the
-`h // (H // Hkv)` head map — no repeat, no extra traffic.
+The kernel's unit of work is a KV TILE: ``tile_blocks`` table-named pool
+blocks joined in VMEM (`decode_tile_tokens`: 128 tokens, 8 blocks of 16),
+and its time is that of the tiles that hold a cached token, not of the
+table. grid = (C,): one slot a grid step, and inside it a loop over the
+slot's LIVE tiles, those with a position in ``[pad, length)``; the trip
+count is a scalar-prefetched value (`pltpu.PrefetchScalarGridSpec`), never
+a shape, so one program serves every batch. A tile past a slot's length,
+or under its left pad, costs neither a fetch nor a step; a slot of length
+0 costs a grid step and reads zeros. The pool stays in HBM
+(`memory_space=ANY`): the kernel copies in each live block of a tile
+(`pltpu.make_async_copy`, the block id read from the prefetched table)
+into one half of a double buffer while it computes the other half, and
+carries the tile in flight across grid steps (the next live slot's first
+tile), so no gathered copy ever exists in HBM and the copies never wait
+for a grid step. Per tile: one [H, tile] score panel, online-softmax
+statistics in float32 (running max / sum / accumulator carried through
+the loop, the flash-attention discipline of `ops/pallas/flash.py`),
+masked by `pad <= kv_pos < length` BEFORE the max, masked probabilities
+zeroed explicitly, and the V rows of a block that was not fetched zeroed,
+so scratch-block garbage (block 0, and whatever a table names past a
+slot's length, NaN included) contributes exactly zero. GQA reads KV heads
+in place via the `h // (H // Hkv)` head map — no repeat, no extra
+traffic.
+
+Where Mosaic cannot slice a pool block out of HBM itself (``hd`` 64: a
+row is half a lane tile), the same tile body is fed by the pipeline
+instead (`_decode_kernel_blockspec`: the pool an operand ``tile_blocks``
+times over, grid = (C, ceil(M / tile_blocks)), index maps clamped into
+the live blocks). There every (operand, grid step) pair costs its
+bookkeeping whether or not it fetches, so that form's time is still its
+table's; on the chip it is 3-9x the first form (PERF.md section 6, PR 28).
 
 Inference-only: decode has no backward, so there is no VJP — the
 XLA reference path with identical semantics lives in
@@ -94,73 +113,229 @@ def stack_as_pool(pool_k, pool_v, tables, layer):
             tables + jnp.asarray(layer, tables.dtype) * n_blocks)
 
 
-def _decode_kernel(tbl_ref, len_ref, pad_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc, m_scr, l_scr, *, scale, block_p, num_kv_blocks,
-                   n_rep):
-    """One (slot, kv-tile) grid step. Scratch persists across the
-    innermost tile axis (the flash forward's accumulation contract)."""
-    c = pl.program_id(0)
-    m = pl.program_id(1)
+#: tokens a KV tile aims at. A tile is what one step of the kernel's loop
+#: computes: ``tile_blocks`` table-named pool blocks joined in VMEM
+_TILE_TOKENS = 128
 
-    @pl.when(m == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+
+def decode_tile_blocks(block_size: int, blocks_per_slot: int) -> int:
+    """Pool blocks a KV tile of the decode kernel joins: as many
+    ``block_size``-token blocks as `_TILE_TOKENS` holds, at most a whole
+    table. The kernel's own rule, from what it sees in its operands (the
+    pool's ``P``, the table's ``M``); a table that is no whole number of
+    tiles ends in a shorter LIVE extent, never in a smaller tile."""
+    return max(1, min(_TILE_TOKENS // block_size, blocks_per_slot))
+
+
+def decode_tile_tokens(block_size: int, blocks_per_slot: int) -> int:
+    """Tokens of one KV tile (`decode_tile_blocks` x ``block_size``): the
+    unit the kernel's time is counted in. A slot of ``length`` cached
+    tokens costs ``ceil(length / tile)`` tiles; `DecodeEngine._step_work`
+    counts them as ``decode_tiles``."""
+    return decode_tile_blocks(block_size, blocks_per_slot) * block_size
+
+
+def _copies_in_kernel(hd: int) -> bool:
+    """Whether the kernel copies its tiles in itself (`_decode_kernel`)
+    or leaves them to the pipeline (`_decode_kernel_blockspec`): Mosaic
+    slices a pool block out of HBM only where its rows are whole
+    128-lane tiles."""
+    return hd % 128 == 0
+
+
+def _live_extent(length, pad, block_p, tile_blocks, table_blocks):
+    """Live blocks ``[b_lo, b_hi)`` and live tiles ``[t_lo, t_hi)`` of a
+    slot: those that hold a position in ``[pad, length)``. Nothing
+    visible (``length <= pad``, a slot that asks for nothing) is an
+    empty extent of both."""
+    length = jnp.minimum(length, table_blocks * block_p)
+    b_hi = jnp.where(length > pad, pl.cdiv(length, block_p), 0)
+    b_lo = jnp.minimum(pad // block_p, b_hi)
+    return b_lo, b_hi, b_lo // tile_blocks, pl.cdiv(b_hi, tile_blocks)
+
+
+def _tile_update(qg, k, v, kv_start, length, pad, carry, *, scale):
+    """Online-softmax update of one slot's statistics by one KV tile:
+    ``qg`` [Hkv, n_rep, hd] float32, ``k`` / ``v`` [tile, Hkv, hd] in the
+    pool's dtype, ``carry`` = (running max [H, 1], sum [H, 1],
+    accumulator [H, hd]), all float32."""
+    m_prev, l_prev, acc = carry
+    hkv, n_rep, hd = qg.shape
+    h, tile = hkv * n_rep, k.shape[0]
+    # GQA head map: query head g*n_rep + r reads kv head g — the
+    # contraction is batched over kv heads, so KV tiles are consumed in
+    # place (no repeat)
+    kg = k.astype(jnp.float32).transpose(1, 0, 2)      # [Hkv, tile, hd]
+    vg = v.astype(jnp.float32).transpose(1, 0, 2)
+    s = (jax.lax.dot_general(
+        qg, kg, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale).reshape(h, tile)            # [Hkv, n_rep, tile] -> [H, tile]
+    # the mask is built in the stats layout [H, tile] directly: Mosaic
+    # cannot reshape an i1 vector across the degenerate n_rep == 1
+    # axis (MHA; LLO_CHECK `vmand ... ProducesVreg` on v5e)
+    kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    visible = (kv_pos < length) & (kv_pos >= pad)
+    s = jnp.where(visible, s, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # masked positions are zeroed EXPLICITLY, not only through the exp: a
+    # fully-masked panel has s == m_new == _NEG_INF and exp(s - m_new)
+    # == 1 — the sentinel-minus-sentinel trap would weight garbage at
+    # full probability
+    p = jnp.where(visible, jnp.exp(s - m_new), 0.0)    # [H, tile]
+    corr = jnp.exp(m_prev - m_new)
+    l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    av = jax.lax.dot_general(
+        p.reshape(hkv, n_rep, tile), vg,
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )                                      # [Hkv, n_rep, hd]
+    return m_new, l_new, corr * acc + av.reshape(h, hd)
+
+
+def _init_carry(h, hd):
+    return (jnp.full((h, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, hd), jnp.float32))
+
+
+def _emit(o_ref, l, acc):
+    safe_l = jnp.where(l == 0.0, 1.0, l)   # nothing visible -> zeros
+    o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
+
+
+def _decode_kernel(tbl_ref, len_ref, pad_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, sems, state, *, scale, block_p, tile_blocks,
+                   table_blocks, n_slots, n_rep):
+    """One slot a grid step: a loop over the slot's LIVE KV tiles, its
+    trip count a prefetched scalar. The pool stays in HBM
+    (`memory_space=ANY`); a tile's live blocks come in by one async copy
+    each into one half of a double buffer while the other half is
+    computed, and the tile in flight when a slot ends is the next live
+    slot's first, so the copies never wait for a grid step (``state``:
+    the half the next tile lands in, and whether it is in flight)."""
+    c = pl.program_id(0)
+    tile = tile_blocks * block_p
+    extent = lambda slot: _live_extent(
+        len_ref[slot], pad_ref[slot], block_p, tile_blocks, table_blocks)
+
+    def fetch(slot, t, half, wait):
+        """Start (or wait for) the copies of tile ``t`` of ``slot`` into
+        ``half``. A block outside the live extent is not fetched; its V
+        rows are zeroed instead (0 x garbage must stay 0; K's garbage is
+        masked in the scores)."""
+        b_lo, b_hi, _, _ = extent(slot)
+        for j in range(tile_blocks):
+            b = t * tile_blocks + j
+            live = (b >= b_lo) & (b < b_hi)
+            rows = pl.ds(j * block_p, block_p)
+            blk = 0 if wait else tbl_ref[
+                slot, jnp.minimum(b, table_blocks - 1)]
+            ck = pltpu.make_async_copy(
+                k_hbm.at[blk], kbuf.at[half, rows], sems.at[0, half])
+            cv = pltpu.make_async_copy(
+                v_hbm.at[blk], vbuf.at[half, rows], sems.at[1, half])
+
+            @pl.when(live)
+            def _copy():
+                if wait:
+                    ck.wait()
+                    cv.wait()
+                else:
+                    ck.start()
+                    cv.start()
+
+            if not wait:
+                @pl.when(jnp.logical_not(live))
+                def _zero():
+                    vbuf[half, rows] = jnp.zeros(
+                        (block_p, *vbuf.shape[2:]), vbuf.dtype)
+
+    @pl.when(c == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
 
     length = len_ref[c]
-    kv_start = m * block_p
+    pad = pad_ref[c]
+    _, _, t_lo, t_hi = extent(c)
+    half0 = state[0]
+    h, hd = q_ref.shape[1:]
+    qg = q_ref[0].astype(jnp.float32).reshape(h // n_rep, n_rep, hd)
 
-    # tiles entirely past the slot's length (or entirely under its
-    # left pad) hold nothing visible — skip the DMA'd tile's compute
-    # (its garbage never reaches the stats)
-    @pl.when((kv_start < length) & (kv_start + block_p > pad_ref[c]))
+    @pl.when((t_lo < t_hi) & (state[1] == 0))
+    def _first():
+        fetch(c, t_lo, half0, wait=False)
+
+    def next_live(slot):
+        # the first slot after ``slot`` with a live tile (n_slots: none)
+        def dead(s):
+            _, _, lo, hi = extent(jnp.minimum(s, n_slots - 1))
+            return (s < n_slots) & (lo >= hi)
+
+        return jax.lax.while_loop(dead, lambda s: s + 1, slot + 1)
+
+    def _tile(t, carry):
+        half = (half0 + t - t_lo) % 2
+
+        @pl.when(t + 1 < t_hi)
+        def _next_tile():
+            fetch(c, t + 1, 1 - half, wait=False)
+
+        @pl.when(t + 1 == t_hi)
+        def _next_slot():
+            nxt = next_live(c)
+            state[1] = (nxt < n_slots).astype(jnp.int32)
+
+            @pl.when(nxt < n_slots)
+            def _():
+                slot = jnp.minimum(nxt, n_slots - 1)
+                fetch(slot, extent(slot)[2], 1 - half, wait=False)
+
+        fetch(c, t, half, wait=True)
+        return _tile_update(qg, kbuf[half], vbuf[half], t * tile, length,
+                            pad, carry, scale=scale)
+
+    _, l, acc = jax.lax.fori_loop(t_lo, t_hi, _tile, _init_carry(h, hd))
+
+    @pl.when(t_lo < t_hi)
+    def _advance():
+        state[0] = (half0 + t_hi - t_lo) % 2
+
+    _emit(o_ref, l, acc)
+
+
+def _decode_kernel_blockspec(tbl_ref, len_ref, pad_ref, q_ref, *rest, scale,
+                             block_p, tile_blocks, table_blocks, n_rep):
+    """One (slot, KV tile) a grid step, the tile's blocks brought in by
+    the pipeline: the pool is an operand ``tile_blocks`` times over, each
+    copy's index_map another entry of the table (`block_spec`, below). For
+    pools whose rows Mosaic cannot slice in HBM itself (``hd`` 64)."""
+    k_refs, v_refs = rest[:tile_blocks], rest[tile_blocks:2 * tile_blocks]
+    o_ref, acc, m_scr, l_scr = rest[2 * tile_blocks:]
+    c, t = pl.program_id(0), pl.program_id(1)
+    h, hd = q_ref.shape[1:]
+
+    @pl.when(t == 0)
+    def _init():
+        m_scr[...], l_scr[...], acc[...] = _init_carry(h, hd)
+
+    length, pad = len_ref[c], pad_ref[c]
+    _, _, t_lo, t_hi = _live_extent(length, pad, block_p, tile_blocks,
+                                    table_blocks)
+
+    @pl.when((t >= t_lo) & (t < t_hi))
     def _body():
-        q = q_ref[0].astype(jnp.float32)       # [H, hd]
-        k = k_ref[0].astype(jnp.float32)       # [P, Hkv, hd]
-        v = v_ref[0].astype(jnp.float32)
-        h, hd = q.shape
-        hkv = k.shape[1]
-        # GQA head map: query head g*n_rep + r reads kv head g — group
-        # the q heads and batch the contraction over kv heads, so KV
-        # tiles are consumed in place (no repeat)
-        qg = q.reshape(hkv, n_rep, hd)
-        kg = k.transpose(1, 0, 2)              # [Hkv, P, hd]
-        vg = v.transpose(1, 0, 2)
-        s = (jax.lax.dot_general(
-            qg, kg, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale).reshape(h, block_p)         # [Hkv, n_rep, P] -> [H, P]
-        # the mask is built in the stats layout [H, P] directly: Mosaic
-        # cannot reshape an i1 vector across the degenerate n_rep == 1
-        # axis (MHA; LLO_CHECK `vmand ... ProducesVreg` on v5e)
-        kv_pos = kv_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        visible = (kv_pos < length) & (kv_pos >= pad_ref[c])
-        s = jnp.where(visible, s, _NEG_INF)
-        m_prev = m_scr[:, 0]                   # [H]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        # masked positions are zeroed EXPLICITLY, not only through the
-        # exp: a fully-masked tile (every position below the slot's
-        # pad) has s == m_new == _NEG_INF and exp(s - m_new) == 1 —
-        # the sentinel-minus-sentinel trap would weight garbage at
-        # full probability
-        p = jnp.where(visible, jnp.exp(s - m_new[:, None]), 0.0)  # [H, P]
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:, 0] = corr * l_scr[:, 0] + jnp.sum(p, axis=1)
-        av = jax.lax.dot_general(
-            p.reshape(hkv, n_rep, block_p), vg,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                      # [Hkv, n_rep, hd]
-        acc[:] = corr[:, None] * acc[:] + av.reshape(h, hd)
-        m_scr[:, 0] = m_new
+        qg = q_ref[0].astype(jnp.float32).reshape(h // n_rep, n_rep, hd)
+        join = lambda refs: refs[0][0] if tile_blocks == 1 else \
+            jnp.concatenate([r[0] for r in refs], axis=0)
+        m_scr[...], l_scr[...], acc[...] = _tile_update(
+            qg, join(k_refs), join(v_refs), t * tile_blocks * block_p,
+            length, pad, (m_scr[...], l_scr[...], acc[...]), scale=scale)
 
-    @pl.when(m == num_kv_blocks - 1)
+    @pl.when(t == pl.num_programs(1) - 1)
     def _finish():
-        l = l_scr[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)   # fully-masked slot -> 0s
-        o_ref[0] = (acc[:] / safe_l[:, None]).astype(o_ref.dtype)
+        _emit(o_ref, l_scr[...], acc[...])
 
 
 def paged_attention_pallas(
@@ -181,48 +356,69 @@ def paged_attention_pallas(
     ``tables`` names each slot's pool blocks (block 0 = reserved
     scratch — readable garbage, always masked by ``lengths``/``pad``);
     ``lengths[c]`` is the number of valid cache positions (including
-    the just-written query token); ``pad[c]`` masks a left-padded
+    the just-written query token), 0 for a slot that asks for nothing
+    (it costs no tile and reads zeros); ``pad[c]`` masks a left-padded
     slot's pad columns (positions < pad never attend)."""
     c, h, hd = q.shape
     pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
     n_blocks, p, hkv, _ = pool_k.shape
     m = tables.shape[1]
-    n_rep = h // hkv
+    tb = decode_tile_blocks(p, m)
     scale = scale if scale is not None else hd ** -0.5
     if pad is None:
         pad = jnp.zeros_like(lengths)
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, block_p=p, num_kv_blocks=m,
-        n_rep=n_rep)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # tables, lengths, pad
-        grid=(c, m),
-        in_specs=[
-            pl.BlockSpec((1, h, hd),
-                         lambda ci, mi, tbl, ln, pd: (ci, 0, 0)),
-            # the paged trick: the KV tile for (slot, m) is whichever
-            # pool block the scalar-prefetched table names — the tile
-            # streams HBM -> VMEM with no intermediate gathered copy
-            pl.BlockSpec((1, p, hkv, hd),
-                         lambda ci, mi, tbl, ln, pd:
-                         (tbl[ci, mi], 0, 0, 0)),
-            pl.BlockSpec((1, p, hkv, hd),
-                         lambda ci, mi, tbl, ln, pd:
-                         (tbl[ci, mi], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, hd),
-                               lambda ci, mi, tbl, ln, pd: (ci, 0, 0)),
-        scratch_shapes=[
+    static = dict(scale=scale, block_p=p, tile_blocks=tb, table_blocks=m,
+                  n_rep=h // hkv)
+    q_spec = pl.BlockSpec((1, h, hd), lambda ci, *_: (ci, 0, 0))
+    if _copies_in_kernel(hd):
+        kernel = functools.partial(_decode_kernel, n_slots=c, **static)
+        grid = (c,)
+        # the paged trick: the pool never leaves HBM as a whole; the
+        # kernel copies in whichever blocks the scalar-prefetched table
+        # names, and only those a slot's length reaches
+        kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch = [
+            pltpu.VMEM((2, tb * p, hkv, hd), pool_k.dtype),
+            pltpu.VMEM((2, tb * p, hkv, hd), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),   # (k | v, buffer half)
+            pltpu.SMEM((2,), jnp.int32),
+        ]
+        pools = (pool_k, pool_v)
+    else:
+        kernel = functools.partial(_decode_kernel_blockspec, **static)
+        grid = (c, pl.cdiv(m, tb))
+
+        def block_spec(j):
+            def index(ci, ti, tbl, ln, pd):
+                # clamped into the slot's live blocks: a block outside
+                # them repeats a live one, which the pipeline does not
+                # fetch again and the mask never shows
+                b_lo, b_hi, _, _ = _live_extent(ln[ci], pd[ci], p, tb, m)
+                b = jnp.clip(ti * tb + j, b_lo, jnp.maximum(b_hi - 1, 0))
+                return tbl[ci, b], 0, 0, 0
+
+            return pl.BlockSpec((1, p, hkv, hd), index)
+
+        kv_specs = [block_spec(j) for j in range(tb)] * 2
+        scratch = [
             pltpu.VMEM((h, hd), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
-        ],
-    )
+        ]
+        pools = (pool_k,) * tb + (pool_v,) * tb
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # tables, lengths, pad
+            grid=grid,
+            in_specs=[q_spec, *kv_specs],
+            out_specs=q_spec,
+            scratch_shapes=scratch,
+        ),
         out_shape=jax.ShapeDtypeStruct((c, h, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid)),
         name="rlt_paged_decode",
         interpret=_interpret(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      pad.astype(jnp.int32), q, pool_k, pool_v)
+      pad.astype(jnp.int32), q, *pools)

@@ -35,11 +35,13 @@ from ray_lightning_tpu.serve.kv_cache import serve_kv_plan_bytes
 
 
 def _shape_fused_available(model_cfg, engine_cfg: EngineConfig) -> bool:
-    """Would the fused DECODE kernel tile this (model, engine) shape on
-    a TPU? The PLANNER'S question — shape support only, independent of
-    the host's backend (a CPU host planning a v5p deployment must price
-    the kernel the TPU will run; the runtime dispatch adds the backend
-    gate via `ops.attention.paged_attention_uses_pallas`)."""
+    """Would the fused DECODE kernel take this (model, engine) shape on
+    a TPU (its KV tile: several pool blocks, `decode_tile_tokens`; its
+    steps: a slot's live tiles)? The PLANNER'S question — shape support
+    only, independent of the host's backend (a CPU host planning a v5p
+    deployment must price the kernel the TPU will run; the runtime
+    dispatch adds the backend gate via
+    `ops.attention.paged_attention_uses_pallas`)."""
     from ray_lightning_tpu.ops.pallas.paged_attention import (
         paged_shapes_supported,
     )

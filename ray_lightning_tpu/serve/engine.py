@@ -238,6 +238,8 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     B = cfg.prefill_batch
     n_pool = len(mcfg.pool_leaf_shapes(spec.n_blocks, spec.block_size))
     counters = tuple(model.tick_counters)
+    tiled_decode = model.decode_tile_tokens(
+        spec.block_size, cfg.blocks_per_slot) is not None
     if not (fused and fused_prefill):
         # the reference lanes gather a dense K/V view: Llama's cache path
         L, HKV, HD = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
@@ -324,11 +326,18 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         from ray_lightning_tpu.ops.attention import PagedDecodeView
 
         bi, off = _write_index(tables, pos, decoding)
+        lengths = pos + 1
+        if tiled_decode:
+            # a slot that is not decoding asks for nothing: its row is
+            # discarded below (`where(decoding, ...)`), and at length 0
+            # the kernel gives it no tile, so an idle slot costs no
+            # fetch and the slot being prefilled is not read twice
+            lengths = jnp.where(decoding, lengths, 0)
         # use_pallas=True (static aux) bakes the build-time decision
         # into the program: fused=True MEANS the kernel, wherever and
         # whenever the jit happens to trace (the shape gate already
         # passed at DecodeEngine init)
-        view = PagedDecodeView(tables=tables, lengths=pos + 1,
+        view = PagedDecodeView(tables=tables, lengths=lengths,
                                write_block=bi, write_offset=off,
                                use_pallas=True)
         logits2, pool, counts = _paged_apply(
@@ -903,6 +912,11 @@ class DecodeEngine:
                 f"these shapes). Run on a TPU, or pass use_pallas=True "
                 "(RLT_PALLAS=1) for interpret mode, with a block_size "
                 "and head count the kernels tile")
+        #: tokens of the fused decode kernel's KV tile (`_step_work`'s
+        #: ``decode_tiles``); None on the reference lane and for a
+        #: decoder that states none
+        self._decode_tile = model.decode_tile_tokens(
+            spec.block_size, cfg.blocks_per_slot) if self.fused else None
         self.draft_model = draft_model
         self.dpool_k = self.dpool_v = self.draft_params = None
         if cfg.draft is not None:
@@ -1218,6 +1232,10 @@ class DecodeEngine:
           kv_tokens     cache tokens their queries read: each reads its
                         ``pos`` written tokens and the one this step
                         writes (the kernel's ``lengths = pos + 1``)
+          decode_tiles  KV tiles a layer the decode kernel computes for
+                        them, ``sum(ceil((pos + 1) / tile))`` with the
+                        kernel's own tile (`decode_tile_tokens`); left
+                        out where the decoder states no tile
           prefill_rows  real prompt rows in this step's chunk (pad
                         columns and the zero tail past a prompt's end
                         are not work); 0 without a chunk
@@ -1236,9 +1254,14 @@ class DecodeEngine:
         start, last = int(start), int(last)
         cols = last + 1 if last >= 0 else self.cfg.prefill_chunk
         lead = np.clip(pads - start, 0, cols)   # pad columns in the chunk
-        return {
+        lengths = np.asarray(pos)[dec] + 1
+        work = {
             "decode_slots": int(dec.sum()),
-            "kv_tokens": int((np.asarray(pos)[dec] + 1).sum()),
+            "kv_tokens": int(lengths.sum()),
             "prefill_rows": int(((cols - lead) * active).sum()),
             "prefill_ctx": int((np.maximum(start - pads, 0) * active).sum()),
         }
+        if self._decode_tile:
+            work["decode_tiles"] = int(
+                np.ceil(lengths / self._decode_tile).sum())
+        return work

@@ -1,11 +1,15 @@
 """Block-size autotune for BOTH paged attention kernels
 (docs/SERVING.md "block-size autotune").
 
-The paged decode kernel streams one pool block per grid step and the
-paged prefill kernel streams one pool block per (row, q-tile) step —
-``block_size`` IS the KV tile, so it sets the DMA granularity, the
-VMEM working set, and (through ``blocks_per_slot = span / block_size``)
-the grid depth. The right value is a hardware question the planner
+``block_size`` is the unit both paged kernels fetch by. The paged prefill
+kernel streams one pool block per (row, q-tile) step: there
+``block_size`` IS the KV tile, so it sets the DMA granularity, the VMEM
+working set and (through ``blocks_per_slot = span / block_size``) the
+grid depth. The paged decode kernel joins ``128 / block_size`` blocks
+into one KV tile of its own (`decode_tile_tokens`) and loops over a
+slot's live tiles, so there ``block_size`` sets only the granularity of
+its copies (one async copy a block) and the table's length, not the
+number of steps. The right value is a hardware question the planner
 cannot answer from byte math, so this module measures it:
 
   * **correctness matrix** — every candidate geometry runs BOTH
@@ -45,10 +49,10 @@ __all__ = [
     "load_artifact", "apply_autotune",
 ]
 
-#: candidate KV-tile widths. 8 is the TPU sublane floor
+#: candidate block sizes. 8 is the TPU sublane floor
 #: (`paged_shapes_supported` rejects smaller); 256 tokens is past the
-#: point where a bigger tile stops amortizing anything and only grows
-#: the VMEM working set.
+#: point where a bigger prefill tile stops amortizing anything and only
+#: grows the VMEM working set (the decode kernel's tile is its own).
 DEFAULT_BLOCK_SIZES = (8, 16, 32, 64, 128, 256)
 
 

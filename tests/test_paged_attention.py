@@ -21,6 +21,8 @@ from ray_lightning_tpu.ops.attention import (
     paged_attention_uses_pallas,
 )
 from ray_lightning_tpu.ops.pallas.paged_attention import (
+    decode_tile_blocks,
+    decode_tile_tokens,
     paged_attention_pallas,
     paged_shapes_supported,
 )
@@ -41,61 +43,151 @@ def _rand_case(rng, C, H, hd, Hkv, P, M, N, dtype=jnp.float32):
     return q, pk, pv, tables, lengths
 
 
+#: a table that is no whole number of tiles: 17 blocks of 16, tiles of 8
+M17, P16 = 17, 16
+TILE = decode_tile_tokens(P16, M17)
+#: a slot's length at every edge of a tile (0: the slot asks for nothing)
+TILE_EDGES = [0, 1, TILE - 1, TILE, TILE + 1, M17 * P16]
+
+
+def _assert_matches(got, ref, lengths, pad=None):
+    """Slots with something visible match the reference; the others
+    (which the reference does not define) read zeros."""
+    live = np.asarray(lengths) > (0 if pad is None else np.asarray(pad))
+    got, ref = np.asarray(got), np.asarray(ref)
+    np.testing.assert_allclose(got[live], ref[live], rtol=2e-5, atol=2e-5)
+    assert np.all(got[~live] == 0.0)
+
+
 @pytest.mark.parametrize("form", POOL_FORMS)
-@pytest.mark.parametrize("C,H,hd,Hkv,P,M,N", [
-    (4, 4, 64, 2, 8, 3, 10),     # GQA 2:1
-    (3, 8, 64, 8, 16, 2, 7),     # MHA, 16-token blocks
-    (2, 4, 128, 1, 8, 4, 6),     # MQA, lane-wide head dim
-    (5, 6, 64, 2, 8, 1, 4),      # single-block table
+@pytest.mark.parametrize("C,H,hd,Hkv,P,M,N,lengths", [
+    (4, 4, 64, 2, 8, 3, 10, None),     # GQA 2:1
+    (3, 8, 64, 8, 16, 2, 7, None),     # MHA, 16-token blocks
+    (2, 4, 128, 1, 8, 4, 6, None),     # MQA, lane-wide head dim
+    (5, 6, 64, 2, 8, 1, 4, None),      # single-block table
+    # what a tile of several blocks adds, by both feeds of the tile
+    # body: the kernel's own copies (hd 128), the pipeline's (hd 64)
+    (6, 4, 128, 2, P16, M17, 40, TILE_EDGES),
+    (6, 4, 64, 2, P16, M17, 40, TILE_EDGES),
+    (4, 8, 128, 8, 32, 9, 20, None),   # MHA, 4-block tiles, 3 a table
 ])
-def test_kernel_matches_reference_matrix(C, H, hd, Hkv, P, M, N, form):
+def test_kernel_matches_reference_matrix(C, H, hd, Hkv, P, M, N, lengths,
+                                         form):
     """The parity matrix: block_size x gathered_len x GQA ratio x
     ragged per-slot lengths, interpret mode on CPU; over the 4-D pool
     and over the stacked pool read at a (traced) layer index, by the
     kernel and by the XLA reference alike."""
     rng = np.random.default_rng(C * 100 + P)
-    q, pk, pv, tables, lengths = _rand_case(rng, C, H, hd, Hkv, P, M, N)
+    q, pk, pv, tables, drawn = _rand_case(rng, C, H, hd, Hkv, P, M, N)
+    lengths = drawn if lengths is None else jnp.asarray(lengths, jnp.int32)
     ref = paged_attention_reference(q, pk, pv, tables, lengths)
     fk, fv, at = pool_form(pk, pv, form)
-    for fn in (paged_attention_pallas, paged_attention_reference):
-        got = jax.jit(fn)(q, fk, fv, tables, lengths, **at)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+    got = jax.jit(paged_attention_pallas)(q, fk, fv, tables, lengths, **at)
+    _assert_matches(got, ref, lengths)
+    twin = jax.jit(paged_attention_reference)(q, fk, fv, tables, lengths,
+                                              **at)
+    live = np.asarray(lengths) > 0       # the reference defines no others
+    np.testing.assert_allclose(np.asarray(twin)[live], np.asarray(ref)[live],
+                               rtol=2e-5, atol=2e-5)
 
 
-def test_kernel_pad_masking_matches_reference():
+@pytest.mark.parametrize("hd,P,M,lengths,pad", [
+    (64, 8, 3, None, [0, 3, 5, 1]),
+    # a left pad that covers a whole tile, a tile and a half, and a pad
+    # that swallows the slot: tiles under the pad are never fetched
+    (128, P16, M17, [200, 272, 270, 140], [0, TILE + 2, 2 * TILE + 60, 140]),
+    (64, P16, M17, [200, 272, 270, 140], [0, TILE + 2, 2 * TILE + 60, 140]),
+])
+def test_kernel_pad_masking_matches_reference(hd, P, M, lengths, pad):
     """Left-pad masking (the batched-prefill contract): positions
     < pad[c] are invisible on both paths."""
     rng = np.random.default_rng(7)
-    q, pk, pv, tables, lengths = _rand_case(rng, 4, 4, 64, 2, 8, 3, 9)
-    pad = jnp.asarray([0, 3, 5, 1], jnp.int32)
+    q, pk, pv, tables, drawn = _rand_case(rng, 4, 4, hd, 2, P, M, 9)
+    lengths = drawn if lengths is None else jnp.asarray(lengths, jnp.int32)
+    pad = jnp.asarray(pad, jnp.int32)
     ref = paged_attention_reference(q, pk, pv, tables, lengths, pad)
     got = paged_attention_pallas(q, pk, pv, tables, lengths, pad)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    _assert_matches(got, ref, lengths, pad)
     # and the pad actually matters: an unpadded run differs
     unpadded = paged_attention_reference(q, pk, pv, tables, lengths)
-    assert not np.allclose(np.asarray(unpadded), np.asarray(ref))
+    assert not np.allclose(np.asarray(unpadded)[1], np.asarray(ref)[1])
 
 
-def test_kernel_scratch_block_zero_masked():
-    """The scratch-block-0 edge: table tails past a slot's length point
-    at block 0 (reserved scratch, garbage by contract). Poisoning
-    scratch with huge values must not perturb any visible output —
-    masked positions contribute exactly zero through the softmax."""
+@pytest.mark.parametrize("poison", [1e9, np.inf, np.nan])
+@pytest.mark.parametrize("hd", [128, 64])
+def test_kernel_dead_blocks_never_reach_the_statistics(hd, poison):
+    """Every block a table names past its slot's length (scratch block
+    0 among them) is garbage by contract: huge values, inf and NaN there
+    leave the output BIT-equal. A dead tile is neither fetched nor
+    stepped over, and a dead block inside a live tile is masked in the
+    scores and its V rows are never multiplied (0 x NaN is NaN)."""
     rng = np.random.default_rng(11)
-    q, pk, pv, tables, lengths = _rand_case(rng, 3, 4, 64, 2, 8, 4, 8)
-    # slot 0: short length, tail table entries -> scratch block 0
-    tables = tables.at[0, 2:].set(0)
-    lengths = lengths.at[0].set(12)  # only blocks 0-1 visible
-    poisoned_k = pk.at[0].set(1e9)
-    poisoned_v = pv.at[0].set(1e9)
-    base = paged_attention_pallas(q, pk.at[0].set(0.0),
-                                  pv.at[0].set(0.0), tables, lengths)
-    hot = paged_attention_pallas(q, poisoned_k, poisoned_v, tables,
-                                 lengths)
-    np.testing.assert_array_equal(np.asarray(base[0]),
-                                  np.asarray(hot[0]))
+    C, N = 4, 64
+    q, pk, pv, _, _ = _rand_case(rng, C, 4, hd, 2, P16, M17, N)
+    lengths = np.asarray([TILE + 40, 12, 2 * TILE, 1])
+    owned = -(-lengths // P16)                 # live blocks a slot
+    free = iter(range(1, N))
+    tables = np.zeros((C, M17), np.int32)
+    live_ids = []
+    for c in range(C):
+        ids = [next(free) for _ in range(owned[c])]
+        tables[c, :owned[c]] = ids
+        live_ids += ids
+    dead_ids = [b for b in range(N) if b not in live_ids]   # 0 included
+    # the tails name dead blocks: scratch 0 and others nobody owns
+    for c in range(C):
+        tables[c, owned[c]:] = rng.choice(dead_ids, M17 - owned[c])
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    dead = jnp.asarray(dead_ids)
+    base = paged_attention_pallas(q, pk.at[dead].set(0.0),
+                                  pv.at[dead].set(0.0), tables, lengths)
+    hot = paged_attention_pallas(q, pk.at[dead].set(poison),
+                                 pv.at[dead].set(poison), tables, lengths)
+    np.testing.assert_array_equal(np.asarray(base), np.asarray(hot))
+    ref = paged_attention_reference(q, pk.at[dead].set(0.0),
+                                    pv.at[dead].set(0.0), tables, lengths)
+    _assert_matches(hot, ref, lengths)
+
+
+def test_in_kernel_copies_under_the_tpu_interpreter(monkeypatch):
+    """The kernel's own copies under the interpreter that models the
+    chip's: a copy lands only when it is waited for, memory never
+    written reads NaN, and races between a copy and the tile body are
+    reported. Idle slots between live ones (the tile in flight crosses
+    grid steps), a partial last tile, a left pad of more than a tile."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_lightning_tpu.ops.pallas import paged_attention as kernel
+
+    monkeypatch.setattr(kernel, "_interpret", lambda: pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True))
+    rng = np.random.default_rng(29)
+    q, pk, pv, tables, _ = _rand_case(rng, 9, 4, 128, 2, P16, M17, 30)
+    lengths = jnp.asarray([0, 0, 3, 0, 272, 0, TILE + 9, 200, 0], jnp.int32)
+    pad = jnp.asarray([0, 0, 0, 0, 0, 0, 0, TILE + 20, 0], jnp.int32)
+    got = paged_attention_pallas(q, pk, pv, tables, lengths, pad)
+    _assert_matches(got, paged_attention_reference(
+        q, pk, pv, tables, lengths, pad), lengths, pad)
+    assert not interpret_pallas_call.races.races_found
+
+
+@pytest.mark.parametrize("hd", [128, 64])
+def test_grid_steps_follow_the_tiles_not_the_table(hd):
+    """The lowered call's grid: at most C x ceil(M / tile_blocks) steps
+    a layer with several blocks a tile at P 16, and a table of 17
+    blocks keeps the tile (no divisor of 17, no (C, M) grid)."""
+    C = 6
+    tile_blocks = decode_tile_blocks(P16, M17)
+    assert tile_blocks > 1 and TILE == tile_blocks * P16
+    rng = np.random.default_rng(31)
+    args = _rand_case(rng, C, 4, hd, 2, P16, M17, 40)
+    calls = [e for e in _walk_eqns(jax.make_jaxpr(paged_attention_pallas)(
+        *args).jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["name"] == "rlt_paged_decode"
+    grid = calls[0].params["grid_mapping"].grid
+    assert int(np.prod(grid)) <= C * -(-M17 // tile_blocks) < C * M17
 
 
 def test_kernel_fully_masked_slot_emits_zeros():

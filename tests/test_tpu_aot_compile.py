@@ -77,16 +77,29 @@ def _pool_operands(form, pool_shape, s):
             {"layer": _sds((), jnp.int32, s)})
 
 
+#: (slots, table blocks) beside the grid's own 8 x SPAN / p: the two dense
+#: serving cells' engines (benchmarks/traffic/chat.json, docs.json) and a
+#: table that is no whole number of tiles
+DECODE_CELLS = [
+    ((16, 8), 128, 16, 64, 160),          # serve.internlm2-1.8b.chat
+    ((32, 8), 128, 16, 16, 272),          # serve.mistral-7b-v0.3.docs
+    ((16, 8), 128, 16, 8, 17),
+]
+
+
 @pytest.mark.parametrize("form", FORMS)
-@pytest.mark.parametrize("heads,hd,p",
-                         itertools.product(HEADS, HEAD_DIMS, BLOCKS))
-def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p, form):
+@pytest.mark.parametrize(
+    "heads,hd,p,c,m",
+    [(*shape, 8, None)
+     for shape in itertools.product(HEADS, HEAD_DIMS, BLOCKS)]
+    + DECODE_CELLS)
+def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p, c, m, form):
     from ray_lightning_tpu.ops.pallas.paged_attention import (
         paged_attention_pallas, paged_shapes_supported,
     )
 
     h, hkv = heads
-    c, m = 8, SPAN // p
+    m = m or SPAN // p
     nb = 1 + c * m
     if not paged_shapes_supported((c, h, hd), (nb, p, hkv, hd)):
         pytest.skip("refused by the predicate: dispatch takes the "
@@ -98,6 +111,11 @@ def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p, form):
         _sds((c, h, hd), bf, s), pool, pool, _sds((c, m), i32, s),
         _sds((c,), i32, s), _sds((c,), i32, s), **at).compile()
     assert _n_mosaic(compiled) == 1
+    if hd % 128 == 0:
+        # the pool is read where it lies: no copy of it in front of the
+        # kernel (at hd 64 XLA relayouts it, as it did before PR 28)
+        pool_bytes = int(np.prod(pool.shape)) * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
 
 
 @pytest.mark.parametrize("form", FORMS)

@@ -140,10 +140,17 @@ def _fit(root, telemetry):
 def _expected_work(sched, ecfg):
     """What the next tick's `rlt.serve.dispatch` must carry, from the
     scheduler's own state (its queue is empty, so the tick admits nothing)."""
+    from ray_lightning_tpu.ops.pallas.paged_attention import (
+        decode_tile_tokens,
+    )
+
     dec = sched.decoding
+    lengths = [int(sched.pos[s]) + 1 for s in range(ecfg.capacity) if dec[s]]
+    tile = decode_tile_tokens(ecfg.block_size, ecfg.blocks_per_slot)
     want = {"decode_slots": int(dec.sum()),
-            "kv_tokens": int(sum(int(sched.pos[s]) + 1
-                                 for s in range(ecfg.capacity) if dec[s])),
+            "kv_tokens": sum(lengths),
+            # the kernel's own tile: a slot costs ceil(length / tile)
+            "decode_tiles": sum(-(-n // tile) for n in lengths),
             "prefill_rows": 0, "prefill_ctx": 0}
     if sched.prefill_groups:
         slot = sched.slots[sched.prefill_groups[0].slots[0]]
@@ -284,7 +291,8 @@ def test_driver_phase_brackets_the_scheduler_tick(traced, phase):
 
 
 @pytest.mark.parametrize("counter", ["decode_slots", "kv_tokens",
-                                     "prefill_rows", "prefill_ctx"])
+                                     "decode_tiles", "prefill_rows",
+                                     "prefill_ctx"])
 def test_dispatch_counters_equal_the_schedulers_own(traced, counter):
     """The context convention must not over-count: a roofline share over
     105% is refused by the benchmark's driver."""
