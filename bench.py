@@ -1223,7 +1223,7 @@ def _run(sink: dict | None = None) -> dict:
         # vocab — the only configuration class that holds at the
         # north-star Llama-3-8B (BASELINE.md config 4: remat+scan+FSDP
         # are mandatory at 8B on real chips), benched at its swept
-        # optimum (scripts/sweep_flagship.py) with the inline-backward
+        # optimum with the inline-backward
         # CE (ops/fused_ce.py _ce_inline — no logits-tile recompute).
         # MFU counts useful FLOPs only: the backward recompute remat
         # performs is real work the flagship deliberately trades for

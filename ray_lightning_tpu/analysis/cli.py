@@ -24,7 +24,7 @@ import sys
 from typing import List, Optional
 
 from ray_lightning_tpu.analysis.findings import (
-    RULES, SEVERITY_RANK, Finding, meets,
+    RULES, SEVERITY_RANK, meets,
 )
 from ray_lightning_tpu.analysis.linter import iter_python_files, lint_paths
 
@@ -191,11 +191,6 @@ def run_lint(args) -> int:
               + ("" if not gate_hit else
                  f" — failing (gate: {args.fail_on})"))
     return 1 if gate_hit else 0
-
-
-def format_findings(findings: List[Finding]) -> str:
-    """Convenience for embedding reports in exceptions/tests."""
-    return "\n".join(f.format() for f in findings)
 
 
 # --------------------------------------------------------------------------

@@ -152,6 +152,14 @@ RULES: Dict[str, Rule] = {r.id: r for r in (
          "prefills ONCE and its full blocks map into every table by "
          "refcount, copy-on-write on divergence (serve/kv_cache.py "
          "PrefixCache, docs/SERVING.md 'prefix cache')"),
+    Rule("RLT310", "walk-incomplete", "error",
+         "a jaxpr walk (tracecheck or numcheck) met an equation it "
+         "could not model: a sub-program no rule entered, or a handler "
+         "that raised — usually a primitive jax renamed or added. What "
+         "lies behind it was not audited, so the report's 'clean' "
+         "cannot be trusted until analysis/jaxpr.py or the walker "
+         "learns it (docs/STATIC_ANALYSIS.md 'what the analyses take "
+         "from jax's internals')"),
     Rule("RLT303", "ring-deadlock", "error",
          "a ppermute permutation is not a valid schedule (duplicate "
          "source/destination, out-of-range rank, a full permutation "

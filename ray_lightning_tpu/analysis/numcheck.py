@@ -3,8 +3,8 @@
 The analysis stack audits sharding (RLT1xx), traced-code hygiene
 (RLT2xx), collectives/HBM (RLT3xx), and host concurrency (RLT7xx);
 this module adds the NUMERICS layer: a dtype-provenance pass over the
-same jaxpr tracecheck walks (recursing into pjit/scan/cond/remat/
-shard_map/pallas_call), emitting RLT801-805 through the shared Finding
+same jaxpr tracecheck walks (entering every sub-program
+analysis/jaxpr.py finds), emitting RLT801-805 through the shared Finding
 vocabulary. docs/STATIC_ANALYSIS.md "numcheck — the precision layer"
 is the prose companion (dtype model, sanction rationale, known limits).
 
@@ -86,6 +86,9 @@ from typing import (
 
 from ray_lightning_tpu.analysis.costmodel import dtype_width
 from ray_lightning_tpu.analysis.findings import Finding
+from ray_lightning_tpu.analysis.jaxpr import (
+    call_body, source_of, sub_jaxprs,
+)
 
 __all__ = [
     "LOW_PRECISION_EXTENT", "numcheck_jaxpr",
@@ -115,15 +118,6 @@ _CARRIES_PROVENANCE = frozenset({
     "expand_dims", "rev", "copy", "slice", "dynamic_slice", "gather",
     "sharding_constraint", "name", "reduce_precision", "pad",
     "stop_gradient", "real", "imag", "neg",
-})
-
-#: sub-jaxpr call-like primitives and where their jaxpr hides — the
-#: same recursion set tracecheck's walker owns
-_CALL_PARAM_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
-_CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "custom_vjp_call",
-    "custom_vjp_call_jaxpr", "custom_jvp_call", "remat2", "checkpoint",
-    "custom_lin",
 })
 
 
@@ -221,22 +215,6 @@ class _NumAuditor:
             self._findings[key] = Finding(
                 rule, f"{message} [at {source}]", symbol=source)
 
-    @staticmethod
-    def _src(eqn) -> str:
-        name = eqn.primitive.name
-        try:
-            from jax._src import source_info_util
-
-            frame = source_info_util.user_frame(eqn.source_info)
-            if frame is not None:
-                base = os.path.basename(frame.file_name)
-                if base == "tracecheck.py":
-                    return f"{name} @ <train-step optimizer update>"
-                return f"{name} @ {base}:{frame.start_line}"
-        except Exception:  # noqa: BLE001 — provenance is best-effort
-            pass
-        return name
-
     def _read(self, env: Dict, v) -> _VInfo:
         if not hasattr(v, "count"):  # Literal
             return _info_for(getattr(v, "aval", None))
@@ -251,9 +229,12 @@ class _NumAuditor:
         for eqn in jaxpr.eqns:
             try:
                 self._process(eqn, env)
-            except Exception:  # noqa: BLE001 — numerics auditing must
-                # degrade, never abort the audit: unknown structure ->
-                # default (dtype-only) provenance for the outputs
+            except Exception as exc:  # noqa: BLE001 — the audit's
+                # promise is to finish: what a handler could not model
+                # is an RLT310 error, and the outputs restart from their
+                # dtype
+                self.flag("RLT310", f"{type(exc).__name__}: {exc}",
+                          source=eqn.primitive.name)
                 for v in eqn.outvars:
                     if hasattr(v, "count"):
                         env[v] = _info_for(getattr(v, "aval", None))
@@ -302,7 +283,7 @@ class _NumAuditor:
         name = eqn.primitive.name
         ins = [self._read(env, v) for v in eqn.invars]
         out = [v for v in eqn.outvars]
-        src = self._src(eqn)
+        src = source_of(eqn)
 
         def set_all(infos: Sequence[_VInfo]) -> None:
             for v, info in zip(out, infos):
@@ -444,41 +425,32 @@ class _NumAuditor:
             # from the ref's dtype — an int8 pool read re-arms the
             # quant flag); kernel OUTPUT provenance does not cross the
             # boundary back out (documented limit)
-            closed = eqn.params.get("jaxpr")
-            if closed is not None:
-                try:
-                    self._seed_and_walk(closed, ins)
-                except Exception:  # noqa: BLE001 — best-effort
-                    pass
+            self._seed_and_walk(eqn.params["jaxpr"], ins)
             set_default()
-        elif name in _CALL_PRIMS:
-            closed = next((eqn.params[k] for k in _CALL_PARAM_KEYS
-                           if eqn.params.get(k) is not None), None)
-            if closed is None:
-                set_default()
-            else:
-                _, outs = self._seed_and_walk(closed, ins)
-                set_all(outs + [self._default_out(ins, getattr(
-                    v, "aval", None)) for v in out[len(outs):]])
         elif name == "remat_opt":
-            closed = eqn.params.get("fwd_jaxpr")
-            if closed is None:
-                set_default()
-            else:
-                _, outs = self._seed_and_walk(closed, ins)
-                by_key: Dict[Tuple, List[_VInfo]] = {}
-                inner = getattr(closed, "jaxpr", closed)
-                for ov, info in zip(inner.outvars, outs):
-                    key = (tuple(getattr(ov.aval, "shape", ())),
-                           _dtype_of(ov.aval))
-                    by_key.setdefault(key, []).append(info)
-                for v in out:
-                    key = (tuple(getattr(v.aval, "shape", ())),
-                           _dtype_of(v.aval))
-                    lst = by_key.get(key)
-                    env[v] = (lst.pop(0) if lst
-                              else self._default_out(ins, v.aval))
+            closed = eqn.params["fwd_jaxpr"]
+            _, outs = self._seed_and_walk(closed, ins)
+            by_key: Dict[Tuple, List[_VInfo]] = {}
+            for ov, info in zip(closed.jaxpr.outvars, outs):
+                key = (tuple(getattr(ov.aval, "shape", ())),
+                       _dtype_of(ov.aval))
+                by_key.setdefault(key, []).append(info)
+            for v in out:
+                key = (tuple(getattr(v.aval, "shape", ())),
+                       _dtype_of(v.aval))
+                lst = by_key.get(key)
+                env[v] = (lst.pop(0) if lst
+                          else self._default_out(ins, v.aval))
+        elif (body := call_body(eqn)) is not None:
+            # a plain call, whatever jax names it
+            _, outs = self._seed_and_walk(body, ins)
+            set_all(outs)
         else:
+            subs = sub_jaxprs(eqn)
+            if subs:
+                self.flag("RLT310", "the walk has no rule that enters "
+                          "this equation's sub-program(s) "
+                          f"{[k for k, _ in subs]}", source=src)
             set_default()
 
     # ---- convert / scale / control flow ---------------------------------

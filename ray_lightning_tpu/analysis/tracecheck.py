@@ -7,7 +7,7 @@ tracecheck closes that gap without touching hardware: it traces the
 strategy's real train step with `jax.make_jaxpr` over abstractions
 (`jax.eval_shape` params over an `AbstractMesh` — runs under
 `JAX_PLATFORMS=cpu`), then walks the jaxpr, recursing into
-pjit/scan/while/cond/remat/shard_map sub-jaxprs, and reports:
+every sub-program (analysis/jaxpr.py finds them by structure), and reports:
 
   1. the **collective schedule** — every explicit psum / all_gather /
      reduce_scatter / ppermute / all_to_all (shard_map islands: ring and
@@ -39,7 +39,8 @@ reduce_scatter when the result is parameter-shaped (ZeRO) and psum
 otherwise; axis conflicts are resolved the way GSPMD prefers — gather
 the parameter-derived side (that IS the FSDP plan), flag the
 activation-derived side. Unknown primitives degrade to unknown
-shardings, never to invented findings. Real schedules may beat the
+shardings, never to invented findings, and never in silence
+(`TraceReport.lost_specs`, `.unentered`). Real schedules may beat the
 estimate (e.g. XLA can turn a psum into reduce_scatter+all_gather and
 overlap it); treat the numbers as a reviewable upper bound, stable
 across refactors — the point is the DIFF between two plans, not chip
@@ -54,7 +55,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 from typing import (
     Any, Dict, FrozenSet, List, Mapping, Optional, Sequence, Tuple,
 )
@@ -63,6 +63,9 @@ from ray_lightning_tpu.analysis.costmodel import (
     Topology, collective_cost, compute_time_us, parse_topology,
 )
 from ray_lightning_tpu.analysis.findings import Finding
+from ray_lightning_tpu.analysis.jaxpr import (
+    call_body, dce, pallas_kernel_ident, source_of, sub_jaxprs, walk_eqns,
+)
 from ray_lightning_tpu.ops.dispatch import OVERLAP_PREFETCH_NAME
 
 __all__ = [
@@ -141,6 +144,10 @@ class _VarInfo:
     #: not at the loop's trip count (lm_head inside the CE chunk scan:
     #: one gather per step, not one per chunk).
     born_mult: int = 1
+    #: a partial sum the walk finished as the ZeRO reduce-scatter into
+    #: its parameter's layout (`_resolve_partial`): a gradient at rest.
+    #: Survives casts and barriers, not arithmetic.
+    zero_grad: bool = False
 
 
 @dataclasses.dataclass
@@ -211,21 +218,6 @@ class CollectiveEvent:
                 f"{self.source}{who}")
 
 
-def _pallas_kernel_ident(eqn) -> str:
-    """One kernel-fn identity string for a `pallas_call` eqn
-    ("_decode_kernel at .../paged_attention.py:76" style) — the SINGLE
-    extraction both the step auditor and the serve audit's recursive
-    scanner use, so the fingerprint can never drift between them."""
-    # jax 0.9 keeps the kernel's name and source line on the kernel
-    # jaxpr's debug info ("rlt_paged_decode at .../paged_attention.py:76");
-    # older releases carried the same string as `name_and_src_info`
-    debug = getattr(eqn.params.get("jaxpr"), "debug_info", None)
-    ident = (eqn.params.get("name_and_src_info")
-             or getattr(debug, "func_src_info", None)
-             or eqn.params.get("name") or "pallas")
-    return str(ident)
-
-
 def _aval_dtype(aval) -> Optional[str]:
     dt = getattr(aval, "dtype", None)
     return str(dt) if dt is not None else None
@@ -258,7 +250,7 @@ class TraceReport:
     #: hidden/exposed ICI time, per-scope breakdown. None only when
     #: classification was skipped.
     overlap: Optional[Dict[str, Any]] = None
-    #: pallas kernel identities the walk met (`_pallas_kernel_ident`)
+    #: pallas kernel identities the walk met (`jaxpr.pallas_kernel_ident`)
     #: — the serve audit's "which attention path does this step run"
     #: evidence (empty on pure-XLA programs)
     pallas_kernels: List[str] = dataclasses.field(default_factory=list)
@@ -270,6 +262,20 @@ class TraceReport:
     #: the loss output's provenance path. None when the audit ran with
     #: numerics off.
     precision: Optional[Dict[str, Any]] = None
+    #: primitives that took the walk's default "unknown spec" branch on
+    #: a SHARDED operand, with how many equations did: every spec the
+    #: model lost is lost on the record (what flows on is sized whole
+    #: and implies no collective). A warning; the model pins hold the
+    #: bundled models' set of names.
+    lost_specs: Dict[str, int] = dataclasses.field(default_factory=dict)
+
+    @property
+    def unentered(self) -> List[str]:
+        """What the walks (tracecheck's and numcheck's) could not model
+        at all: an equation whose sub-program no rule entered, or whose
+        handler raised. Each is an RLT310 error finding; non-empty means
+        the report's "clean" is not to be trusted."""
+        return [f.message for f in self.findings if f.rule == "RLT310"]
 
     @property
     def ici_bytes_per_step(self) -> int:
@@ -378,6 +384,12 @@ class TraceReport:
             widest = self.precision.get("loss_widest_dtype")
             if widest:
                 lines.append(f"  loss widest-path dtype: {widest}")
+        if self.lost_specs:
+            lines.append(
+                "warning: sharding lost at primitives the walk has no "
+                "rule for (sized whole downstream, no collective "
+                "implied): " + ", ".join(
+                    f"{n} x{c}" for n, c in sorted(self.lost_specs.items())))
         if self.findings:
             lines.append(f"findings ({len(self.findings)}):")
             lines.extend("  " + f.format() for f in self.findings)
@@ -426,6 +438,8 @@ class TraceReport:
             "fits": self.fits,
             "pallas_kernels": list(self.pallas_kernels),
             "precision": self.precision,
+            "unentered": self.unentered,
+            "lost_specs": dict(sorted(self.lost_specs.items())),
             "findings": [f.to_dict() for f in self.findings],
         }
 
@@ -512,8 +526,7 @@ class _StepAuditor:
     are its aval bytes divided by the product of its sharded axis sizes
     (inside shard_map the aval already IS per-shard)."""
 
-    def __init__(self, mesh_sizes: Mapping[str, int], topo: Topology,
-                 param_shapes: Mapping[Tuple, Tuple[Spec, str]]):
+    def __init__(self, mesh_sizes: Mapping[str, int], topo: Topology):
         self.sizes = {ax: s for ax, s in mesh_sizes.items() if s > 1}
         #: FULL axis sizes (incl. trivial) — the slice-layout math needs
         #: the whole mixed radix, not just the live axes
@@ -522,8 +535,8 @@ class _StepAuditor:
         self._dcn_span_cache: Dict[Tuple[str, ...], int] = {}
         #: shape -> (spec, path) for param/opt leaves AND their
         #: leading-dim-stripped (scan-stacked) suffixes: the ZeRO
-        #: reduce_scatter matcher
-        self.param_shapes = dict(param_shapes)
+        #: reduce_scatter matcher (`seed_state` fills it)
+        self.param_shapes: Dict[Tuple, Tuple[Spec, str]] = {}
         self._events: Dict[Tuple, CollectiveEvent] = {}
         self._findings: Dict[Tuple, Finding] = {}
         self._quiet = 0          # scan-fixpoint passes record nothing
@@ -537,7 +550,7 @@ class _StepAuditor:
         #: (ops.dispatch.OVERLAP_PREFETCH_NAME name equations)
         self.saw_prefetch_marker = False
         #: every pallas kernel the walk met, by its kernel-fn identity
-        #: (`_pallas_kernel_ident`) — surfaced as
+        #: (`jaxpr.pallas_kernel_ident`) — surfaced as
         #: `TraceReport.pallas_kernels`, where the serve audit/smoke
         #: read "which attention path does this step run": the same
         #: fingerprint-over-reimplementation discipline as the flash
@@ -549,6 +562,10 @@ class _StepAuditor:
         #: precision ledger keep `sum(peak_by) == peak` exact through
         #: nested scan/pjit/cond scratch
         self._sub_by: Dict[str, int] = {}
+        #: `TraceReport.lost_specs`; kernel interiors (VMEM, under a
+        #: `pallas_call`) are not counted
+        self.lost_specs: Dict[str, int] = {}
+        self._in_kernel = 0
 
     # ---- bookkeeping ----------------------------------------------------
 
@@ -559,6 +576,23 @@ class _StepAuditor:
             return None
         return tuple(frozenset(ax for ax in s if ax in self.sizes)
                      for s in spec)
+
+    def seed_state(self, named: Mapping[str, Any], pspecs: Sequence,
+                   prefix: str) -> List[_VarInfo]:
+        """Seeds for a tree of state leaves (params, optimizer state)
+        laid out as ``pspecs``, in flatten order; each leaf's shape, and
+        its scan-stacked suffix, also enters `param_shapes` (the ZeRO
+        reduce-scatter matcher)."""
+        seeds = []
+        for (path, leaf), pspec in zip(named.items(), pspecs, strict=True):
+            shape = tuple(getattr(leaf, "shape", ()))
+            spec = self._canon(_spec_of_partition_spec(pspec, len(shape)))
+            seeds.append(_VarInfo(spec, param=True, path=f"{prefix}/{path}"))
+            self.param_shapes.setdefault(shape, (spec, f"{prefix}/{path}"))
+            if len(shape) >= 2:
+                self.param_shapes.setdefault(
+                    shape[1:], (spec[1:], f"{prefix}/{path}"))
+        return seeds
 
     def _div(self, spec: Spec) -> int:
         if spec is None:
@@ -637,6 +671,14 @@ class _StepAuditor:
                 rule, f"{message} [at {source}]",
                 symbol=param_path or source)
 
+    def incomplete(self, where: str, why: str) -> None:
+        """RLT310: the walk could not model the equation at ``where``
+        (a sub-program no rule entered, a handler that raised). Recorded
+        on every pass: what a scan's fixpoint pass could not enter, the
+        recording pass cannot either."""
+        self._findings.setdefault(("RLT310", where, why[:100]), Finding(
+            "RLT310", f"{why} [at {where}]", symbol=where))
+
     @property
     def events(self) -> List[CollectiveEvent]:
         return list(self._events.values())
@@ -655,24 +697,6 @@ class _StepAuditor:
         if got is None:
             return _VarInfo(None, param=False)
         return got
-
-    @staticmethod
-    def _src(eqn) -> str:
-        name = eqn.primitive.name
-        try:
-            from jax._src import source_info_util
-
-            frame = source_info_util.user_frame(eqn.source_info)
-            if frame is not None:
-                base = os.path.basename(frame.file_name)
-                if base == "tracecheck.py":
-                    # the synthetic step wrapper (grads -> tx.update ->
-                    # apply_updates): name the phase, not this file
-                    return f"{name} @ <train-step optimizer update>"
-                return f"{name} @ {base}:{frame.start_line}"
-        except Exception:  # noqa: BLE001 — provenance is best-effort
-            pass
-        return name
 
     # ---- conflict resolution --------------------------------------------
 
@@ -795,14 +819,17 @@ class _StepAuditor:
 
     def _resolve_partial(self, out_aval, out_spec: List[FrozenSet[str]],
                          partial: FrozenSet[str], mult: int,
-                         source: str, path: Optional[str]) -> Spec:
+                         source: str, path: Optional[str],
+                         ) -> Tuple[Spec, bool]:
         """A value is partial-summed over ``partial``: GSPMD finishes it
         with reduce_scatter when the result is parameter-shaped (its grad
-        lands sharded like the param — ZeRO) and all-reduce otherwise."""
+        lands sharded like the param — ZeRO) and all-reduce otherwise.
+        Returns the finished spec and whether it was the reduce-scatter
+        (`_VarInfo.zero_grad`)."""
         partial = partial - frozenset(
             ax for s in out_spec for ax in s)  # cannot both shard & reduce
         if not partial:
-            return tuple(out_spec)
+            return tuple(out_spec), False
         shape = tuple(getattr(out_aval, "shape", ()))
         match = self._param_match(shape, partial)
         if match is not None:
@@ -812,12 +839,12 @@ class _StepAuditor:
                         mult, implicit=True, source=source,
                         param_path=mpath or path, prefetchable=True,
                         dtype=_aval_dtype(out_aval))
-            return tuple(s | m for s, m in zip(out_spec, mspec))
+            return tuple(s | m for s, m in zip(out_spec, mspec)), True
         payload = self._aval_bytes(out_aval, tuple(out_spec))
         self.record("psum", payload, sorted(partial), mult,
                     implicit=True, source=source, param_path=path,
                     dtype=_aval_dtype(out_aval))
-        return tuple(out_spec)
+        return tuple(out_spec), False
 
     # ---- the walk -------------------------------------------------------
 
@@ -861,8 +888,11 @@ class _StepAuditor:
             self._sub_by = {}
             try:
                 sub_peak = self._process(eqn, env, mult, manual)
-            except Exception:  # noqa: BLE001 — propagation must degrade,
-                # never abort the audit: unknown structure -> unknown spec
+            except Exception as exc:  # noqa: BLE001 — the audit's promise
+                # is to finish: what a handler could not model is an
+                # RLT310 error on the report and flows on as unknown
+                self.incomplete(eqn.primitive.name,
+                                f"{type(exc).__name__}: {exc}")
                 for v in eqn.outvars:
                     env[v] = _VarInfo(None)
                 sub_peak = 0
@@ -920,7 +950,7 @@ class _StepAuditor:
         infos = [self._info(v, env) for v in eqn.invars]
         avals = [getattr(v, "aval", None) for v in eqn.invars]
         out = eqn.outvars
-        src = self._src(eqn)
+        src = source_of(eqn)
         sub_peak = 0
 
         if (name == "name"
@@ -1010,6 +1040,14 @@ class _StepAuditor:
                 set_unknown()
         elif name == "slice":
             set_all([self._slice(eqn, infos[0], avals[0])])
+        elif name == "split":
+            # jnp.split: every piece keeps the operand's layout but on
+            # the split dim (the `slice` rule, for all pieces at once)
+            spec, ax = infos[0].spec, eqn.params["axis"]
+            new = (tuple(frozenset() if d == ax else s
+                         for d, s in enumerate(spec))
+                   if spec is not None else None)
+            set_all([dataclasses.replace(infos[0], spec=new) for _ in out])
         elif name in ("dynamic_slice", "dynamic_update_slice"):
             spec = infos[0].spec
             if spec is not None:
@@ -1035,8 +1073,12 @@ class _StepAuditor:
                     frozenset() if d == cd else frozenset.intersection(
                         *(i.spec[d] for i in infos))
                     for d in range(ondim))
-                set_all([_VarInfo(spec,
-                                  param=all(i.param for i in infos))])
+                # pieces of one leaf (the overlap schedule's rolled
+                # copy of the layer stack) keep that leaf's name
+                paths = {i.path for i in infos}
+                set_all([_VarInfo(spec, param=all(i.param for i in infos),
+                                  path=paths.pop() if len(paths) == 1
+                                  else None)])
         elif name == "conv_general_dilated":
             # batch passthrough only: the output batch dim keeps the
             # input's sharding; kernel/feature placement and conv-dgrad
@@ -1064,17 +1106,14 @@ class _StepAuditor:
             # buffers are VMEM, not HBM: they contribute NOTHING to the
             # liveness peak (sub_peak stays 0).
             if not self._quiet:
-                self.pallas_kernels.append(_pallas_kernel_ident(eqn))
-            closed = eqn.params.get("jaxpr")
-            if closed is not None:
-                try:
-                    self._seed_and_walk(closed, infos, env, mult, manual)
-                except Exception:  # noqa: BLE001 — recognition is
-                    pass           # best-effort, never aborts the audit
-                # kernel buffers are VMEM: the recursive walk was for
-                # recognition only, its bytes must not leak into the
-                # enclosing HBM snapshot (sub_peak stays 0)
-                self._sub_by = {}
+                self.pallas_kernels.append(pallas_kernel_ident(eqn))
+            self._in_kernel += 1
+            try:
+                self._seed_and_walk(eqn.params["jaxpr"], infos, env, mult,
+                                    manual)
+            finally:
+                self._in_kernel -= 1
+            self._sub_by = {}  # VMEM: nothing for the HBM snapshot
             set_all([self._like_shaped_input(v, infos, avals)
                      for v in out])
         elif name == "gather":
@@ -1087,40 +1126,23 @@ class _StepAuditor:
         elif name in _REPLICATED_SOURCES:
             set_all([_VarInfo(_repl(len(getattr(v.aval, "shape", ()))),
                               param=True) for v in out])
-        elif name == "sharding_constraint":
+        elif name in ("sharding_constraint", "reshard"):
             set_all([self._sharding_constraint(eqn, infos[0], avals[0],
                                                mult, src)])
-        elif name in ("pjit", "closed_call", "core_call", "custom_vjp_call",
-                      "custom_vjp_call_jaxpr", "custom_jvp_call",
-                      "remat2", "checkpoint", "custom_lin"):
-            closed = (eqn.params.get("jaxpr")
-                      or eqn.params.get("call_jaxpr")
-                      or eqn.params.get("fun_jaxpr"))
-            if closed is None:
-                set_unknown()
-            else:
-                sub_peak, outs = self._seed_and_walk(
-                    closed, infos, env, mult, manual)
-                set_all(outs + [_VarInfo(None)] * (len(out) - len(outs)))
         elif name == "remat_opt":
             # custom-vjp fwd wrapper (jax >= 0.4.3x): fwd_jaxpr computes
             # primal outputs AND residuals, possibly interleaved — match
             # eqn outvars to inner outvars by shape
-            closed = eqn.params.get("fwd_jaxpr")
-            if closed is None:
-                set_unknown()
-            else:
-                sub_peak, outs = self._seed_and_walk(
-                    closed, infos, env, mult, manual)
-                by_shape: Dict[Tuple, List[_VarInfo]] = {}
-                for ov, info in zip(closed.jaxpr.outvars, outs):
-                    by_shape.setdefault(
-                        tuple(getattr(ov.aval, "shape", ())),
-                        []).append(info)
-                for v in out:
-                    lst = by_shape.get(
-                        tuple(getattr(v.aval, "shape", ())))
-                    env[v] = lst.pop(0) if lst else _VarInfo(None)
+            closed = eqn.params["fwd_jaxpr"]
+            sub_peak, outs = self._seed_and_walk(
+                closed, infos, env, mult, manual)
+            by_shape: Dict[Tuple, List[_VarInfo]] = {}
+            for ov, info in zip(closed.jaxpr.outvars, outs):
+                by_shape.setdefault(
+                    tuple(getattr(ov.aval, "shape", ())), []).append(info)
+            for v in out:
+                lst = by_shape.get(tuple(getattr(v.aval, "shape", ())))
+                env[v] = lst.pop(0) if lst else _VarInfo(None)
         elif name == "scan":
             sub_peak = self._scan(eqn, infos, env, mult, manual)
         elif name == "while":
@@ -1137,7 +1159,21 @@ class _StepAuditor:
                     or [_VarInfo(None) for _ in out])
         elif name == "axis_index":
             set_all([_VarInfo(_repl(0), param=True) for _ in out])
+        elif (body := call_body(eqn)) is not None:
+            # a plain call, whatever jax names it (jit, remat2,
+            # custom_jvp_call, custom_vjp_call, closed_call, ...)
+            sub_peak, outs = self._seed_and_walk(body, infos, env, mult,
+                                                 manual)
+            set_all(outs)
         else:
+            subs = sub_jaxprs(eqn)
+            if subs:
+                self.incomplete(src, "the walk has no rule that enters "
+                                f"this equation's sub-program(s) "
+                                f"{[k for k, _ in subs]}")
+            if (not self._quiet and not self._in_kernel and any(
+                    i.spec is not None and _axes_in(i.spec) for i in infos)):
+                self.lost_specs[name] = self.lost_specs.get(name, 0) + 1
             set_unknown()
         return sub_peak
 
@@ -1266,10 +1302,11 @@ class _StepAuditor:
                 if lose_d == prev:
                     seen[ax] = d
         self._charge_flops(eqn, avals, out_spec, partial)
-        spec = self._resolve_partial(
+        spec, zero_grad = self._resolve_partial(
             eqn.outvars[0].aval, out_spec, partial, mult, src,
             li.path if li.param else ri.path if ri.param else None)
-        return _VarInfo(spec, param=li.param and ri.param)
+        return _VarInfo(spec, param=li.param and ri.param,
+                        zero_grad=zero_grad)
 
     def _charge_flops(self, eqn, avals, out_spec, partial) -> None:
         """Accumulate this dot_general's per-device FLOPs into the
@@ -1281,24 +1318,21 @@ class _StepAuditor:
         scan trip."""
         if self._quiet or not self._scope_stack:
             return
-        try:
-            (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
-            lshape = tuple(getattr(avals[0], "shape", ()))
-            rshape = tuple(getattr(avals[1], "shape", ()))
-            batch = math.prod(lshape[d] for d in lb) or 1
-            k = math.prod(lshape[d] for d in lc) or 1
-            m = math.prod(lshape[d] for d in range(len(lshape))
-                          if d not in tuple(lc) + tuple(lb)) or 1
-            n = math.prod(rshape[d] for d in range(len(rshape))
-                          if d not in tuple(rc) + tuple(rb)) or 1
-            axes = set(partial)
-            for s in out_spec:
-                axes |= s
-            div = math.prod(self.sizes.get(ax, 1) for ax in axes) or 1
-            self.scopes[self._scope_stack[-1]]["flops"] += (
-                2.0 * batch * m * n * k / div)
-        except Exception:  # noqa: BLE001 — accounting must not abort
-            pass
+        (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
+        lshape = tuple(getattr(avals[0], "shape", ()))
+        rshape = tuple(getattr(avals[1], "shape", ()))
+        batch = math.prod(lshape[d] for d in lb) or 1
+        k = math.prod(lshape[d] for d in lc) or 1
+        m = math.prod(lshape[d] for d in range(len(lshape))
+                      if d not in tuple(lc) + tuple(lb)) or 1
+        n = math.prod(rshape[d] for d in range(len(rshape))
+                      if d not in tuple(rc) + tuple(rb)) or 1
+        axes = set(partial)
+        for s in out_spec:
+            axes |= s
+        div = math.prod(self.sizes.get(ax, 1) for ax in axes) or 1
+        self.scopes[self._scope_stack[-1]]["flops"] += (
+            2.0 * batch * m * n * k / div)
 
     def _gather_prim(self, eqn, infos, avals, mult, src) -> _VarInfo:
         """lax.gather (embedding lookups, take_along_axis): output batch
@@ -1355,14 +1389,13 @@ class _StepAuditor:
             ax for d in axes_param for ax in info.spec[d])
         out_spec = [s for d, s in enumerate(info.spec)
                     if d not in set(axes_param)]
+        spec, zero_grad = tuple(out_spec), False
         if reduced and eqn.primitive.name in _REDUCE_COMM:
-            spec = self._resolve_partial(
+            spec, zero_grad = self._resolve_partial(
                 eqn.outvars[0].aval, out_spec, reduced, mult, src,
                 info.path)
-        else:
-            spec = tuple(out_spec)
         return _VarInfo(spec, param=all(i.param for i in infos),
-                        path=info.path)
+                        path=info.path, zero_grad=zero_grad)
 
     def _scatter_add(self, eqn, infos, avals, mult, src) -> _VarInfo:
         # operand, indices, updates. The canonical site: an embedding
@@ -1372,12 +1405,12 @@ class _StepAuditor:
         partial = _axes_in(upd.spec) - _axes_in(op.spec)
         base = list(op.spec) if op.spec is not None else [
             frozenset() for _ in getattr(eqn.outvars[0].aval, "shape", ())]
+        spec, zero_grad = tuple(base), False
         if partial:
-            spec = self._resolve_partial(
+            spec, zero_grad = self._resolve_partial(
                 eqn.outvars[0].aval, base, partial, mult, src, op.path)
-        else:
-            spec = tuple(base)
-        return _VarInfo(spec, param=op.param and upd.param, path=op.path)
+        return _VarInfo(spec, param=op.param and upd.param, path=op.path,
+                        zero_grad=zero_grad)
 
     def _scatter_overwrite(self, eqn, infos, avals, mult, src) -> _VarInfo:
         # plain functional scatter (`x.at[idx].set(v)` — the serving
@@ -1419,10 +1452,7 @@ class _StepAuditor:
             return _VarInfo(None, param=info.param, path=info.path)
         in_shape = tuple(getattr(aval, "shape", ()))
         out_shape = tuple(eqn.params["new_sizes"])
-        try:
-            spec = _reshape_spec(in_shape, info.spec, out_shape)
-        except Exception:  # noqa: BLE001 — degenerate shapes: give up
-            spec = None
+        spec = _reshape_spec(in_shape, info.spec, out_shape)
         return _VarInfo(spec, param=info.param, path=info.path)
 
     def _slice(self, eqn, info, aval) -> _VarInfo:
@@ -1440,7 +1470,10 @@ class _StepAuditor:
 
     def _sharding_constraint(self, eqn, info, aval, mult,
                              src) -> _VarInfo:
-        sharding = eqn.params.get("sharding")
+        # `sharding_constraint` states its layout as `sharding`,
+        # `reshard` (the explicit-mode twin) as `dst_sharding`
+        sharding = (eqn.params.get("sharding")
+                    or eqn.params.get("dst_sharding"))
         pspec = getattr(sharding, "spec", None)
         ndim = len(getattr(aval, "shape", ()))
         if pspec is None:
@@ -1448,6 +1481,14 @@ class _StepAuditor:
         annotated = self._canon(_spec_of_partition_spec(pspec, ndim))
         if info.spec is not None:
             lost = _axes_in(info.spec) - _axes_in(annotated)
+            if lost and info.zero_grad:
+                # AD transposes a weight-gather constraint onto the
+                # weight's cotangent, which the walk has just finished
+                # as the reduce-scatter into the parameter's layout —
+                # where the overlap schedule pins it (`shard_alike`).
+                # One reduction a layer, charged there: a gather on top
+                # would count it twice.
+                return dataclasses.replace(info)
             if lost:
                 payload = self._aval_bytes(aval, annotated)
                 self.record("all_gather", payload, sorted(lost), mult,
@@ -1495,7 +1536,7 @@ class _StepAuditor:
                 break
         sid = len(self.scopes)
         self.scopes[sid] = {"trips": length, "flops": 0.0,
-                            "source": self._src(eqn), "marker": False}
+                            "source": source_of(eqn), "marker": False}
         self._scope_stack.append(sid)
         try:
             sub_peak, outs = self._seed_and_walk(
@@ -1579,26 +1620,16 @@ class _StepAuditor:
 
     def _shard_map(self, eqn, infos, env, mult) -> int:
         inner = eqn.params["jaxpr"]
-        out_names = eqn.params.get("out_names", ())
-        seeds = []
-        for iv, outer in zip(inner.invars, infos):
-            ndim = len(getattr(iv.aval, "shape", ()))
-            seeds.append(_VarInfo(_repl(ndim), param=outer.param,
-                                  path=outer.path))
-        sub_env: Dict = {}
-        for iv, s in zip(inner.invars, seeds):
-            sub_env[iv] = s
-        for cv in inner.constvars:
-            sub_env[cv] = _VarInfo(
-                _repl(len(getattr(cv.aval, "shape", ()))), param=True)
-        sub_peak, self._sub_by = self.walk(inner, sub_env, mult, True)
-        for v, names in zip(eqn.outvars, out_names):
+        # inside, every value is the local shard: nothing left to shard
+        seeds = [_VarInfo(_repl(len(getattr(iv.aval, "shape", ()))),
+                          param=outer.param, path=outer.path)
+                 for iv, outer in zip(inner.invars, infos)]
+        sub_peak, _ = self._seed_and_walk(inner, seeds, env, mult, True)
+        for v, pspec in zip(eqn.outvars, eqn.params["out_specs"],
+                            strict=True):
             ndim = len(getattr(v.aval, "shape", ()))
-            spec = [frozenset() for _ in range(ndim)]
-            for d, axes in (names or {}).items():
-                if d < ndim:
-                    spec[d] = frozenset(axes)
-            env[v] = _VarInfo(self._canon(tuple(spec)))
+            env[v] = _VarInfo(self._canon(
+                _spec_of_partition_spec(pspec, ndim)))
         return sub_peak
 
     def _collective(self, eqn, infos, avals, mult, manual, src) -> None:
@@ -1668,18 +1699,13 @@ def _collective_signature(jaxpr) -> List[Tuple[str, Tuple]]:
     """(prim, axes) sequence of every collective in program order,
     recursively — the cond-branch divergence comparator."""
     sig: List[Tuple[str, Tuple]] = []
-    for eqn in jaxpr.eqns:
+    for eqn, _ in walk_eqns(jaxpr):
         if eqn.primitive.name in _COLLECTIVES:
             axes = (eqn.params.get("axes")
                     or eqn.params.get("axis_name") or ())
             if not isinstance(axes, (tuple, list)):
                 axes = (axes,)
             sig.append((eqn.primitive.name, tuple(map(str, axes))))
-        for v in eqn.params.values():
-            for x in (v if isinstance(v, (tuple, list)) else (v,)):
-                inner = getattr(x, "jaxpr", x)
-                if hasattr(inner, "eqns"):
-                    sig.extend(_collective_signature(inner))
     return sig
 
 
@@ -1820,7 +1846,7 @@ def trace_step(module, strategy, n_devices: int, example_batch: Any):
             return params, opt_state, loss, metrics
 
         closed = jax.make_jaxpr(step)(a_params, a_opt, a_batch, a_key)
-    closed = _dce(closed)
+    closed = dce(closed)
 
     meta = {
         "spec": spec,
@@ -1835,28 +1861,6 @@ def trace_step(module, strategy, n_devices: int, example_batch: Any):
         "batch_pspec": strategy.batch_spec(),
     }
     return closed, meta
-
-
-def _dce(closed):
-    """Dead-code-eliminate the traced jaxpr (all outputs kept, all
-    invars kept) so the audit walks the program XLA actually compiles.
-    jit runs the same pass before lowering; without it the walk charges
-    vestigial residuals AD plumbing leaves behind — e.g. grad-of-scan
-    under the overlap schedule stacks the gathered weight carry as ys
-    that NOTHING in the backward scan consumes (measured: a phantom
-    full-stack copy, ~26 GiB on llama3-8b). Degrades to the raw jaxpr
-    if the DCE helper is unavailable."""
-    try:
-        import jax
-        from jax.interpreters import partial_eval as _pe
-
-        jaxpr, _ = _pe.dce_jaxpr(
-            closed.jaxpr, [True] * len(closed.jaxpr.outvars),
-            instantiate=True)
-        return jax.core.ClosedJaxpr(jaxpr, closed.consts)
-    except Exception:  # noqa: BLE001 — an uncooperative jax version
-        # costs precision, never the audit
-        return closed
 
 
 def audit_step(
@@ -1888,63 +1892,29 @@ def audit_step(
         n_devices = topo.n_devices
     closed, meta = trace_step(module, strategy, n_devices, example_batch)
     sizes = meta["mesh_sizes"]
-    live_axes = {ax for ax, s in sizes.items() if s > 1}
-
-    def canon(spec):
-        return tuple(frozenset(ax for ax in s if ax in live_axes)
-                     for s in spec)
-
-    # the ZeRO reduce_scatter matcher: param/opt shapes (and their
-    # scan-stacked suffixes) with their composed specs
-    param_shapes: Dict[Tuple, Tuple[Spec, str]] = {}
-
-    def feed(named, shardings, prefix):
-        for (path, leaf), sh in zip(
-                named.items(), jax.tree.leaves(shardings)):
-            shape = tuple(getattr(leaf, "shape", ()))
-            spec = canon(_spec_of_partition_spec(
-                getattr(sh, "spec", sh), len(shape)))
-            param_shapes.setdefault(shape, (spec, f"{prefix}/{path}"))
-            if len(shape) >= 2:
-                param_shapes.setdefault(
-                    shape[1:], (spec[1:], f"{prefix}/{path}"))
-
-    feed(meta["named_params"], meta["p_shardings"], "params")
-    feed(meta["named_opt"], meta["o_shardings"], "opt_state")
-
-    auditor = _StepAuditor(sizes, topo, param_shapes)
-
+    auditor = _StepAuditor(sizes, topo)
     # seed the top-level env: flatten order mirrors the step signature
-    env: Dict = {}
-    seeds: List[_VarInfo] = []
-    for (path, leaf), sh in zip(meta["named_params"].items(),
-                                jax.tree.leaves(meta["p_shardings"])):
-        ndim = len(getattr(leaf, "shape", ()))
-        seeds.append(_VarInfo(
-            canon(_spec_of_partition_spec(getattr(sh, "spec", sh), ndim)),
-            param=True, path=f"params/{path}"))
-    for (path, leaf), sh in zip(meta["named_opt"].items(),
-                                jax.tree.leaves(meta["o_shardings"])):
-        ndim = len(getattr(leaf, "shape", ()))
-        seeds.append(_VarInfo(
-            canon(_spec_of_partition_spec(getattr(sh, "spec", sh), ndim)),
-            param=True, path=f"opt_state/{path}"))
+    seeds = auditor.seed_state(
+        meta["named_params"],
+        [getattr(sh, "spec", sh)
+         for sh in jax.tree.leaves(meta["p_shardings"])], "params")
+    np_ = len(seeds)
+    seeds += auditor.seed_state(
+        meta["named_opt"],
+        [getattr(sh, "spec", sh)
+         for sh in jax.tree.leaves(meta["o_shardings"])], "opt_state")
     from ray_lightning_tpu.utils.pytree import named_leaves
 
     batch_pspec = meta["batch_pspec"]
     for path, leaf in named_leaves(meta["a_batch"]):
         ndim = len(getattr(leaf, "shape", ()))
         seeds.append(_VarInfo(
-            canon(_spec_of_partition_spec(batch_pspec, ndim)),
+            auditor._canon(_spec_of_partition_spec(batch_pspec, ndim)),
             param=False, path=f"batch/{path}"))
     seeds.append(_VarInfo(None, param=True, path="rng"))  # key leaf
 
     jaxpr = closed.jaxpr
-    n = min(len(jaxpr.invars), len(seeds))
-    for v, s in zip(jaxpr.invars[:n], seeds[:n]):
-        env[v] = s
-    for v in jaxpr.invars[n:]:
-        env[v] = _VarInfo(None)
+    env: Dict = dict(zip(jaxpr.invars, seeds, strict=True))
     for v in jaxpr.constvars:  # hoisted trace-time constants: replicated
         env[v] = _VarInfo(_repl(len(getattr(v.aval, "shape", ()))),
                           param=True)
@@ -1965,7 +1935,6 @@ def audit_step(
 
     params_by = _by_dtype(meta["named_params"], seeds)
     params_dev = sum(params_by.values())
-    np_ = len(meta["named_params"])
     opt_by = _by_dtype(meta["named_opt"], seeds[np_:])
     opt_dev = sum(opt_by.values())
 
@@ -2042,7 +2011,8 @@ def audit_step(
         loss_index = np_ + len(meta["named_opt"])
         nc_findings, nc_info = _numcheck.numcheck_jaxpr(
             closed, loss_index=loss_index)
-        findings.extend(nc_findings)
+        # an equation neither walk could enter is reported once
+        findings.extend(f for f in nc_findings if f not in findings)
         findings.extend(_numcheck.check_gradient_collectives(
             events, meta["named_params"], meta["named_opt"]))
         # activations = what the liveness peak holds per dtype beyond
@@ -2083,4 +2053,5 @@ def audit_step(
         hbm_budget_bytes=budget,
         label=label,
         precision=precision,
+        lost_specs=auditor.lost_specs,
     )

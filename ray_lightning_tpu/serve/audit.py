@@ -30,6 +30,9 @@ from typing import Optional
 from ray_lightning_tpu.analysis.costmodel import (
     Topology, paged_decode_traffic_bytes, parse_topology,
 )
+from ray_lightning_tpu.analysis.jaxpr import (
+    dce, pallas_kernel_ident, walk_eqns,
+)
 from ray_lightning_tpu.serve.engine import EngineConfig, build_step
 from ray_lightning_tpu.serve.kv_cache import serve_kv_plan_bytes
 
@@ -139,15 +142,10 @@ def trace_decode_step(model_cfg, engine_cfg: EngineConfig,
             s((), jnp.int32), s((), jnp.int32),          # pf pos/last_row
             s((B,), jnp.int32),                          # pf pads
         )
-    closed = jax.make_jaxpr(step)(*args)
-    from ray_lightning_tpu.analysis.tracecheck import _dce
-
-    closed = _dce(closed)
-    import jax as _jax
-
+    closed = dce(jax.make_jaxpr(step)(*args))
     params_bytes = sum(
         int(np.prod(leaf.shape or (1,))) * leaf.dtype.itemsize
-        for leaf in _jax.tree.leaves(a_params))
+        for leaf in jax.tree.leaves(a_params))
     pool_shape = tuple(pool.shape)
     return closed, {
         "args": args,
@@ -166,26 +164,11 @@ def trace_decode_step(model_cfg, engine_cfg: EngineConfig,
 def _pallas_kernel_names(jaxpr) -> list:
     """Kernel identities anywhere in the trace (recursive) — the
     fingerprint that the fused path actually lowered the kernel. The
-    identity string is `tracecheck._pallas_kernel_ident`, the same
+    identity string is `analysis.jaxpr.pallas_kernel_ident`, the same
     extraction the step auditor records into
     `TraceReport.pallas_kernels`."""
-    from ray_lightning_tpu.analysis.tracecheck import _pallas_kernel_ident
-
-    names = []
-
-    def _walk(j):
-        for eqn in j.eqns:
-            if eqn.primitive.name == "pallas_call":
-                names.append(_pallas_kernel_ident(eqn))
-            for v in eqn.params.values():
-                vals = v if isinstance(v, (list, tuple)) else (v,)
-                for x in vals:
-                    inner = getattr(x, "jaxpr", None)
-                    if inner is not None and hasattr(inner, "eqns"):
-                        _walk(inner)
-
-    _walk(jaxpr)
-    return names
+    return [pallas_kernel_ident(eqn) for eqn, _ in walk_eqns(jaxpr)
+            if eqn.primitive.name == "pallas_call"]
 
 
 def _dense_paged_gathers(jaxpr, pool_shape, capacity: int) -> list:
@@ -243,38 +226,27 @@ def _prefill_paged_gathers(jaxpr, pool_shape, capacity: int,
                     and out_shape[2:] == (P, HKV, HD))
         return False
 
-    def _walk(j, nested):
-        for eqn in j.eqns:
-            if (nested and eqn.primitive.name == "gather"
-                    and eqn.invars
-                    and tuple(getattr(eqn.invars[0].aval, "shape", ()))
-                    == pool_shape):
-                out_shape = tuple(getattr(eqn.outvars[0].aval,
-                                          "shape", ()))
-                if _match(out_shape):
-                    hits.append(out_shape)
-            for v in eqn.params.values():
-                vals = v if isinstance(v, (list, tuple)) else (v,)
-                for x in vals:
-                    inner = getattr(x, "jaxpr", None)
-                    if inner is not None and hasattr(inner, "eqns"):
-                        _walk(inner, True)
-
-    _walk(jaxpr, False)
+    for eqn, nested in walk_eqns(jaxpr):
+        if (nested and eqn.primitive.name == "gather" and eqn.invars
+                and tuple(getattr(eqn.invars[0].aval, "shape", ()))
+                == pool_shape):
+            out_shape = tuple(getattr(eqn.outvars[0].aval, "shape", ()))
+            if _match(out_shape):
+                hits.append(out_shape)
     return hits
 
 
-def _tp_invar_seeds(model_cfg, meta, tp: int):
+def _tp_invar_seeds(auditor, model_cfg, meta, tp: int):
     """`_VarInfo` seeds for the step's invars under a ``tp``-way tensor
     mesh — the SAME layout `DecodeEngine` places, so the audited
     collectives are the served ones: params via
     `engine.serving_param_specs` (wqkv/gate_up column-split, wo/w_down
-    row-split, embeddings vocab-split), the two pool leaves KV-head
-    sharded (`kv_cache.pool_partition_spec`), every host-fed input and
-    the carried logits replicated (the scheduler is tp-oblivious)."""
+    row-split, embeddings vocab-split; `seed_state` also registers each
+    shape for the vars the walk re-derives, scan-sliced per-layer
+    weights chiefly), the two pool leaves KV-head sharded
+    (`kv_cache.pool_partition_spec`), every host-fed input and the
+    carried logits replicated (the scheduler is tp-oblivious)."""
     import dataclasses as _dc
-
-    import jax
 
     from ray_lightning_tpu.analysis.tracecheck import (
         _repl, _spec_of_partition_spec, _VarInfo,
@@ -285,33 +257,17 @@ def _tp_invar_seeds(model_cfg, meta, tp: int):
     from ray_lightning_tpu.serve.kv_cache import (
         pool_partition_spec, validate_pool_tp,
     )
+    from ray_lightning_tpu.utils.pytree import named_leaves
 
     validate_pool_tp(model_cfg, tp)
-    live = {"tensor"}
-
-    def canon(spec_t):
-        return tuple(frozenset(ax for ax in s if ax in live)
-                     for s in spec_t)
-
     axis_names = tuple(f.name for f in _dc.fields(MeshSpec))
     a_params = meta["args"][0]
-    model = Llama(model_cfg)
-    seeds = []
-    # shape->spec matcher for vars the walk re-derives structurally —
-    # scan-SLICED per-layer weights chiefly (audit_step's discipline:
-    # a stacked [L, ...] leaf also registers its per-trip suffix)
-    param_shapes = {}
-    for (path, spec), leaf in zip(
-            serving_param_specs(model, a_params, axis_names),
-            jax.tree.leaves(a_params)):
-        shape = tuple(getattr(leaf, "shape", ()))
-        cspec = canon(_spec_of_partition_spec(spec, len(shape)))
-        seeds.append(_VarInfo(cspec, param=True, path=f"params/{path}"))
-        param_shapes.setdefault(shape, (cspec, f"params/{path}"))
-        if len(shape) >= 2:
-            param_shapes.setdefault(shape[1:],
-                                    (cspec[1:], f"params/{path}"))
-    pool_spec = canon(_spec_of_partition_spec(pool_partition_spec(tp), 5))
+    seeds = auditor.seed_state(
+        dict(named_leaves(a_params)),
+        [spec for _, spec in serving_param_specs(
+            Llama(model_cfg), a_params, axis_names)], "params")
+    pool_spec = auditor._canon(
+        _spec_of_partition_spec(pool_partition_spec(tp), 5))
     for i, arg in enumerate(meta["args"][1:], start=1):
         ndim = len(getattr(arg, "shape", ()))
         if i in (1, 2):
@@ -320,7 +276,7 @@ def _tp_invar_seeds(model_cfg, meta, tp: int):
                 path="pool_k" if i == 1 else "pool_v"))
         else:
             seeds.append(_VarInfo(_repl(ndim), param=True))
-    return seeds, param_shapes
+    return seeds
 
 
 def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
@@ -368,6 +324,11 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
     that already holds the trace (the smoke legs read meta's gather
     evidence directly) never pays a second full trace of the same
     step — the PR 11 one-trace discipline."""
+    import math
+
+    import jax
+    import numpy as np
+
     from ray_lightning_tpu.analysis.findings import Finding
     from ray_lightning_tpu.analysis.tracecheck import (
         TraceReport, _repl, _StepAuditor, _VarInfo, classify_overlap,
@@ -379,26 +340,15 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
                     else trace_decode_step(model_cfg, engine_cfg,
                                            fused=fused,
                                            fused_prefill=fused_prefill))
-    seeds, param_shapes = (_tp_invar_seeds(model_cfg, meta, tp)
-                           if tp > 1 else (None, {}))
-    auditor = _StepAuditor({"tensor": tp} if tp > 1 else {}, topo,
-                           param_shapes)
+    auditor = _StepAuditor({"tensor": tp} if tp > 1 else {}, topo)
+    seeds = (_tp_invar_seeds(auditor, model_cfg, meta, tp)
+             if tp > 1 else None)
     jaxpr = closed.jaxpr
-    env = {}
+    env = {v: _VarInfo(_repl(len(getattr(v.aval, "shape", ()))),
+                       param=True)
+           for v in (*jaxpr.invars, *jaxpr.constvars)}
     if seeds is not None:
-        n = min(len(jaxpr.invars), len(seeds))
-        for v, s in zip(jaxpr.invars[:n], seeds[:n]):
-            env[v] = s
-        for v in jaxpr.invars[n:]:
-            env[v] = _VarInfo(
-                _repl(len(getattr(v.aval, "shape", ()))), param=True)
-    else:
-        for v in jaxpr.invars:
-            env[v] = _VarInfo(
-                _repl(len(getattr(v.aval, "shape", ()))), param=True)
-    for v in jaxpr.constvars:
-        env[v] = _VarInfo(_repl(len(getattr(v.aval, "shape", ()))),
-                          param=True)
+        env.update(zip(jaxpr.invars, seeds, strict=True))
     peak, peak_by = auditor.walk(jaxpr, env, 1, False)
     if tp > 1:
         # the engine's jit pins every non-pool output REPLICATED at the
@@ -434,9 +384,6 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
             "serving step will OOM on this chip — shrink capacity, "
             "blocks_per_slot, or the pool",
             symbol=label))
-    import math
-
-    import numpy as np
 
     def _view_gib(shape) -> float:
         # k + v gathers at the POOL's dtype (model_cfg.dtype — the
@@ -476,17 +423,16 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
                                scheduled=auditor.saw_prefetch_marker)
     precision = None
     if numerics:
-        import jax as _jax
-
         from ray_lightning_tpu.analysis import numcheck as _numcheck
 
-        findings.extend(_numcheck.numcheck_jaxpr(closed)[0])
+        findings.extend(f for f in _numcheck.numcheck_jaxpr(closed)[0]
+                        if f not in findings)
         # the serve ledger's classes: params, the paged KV pool (args
         # 1-2: the k/v pools — the bytes the int8-KV campaign will
         # shrink), and whatever else the liveness peak holds. tp > 1:
         # per-SHARD bytes via the seeded specs (same division the
         # liveness walk applied)
-        p_leaves = _jax.tree.leaves(meta["args"][0])
+        p_leaves = jax.tree.leaves(meta["args"][0])
         params_by: dict = {}
         for i, leaf in enumerate(p_leaves):
             dt = str(leaf.dtype)
@@ -513,12 +459,9 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
         }
     params_dev = meta["params_bytes"]
     if seeds is not None:
-        import jax as _jax2
-
         params_dev = sum(
             auditor._aval_bytes(leaf, s.spec)
-            for leaf, s in zip(_jax2.tree.leaves(meta["args"][0]),
-                               seeds))
+            for leaf, s in zip(jax.tree.leaves(meta["args"][0]), seeds))
     return TraceReport(
         topology=topo,
         mesh_axes={"tensor": tp} if tp > 1 else {},
@@ -532,6 +475,7 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
         label=label,
         pallas_kernels=auditor.pallas_kernels,
         precision=precision,
+        lost_specs=auditor.lost_specs,
     )
 
 

@@ -13,6 +13,7 @@ contract that keeps the format.sh gate (zero RLT801/805 across the
 examples) meaningful.
 """
 import json
+import os
 import subprocess
 import sys
 import types
@@ -230,6 +231,7 @@ class TestPallasSanction:
 
 
 class TestModelPins:
+    # `findings == []` includes RLT310: nothing the walk could not enter
     def test_fused_ce_accumulates_f32(self):
         # the chunked loop must carry f32 partials and dot with
         # preferred_element_type=f32 even on bf16 hidden/weights
@@ -445,14 +447,17 @@ def _trace_rules(target, topo_name):
     "cifar_resnet_example.py", "bert_finetune_example.py",
 ])
 def test_bundled_targets_numerics_clean(target):
-    _, rules = _trace_rules(target, "v5p-8")
+    rep, rules = _trace_rules(target, "v5p-8")
     assert rules == []
+    # a clean verdict is not a blind one
+    assert rep.unentered == [] and rep.lost_specs == {}
 
 
 @pytest.mark.slow
 def test_llama3_8b_flagship_numerics_clean():
     rep, rules = _trace_rules("llama3-8b", "v5p-64")
     assert rules == []
+    assert rep.unentered == [] and rep.lost_specs == {}
     assert rep.precision["loss_widest_dtype"] == "float32"
     assert rep.precision["params"]  # the ledger is populated
 
@@ -498,6 +503,15 @@ class TestASTPass:
 # --------------------------------------------------------------------------
 
 
+#: the CLI runs from whatever directory the suite's fixtures left, so
+#: the package's location is pinned (as tests/test_tracecheck_examples.py
+#: does): a sandbox without the package on its path failed these three
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CLI_ENV = {**os.environ, "JAX_PLATFORMS": "cpu",
+            "PYTHONPATH": _REPO + os.pathsep + os.environ.get(
+                "PYTHONPATH", "")}
+
+
 class TestCLISmoke:
     def test_lint_numerics_flag(self, tmp_path):
         bad = tmp_path / "bad.py"
@@ -507,13 +521,13 @@ class TestCLISmoke:
         proc = subprocess.run(
             [sys.executable, "-m", "ray_lightning_tpu", "lint",
              "--numerics", str(bad)],
-            capture_output=True, text=True)
+            capture_output=True, text=True, env=_CLI_ENV)
         assert proc.returncode == 1
         assert "RLT801" in proc.stdout
         proc = subprocess.run(
             [sys.executable, "-m", "ray_lightning_tpu", "lint",
              "--no-numerics", str(bad)],
-            capture_output=True, text=True)
+            capture_output=True, text=True, env=_CLI_ENV)
         assert proc.returncode == 0
         assert "RLT801" not in proc.stdout
 
@@ -522,7 +536,7 @@ class TestCLISmoke:
             [sys.executable, "-m", "ray_lightning_tpu", "trace",
              "mnist_dp_example.py", "--topo", "v5p-8", "--no-numerics",
              "--json"],
-            capture_output=True, text=True)
+            capture_output=True, text=True, env=_CLI_ENV)
         assert proc.returncode == 0, proc.stderr
         d = json.loads(proc.stdout)
         assert d["precision"] is None
@@ -531,7 +545,7 @@ class TestCLISmoke:
         proc = subprocess.run(
             [sys.executable, "-m", "ray_lightning_tpu", "trace",
              "mnist_dp_example.py", "--topo", "v5p-8", "--json"],
-            capture_output=True, text=True)
+            capture_output=True, text=True, env=_CLI_ENV)
         assert proc.returncode == 0, proc.stderr
         d = json.loads(proc.stdout)
         assert d["precision"]["loss_widest_dtype"] == "float32"

@@ -44,6 +44,7 @@ def test_example_step_audits_clean(example):
                         label=label)
     bad = [f for f in report.findings if f.rule in ("RLT301", "RLT303")]
     assert not bad, "\n".join(f.format() for f in bad)
+    assert report.unentered == [] and report.lost_specs == {}
 
 
 def test_llama_fsdp_v5p64_hbm_estimate_sane():
@@ -84,6 +85,9 @@ def test_trace_cli_json_llama(tmp_path):
     assert d["ici_bytes_per_step"] > 0
     assert d["peak_hbm_bytes"] > 0
     assert d["fits"] is True
+    # a clean verdict is not a blind one: nothing unentered, no spec
+    # lost at a primitive the walk has no rule for
+    assert d["unentered"] == [] and d["lost_specs"] == {}
     # the un-overlapped ZeRO scan legitimately draws RLT305 advisories
     # (exposed per-trip weight gathers — the overlap knob's pointer);
     # anything else is a regression
