@@ -479,6 +479,21 @@ class Llama(nn.Module):
 
         return decode_tile_tokens(block_size, blocks_per_slot)
 
+    def prefill_tile_shape(self, prefill_batch: int, prefill_chunk: int,
+                           block_size: int, blocks_per_slot: int):
+        """(query tile rows, KV tile tokens) of the paged prefill kernel
+        for the engine's chunk, by the kernel's own rule: its time is that
+        of the KV tiles each query tile can see (`serve/engine.py:
+        _step_work` counts them as ``prefill_tiles``)."""
+        from ray_lightning_tpu.ops.pallas.paged_prefill import (
+            prefill_tile_shape,
+        )
+
+        cfg = self.cfg
+        return prefill_tile_shape(
+            (prefill_batch, prefill_chunk, cfg.n_heads, cfg.head_dim),
+            (block_size, cfg.n_kv_heads, cfg.head_dim), blocks_per_slot)
+
     def paged_lanes(self, capacity: int, prefill_batch: int,
                     prefill_chunk: int, pool_block, use_pallas):
         """(decode, prefill): would the paged lanes take the kernels at

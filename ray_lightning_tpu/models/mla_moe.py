@@ -464,6 +464,12 @@ class MlaMoe(nn.Module):
         may be handed a length of 0)."""
         return None
 
+    def prefill_tile_shape(self, prefill_batch: int, prefill_chunk: int,
+                           block_size: int, blocks_per_slot: int):
+        """None: `rlt_mla_prefill` states no tile to the engine (its grid
+        is its table's; dead tiles are clamped, not left out)."""
+        return None
+
     def paged_lanes(self, capacity: int, prefill_batch: int,
                     prefill_chunk: int, pool_block, use_pallas):
         """(decode, prefill): would the paged lanes take the kernels at

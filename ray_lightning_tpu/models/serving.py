@@ -12,6 +12,8 @@ itself:
     model.serving_param_specs()     per-leaf placement of a sharded replica
     model.tick_counters             device-side counts of a paged call
     model.decode_tile_tokens(P, M)  the decode kernel's KV tile, or None
+    model.prefill_tile_shape(B, CH, P, M)  the prefill kernel's query tile
+                                    and KV tile, or None
     model.serving_unsupported       what the engine has to refuse
     model(tokens, cache=, pos=, pad=, paged=)
 

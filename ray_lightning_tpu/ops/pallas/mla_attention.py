@@ -20,10 +20,8 @@ grid = (B, R / bq, M / tile_blocks). One grid step reads a TILE of
 ``tile_blocks`` table-named pool blocks: the pool is handed to the call
 ``tile_blocks`` times, each copy with a BlockSpec whose index_map reads
 another entry of the scalar-prefetched table, and the kernel joins the
-blocks into one ``[tile_blocks * P, Dk]`` K tile in VMEM. With the
-existing paged kernels a grid step is one 16-token block and their time
-is their grid's steps (PERF.md section 7); here a step is a matmul of
-``[bq * H, Dk] x [Dk, tile]``. Blocks past the last position a query tile
+blocks into one ``[tile_blocks * P, Dk]`` K tile in VMEM; a grid step is
+a matmul of ``[bq * H, Dk] x [Dk, tile]``. Blocks past the last position a query tile
 can see are clamped to that last block in the index_map, so the pipeline
 fetches nothing new for them, and their compute is skipped.
 

@@ -154,6 +154,41 @@ def _live_extent(length, pad, block_p, tile_blocks, table_blocks):
     return b_lo, b_hi, b_lo // tile_blocks, pl.cdiv(b_hi, tile_blocks)
 
 
+def _fetch_tile(tbl_ref, k_hbm, v_hbm, kbuf, vbuf, sems, row, t, half, b_lo,
+                b_hi, *, block_p, tile_blocks, table_blocks, wait):
+    """Start (or wait for) the copies of KV tile ``t`` of table row
+    ``row`` into ``half`` of the double buffer, one async copy a pool
+    block, the block id read from the prefetched table. A block outside
+    the live blocks ``[b_lo, b_hi)`` is not fetched; its V rows are
+    zeroed instead (0 x garbage must stay 0; K's garbage is masked in
+    the scores). Shared with `paged_prefill.py`."""
+    for j in range(tile_blocks):
+        b = t * tile_blocks + j
+        live = (b >= b_lo) & (b < b_hi)
+        rows = pl.ds(j * block_p, block_p)
+        blk = 0 if wait else tbl_ref[
+            row, jnp.minimum(b, table_blocks - 1)]
+        ck = pltpu.make_async_copy(
+            k_hbm.at[blk], kbuf.at[half, rows], sems.at[0, half])
+        cv = pltpu.make_async_copy(
+            v_hbm.at[blk], vbuf.at[half, rows], sems.at[1, half])
+
+        @pl.when(live)
+        def _copy():
+            if wait:
+                ck.wait()
+                cv.wait()
+            else:
+                ck.start()
+                cv.start()
+
+        if not wait:
+            @pl.when(jnp.logical_not(live))
+            def _zero():
+                vbuf[half, rows] = jnp.zeros(
+                    (block_p, *vbuf.shape[2:]), vbuf.dtype)
+
+
 def _tile_update(qg, k, v, kv_start, length, pad, carry, *, scale):
     """Online-softmax update of one slot's statistics by one KV tile:
     ``qg`` [Hkv, n_rep, hd] float32, ``k`` / ``v`` [tile, Hkv, hd] in the
@@ -220,36 +255,10 @@ def _decode_kernel(tbl_ref, len_ref, pad_ref, q_ref, k_hbm, v_hbm, o_ref,
         len_ref[slot], pad_ref[slot], block_p, tile_blocks, table_blocks)
 
     def fetch(slot, t, half, wait):
-        """Start (or wait for) the copies of tile ``t`` of ``slot`` into
-        ``half``. A block outside the live extent is not fetched; its V
-        rows are zeroed instead (0 x garbage must stay 0; K's garbage is
-        masked in the scores)."""
         b_lo, b_hi, _, _ = extent(slot)
-        for j in range(tile_blocks):
-            b = t * tile_blocks + j
-            live = (b >= b_lo) & (b < b_hi)
-            rows = pl.ds(j * block_p, block_p)
-            blk = 0 if wait else tbl_ref[
-                slot, jnp.minimum(b, table_blocks - 1)]
-            ck = pltpu.make_async_copy(
-                k_hbm.at[blk], kbuf.at[half, rows], sems.at[0, half])
-            cv = pltpu.make_async_copy(
-                v_hbm.at[blk], vbuf.at[half, rows], sems.at[1, half])
-
-            @pl.when(live)
-            def _copy():
-                if wait:
-                    ck.wait()
-                    cv.wait()
-                else:
-                    ck.start()
-                    cv.start()
-
-            if not wait:
-                @pl.when(jnp.logical_not(live))
-                def _zero():
-                    vbuf[half, rows] = jnp.zeros(
-                        (block_p, *vbuf.shape[2:]), vbuf.dtype)
+        _fetch_tile(tbl_ref, k_hbm, v_hbm, kbuf, vbuf, sems, slot, t, half,
+                    b_lo, b_hi, block_p=block_p, tile_blocks=tile_blocks,
+                    table_blocks=table_blocks, wait=wait)
 
     @pl.when(c == 0)
     def _reset():

@@ -912,11 +912,6 @@ class DecodeEngine:
                 f"these shapes). Run on a TPU, or pass use_pallas=True "
                 "(RLT_PALLAS=1) for interpret mode, with a block_size "
                 "and head count the kernels tile")
-        #: tokens of the fused decode kernel's KV tile (`_step_work`'s
-        #: ``decode_tiles``); None on the reference lane and for a
-        #: decoder that states none
-        self._decode_tile = model.decode_tile_tokens(
-            spec.block_size, cfg.blocks_per_slot) if self.fused else None
         self.draft_model = draft_model
         self.dpool_k = self.dpool_v = self.draft_params = None
         if cfg.draft is not None:
@@ -945,6 +940,16 @@ class DecodeEngine:
             # speculative_plan charges the gathered views.
             self.fused = False
             self.fused_prefill = False
+        #: tokens of the fused decode kernel's KV tile (`_step_work`'s
+        #: ``decode_tiles``); None on the reference lane and for a
+        #: decoder that states none
+        self._decode_tile = model.decode_tile_tokens(
+            spec.block_size, cfg.blocks_per_slot) if self.fused else None
+        #: (query tile rows, KV tile tokens) of the fused prefill kernel
+        #: (`_step_work`'s ``prefill_tiles``); None likewise
+        self._prefill_tile = model.prefill_tile_shape(
+            cfg.prefill_batch, cfg.prefill_chunk, spec.block_size,
+            cfg.blocks_per_slot) if self.fused_prefill else None
         self.cfg = cfg
         self.spec = cfg.pool_spec
         #: replica-group mesh (docs/SERVING.md "sharded replicas"):
@@ -1241,6 +1246,13 @@ class DecodeEngine:
                         are not work); 0 without a chunk
           prefill_ctx   tokens already in those rows' caches before the
                         chunk (summed over the group's rows)
+          prefill_tiles KV tiles a layer the prefill kernel computes for
+                        the chunk: for each row of the group (a vacant
+                        one rides the scratch table and is computed too)
+                        and each query tile, the tiles with a position in
+                        ``[pad, start + (qi + 1) * bq)``, by the kernel's
+                        own tiles (`prefill_tile_shape`); 0 without a
+                        chunk, left out where the decoder states no tile
         """
         dec = np.asarray(decoding)
         if self.cfg.prefill_batch == 1:
@@ -1264,4 +1276,12 @@ class DecodeEngine:
         if self._decode_tile:
             work["decode_tiles"] = int(
                 np.ceil(lengths / self._decode_tile).sum())
+        if self._prefill_tile:
+            from ray_lightning_tpu.ops.pallas.paged_prefill import (
+                prefill_live_tiles,
+            )
+
+            work["prefill_tiles"] = prefill_live_tiles(
+                start, pads, self.cfg.prefill_chunk, *self._prefill_tile,
+                self.cfg.max_slot_len) if active.any() else 0
         return work

@@ -1,14 +1,12 @@
 """Block-size autotune for BOTH paged attention kernels
 (docs/SERVING.md "block-size autotune").
 
-``block_size`` is the unit both paged kernels fetch by. The paged prefill
-kernel streams one pool block per (row, q-tile) step: there
-``block_size`` IS the KV tile, so it sets the DMA granularity, the VMEM
-working set and (through ``blocks_per_slot = span / block_size``) the
-grid depth. The paged decode kernel joins ``128 / block_size`` blocks
-into one KV tile of its own (`decode_tile_tokens`) and loops over a
-slot's live tiles, so there ``block_size`` sets only the granularity of
-its copies (one async copy a block) and the table's length, not the
+``block_size`` is the unit both paged kernels fetch by. Each joins
+blocks into a KV tile of its own (`decode_tile_tokens`: ``128 /
+block_size`` blocks; `prefill_tile_shape`: ``512 / block_size`` where
+VMEM allows) and loops over the live tiles of a slot or of a chunk, so
+``block_size`` sets only the granularity of the copies (one async copy a
+block) and the table's length, not the
 number of steps. The right value is a hardware question the planner
 cannot answer from byte math, so this module measures it:
 

@@ -29,6 +29,8 @@ from ray_lightning_tpu.ops.pallas.paged_prefill import (
     _fit_q_block,
     paged_prefill_pallas,
     paged_prefill_shapes_supported,
+    prefill_live_tiles,
+    prefill_tile_shape,
 )
 from ray_lightning_tpu.serve.engine import DecodeEngine, EngineConfig
 from ray_lightning_tpu.serve.scheduler import Request, Scheduler
@@ -130,6 +132,205 @@ def test_bf16_parity_tolerance():
         rtol=2e-2, atol=2e-2)
 
 
+# ---- what a KV tile of several table-named blocks adds ----------------------
+
+#: a table that is no whole number of tiles: 72 blocks of 16, tiles of 32
+P16, M72, N_POOL = 16, 72, 160
+_, TILE = prefill_tile_shape((1, 32, 4, 128), (N_POOL, P16, 2, 128), M72)
+
+
+def _owned_case(rng, B, CH, H, hd, Hkv, M, pos, pad=None, dtype=jnp.float32):
+    """Rows that OWN the blocks their chunk can see (distinct ids, the
+    scattered pool of a running engine); every other table entry names a
+    block nobody owns, scratch block 0 among them. Returns the case and
+    the ids of the dead blocks."""
+    q = jnp.asarray(rng.standard_normal((B, CH, H, hd)), dtype)
+    pk = jnp.asarray(rng.standard_normal((N_POOL, P16, Hkv, hd)), dtype)
+    pv = jnp.asarray(rng.standard_normal((N_POOL, P16, Hkv, hd)), dtype)
+    need = min(-(-(pos + CH) // P16), M)
+    ids = 1 + rng.permutation(N_POOL - 1)
+    tables = np.zeros((B, M), np.int32)
+    live = set()
+    for b in range(B):
+        lo = 0 if pad is None else int(pad[b]) // P16
+        own = ids[b * need:(b + 1) * need][lo:]
+        tables[b, lo:need] = own
+        live.update(int(i) for i in own)
+    dead = np.asarray([i for i in range(N_POOL) if i not in live])
+    tables = np.where(tables == 0, rng.choice(dead, tables.shape), tables)
+    return q, pk, pv, jnp.asarray(tables), jnp.asarray(dead)
+
+
+@pytest.mark.parametrize("hd", [128, 64])
+@pytest.mark.parametrize("pos", [
+    0,                                   # one partial tile
+    TILE - 33, TILE - 32, TILE - 31,     # the chunk's END at a tile edge
+    TILE - 1, TILE, TILE + 1,            # the chunk's START at a tile edge
+    2 * TILE - 16,                       # astride the last, shorter tile
+    M72 * P16 - 32,                      # the table's last tokens
+])
+def test_kernel_matches_reference_at_every_tile_edge(pos, hd):
+    """``pos`` one below, on and one above a tile edge, by both feeds of
+    the tile body (the kernel's own copies at hd 128, the pipeline's at
+    hd 64): a tile wholly visible skips the mask, the tile on the
+    diagonal builds it, a tile past it is neither fetched nor stepped
+    over."""
+    rng = np.random.default_rng(pos + hd)
+    q, pk, pv, tables, _ = _owned_case(rng, 1, 32, 4, hd, 2, M72, pos)
+    ref = paged_prefill_reference(q, pk, pv, tables, pos)
+    got = jax.jit(paged_prefill_pallas)(q, pk, pv, tables, pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", POOL_FORMS)
+@pytest.mark.parametrize("hd,M", [(128, M72), (64, M72), (128, 17),
+                                  (64, 17)])
+def test_last_query_tile_sees_one_more_kv_tile(hd, M, form):
+    """Two query tiles of 128 rows whose live extents differ: the first
+    ends on a tile edge, the second sees one tile more (at M 17 the
+    table is one shorter tile and both see it). Over the pool and over
+    the stack at a traced layer."""
+    CH = 256
+    bq, tile = prefill_tile_shape((1, CH, 4, hd), (N_POOL, P16, 2, hd), M)
+    assert (bq, tile) == (128, min(TILE, M * P16))
+    pos = tile - 128 if M == M72 else 0
+    rng = np.random.default_rng(hd + M)
+    q, pk, pv, tables, _ = _owned_case(rng, 1, CH, 4, hd, 2, M, pos)
+    ref = paged_prefill_reference(q, pk, pv, tables, pos)
+    fk, fv, at = pool_form(pk, pv, form)
+    got = jax.jit(paged_prefill_pallas)(q, fk, fv, tables, pos, **at)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    want = sum(-(-(pos + (qi + 1) * bq) // tile) for qi in range(2))
+    assert prefill_live_tiles(pos, [0], CH, bq, tile, M * P16) == want
+    assert want == (3 if M == M72 else 2)
+
+
+@pytest.mark.parametrize("hd", [128, 64])
+def test_left_pad_longer_than_a_tile(hd):
+    """The batched lane's rows: one unpadded, one whose left pad covers
+    a whole tile and a part of the next, so its first tile is never
+    fetched and its second is masked from the pad on."""
+    rng = np.random.default_rng(41 + hd)
+    pos = TILE + 200
+    pad = np.asarray([0, TILE + 37], np.int32)
+    q, pk, pv, tables, _ = _owned_case(rng, 2, 32, 4, hd, 2, M72, pos, pad)
+    pad = jnp.asarray(pad)
+    ref = paged_prefill_reference(q, pk, pv, tables, pos, pad=pad)
+    got = paged_prefill_pallas(q, pk, pv, tables, pos, pad=pad)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    unpadded = paged_prefill_reference(q, pk, pv, tables, pos)
+    assert not np.allclose(np.asarray(unpadded)[1], np.asarray(ref)[1])
+    assert prefill_live_tiles(pos, pad, 32, 32, TILE, M72 * P16) == 2 + 1
+
+
+@pytest.mark.parametrize("poison", [1e9, "nan-inf"])
+@pytest.mark.parametrize("hd", [128, 64])
+def test_kernel_dead_blocks_never_reach_the_statistics(hd, poison):
+    """Every block no live row owns (scratch block 0, the table's tail,
+    the blocks under a row's pad) is garbage by contract: NaN in its V
+    and inf in its K leave the output BIT-equal. A dead tile is neither
+    fetched nor stepped over; a dead block inside a live tile is masked
+    in the scores and its V rows are never multiplied (0 x NaN is
+    NaN)."""
+    rng = np.random.default_rng(43 + hd)
+    pos = TILE + 40                       # a full tile and a partial one
+    pad = np.asarray([0, TILE // 2 + 5], np.int32)
+    q, pk, pv, tables, dead = _owned_case(rng, 2, 32, 4, hd, 2, M72, pos,
+                                          pad)
+    pad = jnp.asarray(pad)
+    in_k, in_v = (np.inf, np.nan) if poison == "nan-inf" else (poison,) * 2
+    base = paged_prefill_pallas(q, pk.at[dead].set(0.0),
+                                pv.at[dead].set(0.0), tables, pos, pad=pad)
+    hot = paged_prefill_pallas(q, pk.at[dead].set(in_k),
+                               pv.at[dead].set(in_v), tables, pos, pad=pad)
+    np.testing.assert_array_equal(np.asarray(base), np.asarray(hot))
+    ref = paged_prefill_reference(q, pk.at[dead].set(0.0),
+                                  pv.at[dead].set(0.0), tables, pos, pad=pad)
+    np.testing.assert_allclose(np.asarray(hot), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_in_kernel_copies_under_the_tpu_interpreter(monkeypatch):
+    """The kernel's own copies under the interpreter that models the
+    chip's: a copy lands only when it is waited for, memory never
+    written reads NaN, and races between a copy and the tile body are
+    reported. Two rows (the double buffer starts anew each grid step),
+    two query tiles with different extents, a partial last tile, a left
+    pad of more than a tile."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_lightning_tpu.ops.pallas import paged_prefill as kernel
+
+    monkeypatch.setattr(kernel, "_interpret", lambda: pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True))
+    rng = np.random.default_rng(47)
+    pos = 2 * TILE - 128
+    pad = np.asarray([0, TILE + 20], np.int32)
+    q, pk, pv, tables, _ = _owned_case(rng, 2, 256, 4, 128, 2, M72, pos, pad)
+    pad = jnp.asarray(pad)
+    got = paged_prefill_pallas(q, pk, pv, tables, pos, pad=pad)
+    ref = paged_prefill_reference(q, pk, pv, tables, pos, pad=pad)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert not interpret_pallas_call.races.races_found
+
+
+@pytest.mark.parametrize("hd", [128, 64])
+def test_grid_steps_follow_the_tiles_not_the_table(hd):
+    """The lowered call's grid: a step a (row, query tile) where the
+    kernel copies its tiles in itself, a step a (row, query tile, KV
+    tile) where the pipeline does, never one a table entry."""
+    rng = np.random.default_rng(53)
+    q, pk, pv, tables, _ = _owned_case(rng, 2, 32, 4, hd, 2, M72, 64)
+    from ray_lightning_tpu.analysis.jaxpr import walk_eqns
+
+    calls = [eqn for eqn, _ in walk_eqns(jax.make_jaxpr(
+        paged_prefill_pallas)(q, pk, pv, tables, 64).jaxpr)
+        if eqn.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["name"] == "rlt_paged_prefill"
+    grid = calls[0].params["grid_mapping"].grid
+    tiles = -(-M72 // (TILE // P16))
+    assert int(np.prod(grid)) == (2 if hd == 128 else 2 * tiles) < 2 * M72
+
+
+@pytest.mark.parametrize("pos,pads,chunk,bq", [
+    (0, [0], 128, 128), (511, [0], 128, 64), (512, [0, 700], 128, 128),
+    (1100, [3, 1150], 32, 32), (1120, [0], 32, 32),
+])
+def test_live_tiles_counted_as_the_kernel_loops(pos, pads, chunk, bq):
+    """`prefill_live_tiles` (what `rlt.serve.dispatch` carries as
+    ``prefill_tiles``) against a count position by position."""
+    table = M72 * P16
+    want = 0
+    for pad in pads:
+        for qi in range(chunk // bq):
+            seen = {kv // TILE for kv in range(table)
+                    if pad <= kv < pos + (qi + 1) * bq}
+            want += len(seen)
+    assert prefill_live_tiles(pos, pads, chunk, bq, TILE, table) == want
+
+
+def test_bf16_operands_match_the_xla_twin():
+    """bf16 pool: q and k enter the score product as bf16 with float32
+    out, the probabilities are cast to bf16 for the value product, as
+    `ops.attention.dot_product_attention` does; over two tiles."""
+    rng = np.random.default_rng(59)
+    pos = TILE + 64
+    q, pk, pv, tables, _ = _owned_case(rng, 1, 32, 4, 128, 2, M72, pos,
+                                       dtype=jnp.bfloat16)
+    ref = paged_prefill_reference(q, pk, pv, tables, pos)
+    got = paged_prefill_pallas(q, pk, pv, tables, pos)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        rtol=2e-2, atol=2e-2)
+
+
 # ---- dispatch predicate ----------------------------------------------------
 
 
@@ -159,22 +360,45 @@ def test_shapes_supported_contract():
                                           (16, 8, 1, 64))
 
 
-def test_fit_q_block_halving():
-    assert _fit_q_block(256, 4, 64) == 128
-    assert _fit_q_block(12, 4, 64) == 12
-    assert _fit_q_block(6, 4, 64) == 6
-    assert _fit_q_block(192, 4, 64) == 64  # 128 does not divide -> halve
+@pytest.mark.parametrize("ch,h,hd,want", [
+    (256, 4, 64, 128),
+    (12, 4, 64, 12),
+    (6, 4, 64, 6),
+    (192, 4, 64, 64),      # 128 does not divide -> halve
+    # the query tile is the whole 128-row chunk at the serving cells'
+    # heads (it was 64 rows at 32 heads of 128 while the tile body
+    # materialised six float32 temporaries of tile size a KV block; the
+    # v5e compile takes it now, tests/test_tpu_aot_compile.py)
+    (128, 16, 128, 128),   # serve.internlm2-1.8b.chat
+    (128, 32, 128, 128),   # serve.mistral-7b-v0.3.docs, llama3_8b
+    (128, 64, 128, 128),
+    (128, 128, 128, 64),   # the budget still halves what VMEM refuses
+])
+def test_query_tile_rule(ch, h, hd, want):
+    assert _fit_q_block(ch, h, hd) == want
 
 
-def test_fit_q_block_respects_the_vmem_budget():
-    """32 heads of 128 at CH=128 (the llama3_8b geometry) blew v5e's
-    16 MiB scoped VMEM at bq=128 (AOT compile, tests/
-    test_tpu_aot_compile.py); the budget halves the tile instead of
-    promising what Mosaic refuses."""
-    assert _fit_q_block(128, 32, 128) == 64
-    assert _fit_q_block(128, 16, 128) == 128
-    assert paged_prefill_shapes_supported((1, 128, 32, 128),
-                                          (65, 16, 8, 128))
+@pytest.mark.parametrize("q_shape,pool_shape,m,want", [
+    # both dense serving cells: one 128-row query tile, a KV tile of 32
+    # table-named blocks of 16 (512 tokens)
+    ((1, 128, 16, 128), (3072, 16, 8, 128), 160, (128, 512)),
+    ((1, 128, 32, 128), (3072, 16, 8, 128), 272, (128, 512)),
+    ((1, 128, 32, 128), (24, 3072, 16, 8, 128), 272, (128, 512)),  # stack
+    # the tile is counted in tokens: twice the blocks at half the block
+    ((1, 128, 32, 128), (3072, 8, 8, 128), 544, (128, 512)),
+    # at most a whole table; 17 blocks keep the rule (no divisor search)
+    ((2, 32, 4, 128), (160, 16, 2, 128), 17, (32, 17 * 16)),
+    ((2, 32, 4, 128), (160, 16, 2, 128), 72, (32, 512)),
+    # the KV tile yields to the VMEM the query tile leaves: MHA's K and
+    # V tiles are four times GQA 4:1's
+    ((1, 128, 32, 128), (600, 16, 32, 128), 272, (128, 256)),
+    ((1, 128, 64, 128), (600, 16, 64, 128), 272, (128, 64)),
+])
+def test_kv_tile_rule(q_shape, pool_shape, m, want):
+    """The tile follows from the operands alone: no argument, no
+    environment variable."""
+    assert prefill_tile_shape(q_shape, pool_shape, m) == want
+    assert paged_prefill_shapes_supported(q_shape, pool_shape)
 
 
 def test_uses_pallas_respects_dispatch_context():

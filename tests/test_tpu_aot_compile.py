@@ -118,22 +118,42 @@ def test_paged_decode_compiles(v5e, as_on_tpu, heads, hd, p, c, m, form):
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
 
 
+#: (heads, hd, P, chunk, table blocks, the query tile's rows and the KV
+#: tile's tokens the kernel's rule picks) beside the grid's own SPAN / p: the two
+#: dense serving cells' engines, MHA at the wide head (its K and V tiles
+#: are four times GQA 4:1's, so the KV tile halves), and a table that is
+#: no whole number of tiles
+PREFILL_CELLS = [
+    ((16, 8), 128, 16, 128, 160, (128, 512)),   # serve.internlm2-1.8b.chat
+    ((32, 8), 128, 16, 128, 272, (128, 512)),   # serve.mistral-7b-v0.3.docs
+    ((32, 32), 128, 16, 128, 272, (128, 256)),
+    ((16, 8), 128, 16, 128, 17, (128, 17 * 16)),
+]
+
+
 @pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize(
-    "heads,hd,p,ch",
-    itertools.product(HEADS, HEAD_DIMS, BLOCKS, [64, 128]))
-def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch, form):
+    "heads,hd,p,ch,m,tiles",
+    [(*shape, None, None)
+     for shape in itertools.product(HEADS, HEAD_DIMS, BLOCKS, [64, 128])]
+    + PREFILL_CELLS)
+def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch, m, tiles,
+                                form):
     from ray_lightning_tpu.ops.pallas.paged_prefill import (
         paged_prefill_pallas, paged_prefill_shapes_supported,
+        prefill_tile_shape,
     )
 
     h, hkv = heads
-    b, m = 1, SPAN // p
+    b, m = 1, m or SPAN // p
     nb = 1 + 8 * m
     if not paged_prefill_shapes_supported((b, ch, h, hd),
                                           (nb, p, hkv, hd)):
         pytest.skip("refused by the predicate: dispatch takes the "
                     "reference lane")
+    if tiles:
+        assert prefill_tile_shape((b, ch, h, hd), (nb, p, hkv, hd),
+                                  m) == tiles
     s = SingleDeviceSharding(v5e[0])
     bf, i32 = jnp.bfloat16, jnp.int32
     pool, at = _pool_operands(form, (nb, p, hkv, hd), s)
@@ -141,6 +161,11 @@ def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch, form):
         _sds((b, ch, h, hd), bf, s), pool, pool, _sds((b, m), i32, s),
         _sds((), i32, s), _sds((b,), i32, s), **at).compile()
     assert _n_mosaic(compiled) == 1
+    if hd % 128 == 0:
+        # the pool is read where it lies: no copy of it in front of the
+        # kernel (at hd 64 XLA relayouts it, as for the decode kernel)
+        pool_bytes = int(np.prod(pool.shape)) * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
 
 
 @pytest.mark.parametrize("seq", [1024, 2048, 4096])

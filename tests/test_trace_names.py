@@ -143,6 +143,9 @@ def _expected_work(sched, ecfg):
     from ray_lightning_tpu.ops.pallas.paged_attention import (
         decode_tile_tokens,
     )
+    from ray_lightning_tpu.ops.pallas.paged_prefill import (
+        prefill_tile_shape,
+    )
 
     dec = sched.decoding
     lengths = [int(sched.pos[s]) + 1 for s in range(ecfg.capacity) if dec[s]]
@@ -151,7 +154,7 @@ def _expected_work(sched, ecfg):
             "kv_tokens": sum(lengths),
             # the kernel's own tile: a slot costs ceil(length / tile)
             "decode_tiles": sum(-(-n // tile) for n in lengths),
-            "prefill_rows": 0, "prefill_ctx": 0}
+            "prefill_rows": 0, "prefill_ctx": 0, "prefill_tiles": 0}
     if sched.prefill_groups:
         slot = sched.slots[sched.prefill_groups[0].slots[0]]
         done, size = slot.prefill_next, slot.req.prompt.size
@@ -161,6 +164,16 @@ def _expected_work(sched, ecfg):
         start = min(done, ecfg.max_slot_len - ecfg.prefill_chunk)
         want["prefill_rows"] = min(ecfg.prefill_chunk, size - start)
         want["prefill_ctx"] = start
+        # the kernel's own tiles: query tile qi of the chunk sees the KV
+        # tiles that hold a position below start + (qi + 1) * bq
+        mcfg = sched.engine.model.cfg
+        bq, tile = prefill_tile_shape(
+            (1, ecfg.prefill_chunk, mcfg.n_heads, mcfg.head_dim),
+            (ecfg.block_size, mcfg.n_kv_heads, mcfg.head_dim),
+            ecfg.blocks_per_slot)
+        want["prefill_tiles"] = sum(
+            -(-min(start + (qi + 1) * bq, ecfg.max_slot_len) // tile)
+            for qi in range(ecfg.prefill_chunk // bq))
     return want
 
 
@@ -292,7 +305,7 @@ def test_driver_phase_brackets_the_scheduler_tick(traced, phase):
 
 @pytest.mark.parametrize("counter", ["decode_slots", "kv_tokens",
                                      "decode_tiles", "prefill_rows",
-                                     "prefill_ctx"])
+                                     "prefill_ctx", "prefill_tiles"])
 def test_dispatch_counters_equal_the_schedulers_own(traced, counter):
     """The context convention must not over-count: a roofline share over
     105% is refused by the benchmark's driver."""
