@@ -463,6 +463,8 @@ class Llama(nn.Module):
     tick_counters = ()
     #: serving modes the engine has to refuse for this decoder: none
     serving_unsupported = ()
+    #: no sliding-window layers: one group of the pool
+    kv_window = None
 
     def serving_param_specs(self):
         return llama_param_specs(self.cfg)

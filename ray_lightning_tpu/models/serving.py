@@ -15,6 +15,9 @@ itself:
     model.prefill_tile_shape(B, CH, P, M)  the prefill kernel's query tile
                                     and KV tile, or None
     model.serving_unsupported       what the engine has to refuse
+    model.kv_window                 the window of its sliding-window layers
+                                    (their K/V live in a group of their
+                                    own, `serve/kv_cache.py`), or None
     model(tokens, cache=, pos=, pad=, paged=)
 
 A new decoder enters with a config dataclass, a flax module with those
@@ -32,6 +35,8 @@ _DECODERS = {
     "LlamaConfig": ("ray_lightning_tpu.models.llama", "LlamaConfig", "Llama"),
     "MlaMoeConfig": ("ray_lightning_tpu.models.mla_moe", "MlaMoeConfig",
                      "MlaMoe"),
+    "WindowMoeConfig": ("ray_lightning_tpu.models.window_moe",
+                        "WindowMoeConfig", "WindowMoe"),
 }
 
 

@@ -198,19 +198,30 @@ class PagedDecodeView:
     `DecodeEngine.attention_path` reports (a trace-time backend
     re-probe could pick differently if, e.g., the jit traces after a
     `force_pallas` context has exited). None defers to the ambient
-    dispatch policy."""
+    dispatch policy.
+
+    ``window_tables [C, M]`` / ``window_write_block [C]`` are the same
+    two things for the WINDOW group of a decoder whose sliding-window
+    layers keep their K/V in a group of their own (serve/kv_cache.py
+    "two groups"): the same logical indexing by ``pos // P``, an entry
+    behind the window names scratch block 0. None (no leaf at all) for a
+    decoder with one group."""
 
     def __init__(self, tables, lengths, write_block, write_offset,
+                 window_tables=None, window_write_block=None,
                  use_pallas: bool | None = None):
         self.tables = tables
         self.lengths = lengths
         self.write_block = write_block
         self.write_offset = write_offset
+        self.window_tables = window_tables
+        self.window_write_block = window_write_block
         self.use_pallas = use_pallas
 
     def tree_flatten(self):
         return ((self.tables, self.lengths, self.write_block,
-                 self.write_offset), self.use_pallas)
+                 self.write_offset, self.window_tables,
+                 self.window_write_block), self.use_pallas)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -235,10 +246,12 @@ def paged_attention_reference(
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
     layer=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """XLA reference with the kernel's exact semantics: gather each
     slot's blocks into a dense [C, M*P, Hkv, hd] view (the copy the
-    pallas kernel exists to retire), mask `pad <= kv_pos < length`, and
+    pallas kernel exists to retire), mask `pad <= kv_pos < length` (and
+    `kv_pos >= length - window` under a sliding window), and
     run the shared masked-SDPA reference. Scratch-block garbage and
     table tails are masked to exact softmax zeros, so a longer table
     cannot perturb the visible reduction (the serving numerics
@@ -253,6 +266,8 @@ def paged_attention_reference(
     mask = kv_pos < lengths[:, None]
     if pad is not None:
         mask = mask & (kv_pos >= pad[:, None])
+    if window is not None:
+        mask = mask & (kv_pos >= lengths[:, None] - window)
     return dot_product_attention(q[:, None], k, v, causal=False,
                                  mask=mask, scale=scale)[:, 0]
 
@@ -277,17 +292,25 @@ class PagedPrefillView:
     layer scan into `paged_prefill`'s call site, so the compiled
     attention can never diverge from what
     `DecodeEngine.prefill_path` reports. None defers to the ambient
-    dispatch policy."""
+    dispatch policy.
+
+    ``window_tables [B, M]`` / ``window_write_block [B, CH]``: the window
+    group's, as in `PagedDecodeView`; None for a decoder with one
+    group."""
 
     def __init__(self, tables, write_block, write_offset,
+                 window_tables=None, window_write_block=None,
                  use_pallas: bool | None = None):
         self.tables = tables
         self.write_block = write_block
         self.write_offset = write_offset
+        self.window_tables = window_tables
+        self.window_write_block = window_write_block
         self.use_pallas = use_pallas
 
     def tree_flatten(self):
-        return ((self.tables, self.write_block, self.write_offset),
+        return ((self.tables, self.write_block, self.write_offset,
+                 self.window_tables, self.window_write_block),
                 self.use_pallas)
 
     @classmethod
@@ -304,6 +327,7 @@ def paged_prefill_reference(
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
     layer=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """XLA reference with the prefill kernel's exact semantics: gather
     each row's blocks into a dense [B, M*P, Hkv, hd] view (the copy the
@@ -314,7 +338,7 @@ def paged_prefill_reference(
     exact softmax zeros; a fully-masked query row (a pad column) emits
     zeros (the serving numerics contract, docs/SERVING.md). A 5-D pool
     is the stack ``[L, n_blocks, P, Hkv, hd]``, gathered at
-    ``layer``."""
+    ``layer``. A sliding ``window`` adds ``kv_pos > pos + j - window``."""
     b, ch, h, hd = q.shape
     p, hkv = pool_k.shape[-3:-1]
     m = tables.shape[1]
@@ -323,6 +347,8 @@ def paged_prefill_reference(
     kv_pos = jnp.arange(m * p)[None, None, :]
     q_pos = (pos + jnp.arange(ch))[None, :, None]
     mask = kv_pos <= q_pos
+    if window is not None:
+        mask = mask & (kv_pos > q_pos - window)
     if pad is not None:
         mask = mask & (kv_pos >= pad[:, None, None])
     else:
@@ -361,6 +387,7 @@ def paged_prefill(
     scale: float | None = None,
     use_pallas: bool | None = None,
     layer=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Chunked causal prefill attention over the block-paged KV pool:
     q [B, CH, H, hd], pool [n_blocks, P, Hkv, hd] (or the stack
@@ -370,7 +397,9 @@ def paged_prefill(
     (or forced, with interpret mode off-TPU) and the shapes tile;
     otherwise the gathering XLA reference path — identical semantics,
     but the dense per-group view is materialized (and charged by the
-    serve planner)."""
+    serve planner). ``window`` (static) is a sliding-window layer's: query
+    j sees ``pos + j - window < kv_pos <= pos + j``; None = every cached
+    token, the program of a full-attention layer."""
     if paged_prefill_uses_pallas(q.shape, pool_k.shape, use_pallas):
         from ray_lightning_tpu.ops.pallas.paged_prefill import (
             paged_prefill_pallas,
@@ -378,9 +407,10 @@ def paged_prefill(
 
         return paged_prefill_pallas(
             q, pool_k, pool_v, tables, pos, pad=pad, scale=scale,
-            layer=layer)
+            layer=layer, window=window)
     return paged_prefill_reference(q, pool_k, pool_v, tables, pos,
-                                   pad=pad, scale=scale, layer=layer)
+                                   pad=pad, scale=scale, layer=layer,
+                                   window=window)
 
 
 def paged_attention_uses_pallas(q_shape, pool_shape,
@@ -412,6 +442,7 @@ def paged_attention(
     scale: float | None = None,
     use_pallas: bool | None = None,
     layer=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Decode attention over the block-paged KV pool: q [C, H, hd],
     pool [n_blocks, P, Hkv, hd] (or the stack
@@ -421,7 +452,9 @@ def paged_attention(
     forced, with interpret mode off-TPU) and the shapes tile; otherwise
     the gathering XLA reference path — identical semantics, but the
     dense per-slot view is materialized (and charged by the serve
-    planner)."""
+    planner). ``window`` (static) is a sliding-window layer's: a slot
+    sees ``length - window <= kv_pos < length``; None = every cached
+    token, the program of a full-attention layer."""
     if paged_attention_uses_pallas(q.shape, pool_k.shape, use_pallas):
         from ray_lightning_tpu.ops.pallas.paged_attention import (
             paged_attention_pallas,
@@ -429,9 +462,10 @@ def paged_attention(
 
         return paged_attention_pallas(
             q, pool_k, pool_v, tables, lengths, pad=pad, scale=scale,
-            layer=layer)
+            layer=layer, window=window)
     return paged_attention_reference(q, pool_k, pool_v, tables, lengths,
-                                     pad=pad, scale=scale, layer=layer)
+                                     pad=pad, scale=scale, layer=layer,
+                                     window=window)
 
 
 # ---- latent attention (MLA) over the latent paged pool -----------------------

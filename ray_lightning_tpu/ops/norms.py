@@ -1,9 +1,10 @@
-"""Normalization ops: RMSNorm reference implementation.
+"""Normalization ops: RMSNorm and the mean-centred LayerNorm.
 
 `rms_norm` here is the jnp reference; `ray_lightning_tpu.ops.pallas.rmsnorm`
 provides the fused TPU kernel and `rms_norm(..., use_pallas=True)` (or the
 RLT_PALLAS=1 env var) selects it. The reduction is done in float32 even for
-bf16 activations — matches Llama reference numerics.
+bf16 activations — matches Llama reference numerics. `layer_norm` is the
+mean-centred norm without a bias (the Cohere family's), jnp only.
 """
 from __future__ import annotations
 
@@ -32,4 +33,15 @@ def rms_norm(
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf * jnp.reciprocal(jnp.sqrt(var + eps))
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray,
+               eps: float = 1e-5) -> jnp.ndarray:
+    """y = (x - mean(x)) / sqrt(var(x) + eps) * weight over the last axis,
+    no bias; mean and variance in f32 whatever the activations' type."""
+    xf = x.astype(jnp.float32)
+    centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    y = centred * jnp.reciprocal(jnp.sqrt(var + eps))
     return (y * weight.astype(jnp.float32)).astype(x.dtype)

@@ -42,6 +42,14 @@ slot's length, NaN included) contributes exactly zero. GQA reads KV heads
 in place via the `h // (H // Hkv)` head map — no repeat, no extra
 traffic.
 
+A sliding ``window`` (static; None for a full-attention layer, which then
+lowers the program it always did) is a lower bound beside the length's
+upper one: a slot sees ``max(pad, length - window) <= kv_pos < length``,
+which is the live extent above with a raised ``pad`` (`window_floor`), so
+tiles wholly behind the window cost neither a fetch nor a step, the
+boundary tile is masked by row, and a block the table names 0 behind the
+window is never fetched.
+
 Where Mosaic cannot slice a pool block out of HBM itself (``hd`` 64: a
 row is half a lane tile), the same tile body is fed by the pipeline
 instead (`_decode_kernel_blockspec`: the pool an operand ``tile_blocks``
@@ -62,6 +70,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -133,6 +142,26 @@ def decode_tile_tokens(block_size: int, blocks_per_slot: int) -> int:
     tokens costs ``ceil(length / tile)`` tiles; `DecodeEngine._step_work`
     counts them as ``decode_tiles``."""
     return decode_tile_blocks(block_size, blocks_per_slot) * block_size
+
+
+def window_floor(q_pos, pad, window):
+    """The first cache position a query at ``q_pos`` sees under a sliding
+    window of ``window`` tokens, itself included: ``q_pos - window + 1``,
+    never below the left pad. None passes ``pad`` through (no window).
+    Shared with `paged_prefill.py` (its query tile's FIRST row)."""
+    if window is None:
+        return pad
+    return jnp.maximum(pad, q_pos - window + 1)
+
+
+def decode_live_tiles(lengths, tile_tokens: int, window=None) -> int:
+    """KV tiles a layer the decode kernel computes for slots of
+    ``lengths`` cached tokens (no left pad): those with a position in
+    ``[max(0, length - window), length)`` (`_live_extent`, on the host).
+    `DecodeEngine._step_work` counts them."""
+    lengths = np.asarray(lengths, np.int64)
+    lo = 0 if window is None else np.maximum(lengths - window, 0)
+    return int((-(-lengths // tile_tokens) - lo // tile_tokens).sum())
 
 
 def _copies_in_kernel(hd: int) -> bool:
@@ -356,6 +385,7 @@ def paged_attention_pallas(
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
     layer=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Decode attention over the paged pool: [C, H, hd] out.
 
@@ -367,7 +397,9 @@ def paged_attention_pallas(
     ``lengths[c]`` is the number of valid cache positions (including
     the just-written query token), 0 for a slot that asks for nothing
     (it costs no tile and reads zeros); ``pad[c]`` masks a left-padded
-    slot's pad columns (positions < pad never attend)."""
+    slot's pad columns (positions < pad never attend); a static
+    ``window`` raises it to ``length - window`` (`window_floor` of the
+    query at ``length - 1``)."""
     c, h, hd = q.shape
     pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
     n_blocks, p, hkv, _ = pool_k.shape
@@ -376,6 +408,8 @@ def paged_attention_pallas(
     scale = scale if scale is not None else hd ** -0.5
     if pad is None:
         pad = jnp.zeros_like(lengths)
+    if window is not None:     # (None: not an equation more than before)
+        pad = window_floor(lengths - 1, pad, window)
     static = dict(scale=scale, block_p=p, tile_blocks=tb, table_blocks=m,
                   n_rep=h // hkv)
     q_spec = pl.BlockSpec((1, h, hd), lambda ci, *_: (ci, 0, 0))

@@ -63,6 +63,15 @@ it did in decode — test-pinned), and the V rows of a block that was not
 fetched are zeroed, so scratch-block garbage (block 0, and whatever a
 table names past the chunk, NaN included) contributes exactly zero.
 
+A sliding ``window`` (static; None for a full-attention layer, which then
+lowers the program it always did) is a lower bound beside the causal
+upper one: query ``j`` sees ``pos + j - window < kv_pos <= pos + j``. A
+query tile's live tiles then start at its FIRST row's floor,
+``q_start - window + 1`` (`paged_attention.window_floor`), so tiles wholly
+behind the window cost neither a fetch nor a step and a block the table names 0
+there is never fetched; inside the boundary tiles the rows mask
+``kv_pos > q_pos - window`` beside the causal mask.
+
 Where Mosaic cannot slice a pool block out of HBM itself (``hd`` 64: a
 row is half a lane tile), the same tile body is fed by the pipeline
 instead (`_prefill_kernel_blockspec`: the pool an operand
@@ -101,6 +110,7 @@ from ray_lightning_tpu.ops.pallas.paged_attention import (
     _fetch_tile,
     _live_extent,
     stack_as_pool,
+    window_floor,
 )
 
 _NEG_INF = -1e30  # never true -inf: exp(-inf - -inf) = nan on empty rows
@@ -176,16 +186,19 @@ def prefill_tile_shape(q_shape, pool_shape, blocks_per_slot: int):
 
 
 def prefill_live_tiles(pos: int, pads, chunk: int, block_q: int,
-                       tile_tokens: int, table_tokens: int) -> int:
+                       tile_tokens: int, table_tokens: int,
+                       window: int | None = None) -> int:
     """KV tiles a layer the kernel computes for one chunk at cache offset
     ``pos`` over rows with left pads ``pads``: for each row and query
     tile, the tiles with a position in ``[pad, pos + (qi + 1) * bq)``
-    (`_live_extent`, on the host). `DecodeEngine._step_work` counts them
-    as ``prefill_tiles``."""
+    (`_live_extent`, on the host), the floor raised to the query tile's
+    ``q_start - window + 1`` under a window. `DecodeEngine._step_work`
+    counts them as ``prefill_tiles``."""
     pads = np.asarray(pads, np.int64)[:, None]
-    ends = np.minimum(
-        pos + (1 + np.arange(chunk // block_q)) * block_q,
-        table_tokens)[None, :]
+    starts = pos + np.arange(chunk // block_q) * block_q
+    if window is not None:
+        pads = np.maximum(pads, starts[None, :] - window + 1)
+    ends = np.minimum(starts + block_q, table_tokens)[None, :]
     hi = -(-ends // tile_tokens)
     lo = np.minimum(pads // tile_tokens, hi)
     return int(np.where(ends > pads, hi - lo, 0).sum())
@@ -235,15 +248,16 @@ def _prepare(q_ref, qg, acc, m_scr, l_scr, *, n_rep):
 
 
 def _tile_step(qg, acc, m_scr, l_scr, k, v, kv_start, q_start, pad, *,
-               scale, n_rep):
+               scale, n_rep, window=None):
     """Online-softmax update of a query tile's statistics by one KV tile
     ``k`` / ``v`` [tile, Hkv, hd] (the pool's dtype) whose first token
     sits at ``kv_start``: two products batched over the kv heads,
     ``[bq * n_rep, hd] x [hd, tile]`` and ``[bq * n_rep, tile] x
     [tile, hd]``, operands in the pool's dtype and float32 out. Max, sum,
     correction and accumulator are float32 and stay in the grouped
-    layout. Only a tile that straddles the query tile's diagonal or the
-    row's pad builds the mask."""
+    layout. Only a tile that straddles the query tile's diagonal, the
+    row's pad or (with a ``window``) the window's trailing edge builds the
+    mask."""
     _, rows, _ = qg.shape
     tile = k.shape[0]
     # [tile, Hkv, hd] -> [Hkv, tile, hd]: a sublane shuffle in float32
@@ -279,6 +293,9 @@ def _tile_step(qg, acc, m_scr, l_scr, k, v, kv_start, q_start, pad, *,
     # every query of the tile sees every token of a KV tile that ends at
     # or before the first query's position and starts at or past the pad
     whole = (kv_start + tile - 1 <= q_start) & (kv_start >= pad)
+    if window is not None:
+        # ... and the tile's first token is inside the LAST query's window
+        whole &= kv_start > q_start + rows // n_rep - 1 - window
 
     @pl.when(whole)
     def _unmasked():
@@ -291,7 +308,10 @@ def _tile_step(qg, acc, m_scr, l_scr, k, v, kv_start, q_start, pad, *,
             jnp.int32, (1, rows, tile), 2)
         q_pos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (1, rows, tile), 1) // n_rep
-        update((kv_pos <= q_pos) & (kv_pos >= pad))
+        visible = (kv_pos <= q_pos) & (kv_pos >= pad)
+        if window is not None:
+            visible &= kv_pos > q_pos - window
+        update(visible)
 
 
 def _finish(o_ref, acc, l_scr, *, n_rep):
@@ -308,7 +328,7 @@ def _finish(o_ref, acc, l_scr, *, n_rep):
 def _prefill_kernel(tbl_ref, pos_ref, pad_ref, q_ref, k_hbm, v_hbm, o_ref,
                     kbuf, vbuf, sems, qg, acc, m_scr, l_scr, *,
                     scale, block_p, tile_blocks, table_blocks, block_q,
-                    n_rep):
+                    n_rep, window):
     """One (row, query tile) a grid step: a loop over the KV tiles the
     query tile can see, its trip count a traced scalar. The pool stays in
     HBM (`memory_space=ANY`); a tile's live blocks come in by one async
@@ -321,7 +341,8 @@ def _prefill_kernel(tbl_ref, pos_ref, pad_ref, q_ref, k_hbm, v_hbm, o_ref,
     # positions below ``q_start + block_q``
     q_start = pos_ref[0] + pl.program_id(1) * block_q
     b_lo, b_hi, t_lo, t_hi = _live_extent(
-        q_start + block_q, pad, block_p, tile_blocks, table_blocks)
+        q_start + block_q, window_floor(q_start, pad, window), block_p,
+        tile_blocks, table_blocks)
     fetch = functools.partial(
         _fetch_tile, tbl_ref, k_hbm, v_hbm, kbuf, vbuf, sems, b,
         b_lo=b_lo, b_hi=b_hi, block_p=block_p, tile_blocks=tile_blocks,
@@ -342,7 +363,7 @@ def _prefill_kernel(tbl_ref, pos_ref, pad_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         fetch(t, half, wait=True)
         _tile_step(qg, acc, m_scr, l_scr, kbuf[half], vbuf[half], t * tile,
-                   q_start, pad, scale=scale, n_rep=n_rep)
+                   q_start, pad, scale=scale, n_rep=n_rep, window=window)
 
     jax.lax.fori_loop(t_lo, t_hi, _tile, None)
     _finish(o_ref, acc, l_scr, n_rep=n_rep)
@@ -350,7 +371,7 @@ def _prefill_kernel(tbl_ref, pos_ref, pad_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _prefill_kernel_blockspec(tbl_ref, pos_ref, pad_ref, q_ref, *rest,
                               scale, block_p, tile_blocks, table_blocks,
-                              block_q, n_rep):
+                              block_q, n_rep, window):
     """One (row, query tile, KV tile) a grid step, the tile's blocks
     brought in by the pipeline: the pool is an operand ``tile_blocks``
     times over, each copy's index_map another entry of the table
@@ -362,7 +383,8 @@ def _prefill_kernel_blockspec(tbl_ref, pos_ref, pad_ref, q_ref, *rest,
     pad = pad_ref[b]
     q_start = pos_ref[0] + pl.program_id(1) * block_q
     _, _, t_lo, t_hi = _live_extent(
-        q_start + block_q, pad, block_p, tile_blocks, table_blocks)
+        q_start + block_q, window_floor(q_start, pad, window), block_p,
+        tile_blocks, table_blocks)
 
     @pl.when(t == 0)
     def _init():
@@ -374,7 +396,7 @@ def _prefill_kernel_blockspec(tbl_ref, pos_ref, pad_ref, q_ref, *rest,
             jnp.concatenate([r[0] for r in refs], axis=0)
         _tile_step(qg, acc, m_scr, l_scr, join(k_refs), join(v_refs),
                    t * tile_blocks * block_p, q_start, pad, scale=scale,
-                   n_rep=n_rep)
+                   n_rep=n_rep, window=window)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _done():
@@ -390,6 +412,7 @@ def paged_prefill_pallas(
     pad: jnp.ndarray | None = None,
     scale: float | None = None,
     layer=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Chunked causal prefill attention over the paged pool:
     [B, CH, H, hd] out.
@@ -405,7 +428,8 @@ def paged_prefill_pallas(
     BEFORE this call (write-then-attend, the decode lane's ordering).
     ``pad[b]`` masks a left-padded row's pad columns; a query that is
     itself a pad column sees nothing and emits zeros (discarded by the
-    engine's active-row scatter)."""
+    engine's active-row scatter). A static ``window`` adds the lower
+    bound ``kv_pos > pos + j - window``."""
     b, ch, h, hd = q.shape
     pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
     n_blocks, p, hkv, _ = pool_k.shape
@@ -417,7 +441,7 @@ def paged_prefill_pallas(
     bq, tile = prefill_tile_shape(q.shape, pool_k.shape, m)
     nq, tb = ch // bq, tile // p
     static = dict(scale=scale, block_p=p, tile_blocks=tb, table_blocks=m,
-                  block_q=bq, n_rep=n_rep)
+                  block_q=bq, n_rep=n_rep, window=window)
     q_spec = pl.BlockSpec((1, bq, h, hd), lambda bi, qi, *_: (bi, qi, 0, 0))
     rows = bq * n_rep
     scratch = [
@@ -449,8 +473,10 @@ def paged_prefill_pallas(
                 # clamped into the blocks the query tile can see: a
                 # block outside them repeats a live one, which the
                 # pipeline does not fetch again and the mask never shows
+                q_start = ps[0] + qi * bq
                 b_lo, b_hi, _, _ = _live_extent(
-                    ps[0] + (qi + 1) * bq, pd[bi], p, tb, m)
+                    q_start + bq, window_floor(q_start, pd[bi], window),
+                    p, tb, m)
                 blk = jnp.clip(ti * tb + j, b_lo, jnp.maximum(b_hi - 1, 0))
                 return tbl[bi, blk], 0, 0, 0
 

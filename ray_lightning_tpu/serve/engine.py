@@ -56,8 +56,11 @@ from ray_lightning_tpu.pipeline.compile_cache import enable_persistent_cache
 from ray_lightning_tpu.serve.kv_cache import (
     PagedPoolSpec,
     init_pool,
+    pool_leaf_shapes,
     pool_partition_spec,
     validate_pool_tp,
+    window_pool_spec,
+    window_ring_table,
 )
 from ray_lightning_tpu.telemetry.spans import annotate
 
@@ -196,7 +199,11 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     handed the leaves whole (``cache=pool``) beside a paged view and
     hands them back. A model with `tick_counters` returns their values
     from a paged call too; the step joins the two lanes' as each
-    counter says (sum / max) and returns them after ``emitted``.
+    counter says (sum / max) and returns them after ``emitted``. A decoder
+    with sliding-window layers (`model.kv_window`) has a second group of
+    leaves, a ring a slot (`serve/kv_cache.py` "two groups"): the step
+    makes that group's table rows from the positions it holds
+    (`window_ring_table`) and hands them to the model in the same views.
 
     ``fused`` selects the decode lane at BUILD time (the dispatch
     decision is static, like a kernel choice — it can never retrace):
@@ -232,11 +239,13 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         (tests/test_paged_prefill).
     """
     mcfg = model.cfg
-    spec = cfg.pool_spec
+    window = model.kv_window
+    spec = window_pool_spec(cfg.pool_spec, window, cfg.capacity,
+                            cfg.prefill_chunk)
     C, P, G, CH = cfg.capacity, spec.block_size, spec.gathered_len, \
         cfg.prefill_chunk
     B = cfg.prefill_batch
-    n_pool = len(mcfg.pool_leaf_shapes(spec.n_blocks, spec.block_size))
+    n_pool = len(pool_leaf_shapes(mcfg, spec))
     counters = tuple(model.tick_counters)
     tiled_decode = model.decode_tile_tokens(
         spec.block_size, cfg.blocks_per_slot) is not None
@@ -337,9 +346,19 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         # into the program: fused=True MEANS the kernel, wherever and
         # whenever the jit happens to trace (the shape gate already
         # passed at DecodeEngine init)
+        ring = {}
+        if window is not None:
+            # the window group's rows: the blocks that hold a position the
+            # slot's query sees, [pos + 1 - window, pos]
+            wtab = jnp.where(decoding[:, None], window_ring_table(
+                spec, jnp.arange(C), jnp.maximum(pos + 1 - window, 0), pos),
+                0)
+            ring = dict(window_tables=wtab,
+                        window_write_block=_write_index(wtab, pos,
+                                                        decoding)[0])
         view = PagedDecodeView(tables=tables, lengths=lengths,
                                write_block=bi, write_offset=off,
-                               use_pallas=True)
+                               use_pallas=True, **ring)
         logits2, pool, counts = _paged_apply(
             params, emitted[:, None], pool, pos, slot_pad, view)
         return pool, logits2[:, 0], counts
@@ -430,9 +449,20 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                     )
 
                     wpos = prefill_pos + jnp.arange(CH)
+                    ring = {}
+                    if window is not None:
+                        # the window group's row: the blocks the chunk
+                        # writes and its first row sees back to
+                        wrow = window_ring_table(
+                            spec, slot,
+                            jnp.maximum(prefill_pos - window + 1, 0),
+                            prefill_pos + CH - 1)
+                        ring = dict(window_tables=wrow,
+                                    window_write_block=wrow[:, wpos // P])
                     view = PagedPrefillView(
                         tables=row[None], write_block=row[wpos // P][None],
-                        write_offset=(wpos % P)[None], use_pallas=True)
+                        write_offset=(wpos % P)[None], use_pallas=True,
+                        **ring)
                     logits, pool, pf_counts = _paged_apply(
                         params, prefill_tokens[None], pool, prefill_pos,
                         None, view)
@@ -885,7 +915,10 @@ class DecodeEngine:
         # shape, the decode lane is the fused paged-attention kernel
         # and the dense gathered view is never built; otherwise the
         # reference lane, the bitwise anchor against generate().
-        spec = cfg.pool_spec
+        #: a decoder with sliding-window layers gets its second group
+        #: here: one ring a slot, sized from what the engine already has
+        spec = window_pool_spec(cfg.pool_spec, model.kv_window,
+                                cfg.capacity, cfg.prefill_chunk)
         refused = _refusal(model, cfg, mesh)
         if refused:
             raise ValueError(refused)
@@ -951,7 +984,7 @@ class DecodeEngine:
             cfg.prefill_batch, cfg.prefill_chunk, spec.block_size,
             cfg.blocks_per_slot) if self.fused_prefill else None
         self.cfg = cfg
-        self.spec = cfg.pool_spec
+        self.spec = spec
         #: replica-group mesh (docs/SERVING.md "sharded replicas"):
         #: None = the historical single-device replica; a mesh with a
         #: ``tensor`` axis lowers the SAME one-compile step as an SPMD
@@ -961,8 +994,7 @@ class DecodeEngine:
         #: (which lives on every rank, lockstep) is tp-oblivious.
         self.mesh = mesh
         self.tp = 1 if mesh is None else int(mesh.shape.get("tensor", 1))
-        n_pool = len(model.cfg.pool_leaf_shapes(spec.n_blocks,
-                                                spec.block_size))
+        n_pool = len(pool_leaf_shapes(model.cfg, spec))
         #: names of the device-side counts the step returns, fetched
         #: with the tick's tokens into `last_counters`
         self._counter_names = tuple(n for n, _ in model.tick_counters)
@@ -1253,6 +1285,17 @@ class DecodeEngine:
                         ``[pad, start + (qi + 1) * bq)``, by the kernel's
                         own tiles (`prefill_tile_shape`); 0 without a
                         chunk, left out where the decoder states no tile
+
+        For a decoder with sliding-window layers (`model.kv_window`), what
+        ONE WINDOW LAYER is asked to do beside what a full layer is (the
+        counters above):
+
+          kv_tokens_window     ``sum(min(pos + 1, window))``
+          prefill_ctx_window   cached tokens before the chunk that its rows
+                               can see on a window layer
+          decode_tiles_window, prefill_tiles_window
+                               the live tiles behind a raised floor
+                               (`decode_live_tiles`, `prefill_live_tiles`)
         """
         dec = np.asarray(decoding)
         if self.cfg.prefill_batch == 1:
@@ -1273,15 +1316,31 @@ class DecodeEngine:
             "prefill_rows": int(((cols - lead) * active).sum()),
             "prefill_ctx": int((np.maximum(start - pads, 0) * active).sum()),
         }
+        window = self.model.kv_window
+        if window is not None:
+            work["kv_tokens_window"] = int(np.minimum(lengths, window).sum())
+            work["prefill_ctx_window"] = int(
+                (np.minimum(np.maximum(start - pads, 0), window - 1)
+                 * active).sum())
         if self._decode_tile:
             work["decode_tiles"] = int(
                 np.ceil(lengths / self._decode_tile).sum())
+            if window is not None:
+                from ray_lightning_tpu.ops.pallas.paged_attention import (
+                    decode_live_tiles,
+                )
+
+                work["decode_tiles_window"] = decode_live_tiles(
+                    lengths, self._decode_tile, window)
         if self._prefill_tile:
             from ray_lightning_tpu.ops.pallas.paged_prefill import (
                 prefill_live_tiles,
             )
 
-            work["prefill_tiles"] = prefill_live_tiles(
+            tiles = lambda floor=None: prefill_live_tiles(
                 start, pads, self.cfg.prefill_chunk, *self._prefill_tile,
-                self.cfg.max_slot_len) if active.any() else 0
+                self.cfg.max_slot_len, floor) if active.any() else 0
+            work["prefill_tiles"] = tiles()
+            if window is not None:
+                work["prefill_tiles_window"] = tiles(window)
         return work

@@ -25,6 +25,21 @@ design; every read of the gathered view is masked by position
 scores contribute *exactly* zero through the softmax — the bitwise
 parity with single-stream `generate` rests on this (docs/SERVING.md
 "numerics").
+
+**Two groups.** A decoder with sliding-window layers (`model.kv_window`)
+keeps their K/V apart from its full layers': a full layer's blocks grow
+with the context, on demand, from the one `BlockAllocator` as above; a
+window layer never reads behind ``pos - window + 1``, so its leaves
+``[L_win, window_blocks, P, Hkv, hd]`` are a RING a slot: slot ``c`` owns
+blocks ``1 + c * ring .. (c + 1) * ring`` for good, logical block ``b``
+(the same indexing by ``pos // P``) lives at ``b % ring``, and writing on
+overwrites what fell out of sight. ``ring = ceil((window +
+prefill_chunk) / P) + 1`` (`window_ring_blocks`) holds everything a chunk
+and its slid-back twin can see, so the group never runs dry, nothing is
+taken or returned, and admission, growth and preemption keep one
+allocator to ask. Its table is arithmetic (`window_ring_table`), made
+inside the step from the positions it already has; an entry behind the
+window names scratch block 0.
 """
 from __future__ import annotations
 
@@ -49,6 +64,11 @@ class PagedPoolSpec:
     n_blocks: int
     block_size: int
     blocks_per_slot: int
+    #: the window group (module text, "two groups"): blocks of one slot's
+    #: ring and the slots that have one; 0 for a decoder with one group.
+    #: Set by the engine (`window_pool_spec`), never by a configuration.
+    window_ring: int = 0
+    window_slots: int = 0
 
     def __post_init__(self):
         if self.block_size < 1 or self.blocks_per_slot < 1:
@@ -61,6 +81,13 @@ class PagedPoolSpec:
     @property
     def gathered_len(self) -> int:
         return self.blocks_per_slot * self.block_size
+
+    @property
+    def window_blocks(self) -> int:
+        """Blocks of the window group's leaves: every slot's ring and the
+        scratch block; 0 without a window group."""
+        return 1 + self.window_slots * self.window_ring \
+            if self.window_ring else 0
 
     @classmethod
     def for_capacity(cls, capacity: int, max_len: int,
@@ -77,6 +104,55 @@ class PagedPoolSpec:
                    blocks_per_slot=bps)
 
 
+def window_ring_blocks(window: int, prefill_chunk: int, block_size: int,
+                       blocks_per_slot: int) -> int:
+    """Blocks of one slot's ring in the window group: ``ceil((window +
+    prefill_chunk) / P) + 1``, at most a whole table. A chunk's rows at
+    ``[start, start + chunk)`` see back to ``start - window + 1``, a span
+    of ``window + chunk - 1`` tokens that touches at most that many
+    blocks; the chunk is written whole before it attends, the scheduler's
+    slid-back last chunk restarts less than a chunk behind the previous
+    one's end, and a decoded token sees less than either."""
+    ring = -(-(window + prefill_chunk) // block_size) + 1
+    return min(ring, blocks_per_slot)
+
+
+def window_pool_spec(spec: PagedPoolSpec, window, capacity: int,
+                     prefill_chunk: int) -> PagedPoolSpec:
+    """``spec`` with the window group a decoder of sliding window
+    ``window`` needs for ``capacity`` slots; unchanged for None."""
+    if window is None:
+        return spec
+    return dataclasses.replace(
+        spec, window_slots=capacity, window_ring=window_ring_blocks(
+            window, prefill_chunk, spec.block_size, spec.blocks_per_slot))
+
+
+def window_ring_table(spec: PagedPoolSpec, slots, first, last):
+    """The window group's table rows ``[n, M]`` int32 for ``slots`` [n]
+    whose rows see cache positions ``first .. last`` ([n] each, or
+    scalars): logical block ``b`` of slot ``c`` is ring block ``1 + c *
+    ring + b % ring`` where it holds one of those positions, scratch
+    block 0 elsewhere (behind the window, or not written yet). `jax.numpy`
+    throughout: the engine's step makes it from the positions it has."""
+    ring, p = spec.window_ring, spec.block_size
+    b = jnp.arange(spec.blocks_per_slot, dtype=jnp.int32)[None, :]
+    slots = jnp.asarray(slots, jnp.int32).reshape(-1, 1)
+    first = jnp.asarray(first, jnp.int32).reshape(-1, 1)
+    last = jnp.asarray(last, jnp.int32).reshape(-1, 1)
+    live = (b >= first // p) & (b <= last // p)
+    return jnp.where(live, 1 + slots * ring + b % ring, 0)
+
+
+def pool_leaf_shapes(cfg, spec: PagedPoolSpec):
+    """The leaves ``cfg`` declares for ``spec``: a decoder with a window
+    group is told that group's blocks too."""
+    if spec.window_ring:
+        return cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size,
+                                    spec.window_blocks)
+    return cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size)
+
+
 def init_pool(cfg, spec: PagedPoolSpec):
     """The zeroed pool: one leaf a shape the model's config declares
     (`cfg.pool_leaf_shapes(n_blocks, block_size)`), in the model's
@@ -84,9 +160,11 @@ def init_pool(cfg, spec: PagedPoolSpec):
     each ``[n_layers, n_blocks, block_size, n_kv_heads, head_dim]`` —
     the same per-position layout as `models.llama.init_cache`,
     block-chunked over the sequence axis; a latent-attention decoder
-    one, ``[n_layers, n_blocks, block_size, row]``."""
-    return tuple(jnp.zeros(shape, cfg.dtype) for shape in
-                 cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size))
+    one, ``[n_layers, n_blocks, block_size, row]``; a decoder with
+    sliding-window layers four, its full layers' K and V and its window
+    group's (module text, "two groups")."""
+    return tuple(jnp.zeros(shape, cfg.dtype)
+                 for shape in pool_leaf_shapes(cfg, spec))
 
 
 def validate_pool_tp(cfg, tp: int) -> None:
@@ -128,8 +206,8 @@ def pool_bytes(cfg, spec: PagedPoolSpec) -> int:
     """HBM held by the pool itself (every leaf the model declares)."""
     import math
 
-    return sum(math.prod(shape) for shape in cfg.pool_leaf_shapes(
-        spec.n_blocks, spec.block_size)) * jnp.dtype(cfg.dtype).itemsize
+    return sum(math.prod(shape) for shape in pool_leaf_shapes(
+        cfg, spec)) * jnp.dtype(cfg.dtype).itemsize
 
 
 def gathered_view_bytes(cfg, spec: PagedPoolSpec, capacity: int) -> int:

@@ -304,6 +304,14 @@ class Scheduler:
                 "prefix_cache=True requires prefill_batch == 1 — the "
                 "batched lane's left-pad alignment shifts block "
                 "boundaries per group, so chains never line up")
+        if prefix_cache and "prefix_cache" in \
+                engine.model.serving_unsupported:
+            raise ValueError(
+                f"{type(engine.model).__name__} cannot share prompt "
+                "prefixes: its sliding-window layers keep their K/V in a "
+                "ring a slot that is overwritten as the context moves on, "
+                "so no block of that group outlives its request (set "
+                "prefix_cache=False)")
         if prefix_cache and engine.mesh is not None:
             raise ValueError(
                 "prefix_cache=True requires an unsharded replica "
@@ -866,9 +874,24 @@ class Scheduler:
                 pad=self.pad if self.cfg.prefill_batch > 1 else None)
             # the model's device-side counts of this tick (fetched with
             # its tokens; none for a decoder that counts nothing)
-            with annotate("serve.account", **self.engine.last_counters):
+            with annotate("serve.account", **self.engine.last_counters,
+                          **self.pool_group_counters()):
                 return self._account(pf_group, was_decoding, emitted,
                                      n_emit)
+
+    def pool_group_counters(self) -> Dict[str, int]:
+        """For an engine with a window group (`serve/kv_cache.py` "two
+        groups"), the blocks each group holds for the slotted requests:
+        ``full_blocks_live`` the allocator's, which is what one table for
+        all layers would hold a layer; ``window_blocks_live`` those of
+        them a slot's ring has room for, ``min(blocks, ring)`` a slot.
+        Empty for an engine with one group."""
+        ring = self.spec.window_ring
+        if not ring:
+            return {}
+        held = [len(slot.blocks) for slot in self.slots.values()]
+        return {"full_blocks_live": sum(held),
+                "window_blocks_live": sum(min(n, ring) for n in held)}
 
     def _grow_decoding(self) -> None:
         # growth check before the step: every decoding slot must own

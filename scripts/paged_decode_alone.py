@@ -10,12 +10,15 @@ the load as well as with the kernel.
 Each argument is ``label=checkout``: a directory that holds
 ``ray_lightning_tpu/`` (the parent commit unpacked by `git archive` into a
 directory `.gitignore` lists), so both kernels are timed in one process on
-one chip. Every block that no slot's length reaches holds inf (K) and NaN
-(V) for the parity reading: a kernel that lets a dead block into its
-statistics reads non-finite. A TPU only: off it the kernels are
-interpreted and a time means nothing, so the script refuses.
+one chip. Every block that no slot's length reaches, or that lies wholly
+behind an input's sliding window, holds inf (K) and NaN (V) for the parity
+reading: a kernel that lets a dead block into its statistics reads
+non-finite. A checkout whose kernel takes no ``window`` skips the inputs
+that have one. A TPU only: off it the kernels are interpreted and a time
+means nothing, so the script refuses.
 """
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -29,13 +32,21 @@ import numpy as np
 SHAPES = {
     "chat": (64, 16, 8, 128, 16, 160, 24, 3072),   # traffic/chat.json
     "docs": (16, 32, 8, 128, 16, 272, 16, 3072),   # traffic/docs.json
+    # traffic/ragdocs.json: 128 heads in groups of 16; two layers of a group
+    "ragdocs": (24, 128, 8, 128, 128, 128, 2, 3073),
 }
 #: name, shape, decoding slots, cached tokens each, the other slots' length
-#: (1: an idle slot as the engine handed it over until PR 28; 0: since)
+#: (1: an idle slot as the engine handed it over until PR 28; 0: since),
+#: and, where the layer has one, its sliding window
 INPUTS = [
     ("14x208", "chat", 14, 208, 1), ("25x336", "chat", 25, 336, 1),
     ("16x1600", "docs", 16, 1600, 1), ("25x336.idle0", "chat", 25, 336, 0),
     ("40x300", "chat", 40, 300, 1),
+    ("24x4096", "ragdocs", 24, 4096, 0),
+    ("24x8192", "ragdocs", 24, 8192, 0),
+    ("24x8192.w4096", "ragdocs", 24, 8192, 0, 4096),
+    ("24x16384", "ragdocs", 24, 16384, 0),
+    ("24x16384.w4096", "ragdocs", 24, 16384, 0, 4096),
 ]
 REPEATS = 30
 
@@ -65,6 +76,15 @@ def _inputs(shape, n_live, tokens, idle, seed=0):
         lengths[slot] = tokens
     order = rng.permutation(c)                 # live slots among idle ones
     return q, pk, pv, jnp.asarray(tables[order]), jnp.asarray(lengths[order])
+
+
+def _windowed(fn, window):
+    """``fn`` with the input's window, or None where ``fn`` takes none."""
+    if window is None:
+        return fn
+    if "window" not in inspect.signature(fn).parameters:
+        return None
+    return lambda *a, **kw: fn(*a, window=window, **kw)
 
 
 def _all_layers(fn, layers):
@@ -98,18 +118,30 @@ def main():
                  "kernels are interpreted")
     kernels = [(label, _kernel(root, label)) for label, root in
                (arg.split("=", 1) for arg in sys.argv[1:])]
-    for name, shape, n_live, tokens, idle in INPUTS:
+    for name, shape, n_live, tokens, idle, *rest in INPUTS:
+        window = rest[0] if rest else None
         q, pk, pv, tables, lengths = args = _inputs(shape, n_live, tokens,
                                                     idle)
         p, layers = SHAPES[shape][4], SHAPES[shape][6]
         owned = np.zeros(pk.shape[1], bool)
         for row, n in zip(np.asarray(tables), np.asarray(lengths)):
-            owned[row[:-(-int(n) // p)]] = True
+            first = max(int(n) - window, 0) // p if window else 0
+            owned[row[first:-(-int(n) // p)]] = True
         dead = ~jnp.asarray(owned)[None, :, None, None, None]
-        ref = np.asarray(paged_attention_reference(
-            q, pk, pv, tables, lengths, layer=1), np.float32)
+        # the gathering reference a KV head's group of query heads at a
+        # time: it repeats K and V over the group, 13 GB at 128 heads
+        hkv = SHAPES[shape][2]
+        rep = q.shape[1] // hkv
+        ref = np.concatenate([np.asarray(paged_attention_reference(
+            q[:, g * rep:(g + 1) * rep], pk[:, :, :, g:g + 1],
+            pv[:, :, :, g:g + 1], tables, lengths, layer=1,
+            **({"window": window} if window else {})), np.float32)
+            for g in range(hkv)], axis=1)
         live = np.asarray(lengths) > 0
         for label, fn in kernels:
+            fn = _windowed(fn, window)
+            if fn is None:
+                continue
             got = np.asarray(jax.jit(lambda *a: fn(*a, layer=1))(
                 q, jnp.where(dead, jnp.inf, pk), jnp.where(dead, jnp.nan, pv),
                 tables, lengths), np.float32)

@@ -13,8 +13,10 @@ Each argument is ``label=checkout[,NAME=value...]``: a directory that
 holds ``ray_lightning_tpu/`` (the parent commit unpacked by `git archive`
 into a directory `.gitignore` lists), and module constants of its
 `paged_prefill.py` to set before tracing (how the forms of PERF.md's
-table were timed in one process). Every block the row does not own holds
-inf (K) and NaN (V) for the parity reading. A TPU only.
+table were timed in one process). Every block the row does not own, or that
+lies wholly behind an input's sliding window, holds inf (K) and NaN (V) for
+the parity reading. A checkout whose kernel takes no ``window`` skips the
+inputs that have one. A TPU only.
 """
 import importlib.util
 import json
@@ -26,13 +28,19 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from paged_decode_alone import SHAPES, _median_ms  # noqa: E402
+from paged_decode_alone import SHAPES, _median_ms, _windowed  # noqa: E402
 
-#: name, shape, cached tokens behind the chunk
+#: name, shape, cached tokens behind the chunk; then, where they differ from
+#: a 128-row chunk of a full layer, the chunk's rows and the sliding window
 INPUTS = [
     ("docs+0", "docs", 0), ("docs+768", "docs", 768),
     ("docs+1920", "docs", 1920), ("docs+3968", "docs", 3968),
     ("chat+0", "chat", 0), ("chat+512", "chat", 512),
+    ("ragdocs+3072", "ragdocs", 3072, 1024),
+    ("ragdocs+7168", "ragdocs", 7168, 1024),
+    ("ragdocs+7168.w4096", "ragdocs", 7168, 1024, 4096),
+    ("ragdocs+15360", "ragdocs", 15360, 1024),
+    ("ragdocs+15360.w4096", "ragdocs", 15360, 1024, 4096),
 ]
 CHUNK = 128
 
@@ -51,14 +59,14 @@ def _kernel(spec, label):
     return mod.paged_prefill_pallas
 
 
-def _inputs(shape, ctx, seed=0):
+def _inputs(shape, ctx, chunk, seed=0):
     _, h, hkv, hd, p, m, layers, nb = SHAPES[shape]
     rng = np.random.default_rng(seed)
     kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(kq, (1, CHUNK, h, hd), jnp.bfloat16)
+    q = jax.random.normal(kq, (1, chunk, h, hd), jnp.bfloat16)
     pk = jax.random.normal(kk, (layers, nb, p, hkv, hd), jnp.bfloat16)
     pv = jax.random.normal(kv, (layers, nb, p, hkv, hd), jnp.bfloat16)
-    need = -(-(ctx + CHUNK) // p)
+    need = -(-(ctx + chunk) // p)
     tables = np.zeros((1, m), np.int32)        # the tail names scratch 0
     tables[0, :need] = (1 + rng.permutation(nb - 1))[:need]
     return q, pk, pv, jnp.asarray(tables), jnp.int32(ctx)
@@ -85,16 +93,28 @@ def main():
                  "kernels are interpreted")
     kernels = [(label, _kernel(spec, label)) for label, spec in
                (arg.split("=", 1) for arg in sys.argv[1:])]
-    for name, shape, ctx in INPUTS:
-        q, pk, pv, tables, pos = args = _inputs(shape, ctx)
-        layers = SHAPES[shape][6]
+    for name, shape, ctx, *rest in INPUTS:
+        chunk = rest[0] if rest else CHUNK
+        window = rest[1] if len(rest) > 1 else None
+        q, pk, pv, tables, pos = args = _inputs(shape, ctx, chunk)
+        hkv, p, layers = SHAPES[shape][2], SHAPES[shape][4], SHAPES[shape][6]
+        rep = q.shape[2] // hkv
         owned = np.zeros(pk.shape[1], bool)
-        owned[np.asarray(tables)[0]] = True
+        first = max(ctx - window + 1, 0) // p if window else 0
+        owned[np.asarray(tables)[0][first:]] = True
         owned[0] = False
         dead = ~jnp.asarray(owned)[None, :, None, None, None]
-        ref = np.asarray(paged_prefill_reference(
-            q, pk, pv, tables, pos, layer=1), np.float32)
+        # the gathering reference a KV head's group of query heads at a
+        # time: [128 heads, 1024, 16384] float32 scores would be 8 GB
+        ref = np.concatenate([np.asarray(paged_prefill_reference(
+            q[:, :, g * rep:(g + 1) * rep], pk[:, :, :, g:g + 1],
+            pv[:, :, :, g:g + 1], tables, pos, layer=1,
+            **({"window": window} if window else {})), np.float32)
+            for g in range(hkv)], axis=2)
         for label, fn in kernels:
+            fn = _windowed(fn, window)
+            if fn is None:
+                continue
             got = np.asarray(jax.jit(lambda *a: fn(*a, layer=1))(
                 q, jnp.where(dead, jnp.inf, pk), jnp.where(dead, jnp.nan, pv),
                 tables, pos), np.float32)

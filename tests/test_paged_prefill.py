@@ -373,6 +373,7 @@ def test_shapes_supported_contract():
     (128, 32, 128, 128),   # serve.mistral-7b-v0.3.docs, llama3_8b
     (128, 64, 128, 128),
     (128, 128, 128, 64),   # the budget still halves what VMEM refuses
+    (1024, 128, 128, 64),  # serve.command-a-plus-05-2026.ragdocs
 ])
 def test_query_tile_rule(ch, h, hd, want):
     assert _fit_q_block(ch, h, hd) == want
@@ -393,6 +394,9 @@ def test_query_tile_rule(ch, h, hd, want):
     # V tiles are four times GQA 4:1's
     ((1, 128, 32, 128), (600, 16, 32, 128), 272, (128, 256)),
     ((1, 128, 64, 128), (600, 16, 64, 128), 272, (128, 64)),
+    # 128 heads in groups of 16 (serve.command-a-plus-05-2026.ragdocs): the
+    # 64-row query tile VMEM allows leaves a KV tile of one 128-token block
+    ((1, 1024, 128, 128), (3073, 128, 8, 128), 128, (64, 128)),
 ])
 def test_kv_tile_rule(q_shape, pool_shape, m, want):
     """The tile follows from the operands alone: no argument, no

@@ -168,6 +168,59 @@ def test_paged_prefill_compiles(v5e, as_on_tpu, heads, hd, p, ch, m, tiles,
         assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
 
 
+#: the third decoder's engine (serve.command-a-plus-05-2026.ragdocs): 128
+#: query heads in groups of 16 at hd 128, 1024-row chunks over 128-token
+#: blocks, 24 slots of 128 blocks; the query tile's rows and the KV tile's
+#: tokens `prefill_tile_shape` answers there
+WINDOW_CELL = dict(h=128, hkv=8, hd=128, p=128, c=24, ch=1024, m=128,
+                   window=4096, tiles=(64, 128))
+
+
+def _window_lowered(v5e, kernel, window, form="stack", **kw):
+    from ray_lightning_tpu.ops.pallas.paged_attention import (
+        paged_attention_pallas,
+    )
+    from ray_lightning_tpu.ops.pallas.paged_prefill import (
+        paged_prefill_pallas,
+    )
+
+    w = WINDOW_CELL
+    s = SingleDeviceSharding(v5e[0])
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool, at = _pool_operands(form, (1 + w["c"] * 41, w["p"], w["hkv"],
+                                     w["hd"]), s)
+    if kernel == "decode":
+        fn = lambda q, k, v, t, ln, **at: paged_attention_pallas(
+            q, k, v, t, ln, **at, **kw)
+        args = (_sds((w["c"], w["h"], w["hd"]), bf, s), pool, pool,
+                _sds((w["c"], w["m"]), i32, s), _sds((w["c"],), i32, s))
+    else:
+        fn = lambda q, k, v, t, ps, **at: paged_prefill_pallas(
+            q, k, v, t, ps, **at, **kw)
+        args = (_sds((1, w["ch"], w["h"], w["hd"]), bf, s), pool, pool,
+                _sds((1, w["m"]), i32, s), _sds((), i32, s))
+    kw = dict(kw, window=window)
+    return jax.jit(fn).lower(*args, **at)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_window_kernels_compile_at_128_heads_in_groups_of_16(v5e, as_on_tpu,
+                                                             kernel):
+    from ray_lightning_tpu.ops.pallas.paged_prefill import prefill_tile_shape
+
+    w = WINDOW_CELL
+    assert prefill_tile_shape((1, w["ch"], w["h"], w["hd"]),
+                              (w["p"], w["hkv"], w["hd"]),
+                              w["m"]) == w["tiles"]
+    for window in (w["window"], None):          # a window layer, a full one
+        compiled = _window_lowered(v5e, kernel, window).compile()
+        assert _n_mosaic(compiled) == 1
+    # that ``window=None`` lowers the kernel of before is compared where it
+    # can be, on the jaxpr (tests/test_window_attention.py): the Mosaic
+    # payload in a lowered module's text differs between two lowerings of
+    # one call
+
+
 @pytest.mark.parametrize("seq", [1024, 2048, 4096])
 def test_flash_fwd_bwd_compiles(v5e, as_on_tpu, seq):
     from ray_lightning_tpu.ops.attention import flash_attention
