@@ -160,6 +160,41 @@ class EngineConfig:
         return self.pool_spec.gathered_len
 
 
+def _kth_largest(x, k):
+    """The ``k``-th largest element of the row ``x`` ``[V]`` for a
+    runtime ``k`` in ``[1, V]``, selected, not sorted: the same value as
+    ``jnp.sort(x)[::-1][k - 1]`` and ``lax.top_k(x, k)[0][-1]``, ties,
+    ``+-inf`` and every ``k`` included, at a cost that does not depend
+    on ``k``.
+
+    A float32's bits, with the sign bit flipped for a non-negative and
+    all bits flipped for a negative, are a uint32 key in the floats' own
+    order. The key of the k-th largest is the largest ``c`` with
+    ``count(keys >= c) >= k``, and is settled one bit a pass from the
+    most significant down: a pass is one compare and one row sum, no
+    gather, no scatter, no shape that depends on the data. (More bits a
+    pass, three or fifteen counts a read, were slower on the chip:
+    PERF.md section 6, PR 32.)
+
+    ``-0.0`` and ``0.0`` are two keys and one value: where the k-th
+    largest is a zero its sign may differ from the sort's (which orders
+    them by position), and ``x >= kth`` does not. A NaN has no order: one
+    with the sign bit clear ranks above ``+inf`` (where the sort puts
+    it), one with it set below ``-inf``; a row that holds one has no
+    meaningful draw either way."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+
+    def settle(i, found):
+        trial = found | (top >> i.astype(jnp.uint32))
+        return jnp.where(jnp.sum(keys >= trial) >= k, trial, found)
+
+    key = jax.lax.fori_loop(0, 32, settle, jnp.uint32(0))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(key >= top, key ^ top, ~key), jnp.float32).astype(x.dtype)
+
+
 def _sample_one(logits, key, temp, top_k):
     """Per-slot sampling, runtime-switched, mirroring `generate`'s
     static-python `sample` bit for bit per mode:
@@ -167,16 +202,17 @@ def _sample_one(logits, key, temp, top_k):
       * temp == 0      -> argmax (the categorical draw is computed and
                           discarded — fixed shapes beat a branch)
       * top_k > 0      -> k-th-largest threshold filter; the threshold
-                          VALUE from a descending sort equals
-                          ``lax.top_k(x, k)[0][:, -1]`` for runtime k
+                          VALUE, selected by `_kth_largest` for the
+                          runtime k (clipped to ``[1, V]``), equals
+                          ``lax.top_k(x, k)[0][:, -1]``: no sort of the
+                          vocabulary, no bound on k
       * else           -> plain temperature sampling
 
     The categorical call takes ``[1, V]`` exactly like `generate`'s
     B=1 call so the drawn bits match under vmap."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / jnp.maximum(temp, jnp.finfo(logits.dtype).tiny)
-    srt = jnp.sort(scaled)[::-1]
-    kth = srt[jnp.clip(top_k, 1, scaled.shape[0]) - 1]
+    kth = _kth_largest(scaled, jnp.clip(top_k, 1, scaled.shape[0]))
     filtered = jnp.where(scaled >= kth, scaled, -jnp.inf)
     sampled_from = jnp.where(top_k > 0, filtered, scaled)
     drawn = jax.random.categorical(
@@ -1202,7 +1238,8 @@ class DecodeEngine:
                                  put(ptoks), put(ppos),
                                  put(plast), put(ppad))
         with annotate("serve.dispatch",
-                      **self._step_work(pos, decoding, prefill)):
+                      **self._step_work(pos, decoding, prefill, temp,
+                                        top_k)):
             out = self._step(*args)
         counts = ()
         if spec_mode:
@@ -1260,12 +1297,17 @@ class DecodeEngine:
         return (emitted[:, None],
                 np.asarray(decoding).astype(np.int32), new_rngs)
 
-    def _step_work(self, pos, decoding, prefill) -> dict:
-        """What this step's attention is asked to do, as `rlt.serve.
-        dispatch` carries it into a profiler trace: from the host arrays
-        the tick already holds, no device value is read.
+    def _step_work(self, pos, decoding, prefill, temp, top_k) -> dict:
+        """What this step's attention and sampling are asked to do, as
+        `rlt.serve.dispatch` carries it into a profiler trace: from the
+        host arrays the tick already holds, no device value is read.
 
           decode_slots  slots in the decode phase
+          sampled_slots of those, the ones that draw (``temp > 0``)
+          topk_slots    of those, the ones the top-k threshold filters
+                        (``top_k > 0``): the step finds a threshold for
+                        every slot whatever its mode, and only these use
+                        it
           kv_tokens     cache tokens their queries read: each reads its
                         ``pos`` written tokens and the one this step
                         writes (the kernel's ``lengths = pos + 1``)
@@ -1310,8 +1352,11 @@ class DecodeEngine:
         cols = last + 1 if last >= 0 else self.cfg.prefill_chunk
         lead = np.clip(pads - start, 0, cols)   # pad columns in the chunk
         lengths = np.asarray(pos)[dec] + 1
+        sampled = dec & (np.asarray(temp) > 0)
         work = {
             "decode_slots": int(dec.sum()),
+            "sampled_slots": int(sampled.sum()),
+            "topk_slots": int((sampled & (np.asarray(top_k) > 0)).sum()),
             "kv_tokens": int(lengths.sum()),
             "prefill_rows": int(((cols - lead) * active).sum()),
             "prefill_ctx": int((np.maximum(start - pads, 0) * active).sum()),

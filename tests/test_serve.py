@@ -136,6 +136,51 @@ def test_staggered_streams_bitwise_match_generate(tiny, engine):
                                       err_msg=rid)
 
 
+@pytest.mark.parametrize("k", [1, 40, "V", "V+7"])
+def test_top_k_streams_match_generate(tiny, engine, k):
+    """The threshold is selected for a runtime k a slot (`_kth_largest`),
+    `generate()` reads it off a static ``lax.top_k``: the streams of a
+    mixed greedy / temperature / top-k batch are the same token for token
+    at the ends of k's range too (k = 1 is greedy by another road; k >= V
+    filters nothing, and `generate` takes at most V)."""
+    cfg, model, params, prompts = tiny
+    k = {"V": cfg.vocab_size, "V+7": cfg.vocab_size + 7}.get(k, k)
+    modes = [(0.0, None), (0.7, None), (0.7, k), (1.3, k), (0.7, 5)]
+    reqs = [Request(rid=f"k{i}", prompt=prompts[i][0], max_new_tokens=6,
+                    temperature=t, top_k=top_k, seed=31 + i)
+            for i, (t, top_k) in enumerate(modes)]
+    out = _drain(Scheduler(engine), submit=reqs)
+    for i, r in enumerate(reqs):
+        ref = np.asarray(generate(
+            model, params, prompts[i], r.max_new_tokens,
+            temperature=r.temperature, seed=r.seed,
+            top_k=r.top_k and min(r.top_k, cfg.vocab_size)))[0]
+        np.testing.assert_array_equal(np.array(out[r.rid].tokens), ref,
+                                      err_msg=r.rid)
+
+
+def test_step_holds_no_sort(tiny):
+    """The sort of the vocabulary cannot come back unseen: no `sort`
+    equation anywhere in a dense decoder's step, walked through the seam
+    (`analysis/jaxpr.py:walk_eqns` enters whatever carries a sub-program:
+    tests/test_jaxpr_seam.py). The same walk does see a sort where there
+    is one, and does reach the sampling stage's draw."""
+    from ray_lightning_tpu.analysis.jaxpr import walk_eqns
+    from ray_lightning_tpu.serve.audit import trace_decode_step
+
+    cfg, _, _, _ = tiny
+    closed, _ = trace_decode_step(cfg, EngineConfig(
+        capacity=4, block_size=4, blocks_per_slot=8, prefill_chunk=4))
+    seen = {eqn.primitive.name for eqn, _ in walk_eqns(closed.jaxpr)}
+    assert "sort" not in seen
+    assert {"argmax", "random_bits"} <= seen
+    sorted_somewhere = jax.make_jaxpr(jax.jit(
+        lambda x: jax.lax.cond(x[0] > 0, jnp.sort, jnp.negative, x)))(
+            jnp.zeros(8))
+    assert "sort" in {eqn.primitive.name
+                      for eqn, _ in walk_eqns(sorted_somewhere.jaxpr)}
+
+
 def test_churn_never_recompiles(tiny, engine):
     """Admission/retirement across waves of requests is pure runtime
     data: the step stays ONE compiled program."""

@@ -150,7 +150,10 @@ def _expected_work(sched, ecfg):
     dec = sched.decoding
     lengths = [int(sched.pos[s]) + 1 for s in range(ecfg.capacity) if dec[s]]
     tile = decode_tile_tokens(ecfg.block_size, ecfg.blocks_per_slot)
+    sampled = dec & (sched.temp > 0)
     want = {"decode_slots": int(dec.sum()),
+            "sampled_slots": int(sampled.sum()),
+            "topk_slots": int((sampled & (sched.top_k > 0)).sum()),
             "kv_tokens": sum(lengths),
             # the kernel's own tile: a slot costs ceil(length / tile)
             "decode_tiles": sum(-(-n // tile) for n in lengths),
@@ -196,7 +199,8 @@ def traced(tmp_path_factory):
     sched = driver.replicas[0].sched
     engine = driver.replicas[0].engine
     rng = np.random.default_rng(1)
-    for i, (n, temp) in enumerate([(5, 0.0), (19, 0.8), (3, 0.0)]):
+    # r0 decodes through the traced ticks, so it is the one that draws
+    for i, (n, temp) in enumerate([(5, 0.8), (19, 0.0), (3, 0.0)]):
         driver.submit(Request(
             rid=f"r{i}", prompt=rng.integers(0, 256, n).astype(np.int32),
             max_new_tokens=12, temperature=temp, top_k=5 if temp else None,
@@ -305,7 +309,8 @@ def test_driver_phase_brackets_the_scheduler_tick(traced, phase):
 
 @pytest.mark.parametrize("counter", ["decode_slots", "kv_tokens",
                                      "decode_tiles", "prefill_rows",
-                                     "prefill_ctx", "prefill_tiles"])
+                                     "prefill_ctx", "prefill_tiles",
+                                     "sampled_slots", "topk_slots"])
 def test_dispatch_counters_equal_the_schedulers_own(traced, counter):
     """The context convention must not over-count: a roofline share over
     105% is refused by the benchmark's driver."""

@@ -223,14 +223,19 @@ def test_churn_never_recompiles_and_the_counters_ride_the_tick(tiny, engine):
     pos = np.asarray([39, 32, 0, 0])
     work = engine._step_work(
         pos, np.asarray([True, False, False, False]),
-        (np.int32(1), np.zeros(16, np.int32), np.int32(32), np.int32(-1)))
+        (np.int32(1), np.zeros(16, np.int32), np.int32(32), np.int32(-1)),
+        np.asarray([0.8, 0.8, 0.0, 0.0]), np.asarray([0, 5, 0, 0]))
+    # slot 0 draws without a filter; slot 1 would filter, and is not decoding
+    assert (work["sampled_slots"], work["topk_slots"]) == (1, 0)
     assert work["kv_tokens"] == 40 and work["kv_tokens_window"] == 24
     assert work["prefill_ctx"] == 32 and work["prefill_ctx_window"] == 23
     assert work["decode_tiles"] == work["decode_tiles_window"] == 1
     assert work["prefill_tiles"] >= work["prefill_tiles_window"] >= 1
     deep = engine._step_work(
         np.asarray([126, 0, 0, 0]), np.asarray([True, False, False, False]),
-        (np.int32(-1), np.zeros(16, np.int32), np.int32(0), np.int32(-1)))
+        (np.int32(-1), np.zeros(16, np.int32), np.int32(0), np.int32(-1)),
+        np.asarray([0.8, 0.0, 0.0, 0.0]), np.asarray([5, 0, 0, 0]))
+    assert (deep["sampled_slots"], deep["topk_slots"]) == (1, 1)
     assert (deep["kv_tokens"], deep["kv_tokens_window"]) == (127, 24)
     assert (deep["prefill_rows"], deep["prefill_tiles_window"]) == (0, 0)
 
@@ -244,7 +249,10 @@ def test_a_decoder_with_one_group_carries_no_window_counter():
     assert (eng.spec.window_ring, len(eng.pool)) == (0, 2)
     work = eng._step_work(
         np.asarray([5, 0, 0, 0]), np.asarray([True, False, False, False]),
-        (np.int32(-1), np.zeros(16, np.int32), np.int32(0), np.int32(-1)))
+        (np.int32(-1), np.zeros(16, np.int32), np.int32(0), np.int32(-1)),
+        np.zeros(4, np.float32), np.asarray([5, 0, 0, 0]))
+    # top_k without a temperature is greedy: nothing draws, nothing filters
+    assert (work["sampled_slots"], work["topk_slots"]) == (0, 0)
     assert not [k for k in work if k.endswith("_window")]
     assert Scheduler(eng).pool_group_counters() == {}
 
