@@ -30,6 +30,7 @@ from ray_lightning_tpu.models.moe import (
     MoEMLP,
     moe_param_specs,
 )
+from ray_lightning_tpu.models.ssm_hybrid import SsmHybrid, SsmHybridConfig
 from ray_lightning_tpu.models.window_moe import WindowMoe, WindowMoeConfig
 from ray_lightning_tpu.models.resnet import (
     ResNet,
@@ -65,6 +66,8 @@ __all__ = [
     "resnet18",
     "resnet34",
     "resnet50",
+    "SsmHybrid",
+    "SsmHybridConfig",
     "WindowMoe",
     "WindowMoeConfig",
 ]

@@ -465,6 +465,8 @@ class Llama(nn.Module):
     serving_unsupported = ()
     #: no sliding-window layers: one group of the pool
     kv_window = None
+    #: no recurrent layers: no leaf of the pool holds a row a slot
+    slot_state = False
 
     def serving_param_specs(self):
         return llama_param_specs(self.cfg)

@@ -357,6 +357,8 @@ class MlaMoe(nn.Module):
                            "prefill_batch", "tensor_parallel")
     #: no sliding-window layers: one group of the pool
     kv_window = None
+    #: no recurrent layers: no leaf of the pool holds a row a slot
+    slot_state = False
 
     def serving_param_specs(self):
         """No published placement: a replica holds its share whole."""

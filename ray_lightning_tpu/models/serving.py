@@ -18,6 +18,10 @@ itself:
     model.kv_window                 the window of its sliding-window layers
                                     (their K/V live in a group of their
                                     own, `serve/kv_cache.py`), or None
+    model.slot_state                True where its recurrent layers keep a
+                                    state a SLOT: `pool_leaf_shapes` is
+                                    then told ``state_slots`` too, and the
+                                    step's views say which rows are real
     model(tokens, cache=, pos=, pad=, paged=)
 
 A new decoder enters with a config dataclass, a flax module with those
@@ -37,6 +41,8 @@ _DECODERS = {
                      "MlaMoe"),
     "WindowMoeConfig": ("ray_lightning_tpu.models.window_moe",
                         "WindowMoeConfig", "WindowMoe"),
+    "SsmHybridConfig": ("ray_lightning_tpu.models.ssm_hybrid",
+                        "SsmHybridConfig", "SsmHybrid"),
 }
 
 

@@ -296,6 +296,8 @@ class WindowMoe(nn.Module):
     serving_unsupported = ("reference_lanes", "speculative",
                            "prefill_batch", "tensor_parallel",
                            "prefix_cache")
+    #: no recurrent layers: no leaf of the pool holds a row a slot
+    slot_state = False
 
     @property
     def kv_window(self) -> int:

@@ -205,23 +205,30 @@ class PagedDecodeView:
     layers keep their K/V in a group of their own (serve/kv_cache.py
     "two groups"): the same logical indexing by ``pos // P``, an entry
     behind the window names scratch block 0. None (no leaf at all) for a
-    decoder with one group."""
+    decoder with one group.
+
+    ``state_moves [C]`` bool, for a decoder whose recurrent layers keep a
+    row a slot (serve/kv_cache.py "a row a slot"): the slots whose state
+    this tick's token advances; every other slot's stays as it is. None
+    for a decoder without."""
 
     def __init__(self, tables, lengths, write_block, write_offset,
                  window_tables=None, window_write_block=None,
-                 use_pallas: bool | None = None):
+                 state_moves=None, use_pallas: bool | None = None):
         self.tables = tables
         self.lengths = lengths
         self.write_block = write_block
         self.write_offset = write_offset
         self.window_tables = window_tables
         self.window_write_block = window_write_block
+        self.state_moves = state_moves
         self.use_pallas = use_pallas
 
     def tree_flatten(self):
         return ((self.tables, self.lengths, self.write_block,
                  self.write_offset, self.window_tables,
-                 self.window_write_block), self.use_pallas)
+                 self.window_write_block, self.state_moves),
+                self.use_pallas)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -296,22 +303,32 @@ class PagedPrefillView:
 
     ``window_tables [B, M]`` / ``window_write_block [B, CH]``: the window
     group's, as in `PagedDecodeView`; None for a decoder with one
-    group."""
+    group.
+
+    ``state_slot`` (scalar) / ``real_rows [2]``, for a decoder whose
+    recurrent layers keep a row a slot: the slot whose state the chunk
+    advances, and the first and last of the chunk's rows that are REAL
+    (not sent before, not past the prompt's end; ``last = first - 1``:
+    none): a recurrence advances on those alone. None for a decoder
+    without."""
 
     def __init__(self, tables, write_block, write_offset,
                  window_tables=None, window_write_block=None,
+                 state_slot=None, real_rows=None,
                  use_pallas: bool | None = None):
         self.tables = tables
         self.write_block = write_block
         self.write_offset = write_offset
         self.window_tables = window_tables
         self.window_write_block = window_write_block
+        self.state_slot = state_slot
+        self.real_rows = real_rows
         self.use_pallas = use_pallas
 
     def tree_flatten(self):
         return ((self.tables, self.write_block, self.write_offset,
-                 self.window_tables, self.window_write_block),
-                self.use_pallas)
+                 self.window_tables, self.window_write_block,
+                 self.state_slot, self.real_rows), self.use_pallas)
 
     @classmethod
     def tree_unflatten(cls, aux, children):

@@ -114,12 +114,45 @@ def stack_as_pool(pool_k, pool_v, tables, layer):
     through the index_map it always had; a slot's scratch block 0 is
     the layer's own block 0. A 4-D pool passes through (``layer``
     must then be 0)."""
-    if pool_k.ndim == 4:
+    if pool_k.ndim in (3, 4):      # one pool; 3-D: `headless_stack_as_pool`
         return pool_k, pool_v, tables
     n_layers, n_blocks = pool_k.shape[:2]
     flat = (n_layers * n_blocks, *pool_k.shape[2:])
     return (pool_k.reshape(flat), pool_v.reshape(flat),
             tables + jnp.asarray(layer, tables.dtype) * n_blocks)
+
+
+def headless_stack_as_pool(pool_k, pool_v, tables, layer):
+    """`stack_as_pool` for a decoder with ONE KV head that keeps its stack
+    without the head axis, ``[L, n_blocks, P, hd]``: a leaf ``[.., P, 1,
+    hd]`` has a degenerate second-minor dimension, which the chip's tiled
+    layout pads to a sublane tile (twice the bytes in bfloat16, and Mosaic
+    cannot slice one head of the padded pair out of HBM). The result is the
+    3-D pool ``[L * n_blocks, P, hd]`` both paged kernels take as "one KV
+    head": they add the head axis to a tile in VMEM, where it costs
+    nothing."""
+    n_layers, n_blocks = pool_k.shape[:2]
+    flat = (n_layers * n_blocks, *pool_k.shape[2:])
+    return (pool_k.reshape(flat), pool_v.reshape(flat),
+            tables + jnp.asarray(layer, tables.dtype) * n_blocks)
+
+
+def pool_dims(pool):
+    """(n_blocks, P, Hkv, hd) of one pool: ``[n_blocks, P, Hkv, hd]``, or
+    the headless ``[n_blocks, P, hd]`` of one KV head."""
+    if pool.ndim == 3:
+        return (*pool.shape[:2], 1, pool.shape[2])
+    return tuple(pool.shape)
+
+
+def _by_kv_head(x, upcast: bool):
+    """A KV tile ``[tile, Hkv, hd]`` as ``[Hkv, tile, hd]`` (a sublane
+    shuffle, in float32); a headless tile ``[tile, hd]`` gains the axis in
+    front, which moves nothing."""
+    if x.ndim == 2:
+        return (x.astype(jnp.float32) if upcast else x)[None]
+    y = x.astype(jnp.float32).transpose(1, 0, 2)
+    return y if upcast else y.astype(x.dtype)
 
 
 #: tokens a KV tile aims at. A tile is what one step of the kernel's loop
@@ -229,8 +262,8 @@ def _tile_update(qg, k, v, kv_start, length, pad, carry, *, scale):
     # GQA head map: query head g*n_rep + r reads kv head g — the
     # contraction is batched over kv heads, so KV tiles are consumed in
     # place (no repeat)
-    kg = k.astype(jnp.float32).transpose(1, 0, 2)      # [Hkv, tile, hd]
-    vg = v.astype(jnp.float32).transpose(1, 0, 2)
+    kg = _by_kv_head(k, True)                          # [Hkv, tile, hd]
+    vg = _by_kv_head(v, True)
     s = (jax.lax.dot_general(
         qg, kg, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
@@ -402,7 +435,8 @@ def paged_attention_pallas(
     query at ``length - 1``)."""
     c, h, hd = q.shape
     pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
-    n_blocks, p, hkv, _ = pool_k.shape
+    n_blocks, p, hkv, _ = pool_dims(pool_k)
+    kv_row = pool_k.shape[2:]       # (Hkv, hd), or (hd,) of a headless pool
     m = tables.shape[1]
     tb = decode_tile_blocks(p, m)
     scale = scale if scale is not None else hd ** -0.5
@@ -421,8 +455,8 @@ def paged_attention_pallas(
         # names, and only those a slot's length reaches
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = [
-            pltpu.VMEM((2, tb * p, hkv, hd), pool_k.dtype),
-            pltpu.VMEM((2, tb * p, hkv, hd), pool_v.dtype),
+            pltpu.VMEM((2, tb * p, *kv_row), pool_k.dtype),
+            pltpu.VMEM((2, tb * p, *kv_row), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),   # (k | v, buffer half)
             pltpu.SMEM((2,), jnp.int32),
         ]

@@ -106,9 +106,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_lightning_tpu.ops.dispatch import interpret_mode as _interpret
 from ray_lightning_tpu.ops.pallas.paged_attention import (
+    _by_kv_head,
     _copies_in_kernel,
     _fetch_tile,
     _live_extent,
+    pool_dims,
     stack_as_pool,
     window_floor,
 )
@@ -261,8 +263,8 @@ def _tile_step(qg, acc, m_scr, l_scr, k, v, kv_start, q_start, pad, *,
     _, rows, _ = qg.shape
     tile = k.shape[0]
     # [tile, Hkv, hd] -> [Hkv, tile, hd]: a sublane shuffle in float32
-    kg = k.astype(jnp.float32).transpose(1, 0, 2).astype(k.dtype)
-    vg = v.astype(jnp.float32).transpose(1, 0, 2).astype(v.dtype)
+    kg = _by_kv_head(k, False)
+    vg = _by_kv_head(v, False)
 
     def update(visible):
         s = jax.lax.dot_general(
@@ -432,13 +434,14 @@ def paged_prefill_pallas(
     bound ``kv_pos > pos + j - window``."""
     b, ch, h, hd = q.shape
     pool_k, pool_v, tables = stack_as_pool(pool_k, pool_v, tables, layer)
-    n_blocks, p, hkv, _ = pool_k.shape
+    n_blocks, p, hkv, _ = pool_dims(pool_k)
+    kv_row = pool_k.shape[2:]       # (Hkv, hd), or (hd,) of a headless pool
     m = tables.shape[1]
     n_rep = h // hkv
     scale = scale if scale is not None else hd ** -0.5
     if pad is None:
         pad = jnp.zeros((b,), jnp.int32)
-    bq, tile = prefill_tile_shape(q.shape, pool_k.shape, m)
+    bq, tile = prefill_tile_shape(q.shape, pool_dims(pool_k), m)
     nq, tb = ch // bq, tile // p
     static = dict(scale=scale, block_p=p, tile_blocks=tb, table_blocks=m,
                   block_q=bq, n_rep=n_rep, window=window)
@@ -458,8 +461,8 @@ def paged_prefill_pallas(
         # names, and only those a query tile can see
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = [
-            pltpu.VMEM((2, tb * p, hkv, hd), pool_k.dtype),
-            pltpu.VMEM((2, tb * p, hkv, hd), pool_v.dtype),
+            pltpu.VMEM((2, tb * p, *kv_row), pool_k.dtype),
+            pltpu.VMEM((2, tb * p, *kv_row), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),   # (k | v, buffer half)
             *scratch,
         ]

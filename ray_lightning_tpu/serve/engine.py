@@ -58,6 +58,7 @@ from ray_lightning_tpu.serve.kv_cache import (
     init_pool,
     pool_leaf_shapes,
     pool_partition_spec,
+    state_pool_spec,
     validate_pool_tp,
     window_pool_spec,
     window_ring_table,
@@ -160,6 +161,15 @@ class EngineConfig:
         return self.pool_spec.gathered_len
 
 
+def _pool_spec(model, cfg: EngineConfig) -> PagedPoolSpec:
+    """The configuration's pool with the groups ``model`` declares beside
+    the blocks paged on demand: a ring a slot for sliding-window layers, a
+    row a slot for recurrent ones, each sized from what the engine has."""
+    return state_pool_spec(
+        window_pool_spec(cfg.pool_spec, model.kv_window, cfg.capacity,
+                         cfg.prefill_chunk), model.slot_state, cfg.capacity)
+
+
 def _kth_largest(x, k):
     """The ``k``-th largest element of the row ``x`` ``[V]`` for a
     runtime ``k`` in ``[1, V]``, selected, not sorted: the same value as
@@ -239,7 +249,12 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     with sliding-window layers (`model.kv_window`) has a second group of
     leaves, a ring a slot (`serve/kv_cache.py` "two groups"): the step
     makes that group's table rows from the positions it holds
-    (`window_ring_table`) and hands them to the model in the same views.
+    (`window_ring_table`) and hands them to the model in the same views. A
+    decoder with recurrent layers (`model.slot_state`) has leaves that hold
+    a row a slot (`serve/kv_cache.py` "a row a slot"): the step tells it,
+    from the positions it already holds, which rows of the prefill chunk
+    are real and whose state the decode lane moves, and keeps the K/V of a
+    row that is not real out of the attention group.
 
     ``fused`` selects the decode lane at BUILD time (the dispatch
     decision is static, like a kernel choice — it can never retrace):
@@ -276,8 +291,8 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     """
     mcfg = model.cfg
     window = model.kv_window
-    spec = window_pool_spec(cfg.pool_spec, window, cfg.capacity,
-                            cfg.prefill_chunk)
+    slot_state = model.slot_state
+    spec = _pool_spec(model, cfg)
     C, P, G, CH = cfg.capacity, spec.block_size, spec.gathered_len, \
         cfg.prefill_chunk
     B = cfg.prefill_batch
@@ -392,6 +407,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             ring = dict(window_tables=wtab,
                         window_write_block=_write_index(wtab, pos,
                                                         decoding)[0])
+        if slot_state:
+            # a slot that is idle or being prefilled keeps its state
+            ring["state_moves"] = decoding
         view = PagedDecodeView(tables=tables, lengths=lengths,
                                write_block=bi, write_offset=off,
                                use_pallas=True, **ring)
@@ -495,8 +513,25 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                             prefill_pos + CH - 1)
                         ring = dict(window_tables=wrow,
                                     window_write_block=wrow[:, wpos // P])
+                    tables1, wblock = row[None], row[wpos // P][None]
+                    if slot_state:
+                        # the chunk's REAL rows: from the slot's first
+                        # unsent position (the scheduler may have slid the
+                        # window back over rows it sent before) to the
+                        # prompt's last (zeros follow it). A recurrence
+                        # advances on those alone, and a row sent before
+                        # computes its K/V from a state that has moved on:
+                        # it goes to the scratch block, the first stays
+                        first = jnp.clip(pos[slot] - prefill_pos, 0, CH)
+                        last = jnp.where(prefill_last_row >= 0,
+                                         prefill_last_row, CH - 1)
+                        chunk_rows = jnp.arange(CH)[None]
+                        wblock = jnp.where((chunk_rows >= first)
+                                           & (chunk_rows <= last), wblock, 0)
+                        ring.update(state_slot=slot,
+                                    real_rows=jnp.stack([first, last]))
                     view = PagedPrefillView(
-                        tables=row[None], write_block=row[wpos // P][None],
+                        tables=tables1, write_block=wblock,
                         write_offset=(wpos % P)[None], use_pallas=True,
                         **ring)
                     logits, pool, pf_counts = _paged_apply(
@@ -899,23 +934,37 @@ def serving_param_shardings(model, params, mesh):
         jax.tree_util.tree_structure(params), flat)
 
 
+def why_unsupported(model, feature: str, default: str) -> str:
+    """The decoder's own reason for refusing ``feature``, where its
+    `serving_unsupported` is a mapping that gives one; else ``default``."""
+    no = model.serving_unsupported
+    return no[feature] if isinstance(no, dict) else default
+
+
 def _refusal(model, cfg: EngineConfig, mesh) -> Optional[str]:
     """Why ``model`` cannot be served under ``cfg``/``mesh``, by what it
     declares in `serving_unsupported`; None where it can."""
     name = type(model).__name__
     no = set(model.serving_unsupported)
+    why = lambda feature, default: why_unsupported(model, feature, default)
     if "speculative" in no and cfg.draft is not None:
-        return (f"{name} cannot be a speculative-decoding target: the "
-                "verify chunk rides the dense reference cache path, which "
-                "this decoder does not have (set draft=None)")
+        return (f"{name} cannot be a speculative-decoding target: "
+                + why("speculative",
+                      "the verify chunk rides the dense reference cache "
+                      "path, which this decoder does not have")
+                + " (set draft=None)")
     if "prefill_batch" in no and cfg.prefill_batch > 1:
-        return (f"{name} prefills one slot a tick: its cache path has no "
-                f"left-padded group form (prefill_batch "
-                f"{cfg.prefill_batch}; set prefill_batch=1)")
+        return (f"{name} prefills one slot a tick: "
+                + why("prefill_batch", "its cache path has no left-padded "
+                      "group form")
+                + f" (prefill_batch {cfg.prefill_batch}; set "
+                "prefill_batch=1)")
     if "tensor_parallel" in no and mesh is not None and mesh.size > 1:
-        return (f"{name} has no tensor-parallel replica: it publishes no "
-                "parameter placement and its paged kernels have no manual "
-                "region (serve it with mesh=None, one chip a replica)")
+        return (f"{name} has no tensor-parallel replica: "
+                + why("tensor_parallel",
+                      "it publishes no parameter placement and its paged "
+                      "kernels have no manual region")
+                + " (serve it with mesh=None, one chip a replica)")
     return None
 
 
@@ -953,8 +1002,7 @@ class DecodeEngine:
         # reference lane, the bitwise anchor against generate().
         #: a decoder with sliding-window layers gets its second group
         #: here: one ring a slot, sized from what the engine already has
-        spec = window_pool_spec(cfg.pool_spec, model.kv_window,
-                                cfg.capacity, cfg.prefill_chunk)
+        spec = _pool_spec(model, cfg)
         refused = _refusal(model, cfg, mesh)
         if refused:
             raise ValueError(refused)
@@ -1338,6 +1386,14 @@ class DecodeEngine:
           decode_tiles_window, prefill_tiles_window
                                the live tiles behind a raised floor
                                (`decode_live_tiles`, `prefill_live_tiles`)
+
+        For a decoder with recurrent layers (`model.slot_state`), what ONE
+        STATE-SPACE LAYER is asked to do:
+
+          scan_rows     rows of the chunk its scan advances on: the real
+                        ones, ``prefill_rows`` less those the scheduler
+                        sent before (a window slid back at a slot's end)
+          state_slots   slots whose state the decode lane moves by a row
         """
         dec = np.asarray(decoding)
         if self.cfg.prefill_batch == 1:
@@ -1367,6 +1423,14 @@ class DecodeEngine:
             work["prefill_ctx_window"] = int(
                 (np.minimum(np.maximum(start - pads, 0), window - 1)
                  * active).sum())
+        if self.model.slot_state:
+            # the chunk's real rows as the step reckons them: from the
+            # slot's first unsent position to the prompt's last
+            sent = (int(np.asarray(pos)[int(pslot)]) - start
+                    if active.any() else 0)
+            work["scan_rows"] = int(max(cols - max(sent, 0), 0)
+                                    * active.sum())
+            work["state_slots"] = work["decode_slots"]
         if self._decode_tile:
             work["decode_tiles"] = int(
                 np.ceil(lengths / self._decode_tile).sum())

@@ -40,6 +40,22 @@ taken or returned, and admission, growth and preemption keep one
 allocator to ask. Its table is arithmetic (`window_ring_table`), made
 inside the step from the positions it already has; an entry behind the
 window names scratch block 0.
+
+**A row a slot.** A decoder with recurrent layers (`model.slot_state`)
+keeps for each of them a state that does not grow with the context: its
+leaves have a SLOT axis where the others have blocks, ``spec.state_slots``
+long (the engine's capacity, `state_pool_spec`), the decoder says what a
+row holds and in which type (`cfg.pool_leaf_shapes(n_blocks, P,
+state_slots=...)`; a leaf that is not in the activations' type is a
+`jax.ShapeDtypeStruct`). Slot ``c`` owns row ``c`` for good: nothing is
+taken, returned, grown or preempted, there is no table and no scratch row.
+What a row-a-token pool gets for free it does not: a K/V row written twice
+is the same row, a state advanced twice is not. So the step tells the
+decoder which rows of a prefill chunk are real (`PagedPrefillView.
+real_rows`) and which slots the decode lane moves (`PagedDecodeView.
+state_moves`), a chunk whose first real row is position 0 starts from
+zeros, and a row the scheduler sends a second time keeps its first K/V
+(docs/SERVING.md "three kinds of cached state").
 """
 from __future__ import annotations
 
@@ -69,6 +85,10 @@ class PagedPoolSpec:
     #: Set by the engine (`window_pool_spec`), never by a configuration.
     window_ring: int = 0
     window_slots: int = 0
+    #: rows of the leaves that hold a row a slot (module text, "a row a
+    #: slot"); 0 for a decoder without. Set by the engine
+    #: (`state_pool_spec`), never by a configuration.
+    state_slots: int = 0
 
     def __post_init__(self):
         if self.block_size < 1 or self.blocks_per_slot < 1:
@@ -144,13 +164,33 @@ def window_ring_table(spec: PagedPoolSpec, slots, first, last):
     return jnp.where(live, 1 + slots * ring + b % ring, 0)
 
 
+def state_pool_spec(spec: PagedPoolSpec, slot_state: bool,
+                    capacity: int) -> PagedPoolSpec:
+    """``spec`` with a row for each of ``capacity`` slots where the decoder
+    keeps a state a slot (`model.slot_state`); unchanged where not."""
+    if not slot_state:
+        return spec
+    return dataclasses.replace(spec, state_slots=capacity)
+
+
 def pool_leaf_shapes(cfg, spec: PagedPoolSpec):
     """The leaves ``cfg`` declares for ``spec``: a decoder with a window
-    group is told that group's blocks too."""
+    group is told that group's blocks too, one that keeps a row a slot how
+    many slots."""
+    if spec.state_slots:
+        return cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size,
+                                    state_slots=spec.state_slots)
     if spec.window_ring:
         return cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size,
                                     spec.window_blocks)
     return cfg.pool_leaf_shapes(spec.n_blocks, spec.block_size)
+
+
+def _typed(cfg, leaf):
+    """(shape, dtype) of a declared leaf: a plain shape is in the model's
+    activation dtype, a `jax.ShapeDtypeStruct` says its own."""
+    return (tuple(getattr(leaf, "shape", leaf)),
+            jnp.dtype(getattr(leaf, "dtype", cfg.dtype)))
 
 
 def init_pool(cfg, spec: PagedPoolSpec):
@@ -162,9 +202,11 @@ def init_pool(cfg, spec: PagedPoolSpec):
     block-chunked over the sequence axis; a latent-attention decoder
     one, ``[n_layers, n_blocks, block_size, row]``; a decoder with
     sliding-window layers four, its full layers' K and V and its window
-    group's (module text, "two groups")."""
-    return tuple(jnp.zeros(shape, cfg.dtype)
-                 for shape in pool_leaf_shapes(cfg, spec))
+    group's (module text, "two groups"); a decoder with recurrent layers
+    its attention layers' K and V and the leaves that hold a row a slot,
+    each in the type it declares."""
+    return tuple(jnp.zeros(*_typed(cfg, leaf))
+                 for leaf in pool_leaf_shapes(cfg, spec))
 
 
 def validate_pool_tp(cfg, tp: int) -> None:
@@ -206,8 +248,8 @@ def pool_bytes(cfg, spec: PagedPoolSpec) -> int:
     """HBM held by the pool itself (every leaf the model declares)."""
     import math
 
-    return sum(math.prod(shape) for shape in pool_leaf_shapes(
-        cfg, spec)) * jnp.dtype(cfg.dtype).itemsize
+    return sum(math.prod(shape) * dtype.itemsize for shape, dtype in (
+        _typed(cfg, leaf) for leaf in pool_leaf_shapes(cfg, spec)))
 
 
 def gathered_view_bytes(cfg, spec: PagedPoolSpec, capacity: int) -> int:

@@ -20,7 +20,9 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ray_lightning_tpu.serve.engine import DecodeEngine, idle_prefill
+from ray_lightning_tpu.serve.engine import (
+    DecodeEngine, idle_prefill, why_unsupported,
+)
 from ray_lightning_tpu.serve.kv_cache import (
     BlockAllocator,
     PrefixCache,
@@ -308,10 +310,12 @@ class Scheduler:
                 engine.model.serving_unsupported:
             raise ValueError(
                 f"{type(engine.model).__name__} cannot share prompt "
-                "prefixes: its sliding-window layers keep their K/V in a "
-                "ring a slot that is overwritten as the context moves on, "
-                "so no block of that group outlives its request (set "
-                "prefix_cache=False)")
+                "prefixes: " + why_unsupported(
+                    engine.model, "prefix_cache",
+                    "its sliding-window layers keep their K/V in a ring a "
+                    "slot that is overwritten as the context moves on, so "
+                    "no block of that group outlives its request")
+                + " (set prefix_cache=False)")
         if prefix_cache and engine.mesh is not None:
             raise ValueError(
                 "prefix_cache=True requires an unsharded replica "
@@ -884,8 +888,14 @@ class Scheduler:
         groups"), the blocks each group holds for the slotted requests:
         ``full_blocks_live`` the allocator's, which is what one table for
         all layers would hold a layer; ``window_blocks_live`` those of
-        them a slot's ring has room for, ``min(blocks, ring)`` a slot.
-        Empty for an engine with one group."""
+        them a slot's ring has room for, ``min(blocks, ring)`` a slot. For
+        an engine whose decoder keeps a row a slot (`serve/kv_cache.py` "a
+        row a slot"), ``state_slots_live``. Empty for an engine with one
+        group."""
+        if self.spec.state_slots:
+            # an engine whose decoder keeps a row a slot: the slots that
+            # hold a request's state
+            return {"state_slots_live": len(self.slots)}
         ring = self.spec.window_ring
         if not ring:
             return {}
