@@ -871,6 +871,102 @@ def idle_prefill(cfg: EngineConfig):
             np.int32(0), np.int32(-1), np.zeros(B, np.int32))
 
 
+def tick_fields(cfg: EngineConfig):
+    """The tick's runtime inputs as ``(name, shape, dtype)``, in the order
+    the step takes them after ``last_logits``: the layout of the ONE
+    ``int32`` vector the host hands the device a tick (docs/SERVING.md "What
+    crosses to the chip in a tick"). Every element is one word: ``temp`` and
+    ``rngs`` cross by their bits, ``decoding`` as 0/1."""
+    C, CH, B = cfg.capacity, cfg.prefill_chunk, cfg.prefill_batch
+    i32 = np.int32
+    fields = [("tables", (C, cfg.blocks_per_slot), i32), ("pos", (C,), i32),
+              ("decoding", (C,), np.bool_), ("temp", (C,), np.float32),
+              ("top_k", (C,), i32), ("rngs", (C, 2), np.uint32)]
+    if B == 1:
+        fields += [("prefill_slot", (), i32), ("prefill_tokens", (CH,), i32)]
+    else:
+        fields += [("slot_pad", (C,), i32), ("prefill_slots", (B,), i32),
+                   ("prefill_tokens", (B, CH), i32)]
+    fields += [("prefill_pos", (), i32), ("prefill_last_row", (), i32)]
+    if B > 1:
+        fields.append(("prefill_pad", (B,), i32))
+    return tuple(fields)
+
+
+def result_fields(cfg: EngineConfig, n_counters: int):
+    """What the step returns after ``last_logits``, as `tick_fields` has the
+    inputs: the layout of the ONE ``int32`` vector the host reads a tick."""
+    C = cfg.capacity
+    fields = [("rngs", (C, 2), np.uint32)]
+    if cfg.draft is not None:
+        fields += [("toks", (C, cfg.draft.k), np.int32),
+                   ("n_emit", (C,), np.int32)]
+    else:
+        fields.append(("emitted", (C,), np.int32))
+        if n_counters:
+            fields.append(("counts", (n_counters,), np.int32))
+    return tuple(fields)
+
+
+def _words(fields) -> int:
+    return sum(int(np.prod(shape)) for _, shape, _ in fields)
+
+
+def pack_words(fields, values) -> np.ndarray:
+    """``values`` (host arrays, one a field) as one ``int32`` vector: a
+    4-byte dtype by its bits, a bool as 0/1."""
+    out = np.empty(_words(fields), np.int32)
+    at = 0
+    for (name, shape, dtype), value in zip(fields, values):
+        value = np.asarray(value, dtype)
+        if value.shape != shape:
+            raise ValueError(f"tick input {name}: shape {value.shape}, "
+                             f"the step was built for {shape}")
+        if dtype is not np.bool_:
+            value = value.view(np.int32)
+        out[at:at + value.size] = value.reshape(-1)
+        at += value.size
+    return out
+
+
+def unpack_words(fields, words):
+    """The fields of one ``int32`` vector, a host array's (numpy: views of
+    it) or a traced one's (inside the step), bit for bit."""
+    on_host = isinstance(words, np.ndarray)
+    out, at = [], 0
+    for _, shape, dtype in fields:
+        n = int(np.prod(shape))
+        value = words[at:at + n].reshape(shape)
+        at += n
+        if dtype is np.bool_:
+            value = value != 0
+        elif dtype is not np.int32:
+            value = value.view(dtype) if on_host else \
+                jax.lax.bitcast_convert_type(value, dtype)
+        out.append(value)
+    return out
+
+
+def _packed(inner, n_carried: int, fields):
+    """``inner`` (what `build_step` / `build_spec_step` returns, called
+    unchanged) behind the tick's protocol: ``step(*resident, words)`` takes
+    the device-resident arguments (parameters, pool leaves, ``last_logits``)
+    and the one packed vector of `tick_fields`, and returns the
+    ``n_carried`` donated buffers (pool leaves, ``last_logits``) followed by
+    the rest as one packed vector of `result_fields`. Slices, reshapes and
+    bitcasts of a few kilobytes either side of the same program."""
+
+    def step(*args):
+        *resident, words = args
+        out = inner(*resident, *unpack_words(fields, words))
+        return (*out[:n_carried], jnp.concatenate([
+            (x if x.dtype == jnp.int32 else
+             jax.lax.bitcast_convert_type(x, jnp.int32)).reshape(-1)
+            for x in out[n_carried:]]))
+
+    return step
+
+
 def _global_put(x, sharding):
     """Place a host array as a GLOBAL jax array under ``sharding`` —
     single- or multi-process alike. Every process holds the full value
@@ -1083,6 +1179,13 @@ class DecodeEngine:
         #: with the tick's tokens into `last_counters`
         self._counter_names = tuple(n for n, _ in model.tick_counters)
         self.last_counters: dict = {}
+        #: the tick's protocol: one packed ``int32`` vector in, one out
+        #: (`tick_fields`, `result_fields`), their layout a function of
+        #: shapes settled here
+        self._in_fields = tick_fields(cfg)
+        self._h2d_bytes = 4 * _words(self._in_fields)
+        self._out_fields = result_fields(cfg, len(self._counter_names))
+        n_carried = n_pool + 1 if cfg.draft is None else 5
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1094,13 +1197,14 @@ class DecodeEngine:
             self.params = jax.tree_util.tree_map(_global_put, params,
                                                  param_sh)
             # out shardings pin the donated-buffer cycle: pool in/out
-            # identical (donation holds), logits + rngs + emitted
+            # identical (donation holds), logits + the packed result
             # replicated so every rank reads the same host values
             self._step = jax.jit(
-                build_step(model, cfg, fused=self.fused,
-                           fused_prefill=self.fused_prefill),
+                _packed(build_step(model, cfg, fused=self.fused,
+                                   fused_prefill=self.fused_prefill),
+                        n_carried, self._in_fields),
                 donate_argnums=tuple(range(1, n_pool + 2)),
-                out_shardings=(pool_sh,) * n_pool + (self._repl_sh,) * 3)
+                out_shardings=(pool_sh,) * n_pool + (self._repl_sh,) * 2)
             self.pool = tuple(_global_put(leaf, pool_sh)
                               for leaf in init_pool(model.cfg, self.spec))
             self.last_logits = _global_put(
@@ -1125,12 +1229,14 @@ class DecodeEngine:
                 # donated: both pools + last_logits (positions 2-6 of
                 # the spec signature — params/draft params stay)
                 self._step = jax.jit(
-                    build_spec_step(model, draft_model, cfg),
+                    _packed(build_spec_step(model, draft_model, cfg),
+                            n_carried, self._in_fields),
                     donate_argnums=(2, 3, 4, 5, 6))
             else:
                 self._step = jax.jit(
-                    build_step(model, cfg, fused=self.fused,
-                               fused_prefill=self.fused_prefill),
+                    _packed(build_step(model, cfg, fused=self.fused,
+                                       fused_prefill=self.fused_prefill),
+                            n_carried, self._in_fields),
                     donate_argnums=tuple(range(1, n_pool + 2)))
             # COMMIT the device-resident buffers to the same device as
             # the weights: a fresh jnp.zeros is uncommitted, but the
@@ -1204,13 +1310,10 @@ class DecodeEngine:
         except Exception:  # noqa: BLE001 — introspection is advisory
             return -1
 
-    def warmup(self) -> None:
-        """Compile (or deserialize, when a persistent compile cache is
-        armed — `pipeline.compile_cache`) the step before the replica
-        is marked live: an idle tick on the zero pool. P99 TTFT is a
-        compile-cache metric (ROADMAP item 1)."""
+    def idle_inputs(self) -> dict:
+        """`tick`'s arguments with no slot live and no chunk."""
         C = self.cfg.capacity
-        self.tick(
+        return dict(
             tables=np.zeros((C, self.spec.blocks_per_slot), np.int32),
             pos=np.zeros(C, np.int32),
             decoding=np.zeros(C, bool),
@@ -1220,6 +1323,20 @@ class DecodeEngine:
             prefill=idle_prefill(self.cfg),
             pad=np.zeros(C, np.int32),
         )
+
+    def lower_idle(self):
+        """The step lowered on an idle tick's inputs, as `tick` calls it:
+        what a test or an audit reads the served program from
+        (``.as_text()``, ``.compile()``)."""
+        return self._step.lower(*self._resident(),
+                                self._pack(**self.idle_inputs()))
+
+    def warmup(self) -> None:
+        """Compile (or deserialize, when a persistent compile cache is
+        armed — `pipeline.compile_cache`) the step before the replica
+        is marked live: an idle tick on the zero pool. P99 TTFT is a
+        compile-cache metric (ROADMAP item 1)."""
+        self.tick(**self.idle_inputs())
 
     # ---- copy-on-write fork ----------------------------------------------
 
@@ -1238,6 +1355,37 @@ class DecodeEngine:
 
     # ---- the tick --------------------------------------------------------
 
+    def _resident(self) -> tuple:
+        """The step's device-resident arguments, in its order."""
+        if self.cfg.draft is not None:
+            return (self.params, self.draft_params, *self.pool,
+                    self.dpool_k, self.dpool_v, self.last_logits)
+        return (self.params, *self.pool, self.last_logits)
+
+    def _pack(self, tables, pos, decoding, temp, top_k, rngs, prefill,
+              pad=None) -> np.ndarray:
+        """`tick`'s arguments as the one vector of `tick_fields`."""
+        lead = (tables, pos, decoding, temp, top_k, rngs)
+        if self.cfg.prefill_batch > 1:
+            lead += (np.zeros(self.cfg.capacity, np.int32)
+                     if pad is None else pad,)
+        return pack_words(self._in_fields, (*lead, *prefill))
+
+    def _put(self, words: np.ndarray):
+        """The tick's ONE host-to-device placement."""
+        if self.mesh is None:
+            return jax.device_put(words, self.device)
+        # replicated over the replica's own mesh: each rank computed the
+        # SAME host values (lockstep scheduler), so assembling the global
+        # view is pure placement, no wire traffic
+        return _global_put(words, self._repl_sh)
+
+    def _fetch(self, words) -> list:
+        """The tick's ONE device-to-host read (its copy was queued behind
+        the step at dispatch): the fields of `result_fields`, host arrays
+        the caller owns."""
+        return unpack_words(self._out_fields, np.array(words))
+
     def tick(self, tables, pos, decoding, temp, top_k, rngs, prefill,
              pad=None):
         """Run one step; returns ``(toks [C, W] i32 np, n_emit [C] i32
@@ -1249,101 +1397,67 @@ class DecodeEngine:
         internally. ``pad`` ([C] i32 per-slot left pad) exists only on
         the batched-prefill program (prefill_batch > 1) and is ignored
         otherwise — the single-slot program is the historical one, with
-        no pad inputs."""
-        if self.mesh is None:
-            def put(x):
-                return jax.device_put(x, self.device)
-        else:
-            # every runtime input is replicated over the replica's own
-            # mesh: each rank computed the SAME host values (lockstep
-            # scheduler), so assembling the global view is pure
-            # placement, no wire traffic
-            def put(x):
-                return _global_put(x, self._repl_sh)
+        no pad inputs.
+
+        The host crosses to the device once each way (docs/SERVING.md
+        "What crosses to the chip in a tick"): the arguments go as one
+        packed vector, and the step's small results come back as one,
+        whose copy to the host is queued right behind the step."""
         spec_mode = self.cfg.draft is not None
-        with annotate("serve.put"):
-            if spec_mode:
-                common = (
-                    self.params, self.draft_params, *self.pool,
-                    self.dpool_k, self.dpool_v,
-                    self.last_logits,
-                    put(tables), put(pos), put(decoding),
-                    put(temp), put(top_k), put(rngs))
-            else:
-                common = (
-                    self.params, *self.pool, self.last_logits,
-                    put(tables), put(pos), put(decoding),
-                    put(temp), put(top_k), put(rngs))
-            if self.cfg.prefill_batch == 1:
-                pslot, ptoks, ppos, plast = prefill
-                args = common + (put(pslot), put(ptoks),
-                                 put(ppos), put(plast))
-            else:
-                if pad is None:
-                    pad = np.zeros(self.cfg.capacity, np.int32)
-                pslot, ptoks, ppos, plast, ppad = prefill
-                args = common + (put(pad), put(pslot),
-                                 put(ptoks), put(ppos),
-                                 put(plast), put(ppad))
+        with annotate("serve.put", h2d_arrays=1, h2d_bytes=self._h2d_bytes):
+            words = self._put(self._pack(tables, pos, decoding, temp,
+                                         top_k, rngs, prefill, pad))
         with annotate("serve.dispatch",
                       **self._step_work(pos, decoding, prefill, temp,
                                         top_k)):
-            out = self._step(*args)
-        counts = ()
-        if spec_mode:
-            (*pool, self.dpool_k, self.dpool_v,
-             self.last_logits, new_rngs, toks, n_emit) = out
-            self.pool = tuple(pool)
-            with annotate("serve.fetch"):
-                toks = np.array(toks)
-                n_emit = np.array(n_emit)
-        else:
-            n_pool = len(self.pool)
-            self.pool = tuple(out[:n_pool])
-            self.last_logits, new_rngs, emitted = out[n_pool:n_pool + 3]
-            counts = out[n_pool + 3:]
-        self.steps += 1
-        m = self.metrics
-        if m.enabled:
-            # counted from the HOST-OWNED inputs this call received —
-            # the device outputs above stay un-inspected on the base
-            # step, so metrics adds zero host syncs (the spec step's
-            # n_emit is already a host-fetched output the scheduler
-            # needs anyway). prefill_tokens counts chunk positions
-            # advanced (incl. pad columns on the batched lane);
-            # decode_tokens counts tokens emitted.
-            n_dec = int(n_emit.sum()) if spec_mode else \
-                int(np.sum(np.asarray(decoding)))
-            if self.cfg.prefill_batch == 1:
-                n_pf_rows = 1 if int(prefill[0]) >= 0 else 0
-            else:
-                n_pf_rows = int(np.sum(np.asarray(prefill[0]) >= 0))
-            if n_dec:
-                m.count("decode_tokens", n_dec)
-            if n_pf_rows:
-                m.count("prefill_tokens",
-                        n_pf_rows * self.cfg.prefill_chunk)
-            m.gauge("engine_steps", self.steps)
-            m.gauge("compile_count", self.compile_count)
-        with annotate("serve.fetch"):
-            if spec_mode:
-                return toks, n_emit, np.array(new_rngs)
+            *carried, result = self._step(*self._resident(), words)
             if self.mesh is not None:
-                # replicated outputs: any addressable shard IS the global
-                # value — np.array on a multi-process global array would
-                # raise (non-addressable devices)
-                emitted = np.array(emitted.addressable_data(0))
-                new_rngs = np.array(new_rngs.addressable_data(0))
-            else:
-                emitted = np.array(emitted)
-                new_rngs = np.array(new_rngs)
-            if counts:
-                # the model's device-side counts ride the same fetch
-                self.last_counters = dict(zip(
-                    self._counter_names,
-                    (int(v) for v in np.array(counts[0]))))
+                # replicated: any addressable shard IS the global value
+                # (np.array on a multi-process global array would raise)
+                result = result.addressable_data(0)
+            result.copy_to_host_async()
+        if spec_mode:
+            (*pool, self.dpool_k, self.dpool_v, self.last_logits) = carried
+        else:
+            *pool, self.last_logits = carried
+        self.pool = tuple(pool)
+        self.steps += 1
+        if spec_mode:
+            with annotate("serve.fetch", d2h_arrays=1):
+                new_rngs, toks, n_emit = self._fetch(result)
+            self._record(int(n_emit.sum()), prefill)
+            return toks, n_emit, new_rngs
+        # counted from the host-owned inputs while the step runs: the
+        # read below is where the host waits for it
+        self._record(int(np.sum(np.asarray(decoding))), prefill)
+        with annotate("serve.fetch", d2h_arrays=1):
+            new_rngs, emitted, *counts = self._fetch(result)
+        if counts:
+            # the model's device-side counts ride the same read
+            self.last_counters = dict(zip(
+                self._counter_names, (int(v) for v in counts[0])))
         return (emitted[:, None],
                 np.asarray(decoding).astype(np.int32), new_rngs)
+
+    def _record(self, n_dec: int, prefill) -> None:
+        """A tick's live metrics: ``n_dec`` tokens emitted, and the chunk
+        positions advanced (pad columns on the batched lane included), both
+        from host values the tick holds anyway, so metrics adds no host
+        sync (the speculative step's ``n_emit`` is a result the scheduler
+        needs)."""
+        m = self.metrics
+        if not m.enabled:
+            return
+        if self.cfg.prefill_batch == 1:
+            n_pf_rows = 1 if int(prefill[0]) >= 0 else 0
+        else:
+            n_pf_rows = int(np.sum(np.asarray(prefill[0]) >= 0))
+        if n_dec:
+            m.count("decode_tokens", n_dec)
+        if n_pf_rows:
+            m.count("prefill_tokens", n_pf_rows * self.cfg.prefill_chunk)
+        m.gauge("engine_steps", self.steps)
+        m.gauge("compile_count", self.compile_count)
 
     def _step_work(self, pos, decoding, prefill, temp, top_k) -> dict:
         """What this step's attention and sampling are asked to do, as

@@ -278,6 +278,19 @@ def validate_request(cfg, spec, req: Request) -> None:
 
 
 def _key_data(seed: int) -> np.ndarray:
+    """``jax.random.key_data(jax.random.key(seed))``, the two words a slot's
+    RNG starts from, made on the host: an admission launches nothing on the
+    device. Under the default ``threefry2x32`` a key IS its seed's high and
+    low 32 bits (the high word 0 where jax holds the seed in 32 bits, x64
+    off), test-pinned against jax's own; any other implementation, a
+    configured seed offset or a seed that is no python int of 64 bits takes
+    jax's path."""
+    cfg = jax.config
+    if (cfg.jax_default_prng_impl == "threefry2x32"
+            and not cfg.jax_random_seed_offset
+            and isinstance(seed, int) and -2 ** 63 <= seed < 2 ** 63):
+        high = seed >> 32 if cfg.jax_enable_x64 else 0
+        return np.array([high & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
     return np.array(jax.random.key_data(jax.random.key(seed)),
                     np.uint32)
 

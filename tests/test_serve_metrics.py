@@ -315,22 +315,7 @@ def test_metrics_off_is_byte_identical_program(tiny):
                         prefill_chunk=4)
 
     def lowered_text(engine):
-        C = ecfg.capacity
-        spec = ecfg.pool_spec
-        from ray_lightning_tpu.serve.engine import idle_prefill
-
-        pslot, ptoks, ppos, plast = idle_prefill(ecfg)
-        return engine._step.lower(
-            engine.params, engine.pool_k, engine.pool_v,
-            engine.last_logits,
-            jnp.asarray(np.zeros((C, spec.blocks_per_slot), np.int32)),
-            jnp.asarray(np.zeros(C, np.int32)),
-            jnp.asarray(np.zeros(C, bool)),
-            jnp.asarray(np.zeros(C, np.float32)),
-            jnp.asarray(np.zeros(C, np.int32)),
-            jnp.asarray(np.zeros((C, 2), np.uint32)),
-            jnp.asarray(pslot), jnp.asarray(ptoks), jnp.asarray(ppos),
-            jnp.asarray(plast)).as_text()
+        return engine.lower_idle().as_text()
 
     eng_off = DecodeEngine(model, params, ecfg)
     eng_on = DecodeEngine(model, params, ecfg,

@@ -229,21 +229,18 @@ def traced(tmp_path_factory):
     batch = warm._place_train_batch({"tokens": tokens[:2]})[1]
     train_text = warm._train_step._jitted.lower(
         warm.state, batch, warm._base_rng).as_text(debug_info=True)
-    C, spec = ecfg.capacity, ecfg.pool_spec
-    serve_text = engine._step.lower(
-        engine.params, engine.pool_k, engine.pool_v, engine.last_logits,
-        jnp.zeros((C, spec.blocks_per_slot), jnp.int32),
-        jnp.zeros(C, jnp.int32), jnp.zeros(C, bool),
-        jnp.zeros(C, jnp.float32), jnp.zeros(C, jnp.int32),
-        jnp.zeros((C, 2), jnp.uint32),
-        *map(jnp.asarray, idle_prefill(ecfg))).as_text(debug_info=True)
+    serve_text = engine.lower_idle().as_text(debug_info=True)
     assert (engine.attention_path, engine.prefill_path) == (
         "paged-pallas", "paged-pallas")
     driver.stop(drain=False)
     return {"off": _host_events(str(root / "off")),
             "on": _host_events(str(root / "on")), "ring": ring,
             "expected": expected, "tick_ids": tick_ids,
-            "train_text": train_text, "serve_text": serve_text}
+            "train_text": train_text, "serve_text": serve_text,
+            # tables, four words a slot and its two RNG words, the chunk,
+            # and three scalars, four bytes each
+            "h2d_bytes": 4 * (ecfg.capacity * (ecfg.blocks_per_slot + 6)
+                              + ecfg.prefill_chunk + 3)}
 
 
 # ---- (b) scopes -------------------------------------------------------------
@@ -321,6 +318,16 @@ def test_dispatch_counters_equal_the_schedulers_own(traced, counter):
     # the three ticks hold decode-only work, a whole chunk behind cached
     # context and a partial last chunk, so each counter is exercised
     assert any(want), (counter, want)
+
+
+@pytest.mark.parametrize("phase,counter", [
+    ("put", "h2d_arrays"), ("put", "h2d_bytes"), ("fetch", "d2h_arrays")])
+def test_a_tick_crosses_to_the_device_once_each_way(traced, phase, counter):
+    """`rlt.serve.put` and `.fetch` say what crossed: one packed array
+    each way, of the size the engine's layout gives."""
+    got = [e["stats"][counter]
+           for e in _named(traced["off"], "rlt.serve." + phase)]
+    assert got == [traced["h2d_bytes"] if counter == "h2d_bytes" else 1] * 3
 
 
 @pytest.mark.parametrize("phase,thread_of,count", [

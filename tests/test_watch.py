@@ -378,32 +378,16 @@ def test_watch_off_program_pin(tmp_path, tiny_serve):
     """The acceptance pin: watch on vs off is a byte-identical lowered
     decode program and ONE compile — the watch layer reads files, it
     never touches the engine (same discipline as telemetry=off)."""
-    import jax.numpy as jnp
-    import numpy as np
-
     from ray_lightning_tpu.serve.driver import (
         ReplicaGroupConfig,
         ServeDriver,
     )
-    from ray_lightning_tpu.serve.engine import DecodeEngine, idle_prefill
+    from ray_lightning_tpu.serve.engine import DecodeEngine
 
     cfg, model, params, prompts, reqs, ecfg = tiny_serve
 
     def lowered_text(engine):
-        C = ecfg.capacity
-        spec = ecfg.pool_spec
-        pslot, ptoks, ppos, plast = idle_prefill(ecfg)
-        return engine._step.lower(
-            engine.params, engine.pool_k, engine.pool_v,
-            engine.last_logits,
-            jnp.asarray(np.zeros((C, spec.blocks_per_slot), np.int32)),
-            jnp.asarray(np.zeros(C, np.int32)),
-            jnp.asarray(np.zeros(C, bool)),
-            jnp.asarray(np.zeros(C, np.float32)),
-            jnp.asarray(np.zeros(C, np.int32)),
-            jnp.asarray(np.zeros((C, 2), np.uint32)),
-            jnp.asarray(pslot), jnp.asarray(ptoks), jnp.asarray(ppos),
-            jnp.asarray(plast)).as_text()
+        return engine.lower_idle().as_text()
 
     baseline = DecodeEngine(model, params, ecfg)
     run = str(tmp_path)

@@ -360,17 +360,7 @@ def test_the_decoder_itself_refuses_a_dense_cache_a_pad_and_a_bare_view(
 @pytest.fixture(scope="module")
 def step_text(engine):
     """The engine's step lowered with debug info: every op's name stack."""
-    from ray_lightning_tpu.serve.engine import idle_prefill
-
-    ecfg, spec = engine.cfg, engine.spec
-    c = ecfg.capacity
-    return engine._step.lower(
-        engine.params, *engine.pool, engine.last_logits,
-        jnp.zeros((c, spec.blocks_per_slot), jnp.int32),
-        jnp.zeros(c, jnp.int32), jnp.zeros(c, bool),
-        jnp.zeros(c, jnp.float32), jnp.zeros(c, jnp.int32),
-        jnp.zeros((c, 2), jnp.uint32),
-        *map(jnp.asarray, idle_prefill(ecfg))).as_text(debug_info=True)
+    return engine.lower_idle().as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("scope", [
