@@ -1444,7 +1444,7 @@ class ServeDriver:
                 # growth-stall preemptions land back in its queue;
                 # admissions are closed there, so move them out
                 self._requeue_from(rep)
-                if not rep.sched.slots and not rep.sched.queue:
+                if not rep.sched.busy():
                     self._stop_replica(rep)
                     continue
             completions = rep.sched.tick()
@@ -1556,6 +1556,8 @@ class ServeDriver:
             # enqueue-time sheds on an otherwise-idle session never saw
             # a tick — surface them before the scheduler closes
             self._drain_sheds(rep.id, rep.sched)
+            # stopping cold: a step still in flight is read and dropped
+            rep.sched.drop_inflight()
             _record_drain(rep.recorder, rep.sched, rep.id)
             self._stop_replica(rep)
         wall = time.perf_counter() - self._session_t0

@@ -876,12 +876,16 @@ def tick_fields(cfg: EngineConfig):
     the step takes them after ``last_logits``: the layout of the ONE
     ``int32`` vector the host hands the device a tick (docs/SERVING.md "What
     crosses to the chip in a tick"). Every element is one word: ``temp`` and
-    ``rngs`` cross by their bits, ``decoding`` as 0/1."""
+    ``rngs`` cross by their bits, ``decoding`` and ``fresh`` as 0/1.
+    ``fresh`` is the wrapper's (`_packed`), not the step's: a slot's key is
+    the host's ``rngs`` row where it is set and the one the device carries
+    where it is not."""
     C, CH, B = cfg.capacity, cfg.prefill_chunk, cfg.prefill_batch
     i32 = np.int32
     fields = [("tables", (C, cfg.blocks_per_slot), i32), ("pos", (C,), i32),
               ("decoding", (C,), np.bool_), ("temp", (C,), np.float32),
-              ("top_k", (C,), i32), ("rngs", (C, 2), np.uint32)]
+              ("top_k", (C,), i32), ("rngs", (C, 2), np.uint32),
+              ("fresh", (C,), np.bool_)]
     if B == 1:
         fields += [("prefill_slot", (), i32), ("prefill_tokens", (CH,), i32)]
     else:
@@ -949,17 +953,25 @@ def unpack_words(fields, words):
 
 def _packed(inner, n_carried: int, fields):
     """``inner`` (what `build_step` / `build_spec_step` returns, called
-    unchanged) behind the tick's protocol: ``step(*resident, words)`` takes
-    the device-resident arguments (parameters, pool leaves, ``last_logits``)
-    and the one packed vector of `tick_fields`, and returns the
-    ``n_carried`` donated buffers (pool leaves, ``last_logits``) followed by
-    the rest as one packed vector of `result_fields`. Slices, reshapes and
-    bitcasts of a few kilobytes either side of the same program."""
+    unchanged) behind the tick's protocol: ``step(*resident, rngs, words)``
+    takes the device-resident arguments (parameters, pool leaves,
+    ``last_logits``), the per-slot keys the device carries ``[C, 2]
+    uint32`` and the one packed vector of `tick_fields`, and returns the
+    ``n_carried`` donated buffers (pool leaves, ``last_logits``), the keys
+    the step advanced, and the rest as one packed vector of `result_fields`.
+    A slot the host marks ``fresh`` (admitted this tick) starts from the
+    host's key; every other slot's key never leaves the device. Slices,
+    reshapes, bitcasts and one `where` of a few kilobytes either side of
+    the same program."""
+    names = [name for name, _, _ in fields]
 
     def step(*args):
-        *resident, words = args
-        out = inner(*resident, *unpack_words(fields, words))
-        return (*out[:n_carried], jnp.concatenate([
+        *resident, rngs, words = args
+        values = dict(zip(names, unpack_words(fields, words)))
+        fresh = values.pop("fresh")
+        values["rngs"] = jnp.where(fresh[:, None], values["rngs"], rngs)
+        out = inner(*resident, *values.values())
+        return (*out[:n_carried], out[n_carried], jnp.concatenate([
             (x if x.dtype == jnp.int32 else
              jax.lax.bitcast_convert_type(x, jnp.int32)).reshape(-1)
             for x in out[n_carried:]]))
@@ -1068,9 +1080,10 @@ class DecodeEngine:
     """One replica's compiled step + its device-resident buffers.
 
     Owns ``pool`` (the leaves the model declares: `pool_k`/`pool_v`
-    name a K/V pair's) and ``last_logits`` (donated through every step
-    — callers must never hold references to them) and the compile-count
-    pin. The host-side request state lives in `serve.scheduler`.
+    name a K/V pair's), ``last_logits`` and ``rngs`` (the slots' keys,
+    ``[C, 2] uint32``; all donated through every step — callers must
+    never hold references to them) and the compile-count pin. The
+    host-side request state lives in `serve.scheduler`.
     """
 
     def __init__(self, model, params, cfg: EngineConfig,
@@ -1203,13 +1216,15 @@ class DecodeEngine:
                 _packed(build_step(model, cfg, fused=self.fused,
                                    fused_prefill=self.fused_prefill),
                         n_carried, self._in_fields),
-                donate_argnums=tuple(range(1, n_pool + 2)),
-                out_shardings=(pool_sh,) * n_pool + (self._repl_sh,) * 2)
+                donate_argnums=tuple(range(1, n_pool + 3)),
+                out_shardings=(pool_sh,) * n_pool + (self._repl_sh,) * 3)
             self.pool = tuple(_global_put(leaf, pool_sh)
                               for leaf in init_pool(model.cfg, self.spec))
             self.last_logits = _global_put(
                 jnp.zeros((cfg.capacity, model.cfg.vocab_size),
                           jnp.float32), self._repl_sh)
+            self.rngs = _global_put(
+                np.zeros((cfg.capacity, 2), np.uint32), self._repl_sh)
         else:
             # canonicalize the weights' placement: trainer-produced
             # params arrive committed to a NamedSharding over the
@@ -1227,17 +1242,18 @@ class DecodeEngine:
             self.params = jax.device_put(params, device)
             if cfg.draft is not None:
                 # donated: both pools + last_logits (positions 2-6 of
-                # the spec signature — params/draft params stay)
+                # the spec signature — params/draft params stay) and the
+                # carried keys behind them
                 self._step = jax.jit(
                     _packed(build_spec_step(model, draft_model, cfg),
                             n_carried, self._in_fields),
-                    donate_argnums=(2, 3, 4, 5, 6))
+                    donate_argnums=(2, 3, 4, 5, 6, 7))
             else:
                 self._step = jax.jit(
                     _packed(build_step(model, cfg, fused=self.fused,
                                        fused_prefill=self.fused_prefill),
                             n_carried, self._in_fields),
-                    donate_argnums=tuple(range(1, n_pool + 2)))
+                    donate_argnums=tuple(range(1, n_pool + 3)))
             # COMMIT the device-resident buffers to the same device as
             # the weights: a fresh jnp.zeros is uncommitted, but the
             # step's outputs are committed, so an uncommitted
@@ -1251,6 +1267,8 @@ class DecodeEngine:
                 jnp.zeros((cfg.capacity, model.cfg.vocab_size),
                           jnp.float32),
                 device)
+            self.rngs = jax.device_put(
+                np.zeros((cfg.capacity, 2), np.uint32), device)
             if cfg.draft is not None:
                 self.draft_params = jax.device_put(draft_params, device)
                 dpk, dpv = init_pool(draft_model.cfg, self.spec)
@@ -1260,6 +1278,8 @@ class DecodeEngine:
         # tiny jit so the step's compile_count pin is undisturbed
         self._copy = jax.jit(_copy_pool_block, donate_argnums=(0,))
         self.steps = 0
+        #: dispatched steps whose result `collect` has not read yet
+        self._uncollected = 0
         # live metrics (telemetry/metrics.py): per-tick prefill/decode
         # token counts + the compile counter. The registry NEVER enters
         # build_step — metrics on or off lowers a byte-identical
@@ -1325,10 +1345,10 @@ class DecodeEngine:
         )
 
     def lower_idle(self):
-        """The step lowered on an idle tick's inputs, as `tick` calls it:
-        what a test or an audit reads the served program from
+        """The step lowered on an idle tick's inputs, as `dispatch` calls
+        it: what a test or an audit reads the served program from
         (``.as_text()``, ``.compile()``)."""
-        return self._step.lower(*self._resident(),
+        return self._step.lower(*self._resident(), self.rngs,
                                 self._pack(**self.idle_inputs()))
 
     def warmup(self) -> None:
@@ -1363,12 +1383,13 @@ class DecodeEngine:
         return (self.params, *self.pool, self.last_logits)
 
     def _pack(self, tables, pos, decoding, temp, top_k, rngs, prefill,
-              pad=None) -> np.ndarray:
-        """`tick`'s arguments as the one vector of `tick_fields`."""
-        lead = (tables, pos, decoding, temp, top_k, rngs)
+              pad=None, fresh=None) -> np.ndarray:
+        """`dispatch`'s arguments as the one vector of `tick_fields`."""
+        C = self.cfg.capacity
+        lead = (tables, pos, decoding, temp, top_k, rngs,
+                np.ones(C, bool) if fresh is None else fresh)
         if self.cfg.prefill_batch > 1:
-            lead += (np.zeros(self.cfg.capacity, np.int32)
-                     if pad is None else pad,)
+            lead += (np.zeros(C, np.int32) if pad is None else pad,)
         return pack_words(self._in_fields, (*lead, *prefill))
 
     def _put(self, words: np.ndarray):
@@ -1386,58 +1407,86 @@ class DecodeEngine:
         the caller owns."""
         return unpack_words(self._out_fields, np.array(words))
 
-    def tick(self, tables, pos, decoding, temp, top_k, rngs, prefill,
-             pad=None):
-        """Run one step; returns ``(toks [C, W] i32 np, n_emit [C] i32
-        np, rngs' [C, 2] u32 np)`` — ``toks[s, :n_emit[s]]`` are slot
-        s's tokens this tick, oldest first. W == 1 on the base step
-        (``n_emit`` = the decoding mask); W == cfg.draft.k on a
-        speculative engine, where ``n_emit`` counts the carried token
-        plus accepted proposals. The donated device buffers are swapped
-        internally. ``pad`` ([C] i32 per-slot left pad) exists only on
-        the batched-prefill program (prefill_batch > 1) and is ignored
-        otherwise — the single-slot program is the historical one, with
-        no pad inputs.
+    def dispatch(self, tables, pos, decoding, temp, top_k, rngs, prefill,
+                 pad=None, fresh=None):
+        """The first half of a tick: send one step to the device and come
+        back without waiting for it. Returns the handle `collect` reads the
+        step's tokens from. The donated device buffers are swapped here, so
+        the next step can be dispatched on them before this one's tokens
+        are read; at most one earlier step may be uncollected then.
+
+        ``rngs`` ([C, 2] u32) are the host's keys and ``fresh`` ([C] bool)
+        the slots that take them this step: every other slot draws from
+        the key the device carries for it (`_packed`). Without ``fresh``
+        every slot takes the host's. ``pad`` ([C] i32 per-slot left pad)
+        exists only on the batched-prefill program (prefill_batch > 1) and
+        is ignored otherwise — the single-slot program is the historical
+        one, with no pad inputs.
 
         The host crosses to the device once each way (docs/SERVING.md
         "What crosses to the chip in a tick"): the arguments go as one
         packed vector, and the step's small results come back as one,
         whose copy to the host is queued right behind the step."""
-        spec_mode = self.cfg.draft is not None
+        if self._uncollected > 1:
+            raise RuntimeError(
+                "two dispatched steps are uncollected: collect() the "
+                "older before building the next")
         with annotate("serve.put", h2d_arrays=1, h2d_bytes=self._h2d_bytes):
             words = self._put(self._pack(tables, pos, decoding, temp,
-                                         top_k, rngs, prefill, pad))
-        with annotate("serve.dispatch",
+                                         top_k, rngs, prefill, pad, fresh))
+        with annotate("serve.dispatch", ahead=self._uncollected,
                       **self._step_work(pos, decoding, prefill, temp,
                                         top_k)):
-            *carried, result = self._step(*self._resident(), words)
+            *carried, self.rngs, result = self._step(
+                *self._resident(), self.rngs, words)
             if self.mesh is not None:
                 # replicated: any addressable shard IS the global value
                 # (np.array on a multi-process global array would raise)
                 result = result.addressable_data(0)
             result.copy_to_host_async()
-        if spec_mode:
+        if self.cfg.draft is not None:
             (*pool, self.dpool_k, self.dpool_v, self.last_logits) = carried
         else:
             *pool, self.last_logits = carried
+            # counted from the host-owned inputs while the step runs
+            self._record(int(np.sum(np.asarray(decoding))), prefill)
         self.pool = tuple(pool)
         self.steps += 1
-        if spec_mode:
-            with annotate("serve.fetch", d2h_arrays=1):
-                new_rngs, toks, n_emit = self._fetch(result)
+        self._uncollected += 1
+        # the base step emits one token a decoding slot: its ``n_emit`` is
+        # the mask it was sent, known before the result is
+        return result, np.asarray(decoding).astype(np.int32), prefill
+
+    def collect(self, handle):
+        """The second half of a tick: the ONE blocking read of a dispatched
+        step's result. Returns ``(toks [C, W] i32 np, n_emit [C] i32 np,
+        rngs' [C, 2] u32 np)`` — ``toks[s, :n_emit[s]]`` are slot s's
+        tokens of that step, oldest first. W == 1 on the base step
+        (``n_emit`` = the decoding mask it was dispatched with); W ==
+        cfg.draft.k on a speculative engine, where ``n_emit`` counts the
+        carried token plus accepted proposals. ``rngs'`` are the keys the
+        step left on the device, for a reader that wants them; the next
+        step does not need them back."""
+        result, n_emit, prefill = handle
+        self._uncollected -= 1
+        with annotate("serve.fetch", d2h_arrays=1):
+            new_rngs, *rest = self._fetch(result)
+        if self.cfg.draft is not None:
+            toks, n_emit = rest
             self._record(int(n_emit.sum()), prefill)
             return toks, n_emit, new_rngs
-        # counted from the host-owned inputs while the step runs: the
-        # read below is where the host waits for it
-        self._record(int(np.sum(np.asarray(decoding))), prefill)
-        with annotate("serve.fetch", d2h_arrays=1):
-            new_rngs, emitted, *counts = self._fetch(result)
+        emitted, *counts = rest
         if counts:
             # the model's device-side counts ride the same read
             self.last_counters = dict(zip(
                 self._counter_names, (int(v) for v in counts[0])))
-        return (emitted[:, None],
-                np.asarray(decoding).astype(np.int32), new_rngs)
+        return emitted[:, None], n_emit, new_rngs
+
+    def tick(self, tables, pos, decoding, temp, top_k, rngs, prefill,
+             pad=None, fresh=None):
+        """One step, dispatched and collected: `collect` of `dispatch`."""
+        return self.collect(self.dispatch(
+            tables, pos, decoding, temp, top_k, rngs, prefill, pad, fresh))
 
     def _record(self, n_dec: int, prefill) -> None:
         """A tick's live metrics: ``n_dec`` tokens emitted, and the chunk

@@ -3,8 +3,10 @@ reservation/growth, retirement, preemption.
 
 The scheduler owns every mutable serving decision and keeps it in plain
 numpy — the compiled step only ever sees fixed-shape arrays built here.
-One `tick()` = admit what fits, pick the next prefill chunk, run the
-engine once, account emissions. Determinism: given the same request
+One `tick()` = admit what fits, pick the next prefill chunk, dispatch
+the engine's next step from what can be counted, then read and account
+the tokens of the step before it (docs/SERVING.md "The order of a
+tick"). Determinism: given the same request
 stream (ids, seeds, arrival order) the schedule — and therefore every
 emitted token — is a pure function of the inputs, which is what lets a
 respawned replica REPLAY lost requests to bitwise-identical streams
@@ -197,7 +199,7 @@ class SLOConfig:
 
 
 class _Slot:
-    __slots__ = ("req", "blocks", "emitted", "prefill_next",
+    __slots__ = ("req", "blocks", "emitted", "asked", "prefill_next",
                  "admitted_at", "first_token_at", "preempted", "seq",
                  "shared_blocks", "hashes")
 
@@ -205,7 +207,10 @@ class _Slot:
                  seq: int):
         self.req = req
         self.blocks = blocks            # allocated pool block ids
-        self.emitted: List[int] = []
+        self.emitted: List[int] = []    # tokens read back so far
+        #: tokens the dispatched steps were asked for: ``len(emitted)``
+        #: plus the one of a step whose result is not collected yet
+        self.asked = 0
         self.prefill_next = 0           # prompt tokens already chunked
         self.admitted_at = time.perf_counter()
         self.first_token_at: Optional[float] = None
@@ -363,13 +368,31 @@ class Scheduler:
         self.prefill_tokens_issued = 0
         self._emitted_total = 0
         self._decode_slot_steps = 0
+        #: dispatched steps whose tokens are not read yet, oldest first,
+        #: each ``(the engine's handle, [(slot id, _Slot)] it decoded)``
+        self._inflight: Deque[Tuple[object, List[Tuple[int, _Slot]]]] = \
+            deque()
+        #: results a tick leaves unread behind the step it dispatched: 1
+        #: where the next tick's inputs follow from counts (a decoding
+        #: slot emits one token a step), 0 where they are data (the
+        #: speculative step's accepted proposals move ``pos``)
+        self._ahead = 0 if self.cfg.draft is not None else 1
+        #: steps dispatched while the one before was still unread
+        self.ticks_sent_ahead = 0
+        #: tokens read and thrown away: a step dispatched before its
+        #: slot's stop token was read, or before the slot was preempted
+        self.tokens_dropped = 0
         C = self.cfg.capacity
         self.tables = new_block_table(self.spec, C)
         self.pos = np.zeros(C, np.int32)
         self.decoding = np.zeros(C, bool)
         self.temp = np.zeros(C, np.float32)
         self.top_k = np.zeros(C, np.int32)
+        #: the key a slot's request starts from, sent in the tick that
+        #: admits it (``fresh``); from then on the slot's key lives on the
+        #: device and advances there, once an emitted token
         self.rngs = np.zeros((C, 2), np.uint32)
+        self.fresh = np.zeros(C, bool)
         #: per-slot left pad (batched prefill admits left-padded rows;
         #: 0 everywhere on single-slot engines) — the decode lanes mask
         #: pad columns exactly like generate(prompt_lengths=...)
@@ -536,7 +559,10 @@ class Scheduler:
         return True
 
     def busy(self) -> bool:
-        return bool(self.queue or self.slots)
+        """True while a request is queued or slotted, or a dispatched
+        step's result is unread: ``while busy(): tick()`` drains all
+        three."""
+        return bool(self.queue or self.slots or self._inflight)
 
     # ---- drain / eviction (the scale-down seams, docs/AUTOSCALE.md) ------
 
@@ -566,7 +592,10 @@ class Scheduler:
         blocks, and return the requests with their preemption count
         bumped — the existing bitwise replay seam: a consumer discards
         the partial stream and the re-decode regenerates it identically
-        from the seed (exactly what replica-death replay does)."""
+        from the seed (exactly what replica-death replay does). A step
+        in flight is read and dropped first: its tokens belong to streams
+        that restart."""
+        self.drop_inflight()
         out: List[Tuple[Request, int]] = []
         for s in sorted(self.slots):
             slot = self.slots.pop(s)
@@ -669,6 +698,7 @@ class Scheduler:
         self.temp[s] = req.temperature
         self.top_k[s] = req.top_k or 0
         self.rngs[s] = _key_data(req.seed)
+        self.fresh[s] = True
         self._queue_wait[req.rid] = (
             slot.admitted_at - req.arrival if req.arrival else 0.0)
         if self.prefix is not None:
@@ -799,6 +829,12 @@ class Scheduler:
         is discarded, the stream restarts delayed but identical)."""
         slot = self.slots.pop(s)
         self.last_preemptions.append(slot.req.rid)
+        if self.last_emissions:
+            # tokens this tick read before the eviction (a dry pool reads
+            # the step in flight first) belong to the stream a consumer
+            # discards: a tick's preemptions come before its emissions
+            self.last_emissions = [e for e in self.last_emissions
+                                   if e[0] != slot.req.rid]
         self.last_preemption_details.append(self._partial_timing(
             slot, time.perf_counter(), preempted=slot.preempted + 1))
         self.metrics.count("preemptions")
@@ -870,31 +906,79 @@ class Scheduler:
     # ---- the tick --------------------------------------------------------
 
     def tick(self) -> List[Completion]:
-        """Admit -> prefill-chunk pick -> engine step -> account.
-        Returns the requests that COMPLETED this tick. Each phase is an
-        `rlt.serve.*` event in a profiler trace (telemetry/spans.py
-        `annotate`; annotations only, the tick never enters the span
-        ring), nested under `rlt.serve.tick`."""
+        """Admit -> prefill-chunk pick -> dispatch the next step -> read
+        and account the step before it. Returns the requests whose LAST
+        token was read this tick (docs/SERVING.md "The order of a tick"):
+        the step dispatched here runs on the device while the host reads
+        the previous one's tokens, so a token is reported a tick after the
+        step that sampled it was dispatched. Where the next step's inputs
+        cannot be counted (a speculative engine) the step is read in the
+        tick that dispatched it. A tick with nothing to dispatch only
+        reads. Each phase is an `rlt.serve.*` event in a profiler trace
+        (telemetry/spans.py `annotate`; annotations only, the tick never
+        enters the span ring), nested under `rlt.serve.tick`."""
         with annotate("serve.tick", tick=self._ticks):
             self.last_preemptions = []
             self.last_preemption_details = []
+            self.last_emissions = []
+            done: List[Completion] = []
             with annotate("serve.admit"):
                 self._admit()
             with annotate("serve.grow"):
-                self._grow_decoding()
+                self._grow_decoding(done)
             with annotate("serve.build"):
                 prefill, pf_group = self._build_prefill()
-            was_decoding = self.decoding.copy()
-            emitted, n_emit, self.rngs = self.engine.tick(
-                self.tables, self.pos, self.decoding, self.temp,
-                self.top_k, self.rngs, prefill,
-                pad=self.pad if self.cfg.prefill_batch > 1 else None)
-            # the model's device-side counts of this tick (fetched with
-            # its tokens; none for a decoder that counts nothing)
-            with annotate("serve.account", **self.engine.last_counters,
-                          **self.pool_group_counters()):
-                return self._account(pf_group, was_decoding, emitted,
-                                     n_emit)
+            occupancy, unread = 0.0, 0
+            if pf_group is not None or self.decoding.any():
+                occupancy = float(self.decoding.mean())
+                self._dispatch(prefill, pf_group)
+                unread = self._ahead
+            self._occupancy_sum += occupancy
+            self._ticks += 1
+            while len(self._inflight) > unread:
+                done.extend(self._collect(self._inflight.popleft()))
+            self._gauges(occupancy, len(done))
+            return done
+
+    def _dispatch(self, prefill, pf_group) -> None:
+        """Send the step and account what follows from counts alone: the
+        chunk's progress, and for a step that emits one token a decoding
+        slot, every such slot's position and the tokens it has been asked
+        for. A slot asked for its last token is not decoding in the next
+        step; it keeps its blocks until that token is read."""
+        decoded = [(s, slot) for s, slot in self.slots.items()
+                   if self.decoding[s]]
+        if self._inflight:
+            self.ticks_sent_ahead += 1
+            self.metrics.count("ticks_sent_ahead")
+        handle = self.engine.dispatch(
+            self.tables, self.pos, self.decoding, self.temp, self.top_k,
+            self.rngs, prefill,
+            pad=self.pad if self.cfg.prefill_batch > 1 else None,
+            fresh=self.fresh)
+        self.fresh[:] = False
+        self._inflight.append((handle, decoded))
+        self._count_prefill(pf_group)
+        if self._ahead:
+            for s, slot in decoded:
+                self.pos[s] += 1
+                slot.asked += 1
+                if slot.asked >= slot.req.max_new_tokens:
+                    self.decoding[s] = False
+
+    def drop_inflight(self) -> None:
+        """Read every dispatched step's result and throw its tokens away:
+        for whoever tears slots down outside a tick. The read waits for a
+        step that is already running and for nothing else."""
+        while self._inflight:
+            handle, decoded = self._inflight.popleft()
+            _toks, n_emit, _ = self.engine.collect(handle)
+            self._drop(sum(int(n_emit[s]) for s, _ in decoded))
+
+    def _drop(self, n: int) -> None:
+        if n:
+            self.tokens_dropped += n
+            self.metrics.count("tokens_dropped", n)
 
     def pool_group_counters(self) -> Dict[str, int]:
         """For an engine with a window group (`serve/kv_cache.py` "two
@@ -916,7 +1000,7 @@ class Scheduler:
         return {"full_blocks_live": sum(held),
                 "window_blocks_live": sum(min(n, ring) for n in held)}
 
-    def _grow_decoding(self) -> None:
+    def _grow_decoding(self, done: List[Completion]) -> None:
         # growth check before the step: every decoding slot must own
         # the block its write lands in. On a dry pool a grower may only
         # evict slots STRICTLY AFTER itself in policy order (decoding
@@ -931,7 +1015,10 @@ class Scheduler:
         # lets a later grower evict an earlier slot (or the grower
         # evict itself while holding victims) lets two oversubscribed
         # requests cycle forever (observed livelock, test-pinned
-        # against).
+        # against). A request whose last token is in a step not read yet
+        # still holds its blocks: on a dry pool that step is read first
+        # (its completions go to ``done``), and nobody is evicted for
+        # blocks a retirement was about to free.
         for s in sorted([s for s in self.slots if self.decoding[s]],
                         key=lambda s: self._policy_key(self.slots[s])):
             if s not in self.slots:
@@ -939,6 +1026,11 @@ class Scheduler:
             me = self.slots[s]
             me_key = self._policy_key(me)
             while not self._grow(s, me):
+                if self._inflight:
+                    done.extend(self._collect(self._inflight.popleft()))
+                    if self.slots.get(s) is not me:
+                        break  # its stop token was in that step
+                    continue
                 # a dry pool at a growth boundary: the signal item 1(c)
                 # autoscale watches — every stall is one eviction (or a
                 # self-preempt) the pool's size forced
@@ -1026,14 +1118,10 @@ class Scheduler:
                        np.int32(last_row), pads)
         return prefill, pf_group
 
-    def _account(self, pf_group, was_decoding, emitted,
-                 n_emit) -> List[Completion]:
-        """Prefill and decode accounting, retirement and gauges after the
-        engine's step."""
+    def _count_prefill(self, pf_group) -> None:
+        """The dispatched chunk's progress: positions, the hand-over to
+        the decode lane after a prompt's last chunk, the prefix cache."""
         ch = self.cfg.prefill_chunk
-        self._occupancy_sum += float(was_decoding.mean())
-        self._ticks += 1
-        # prefill accounting
         if pf_group is not None and self.cfg.prefill_batch == 1:
             pf_slot = pf_group.slots[0]
             slot = self.slots[pf_slot]
@@ -1046,7 +1134,8 @@ class Scheduler:
                 self.decoding[pf_slot] = True
                 if self.prefix is not None:
                     # publish the fully prefilled chain: every FULL
-                    # prompt block becomes matchable for later admits
+                    # prompt block becomes matchable for later admits,
+                    # whose steps run after the one just dispatched
                     n_full = (slot.req.prompt.size
                               // self.spec.block_size)
                     self.prefix.register(slot.hashes[:n_full],
@@ -1060,33 +1149,52 @@ class Scheduler:
                 self.prefill_groups.popleft()
                 for s in pf_group.slots:
                     self.decoding[s] = True
-        # decode accounting — the engine hands back up to W tokens per
-        # slot (W == 1 on the base step): append in order, truncating
-        # at eos / max_new exactly where plain greedy decode stops
+
+    def _collect(self, flight) -> List[Completion]:
+        """Read one dispatched step's tokens and account what needs their
+        values: the streams, the first-token stamps, stop tokens, the
+        completions. The engine hands back up to W tokens a slot (W == 1
+        on the base step): append in order, truncating at eos / max_new
+        exactly where plain greedy decode stops. A slot that was retired
+        by a stop token or preempted after the step was dispatched is not
+        the request the token was sampled for any more: the token is
+        dropped."""
+        handle, decoded = flight
+        emitted, n_emit, _ = self.engine.collect(handle)
+        live = [(s, slot) for s, slot in decoded
+                if self.slots.get(s) is slot]
+        dropped = sum(int(n_emit[s]) for s, slot in decoded
+                      if self.slots.get(s) is not slot)
         done: List[Completion] = []
-        self.last_emissions = []
-        n_active = int(was_decoding.sum())
-        if n_active:
-            self._decode_slot_steps += n_active
-            self._emitted_total += int(n_emit[was_decoding].sum())
-        for s in list(self.slots):
-            if not was_decoding[s]:
-                continue
-            slot = self.slots[s]
-            if slot.first_token_at is None:
-                slot.first_token_at = time.perf_counter()
-            req = slot.req
-            for _j in range(int(n_emit[s])):
-                tok = int(emitted[s, _j])
-                slot.emitted.append(tok)
-                self.last_emissions.append((req.rid, tok))
-                self.pos[s] += 1
-                if req.eos_id is not None and tok == req.eos_id:
-                    done.append(self._retire(s, "eos"))
-                    break
-                if len(slot.emitted) >= req.max_new_tokens:
-                    done.append(self._retire(s, "length"))
-                    break
+        # the model's device-side counts of the step just read (none for a
+        # decoder that counts nothing)
+        with annotate("serve.account", tokens_dropped=dropped,
+                      **self.engine.last_counters,
+                      **self.pool_group_counters()):
+            self._drop(dropped)
+            self._decode_slot_steps += len(live)
+            for s, slot in live:
+                if slot.first_token_at is None:
+                    slot.first_token_at = time.perf_counter()
+                req = slot.req
+                self._emitted_total += int(n_emit[s])
+                for _j in range(int(n_emit[s])):
+                    tok = int(emitted[s, _j])
+                    slot.emitted.append(tok)
+                    self.last_emissions.append((req.rid, tok))
+                    if not self._ahead:
+                        # the speculative step's advance is data
+                        self.pos[s] += 1
+                    if req.eos_id is not None and tok == req.eos_id:
+                        done.append(self._retire(s, "eos"))
+                        break
+                    if len(slot.emitted) >= req.max_new_tokens:
+                        done.append(self._retire(s, "length"))
+                        break
+        return done
+
+    def _gauges(self, occupancy: float, completed: int) -> None:
+        """The tick's live gauges and its flight-recorder entry."""
         m = self.metrics
         if m.enabled or self.flight.enabled:
             # every value below is host bookkeeping the tick already
@@ -1103,7 +1211,7 @@ class Scheduler:
                 m.gauge("free_slots", len(self.free_slots))
                 m.gauge("blocks_free", free)
                 m.gauge("blocks_in_use", total - free)
-                m.gauge("slot_occupancy", float(was_decoding.mean()))
+                m.gauge("slot_occupancy", occupancy)
                 if self.slo is not None:
                     # per-class pressure feeds `load_signal()`'s
                     # pressure_<class> fields (autoscale + watch);
@@ -1116,9 +1224,8 @@ class Scheduler:
                                queue_depth=queue_depth,
                                decoding=decoding, prefilling=prefilling,
                                blocks_free=free,
-                               completed=len(done))
+                               completed=completed)
             m.tick_end()
-        return done
 
     # ---- metrics ---------------------------------------------------------
 
@@ -1175,7 +1282,9 @@ class Scheduler:
             out.append({
                 **self._partial_timing(slot, now,
                                        preempted=slot.preempted),
-                "state": "decoding" if self.decoding[s]
+                # a slot asked for its last token decodes no more and
+                # waits for the read
+                "state": "decoding" if self.decoding[s] or slot.asked
                 else "prefilling",
             })
         for req, preempts in self.queue:

@@ -175,9 +175,10 @@ def test_the_result_layout_follows_the_program():
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_a_tick_places_one_array_and_reads_one(tiny_llama_f32, monkeypatch,
                                                program):
-    """Through churn: every tick makes exactly one host-to-device placement
+    """Through churn: every step makes exactly one host-to-device placement
     and one device-to-host read, an admission launches nothing (no
-    `jax.random.key`), and the step compiles once."""
+    `jax.random.key`), and the step compiles once. A tick dispatches one
+    step, except the last of a program sent ahead, which only reads."""
     eng, _ = _engine(program, tiny_llama_f32)
     eng.warmup()
     calls = {"put": 0, "fetch": 0, "device_put": 0, "words": set()}
@@ -222,7 +223,10 @@ def test_a_tick_places_one_array_and_reads_one(tiny_llama_f32, monkeypatch,
         sched.tick()
         ticks += 1
     assert ticks > 7
-    assert calls["put"] == calls["fetch"] == calls["device_put"] == ticks
+    steps = ticks if program == "speculative" else ticks - 1
+    assert calls["put"] == calls["fetch"] == calls["device_put"] == steps
+    assert sched.ticks_sent_ahead == (0 if program == "speculative"
+                                      else steps - 1)
     assert calls["words"] == {(np.dtype(np.int32), (eng._h2d_bytes // 4,))}
     assert eng.compile_count == 1
 

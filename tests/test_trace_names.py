@@ -237,9 +237,10 @@ def traced(tmp_path_factory):
             "on": _host_events(str(root / "on")), "ring": ring,
             "expected": expected, "tick_ids": tick_ids,
             "train_text": train_text, "serve_text": serve_text,
-            # tables, four words a slot and its two RNG words, the chunk,
-            # and three scalars, four bytes each
-            "h2d_bytes": 4 * (ecfg.capacity * (ecfg.blocks_per_slot + 6)
+            # tables, four words a slot, its two RNG words and the flag
+            # that says they are fresh, the chunk, and three scalars, four
+            # bytes each
+            "h2d_bytes": 4 * (ecfg.capacity * (ecfg.blocks_per_slot + 7)
                               + ecfg.prefill_chunk + 3)}
 
 
@@ -328,6 +329,24 @@ def test_a_tick_crosses_to_the_device_once_each_way(traced, phase, counter):
     got = [e["stats"][counter]
            for e in _named(traced["off"], "rlt.serve." + phase)]
     assert got == [traced["h2d_bytes"] if counter == "h2d_bytes" else 1] * 3
+
+
+def test_a_step_is_dispatched_before_the_one_before_it_is_read(traced):
+    """Every traced tick sends its step ahead (`ahead=1`: the previous
+    step's result was unread), reads after it has dispatched, on one
+    thread, and throws no token away."""
+    for tick in _named(traced["off"], "rlt.serve.tick"):
+        (sent,) = [e for e in _named(traced["off"], "rlt.serve.dispatch")
+                   if _inside(e, tick)]
+        (read,) = [e for e in _named(traced["off"], "rlt.serve.fetch")
+                   if _inside(e, tick)]
+        (account,) = [e for e in _named(traced["off"], "rlt.serve.account")
+                      if _inside(e, tick)]
+        assert sent["stats"]["ahead"] == 1
+        assert sent["end"] <= read["start"] <= read["end"] \
+            <= account["start"]
+        assert sent["thread"] == read["thread"] == tick["thread"]
+        assert account["stats"]["tokens_dropped"] == 0
 
 
 @pytest.mark.parametrize("phase,thread_of,count", [
