@@ -31,6 +31,9 @@ from ray_lightning_tpu.models.moe import (
     moe_param_specs,
 )
 from ray_lightning_tpu.models.ssm_hybrid import SsmHybrid, SsmHybridConfig
+from ray_lightning_tpu.models.delta_hybrid import (
+    DeltaHybrid, DeltaHybridConfig,
+)
 from ray_lightning_tpu.models.window_moe import WindowMoe, WindowMoeConfig
 from ray_lightning_tpu.models.resnet import (
     ResNet,
@@ -66,6 +69,8 @@ __all__ = [
     "resnet18",
     "resnet34",
     "resnet50",
+    "DeltaHybrid",
+    "DeltaHybridConfig",
     "SsmHybrid",
     "SsmHybridConfig",
     "WindowMoe",

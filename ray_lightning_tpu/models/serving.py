@@ -20,8 +20,10 @@ itself:
                                     own, `serve/kv_cache.py`), or None
     model.slot_state                True where its recurrent layers keep a
                                     state a SLOT: `pool_leaf_shapes` is
-                                    then told ``state_slots`` too, and the
-                                    step's views say which rows are real
+                                    then told ``state_slots`` too, the
+                                    step's views say which rows are real,
+                                    and `tick_counters` names the two
+                                    counts of that work (rows, slots)
     model(tokens, cache=, pos=, pad=, paged=)
 
 A new decoder enters with a config dataclass, a flax module with those
@@ -43,6 +45,8 @@ _DECODERS = {
                         "WindowMoeConfig", "WindowMoe"),
     "SsmHybridConfig": ("ray_lightning_tpu.models.ssm_hybrid",
                         "SsmHybridConfig", "SsmHybrid"),
+    "DeltaHybridConfig": ("ray_lightning_tpu.models.delta_hybrid",
+                          "DeltaHybridConfig", "DeltaHybrid"),
 }
 
 

@@ -1551,12 +1551,14 @@ class DecodeEngine:
                                (`decode_live_tiles`, `prefill_live_tiles`)
 
         For a decoder with recurrent layers (`model.slot_state`), what ONE
-        STATE-SPACE LAYER is asked to do:
+        RECURRENT LAYER is asked to do, under the names the decoder's own
+        `tick_counters` give the two counts (`SsmHybrid`: ``scan_rows``,
+        ``state_slots``; `DeltaHybrid`: ``delta_rows``, ``state_slots``):
 
-          scan_rows     rows of the chunk its scan advances on: the real
-                        ones, ``prefill_rows`` less those the scheduler
+          <rows>        rows of the chunk its recurrence advances on: the
+                        real ones, ``prefill_rows`` less those the scheduler
                         sent before (a window slid back at a slot's end)
-          state_slots   slots whose state the decode lane moves by a row
+          <slots>       slots whose state the decode lane moves by a row
         """
         dec = np.asarray(decoding)
         if self.cfg.prefill_batch == 1:
@@ -1591,9 +1593,9 @@ class DecodeEngine:
             # slot's first unsent position to the prompt's last
             sent = (int(np.asarray(pos)[int(pslot)]) - start
                     if active.any() else 0)
-            work["scan_rows"] = int(max(cols - max(sent, 0), 0)
-                                    * active.sum())
-            work["state_slots"] = work["decode_slots"]
+            rows, slots = self._counter_names
+            work[rows] = int(max(cols - max(sent, 0), 0) * active.sum())
+            work[slots] = work["decode_slots"]
         if self._decode_tile:
             work["decode_tiles"] = int(
                 np.ceil(lengths / self._decode_tile).sum())

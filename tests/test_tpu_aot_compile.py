@@ -221,6 +221,81 @@ def test_window_kernels_compile_at_128_heads_in_groups_of_16(v5e, as_on_tpu,
     # one call
 
 
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_thirty_kv_heads_are_no_sublane_tile_and_thirty_two_are(
+        v5e, as_on_tpu, kernel):
+    """The paperqa cell's full layers: one query head a KV head, 30 of each.
+    The chip pads the pool's head axis to 32 and Mosaic cannot slice 30 of
+    the padded 32 out of HBM, so `models/delta_hybrid.py` declares 32 (two
+    dead heads). A head-major pool would lift this (PERF.md section 7)."""
+    from ray_lightning_tpu.ops.pallas.paged_attention import (
+        paged_attention_pallas,
+    )
+    from ray_lightning_tpu.ops.pallas.paged_prefill import (
+        paged_prefill_pallas,
+    )
+
+    s = SingleDeviceSharding(v5e[0])
+    bf, i32 = jnp.bfloat16, jnp.int32
+    c, m, ch, p, hd = 16, 67, 2048, 128, 128
+
+    def lowered(h):
+        pool, at = _pool_operands("stack", (641, p, h, hd), s)
+        if kernel == "decode":
+            return jax.jit(paged_attention_pallas).lower(
+                _sds((c, h, hd), bf, s), pool, pool, _sds((c, m), i32, s),
+                _sds((c,), i32, s), _sds((c,), i32, s), **at)
+        return jax.jit(paged_prefill_pallas).lower(
+            _sds((1, ch, h, hd), bf, s), pool, pool, _sds((1, m), i32, s),
+            _sds((), i32, s), _sds((1,), i32, s), **at)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        lowered(30).compile()
+    compiled = lowered(32).compile()
+    assert _n_mosaic(compiled) == 1
+    # the leaf of 32 heads at its own bytes: nothing padded, nothing copied
+    pool_bytes = 2 * 3 * 641 * p * 32 * hd * 2
+    m_ = compiled.memory_analysis()
+    assert pool_bytes <= m_.argument_size_in_bytes < pool_bytes * 1.01
+    assert m_.temp_size_in_bytes < pool_bytes // 8
+
+
+def test_delta_rule_kernels_compile_at_the_published_dims(v5e, as_on_tpu):
+    """`rlt_delta_chunk` over a 2,048-row chunk and `rlt_delta_step` over 16
+    slots at 30 heads, d_k 96, d_v 192 (the paperqa cell), bfloat16 rows:
+    the state two heads side by side is whole tiles, so it goes in and out
+    at its own bytes, and the one-row update writes it in place."""
+    from ray_lightning_tpu.ops import gated_delta as gd
+
+    s = SingleDeviceSharding(v5e[0])
+    bf, f32 = jnp.bfloat16, jnp.float32
+    h, dk, dv = 30, 96, 192
+    assert gd.gated_delta_uses_pallas(2048, h, dk, dv)
+    assert gd.gated_delta_uses_pallas(1, h, dk, dv)
+
+    def args(b, t):
+        lead = (b, t) if t else (b,)
+        return (_sds((*lead, h, dk), bf, s), _sds((*lead, h, dk), bf, s),
+                _sds((*lead, h, dv), bf, s), _sds((*lead, h), f32, s),
+                _sds((*lead, h), f32, s),
+                _sds((b, *gd.pair_shape(h, dk, dv)), f32, s),
+                _sds(lead if t else (b,), jnp.bool_, s))
+
+    chunk = jax.jit(lambda *a: gd.gated_delta_rule(*a)).lower(
+        *args(1, 2048)).compile()
+    assert _n_mosaic(chunk) == 1
+    step = jax.jit(lambda *a: gd.gated_delta_update(*a),
+                   donate_argnums=5).lower(*args(16, 0)).compile()
+    assert _n_mosaic(step) == 1
+    state_bytes = 16 * 15 * 96 * 384 * 4
+    m_ = step.memory_analysis()
+    # unpadded (a leaf of one head's [96, 192] would read a third more),
+    # aliased onto its input, no second copy among the temporaries
+    assert state_bytes <= m_.argument_size_in_bytes < state_bytes * 1.03
+    assert m_.alias_size_in_bytes >= state_bytes
+    assert m_.temp_size_in_bytes < state_bytes // 8
+
+
 @pytest.mark.parametrize("seq", [1024, 2048, 4096])
 def test_flash_fwd_bwd_compiles(v5e, as_on_tpu, seq):
     from ray_lightning_tpu.ops.attention import flash_attention
