@@ -231,6 +231,49 @@ def _remat_policy(name: str):
     }[name]
 
 
+def _activation_pin(cfg: "LlamaConfig", mesh, batch: int, seq: int):
+    """``pin(a, *tail)`` for the training branch of a block: batch over the
+    mesh's data-parallel axes (`parallel.mesh.dp_axis_names`), the sequence
+    over ``seq`` where the sequence-parallel island already puts it, and the
+    dimensions behind them as ``tail`` says (``"tensor"`` where
+    `_PER_LAYER_SPECS` splits the weight that makes them, `None` for the
+    residual stream's features): the layout `flash_attention_on_mesh` and
+    `seq_island` give q, k and v, stated for every activation of the layer.
+
+    Why: a strategy that overlays `fsdp` on the weights says nothing of the
+    activations, and GSPMD is then free to let a weight's split leak into a
+    product's output and reshard the ACTIVATIONS (a whole batch's residual
+    stream gathered at `wqkv`, six all-to-alls of the MLP hidden in the
+    backward: 1.3 GB a layer a chip at the fsdp4 cell's shapes, against
+    0.38 GB of weights and gradients; PERF.md section 6, PR 42). Pinned,
+    the one freedom left is to gather a layer's weights where a product
+    needs them and scatter their gradients. `with_sharding_constraint`
+    transposes to itself, so the cotangents are pinned too.
+
+    The identity (no constraint in the traced program) with no mesh, with
+    every data-parallel axis of size 1, or with a batch they do not divide
+    (the rule `flash_attention_on_mesh` has for a one-prompt call)."""
+    from ray_lightning_tpu.parallel.mesh import (
+        batch_size_divisor,
+        dp_axis_names,
+    )
+
+    n = 1 if mesh is None else batch_size_divisor(mesh)
+    if n == 1 or batch % n:
+        return lambda a, *tail: a
+    from jax.sharding import NamedSharding
+
+    s = mesh.shape.get("seq", 1)
+    lead = (dp_axis_names(mesh),
+            "seq" if cfg.seq_parallel and s > 1 and seq % s == 0 else None)
+
+    def pin(a, *tail):
+        return jax.lax.with_sharding_constraint(
+            a, NamedSharding(mesh, P(*lead, *tail)))
+
+    return pin
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     mesh: Optional[Any] = None  # jax.sharding.Mesh (static, hashable)
@@ -275,21 +318,31 @@ class LlamaBlock(nn.Module):
                         param_dtype=jnp.float32,
                         dot_general=_f32_acc_dot_general)
 
+        B, S = x.shape[0], x.shape[1]
+        n_q, n_kv = cfg.n_heads, cfg.n_kv_heads
+        # training only: every activation of the layer stays on the batch
+        # axes, so under FSDP the weights travel (`_activation_pin`; the
+        # identity off a data-parallel mesh). `wide`: the axis of a
+        # dimension a column-parallel weight makes
+        pin = _activation_pin(cfg, self.mesh if cache is None else None,
+                              B, S)
+        wide = ("tensor" if self.mesh is not None
+                and self.mesh.shape.get("tensor", 1) > 1 else None)
+        x = pin(x, None)
+
         # `attn` / `mlp`: the block's two halves in a profiler trace (flax
         # scopes only the Dense calls; norms, RoPE, the kernel and the
         # gate fall outside them)
         with jax.named_scope("attn"):
             attn_norm_w = self.param("attn_norm", nn.initializers.ones, (d,))
-            h = rms_norm(x, attn_norm_w, cfg.norm_eps)
+            h = pin(rms_norm(x, attn_norm_w, cfg.norm_eps), None)
             # fused QKV projection: one [D, (H + 2*Hkv) * hd] matmul
-            n_q, n_kv = cfg.n_heads, cfg.n_kv_heads
-            qkv = dense((n_q + 2 * n_kv) * hd, name="wqkv")(h)
+            qkv = pin(dense((n_q + 2 * n_kv) * hd, name="wqkv")(h), wide)
             q, k, v = jnp.split(
                 qkv, [n_q * hd, (n_q + n_kv) * hd], axis=-1)
-            B, S = x.shape[0], x.shape[1]
-            q = q.reshape(B, S, n_q, hd)
-            k = k.reshape(B, S, n_kv, hd)
-            v = v.reshape(B, S, n_kv, hd)
+            q = pin(q.reshape(B, S, n_q, hd), wide, None)
+            k = pin(k.reshape(B, S, n_kv, hd), wide, None)
+            v = pin(v.reshape(B, S, n_kv, hd), wide, None)
             if cache is None:
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
@@ -439,16 +492,18 @@ class LlamaBlock(nn.Module):
                     attn = dot_product_attention(
                         q, ck, cv, causal=False, mask=mask)
                 new_cache = (ck, cv)
-            attn = attn.reshape(B, S, n_q * hd)
-            x = x + dense(d, name="wo")(attn)
+            attn = pin(attn.reshape(B, S, n_q * hd), wide)
+            x = pin(x + dense(d, name="wo")(attn), None)
 
         with jax.named_scope("mlp"):
             mlp_norm_w = self.param("mlp_norm", nn.initializers.ones, (d,))
-            h = rms_norm(x, mlp_norm_w, cfg.norm_eps)
+            h = pin(rms_norm(x, mlp_norm_w, cfg.norm_eps), None)
             # fused gate+up: one [D, 2F] matmul
-            gate_up = dense(2 * cfg.hidden_dim, name="w_gate_up")(h)
+            gate_up = pin(dense(2 * cfg.hidden_dim, name="w_gate_up")(h),
+                          wide)
             gate, up = jnp.split(gate_up, 2, axis=-1)
-            x = x + dense(d, name="w_down")(nn.silu(gate) * up)
+            hidden = pin(nn.silu(gate) * up, wide)
+            x = pin(x + dense(d, name="w_down")(hidden), None)
         return x, new_cache  # (carry, ys) pair so nn.scan drives the block
 
 

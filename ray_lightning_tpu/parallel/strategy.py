@@ -354,7 +354,14 @@ class FSDP(Strategy):
     Params and optimizer state are sharded along the `fsdp` mesh axis (each
     leaf on its largest divisible dimension); activations stay data-parallel.
     XLA inserts the all-gather (forward/backward) and reduce-scatter (grad)
-    that FSDP implementations hand-schedule. Stands in the "second protocol"
+    that FSDP implementations hand-schedule. "Activations stay
+    data-parallel" is the MODEL's to state, not this overlay's: the
+    annotations here name weights only, and where a chip's rows x sequence
+    exceed the widths GSPMD reshards the activations instead of gathering
+    the weights unless the block pins them to the batch axes
+    (`models/llama.py:_activation_pin`; docs/PERFORMANCE.md "Collective
+    overlap" has the rule and how to list a step's collectives from the
+    compiled HLO without a chip). Stands in the "second protocol"
     slot Horovod occupied in the reference (ray_horovod.py:29-196) and is
     the BASELINE.json Llama-8B strategy.
     """

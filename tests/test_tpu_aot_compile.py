@@ -346,15 +346,17 @@ def test_latent_attention_kernels_compile_at_the_published_dims(
 
 # ---- the 0.5B train step, through the Trainer's own step builder -----------
 
-def _train_step_compiled(strategy, batch=8, seq=2048):
+def _train_step_compiled(strategy, batch=8, seq=2048, cfg=None):
     """AOT-compile `Trainer._make_train_step` for the chip_smoke model
-    with every array abstract, sharded as the strategy shards it."""
+    (or ``cfg``) with every array abstract, sharded as the strategy
+    shards it."""
     import chip_smoke
     from ray_lightning_tpu import Trainer
     from ray_lightning_tpu.core.state import TrainState
     from ray_lightning_tpu.models.llama import LlamaConfig, LlamaModule
 
-    module = LlamaModule(LlamaConfig(**chip_smoke.SmokeSize.full().model))
+    module = LlamaModule(
+        cfg or LlamaConfig(**chip_smoke.SmokeSize.full().model))
     trainer = Trainer(strategy=strategy, enable_checkpointing=False,
                       enable_progress_bar=False)
     strategy.setup(module)
@@ -401,6 +403,69 @@ def test_train_step_compiles(v5e, as_on_tpu, plan):
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 16 * 1024**3, (
         f"{plan}: {total / 1024**3:.2f} GiB does not fit a v5e chip")
+
+
+def layer_scan_activation_moves(compiled, batch, seq):
+    """The collectives of the layer scan's two bodies that move an
+    ACTIVATION: every all-to-all, and every all-gather whose result holds
+    the global batch's rows. Under FSDP a layer should move its weights
+    (rank-2 gathers, or `[1, ...]` slices of the stacked leaves) and
+    scatter their gradients; `models/llama.py:_activation_pin`."""
+    from ray_lightning_tpu.analysis.collectives import step_collectives
+
+    cols = [c for c in step_collectives(compiled.as_text())
+            if c.loop.endswith(("jvp(Llama)/while",
+                                "transpose(jvp(Llama))/while"))]
+    assert cols, "the layer scan's bodies hold no collective at all"
+    return [c for c in cols if c.kind == "all-to-all" or (
+        c.kind == "all-gather"
+        and any(dims[:2] == (batch, seq) for _, dims in c.shapes))]
+
+
+def cell_step_compiled(traffic, devices, **strategy_keys):
+    """The train step of the benchmark's cell with that traffic file, from
+    the cell's own files: (cell, compiled). ``strategy_keys`` override the
+    traffic file's (``overlap="on"``). `scripts/step_collectives.py` prints
+    what this compiles."""
+    import json
+    import os
+
+    import ray_lightning_tpu as rlt
+    from benchmarks.models import dense_decoder as adapter
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(rel):
+        with open(os.path.join(root, rel)) as fh:
+            return json.load(fh)
+
+    tr = load(f"benchmarks/traffic/{traffic}.json")
+    cell = next(w for w in load("BENCHMARK.json")["workloads"]
+                if w["traffic"] == traffic)
+    config = load(f"benchmarks/configs/{cell['config']}.json")
+    keys = {k: v for k, v in tr["strategy"].items() if k != "name"}
+    strategy = getattr(rlt, tr["strategy"]["name"])(
+        devices=devices[:cell["chips"]], **{**keys, **strategy_keys})
+    cfg = adapter.llama_config(
+        config, adapter.hyperparams(config, "train"), "train")
+    return cell, _train_step_compiled(
+        strategy, batch=tr["batch"], seq=tr["seq"], cfg=cfg)
+
+
+def test_fsdp4_cell_layers_move_weights_not_activations(v5e, as_on_tpu):
+    """`train.internlm2-1.8b.fsdp4` at its own shapes: the parent's step
+    gathered the whole batch's residual stream at `wqkv` and exchanged the
+    MLP hidden six times a layer in the backward (1.3 GB a layer a chip
+    against 0.38 GB of weights and gradients; PERF.md section 6, PR 42).
+    The CPU twin at a small size is tests/test_activation_pins.py."""
+    from ray_lightning_tpu.analysis.collectives import format_collectives
+
+    _, compiled = cell_step_compiled("fsdp4", v5e)
+    assert _n_mosaic(compiled) >= 3
+    moves = layer_scan_activation_moves(compiled, 8, 4096)
+    assert not moves, format_collectives(moves)
+    # 6.51 GiB with the activations resharded, 5.63 pinned
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.0 * 1024**3
 
 
 # ---- the serving step -------------------------------------------------------
