@@ -35,6 +35,7 @@ from ray_lightning_tpu.models.delta_hybrid import (
     DeltaHybrid, DeltaHybridConfig,
 )
 from ray_lightning_tpu.models.window_moe import WindowMoe, WindowMoeConfig
+from ray_lightning_tpu.models.conv_moe import ConvMoe, ConvMoeConfig
 from ray_lightning_tpu.models.resnet import (
     ResNet,
     ResNetModule,
@@ -69,6 +70,8 @@ __all__ = [
     "resnet18",
     "resnet34",
     "resnet50",
+    "ConvMoe",
+    "ConvMoeConfig",
     "DeltaHybrid",
     "DeltaHybridConfig",
     "SsmHybrid",

@@ -18,7 +18,11 @@ What it reads of a decoder's configuration, whatever its class:
     expert_choice              how `route` chooses among the scores:
         "noaux_tc"  by groups with a bias that decides the choice and never
                     the weight (reads n_group, topk_group,
-                    routed_scaling_factor; the layer has a `router_bias`)
+                    routed_scaling_factor; the layer has a `router_bias`);
+                    one group is no groups: the biased scores' top k
+    route_norm_eps             (optional) what is added to the sum the
+                               chosen scores are normalised over; 1e-20
+                               where the configuration names none
         "topk"      the plain top-k of the scores, weights normalised (no
                     bias, no groups, no scaling)
 """
@@ -43,9 +47,10 @@ def _mm(x, w, dtype):
                    preferred_element_type=jnp.float32).astype(dtype)
 
 
-def _normalised(scores, experts):
+def _normalised(cfg, scores, experts):
     picked = jnp.take_along_axis(scores, experts, axis=-1)
-    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    eps = getattr(cfg, "route_norm_eps", 1e-20)
+    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
 
 
 def route(cfg, scores, bias=None):
@@ -56,19 +61,21 @@ def route(cfg, scores, bias=None):
     t, e = scores.shape
     if cfg.expert_choice == "topk":
         _, experts = jax.lax.top_k(scores, cfg.n_experts_per_tok)
-        return experts.astype(jnp.int32), _normalised(scores, experts)
+        return experts.astype(jnp.int32), _normalised(cfg, scores, experts)
     if cfg.expert_choice != "noaux_tc":
         raise ValueError(f"expert_choice {cfg.expert_choice!r} is none of "
                          f"{CHOICES}")
     choice = scores + bias[None, :]
-    groups = choice.reshape(t, cfg.n_group, e // cfg.n_group)
-    group_score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
-    _, kept = jax.lax.top_k(group_score, cfg.topk_group)
-    group_mask = jnp.zeros((t, cfg.n_group), bool).at[
-        jnp.arange(t)[:, None], kept].set(True)
-    masked = jnp.where(group_mask[:, :, None], groups, -jnp.inf)
-    _, experts = jax.lax.top_k(masked.reshape(t, e), cfg.n_experts_per_tok)
-    weights = _normalised(scores, experts) * cfg.routed_scaling_factor
+    if cfg.n_group > 1:
+        groups = choice.reshape(t, cfg.n_group, e // cfg.n_group)
+        group_score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, cfg.topk_group)
+        group_mask = jnp.zeros((t, cfg.n_group), bool).at[
+            jnp.arange(t)[:, None], kept].set(True)
+        choice = jnp.where(group_mask[:, :, None], groups,
+                           -jnp.inf).reshape(t, e)
+    _, experts = jax.lax.top_k(choice, cfg.n_experts_per_tok)
+    weights = _normalised(cfg, scores, experts) * cfg.routed_scaling_factor
     return experts.astype(jnp.int32), weights
 
 
@@ -105,7 +112,8 @@ class HeldExperts(nn.Module):
     """The routed part of an expert layer on the chip that holds experts
     ``[cfg.experts_first, cfg.experts_first + cfg.held)``: rows h [T, D]
     -> (sum over a row's chosen HELD experts of w_i E_i(h) [T, D] float32, counts
-    int32 [2]: rows routed here, the fullest expert's rows).
+    int32 [2]: rows routed here, the fullest expert's rows) and, with
+    ``with_hits``, a third: bool [held], the experts that got a row.
 
     ``cfg`` is any configuration with the members the module's text lists.
     The experts' weights are arguments, not parameters of this module:
@@ -115,6 +123,7 @@ class HeldExperts(nn.Module):
     cannot ride the layer scan as its sliced parameters."""
 
     cfg: object
+    with_hits: bool = False
 
     @nn.compact
     def __call__(self, h, stacks, index=0, use_pallas=None):
@@ -154,6 +163,8 @@ class HeldExperts(nn.Module):
             y = jnp.dot(pick.T, weighted,
                         preferred_element_type=jnp.float32)
         counts = jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
+        if self.with_hits:
+            return y, counts, sizes > 0
         return y, counts              # float32, as the combine summed it
 
 

@@ -10,7 +10,10 @@ itself:
                                     paged pool's leaves
     model.paged_lanes(...)          would the paged lanes take the kernels
     model.serving_param_specs()     per-leaf placement of a sharded replica
-    model.tick_counters             device-side counts of a paged call
+    model.tick_counters             device-side counts of a paged call:
+                                    ``(name, "sum" | "max")``, or ``(name,
+                                    "union", words)`` for a bitset a lane
+                                    whose union the engine counts
     model.decode_tile_tokens(P, M)  the decode kernel's KV tile, or None
     model.prefill_tile_shape(B, CH, P, M)  the prefill kernel's query tile
                                     and KV tile, or None
@@ -22,7 +25,7 @@ itself:
                                     state a SLOT: `pool_leaf_shapes` is
                                     then told ``state_slots`` too, the
                                     step's views say which rows are real,
-                                    and `tick_counters` names the two
+                                    and `tick_counters` ENDS in the two
                                     counts of that work (rows, slots)
     model(tokens, cache=, pos=, pad=, paged=)
 
@@ -47,6 +50,8 @@ _DECODERS = {
                         "SsmHybridConfig", "SsmHybrid"),
     "DeltaHybridConfig": ("ray_lightning_tpu.models.delta_hybrid",
                           "DeltaHybridConfig", "DeltaHybrid"),
+    "ConvMoeConfig": ("ray_lightning_tpu.models.conv_moe", "ConvMoeConfig",
+                      "ConvMoe"),
 }
 
 
@@ -58,6 +63,19 @@ def _row(config_type: str):
     module, config, decoder = _DECODERS[config_type]
     mod = importlib.import_module(module)
     return getattr(mod, config), getattr(mod, decoder)
+
+
+def require_llama(cfg, where: str) -> None:
+    """`serve/audit.py` and `serve/cli.py` build `Llama` themselves
+    (ROADMAP Queue 2 mechanism 8): a configuration of any other decoder is
+    refused there by its type's name, not by a traceback from inside
+    `Llama`."""
+    name = type(cfg).__name__
+    if name != "LlamaConfig":
+        raise ValueError(
+            f"{where} builds `Llama` itself and has no path for a "
+            f"configuration of type {name!r} (models/serving.py serves "
+            f"{sorted(_DECODERS)} through `ServeDriver`)")
 
 
 def serving_model(cfg):

@@ -485,6 +485,51 @@ def paged_attention(
                                      window=window)
 
 
+# ---- heads of 64, two a 128-lane row ------------------------------------------
+#
+# Mosaic slices a pool block out of HBM only where a cached row is whole
+# 128-lane tiles (`ops/pallas/paged_attention.py:_copies_in_kernel`), so a
+# decoder with heads of 64 keeps TWO KV HEADS SIDE BY SIDE in one row of its
+# pool leaf and hands both paged kernels heads of 128: KV heads ``2g`` and
+# ``2g + 1`` are the halves of row ``g``, a query head sits in the half of
+# its own KV head with zeros in the other (so the 128-wide score is the
+# 64-wide one, exactly), and its output is that half of the 128. The caller
+# passes the model's ``scale`` (``64 ** -0.5``): the kernels' default would
+# be the padded width's.
+
+
+def pair_kv_heads(x: jnp.ndarray) -> jnp.ndarray:
+    """``[.., Hkv, hd]`` -> ``[.., Hkv / 2, 2 hd]``: the same bytes, two
+    heads a row."""
+    *lead, hkv, hd = x.shape
+    return x.reshape(*lead, hkv // 2, 2 * hd)
+
+
+def _half_of(n_heads: int, n_kv_heads: int) -> jnp.ndarray:
+    """[H] 0/1: which half of its paired row query head h reads (its KV
+    head ``h // n_rep`` is even or odd)."""
+    return (jnp.arange(n_heads) // (n_heads // n_kv_heads)) % 2
+
+
+def pair_query_heads(q: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+    """``[.., H, hd]`` -> ``[.., H, 2 hd]``: each query head in its KV
+    head's half of the lanes, zeros in the other. Paired row ``g`` then
+    serves query heads ``[g * 2 n_rep, (g + 1) * 2 n_rep)``: the kernels'
+    own head map at ``Hkv / 2`` KV heads."""
+    low = (_half_of(q.shape[-2], n_kv_heads) == 0)[:, None]
+    zero = jnp.zeros_like(q)
+    return jnp.concatenate([jnp.where(low, q, zero),
+                            jnp.where(low, zero, q)], axis=-1)
+
+
+def unpair_heads(out: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+    """``[.., H, 2 hd]`` -> ``[.., H, hd]``: each head's own half (the
+    other half is its pair's values under this head's probabilities)."""
+    hd = out.shape[-1] // 2
+    low = (_half_of(out.shape[-2], n_kv_heads) == 0)[:, None]
+    return jnp.where(low, out[..., :hd], out[..., hd:])
+
+
 # ---- latent attention (MLA) over the latent paged pool -----------------------
 #
 # One cached row a token a layer, ``[c_kv | k_r]``; every query head reads

@@ -104,7 +104,9 @@ def trace_decode_step(model_cfg, engine_cfg: EngineConfig,
     import numpy as np
 
     from ray_lightning_tpu.models.llama import Llama
+    from ray_lightning_tpu.models.serving import require_llama
 
+    require_llama(model_cfg, "serve/audit.py:trace_decode_step")
     if fused_prefill is None:
         fused_prefill = fused and _shape_fused_prefill_available(
             model_cfg, engine_cfg)
@@ -512,9 +514,11 @@ def serve_memory_summary(model_cfg, engine_cfg: EngineConfig,
         paged_prefill_traffic_bytes,
     )
     from ray_lightning_tpu.models.llama import Llama
+    from ray_lightning_tpu.models.serving import require_llama
     from ray_lightning_tpu.parallel.plan import hbm_bytes_for_kind
     from ray_lightning_tpu.serve.kv_cache import gathered_view_bytes
 
+    require_llama(model_cfg, "serve/audit.py:serve_memory_summary")
     if fused is None:
         fused = _shape_fused_available(model_cfg, engine_cfg)
     if fused_prefill is None:
@@ -599,7 +603,9 @@ def _param_count(model_cfg) -> int:
     import numpy as np
 
     from ray_lightning_tpu.models.llama import Llama
+    from ray_lightning_tpu.models.serving import require_llama
 
+    require_llama(model_cfg, "serve/audit.py:_param_count")
     model = Llama(model_cfg)
     a_params = jax.eval_shape(
         lambda key: model.init(key, np.zeros((1, 2), np.int32))["params"],

@@ -245,9 +245,10 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     handed the leaves whole (``cache=pool``) beside a paged view and
     hands them back. A model with `tick_counters` returns their values
     from a paged call too; the step joins the two lanes' as each
-    counter says (sum / max) and returns them after ``emitted``. A decoder
-    with sliding-window layers (`model.kv_window`) has a second group of
-    leaves, a ring a slot (`serve/kv_cache.py` "two groups"): the step
+    counter says (sum / max, or the union of a bitset, counted once the
+    lanes are joined: `_join_words`) and returns them after ``emitted``.
+    A decoder with sliding-window layers (`model.kv_window`) has a second
+    group of leaves, a ring a slot (`serve/kv_cache.py` "two groups"): the step
     makes that group's table rows from the positions it holds
     (`window_ring_table`) and hands them to the model in the same views. A
     decoder with recurrent layers (`model.slot_state`) has leaves that hold
@@ -311,8 +312,12 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                           pad=pad, paged=view)
         return out[0], tuple(out[1]), (out[2] if counters else None)
 
+    unions = any(c[1] == "union" for c in counters)
+
     def _join(a, b):
         # a tick's two lanes' counts, each as its counter says
+        if unions:
+            return _join_words(counters, a, b)
         return jnp.stack([a[i] + b[i] if how == "sum"
                           else jnp.maximum(a[i], b[i])
                           for i, (_, how) in enumerate(counters)])
@@ -568,8 +573,10 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             carried = (*pool, last_logits) + ((counts,) if counters else ())
             carried = jax.lax.cond(
                 prefill_slot >= 0, do_prefill, lambda *a: a, *carried)
-            return (*carried[:n_pool + 1], new_rngs, emitted,
-                    *carried[n_pool + 1:])
+            counted = carried[n_pool + 1:]
+            if unions:
+                counted = (_settle_words(counters, counted[0]),)
+            return (*carried[:n_pool + 1], new_rngs, emitted, *counted)
 
         return step
 
@@ -897,6 +904,34 @@ def tick_fields(cfg: EngineConfig):
     return tuple(fields)
 
 
+def _counter_spans(counters):
+    """(join, slice) of each of `model.tick_counters` in a lane's vector of
+    int32 words: one word a counter, or the declared words of a ``(name,
+    "union", words)`` bitset."""
+    at = 0
+    for counter in counters:
+        words = counter[2] if counter[1] == "union" else 1
+        yield counter[1], slice(at, at + words)
+        at += words
+
+
+def _join_words(counters, a, b):
+    """`build_step`'s join for a decoder that declares a ``"union"``
+    counter: a bitset joins by OR (an element set in either lane is set
+    once)."""
+    joins = {"sum": jnp.add, "max": jnp.maximum, "union": jnp.bitwise_or}
+    return jnp.concatenate([joins[how](a[span], b[span])
+                            for how, span in _counter_spans(counters)])
+
+
+def _settle_words(counters, words):
+    """A tick's joined words as one count a counter: a bitset's is how many
+    elements it holds."""
+    return jnp.stack([
+        jnp.sum(jax.lax.population_count(words[span])) if how == "union"
+        else words[span][0] for how, span in _counter_spans(counters)])
+
+
 def result_fields(cfg: EngineConfig, n_counters: int):
     """What the step returns after ``last_logits``, as `tick_fields` has the
     inputs: the layout of the ONE ``int32`` vector the host reads a tick."""
@@ -1190,7 +1225,7 @@ class DecodeEngine:
         n_pool = len(pool_leaf_shapes(model.cfg, spec))
         #: names of the device-side counts the step returns, fetched
         #: with the tick's tokens into `last_counters`
-        self._counter_names = tuple(n for n, _ in model.tick_counters)
+        self._counter_names = tuple(c[0] for c in model.tick_counters)
         self.last_counters: dict = {}
         #: the tick's protocol: one packed ``int32`` vector in, one out
         #: (`tick_fields`, `result_fields`), their layout a function of
@@ -1552,8 +1587,9 @@ class DecodeEngine:
 
         For a decoder with recurrent layers (`model.slot_state`), what ONE
         RECURRENT LAYER is asked to do, under the names the decoder's own
-        `tick_counters` give the two counts (`SsmHybrid`: ``scan_rows``,
-        ``state_slots``; `DeltaHybrid`: ``delta_rows``, ``state_slots``):
+        `tick_counters` END in (`SsmHybrid`: ``scan_rows``,
+        ``state_slots``; `DeltaHybrid`: ``delta_rows``, ``state_slots``;
+        `ConvMoe`: ``conv_rows``, ``state_slots``):
 
           <rows>        rows of the chunk its recurrence advances on: the
                         real ones, ``prefill_rows`` less those the scheduler
@@ -1593,7 +1629,7 @@ class DecodeEngine:
             # slot's first unsent position to the prompt's last
             sent = (int(np.asarray(pos)[int(pslot)]) - start
                     if active.any() else 0)
-            rows, slots = self._counter_names
+            rows, slots = self._counter_names[-2:]
             work[rows] = int(max(cols - max(sent, 0), 0) * active.sum())
             work[slots] = work["decode_slots"]
         if self._decode_tile:

@@ -7,7 +7,8 @@ others' programs did not change.
     python3 scripts/step_jaxpr_same.py _parent
 
 Traces `build_step` on the CPU at each decoder's tiny size (the dense, the
-latent-attention, the window and the state-space decoder), both lanes fused
+latent-attention, the window, the state-space and the delta-rule decoder),
+both lanes fused
 and (for the dense decoder) both on the reference lanes, and the dense
 decoder's batched-prefill step; each checkout in a process of its own.
 Prints SAME or DIFFERENT a program and exits non-zero on any difference.
@@ -24,6 +25,7 @@ from ray_lightning_tpu.models.llama import Llama, LlamaConfig
 from ray_lightning_tpu.models.mla_moe import MlaMoe, MlaMoeConfig
 from ray_lightning_tpu.models.window_moe import WindowMoe, WindowMoeConfig
 from ray_lightning_tpu.models.ssm_hybrid import SsmHybrid, SsmHybridConfig
+from ray_lightning_tpu.models.delta_hybrid import DeltaHybrid, DeltaHybridConfig
 from ray_lightning_tpu.serve.engine import EngineConfig, build_step, idle_prefill
 from ray_lightning_tpu.serve.kv_cache import init_pool, state_pool_spec, window_pool_spec
 for name, cls, cfg, ekw in (
@@ -32,6 +34,7 @@ for name, cls, cfg, ekw in (
     ("mla_moe", MlaMoe, MlaMoeConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
     ("window_moe", WindowMoe, WindowMoeConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
     ("ssm_hybrid", SsmHybrid, SsmHybridConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
+    ("delta_hybrid", DeltaHybrid, DeltaHybridConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
 ):
     model = cls(cfg); ecfg = EngineConfig(**ekw)
     params = jax.eval_shape(model.init, jax.random.key(0), jnp.zeros((1,8),jnp.int32))["params"]
@@ -43,7 +46,7 @@ for name, cls, cfg, ekw in (
     if ecfg.prefill_batch > 1: runtime.append(np.zeros(c, np.int32))
     runtime += list(idle_prefill(ecfg))
     for fused in ((True, True), (False, False)):
-        if name in ("mla_moe", "window_moe", "ssm_hybrid") and not fused[0]: continue
+        if name not in ("llama", "llama_b2") and not fused[0]: continue
         text = str(jax.make_jaxpr(build_step(model, ecfg, fused=fused[0], fused_prefill=fused[1]))(params, *pool, jnp.zeros((c, cfg.vocab_size), jnp.float32), *runtime))
         print(name, fused, len(text), hashlib.sha256(text.encode()).hexdigest())
 '''

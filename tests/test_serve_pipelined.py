@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_lightning_tpu.models.conv_moe import ConvMoe, ConvMoeConfig
 from ray_lightning_tpu.models.llama import Llama, generate
 from ray_lightning_tpu.models.mla_moe import MlaMoe, MlaMoeConfig
 from ray_lightning_tpu.models.ssm_hybrid import SsmHybrid, SsmHybridConfig
@@ -24,7 +25,8 @@ SMALL = dict(capacity=3, block_size=4, blocks_per_slot=10, prefill_chunk=4,
 TILED = dict(capacity=3, block_size=16, blocks_per_slot=5, prefill_chunk=16,
              n_blocks=6)
 CASES = [("single", False), ("single", True), ("batch2", False),
-         ("counters", False), ("counters", True), ("state", False)]
+         ("counters", False), ("counters", True), ("state", False),
+         ("convmoe", False)]
 
 
 @pytest.fixture(scope="module")
@@ -48,9 +50,11 @@ def engines(tiny_llama_f32):
                                             draft=DraftConfig(k=3)),
                 draft_model=draft, draft_params=dparams)
         else:
-            kind, mcfg = ((MlaMoe, MlaMoeConfig.tiny())
-                          if program == "counters"
-                          else (SsmHybrid, SsmHybridConfig.tiny()))
+            kind, mcfg = {
+                "counters": (MlaMoe, MlaMoeConfig.tiny()),
+                "state": (SsmHybrid, SsmHybridConfig.tiny()),
+                "convmoe": (ConvMoe, ConvMoeConfig.tiny()),
+            }[program]
             other = kind(mcfg)
             oparams = other.init(jax.random.key(0),
                                  jnp.zeros((1, 8), jnp.int32))["params"]
@@ -217,7 +221,8 @@ def _spied(eng, monkeypatch):
     return events
 
 
-@pytest.mark.parametrize("program", ["single", "batch2", "counters"])
+@pytest.mark.parametrize("program", ["single", "batch2", "counters",
+                                     "convmoe"])
 def test_a_step_is_dispatched_before_the_one_before_it_is_read(
         engines, monkeypatch, program):
     eng = engines(program)

@@ -84,6 +84,9 @@ DECODE_CELLS = [
     ((16, 8), 128, 16, 64, 160),          # serve.internlm2-1.8b.chat
     ((32, 8), 128, 16, 16, 272),          # serve.mistral-7b-v0.3.docs
     ((16, 8), 128, 16, 8, 17),
+    # serve.LFM2-24B-A2B.agentsteps: 32 / 8 heads of 64 as the kernel sees
+    # them, two KV heads a 128-lane row (`ops.attention.pair_kv_heads`)
+    ((32, 4), 128, 128, 128, 17),
 ]
 
 
@@ -128,6 +131,8 @@ PREFILL_CELLS = [
     ((32, 8), 128, 16, 128, 272, (128, 512)),   # serve.mistral-7b-v0.3.docs
     ((32, 32), 128, 16, 128, 272, (128, 256)),
     ((16, 8), 128, 16, 128, 17, (128, 17 * 16)),
+    # serve.LFM2-24B-A2B.agentsteps: heads of 64 in pairs, a 1024-row chunk
+    ((32, 4), 128, 128, 1024, 17, (128, 512)),
 ]
 
 
