@@ -172,6 +172,23 @@ def _is_prefill_view(paged) -> bool:
     return isinstance(paged, PagedPrefillView)
 
 
+def _is_joined_view(paged) -> bool:
+    """Is this paged view a tick's two lanes joined (the decode rows,
+    then the prefill chunk, in one call)?"""
+    from ray_lightning_tpu.ops.attention import PagedJoinedView
+
+    return isinstance(paged, PagedJoinedView)
+
+
+def _view_dispatch(view, cfg) -> Optional[bool]:
+    """``use_pallas`` for a paged lane's attention: the view's STATIC
+    ``use_pallas`` (the serve engine's build-time decision) pins the
+    dispatch; absent that, the flash-style ambient policy."""
+    if view.use_pallas is not None:
+        return view.use_pallas
+    return None if cfg.use_flash else False
+
+
 def _is_flash_remat_opt(params) -> bool:
     """Is this `remat_opt` equation the flash kernel's hoisted fwd rule?
 
@@ -310,8 +327,13 @@ class LlamaBlock(nn.Module):
         the group's shared scalar write offset, the whole chunk's K/V
         is scattered through ``write_block/write_offset`` and
         `ops.attention.paged_prefill` attends causally through the
-        tables. ``paged=None`` lowers the identical historical
-        program."""
+        tables. A `PagedJoinedView` carries one of each: B is 1, the S
+        rows are the C slots' decode tokens and then the chunk's CH,
+        ``pos`` is each row's cache position ``[C + CH]``, and the rows
+        part only for their K/V writes and their attention (each lane's
+        as its own branch has them), so a tick that carries a chunk reads
+        this block's weights once. ``paged=None`` lowers the identical
+        historical program."""
         cfg = self.cfg
         d, hd = cfg.dim, cfg.head_dim
         dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
@@ -384,6 +406,50 @@ class LlamaBlock(nn.Module):
 
                     attn = checkpoint_name(attn, "attn_out")
                 new_cache = None
+            elif paged is not None and _is_joined_view(paged):
+                # both paged lanes in ONE pass (serve/engine.py, a tick that
+                # carries a chunk): B == 1 and the S rows are the C slots'
+                # decode tokens, then the CH rows of the chunk, so `wqkv`
+                # above and `wo` and the MLP below read their weights once a
+                # tick. ``pos`` is each row's cache position ``[C + CH]``.
+                # The rows part here only, for attention: each lane's
+                # kernel call is that of its own branch below, over the
+                # same pool. The slot that takes the chunk is not decoding:
+                # its decode row writes to scratch and is discarded, and the
+                # chunk writes only blocks its slot owns, so neither lane
+                # sees a row the other wrote this tick.
+                assert B == 1 and pad is None, "one unpadded chunk a tick"
+                dec, chunk = paged.decode, paged.prefill
+                C = dec.tables.shape[0]
+                q = apply_rope(q, cos, sin, positions=pos[None, :])
+                k = apply_rope(k, cos, sin, positions=pos[None, :])
+                pk, pv = cache  # [L, n_blocks, P, Hkv, hd] — the stack
+                # write-then-attend, as either lane alone: one scatter a
+                # leaf, the decode rows at their (scratch-redirected) write
+                # index and the chunk's through its own
+                with jax.named_scope("kv_pool"):
+                    block = jnp.concatenate(
+                        [dec.write_block, chunk.write_block[0]])
+                    offset = jnp.concatenate(
+                        [dec.write_offset, chunk.write_offset[0]])
+                    pk = pk.at[layer, block, offset].set(
+                        k[0].astype(pk.dtype))
+                    pv = pv.at[layer, block, offset].set(
+                        v[0].astype(pv.dtype))
+                from ray_lightning_tpu.ops.attention import (
+                    paged_attention, paged_prefill,
+                )
+
+                attn = jnp.concatenate([
+                    paged_attention(
+                        q[0, :C], pk, pv, dec.tables, dec.lengths,
+                        use_pallas=_view_dispatch(dec, cfg),
+                        layer=layer)[None],
+                    paged_prefill(
+                        q[:, C:], pk, pv, chunk.tables, pos[C],
+                        use_pallas=_view_dispatch(chunk, cfg),
+                        layer=layer)], axis=1)
+                new_cache = (pk, pv)
             elif paged is not None and _is_prefill_view(paged):
                 # paged PREFILL (serve/engine.py fused prefill lane): a
                 # CH-token chunk per head-group row against the SHARED
@@ -412,13 +478,9 @@ class LlamaBlock(nn.Module):
                                paged.write_offset].set(v.astype(pv.dtype))
                 from ray_lightning_tpu.ops.attention import paged_prefill
 
-                # the view's STATIC use_pallas (the serve engine's
-                # build-time decision) pins the dispatch; absent that,
-                # fall back to the flash-style ambient policy
-                up = (paged.use_pallas if paged.use_pallas is not None
-                      else (None if cfg.use_flash else False))
                 attn = paged_prefill(q, pk, pv, paged.tables, pos, pad=pad,
-                                     use_pallas=up, layer=layer)
+                                     use_pallas=_view_dispatch(paged, cfg),
+                                     layer=layer)
                 new_cache = (pk, pv)
             elif paged is not None:
                 # paged decode (serve/engine.py fused lane): one token per
@@ -447,14 +509,10 @@ class LlamaBlock(nn.Module):
                                    v[:, 0].astype(pv.dtype))
                 from ray_lightning_tpu.ops.attention import paged_attention
 
-                # the view's STATIC use_pallas (the serve engine's
-                # build-time decision) pins the dispatch; absent that,
-                # fall back to the flash-style ambient policy
-                up = (paged.use_pallas if paged.use_pallas is not None
-                      else (None if cfg.use_flash else False))
                 attn = paged_attention(
                     q[:, 0], pk, pv, paged.tables, paged.lengths, pad=pad,
-                    use_pallas=up, layer=layer)[:, None]
+                    use_pallas=_view_dispatch(paged, cfg),
+                    layer=layer)[:, None]
                 new_cache = (pk, pv)
             else:
                 positions = pos + jnp.arange(S)
@@ -522,6 +580,9 @@ class Llama(nn.Module):
     kv_window = None
     #: no recurrent layers: no leaf of the pool holds a row a slot
     slot_state = False
+    #: a `PagedJoinedView` is served: a tick's decode rows and its prefill
+    #: chunk go through the layers in one call, the weights read once
+    joins_lanes = True
 
     def serving_param_specs(self):
         return llama_param_specs(self.cfg)
@@ -591,8 +652,12 @@ class Llama(nn.Module):
         its xs): every block writes into and reads from the one stack
         at its own layer, so the returned pool is the donated buffer
         updated in place, and no layer's pool is ever sliced out or
-        copied. The dense cache (``paged=None``) rides the scan as
-        xs in / ys out, as it always has."""
+        copied. Under a `PagedJoinedView` (`joins_lanes`: a tick's decode
+        rows and its prefill chunk in one call, ``tokens [1, C + CH]``)
+        the head reads the C decode rows and the one chunk row the view's
+        ``last_row`` keeps: logits ``[1, C + 1, V]``. The dense cache
+        (``paged=None``) rides the scan as xs in / ys out, as it always
+        has."""
         cfg = self.cfg
         # take from the f32 table and round the (token-sized) result,
         # rather than dtype=cfg.dtype (which rounds the TABLE before the
@@ -684,6 +749,14 @@ class Llama(nn.Module):
         final_w = self.param("final_norm", nn.initializers.ones, (cfg.dim,))
         if last_only:
             x = x[:, -1:, :]
+        if paged is not None and _is_joined_view(paged):
+            # the head reads the C decode rows and the ONE row of the chunk
+            # the step keeps, taken before the product: [C + 1, V] logits,
+            # not [C + CH, V]
+            C = paged.decode.tables.shape[0]
+            x = jnp.concatenate([
+                x[:, :C], jax.lax.dynamic_slice_in_dim(
+                    x, C + paged.last_row, 1, axis=1)], axis=1)
         x = rms_norm(x, final_w, cfg.norm_eps)
         if return_hidden:
             # lm_head params still exist (init traces the default path);

@@ -27,6 +27,16 @@ itself:
                                     step's views say which rows are real,
                                     and `tick_counters` ENDS in the two
                                     counts of that work (rows, slots)
+    model.joins_lanes               True where a paged call serves a
+                                    `PagedJoinedView`: a tick's C decode
+                                    rows and its CH chunk rows in ONE call
+                                    (``tokens [1, C + CH]``, ``pos`` a
+                                    position a row, logits ``[1, C + 1,
+                                    V]``: the decode rows and the chunk
+                                    row the view keeps), so a tick that
+                                    carries a chunk reads the weights
+                                    once. Absent or False: the engine
+                                    calls the model once a lane
     model(tokens, cache=, pos=, pad=, paged=)
 
 A new decoder enters with a config dataclass, a flax module with those

@@ -335,6 +335,35 @@ class PagedPrefillView:
         return cls(*children, use_pallas=aux)
 
 
+@jax.tree_util.register_pytree_node_class
+class PagedJoinedView:
+    """A tick's two lanes in ONE model call (serve/engine.py, a decoder
+    with `joins_lanes`): the call's ``C + CH`` rows are the ``C`` slots'
+    decode tokens, then the prefill chunk's ``CH``.
+
+    ``decode`` is the `PagedDecodeView` of the first ``C`` rows and
+    ``prefill`` the `PagedPrefillView` (one group row) of the rest, each
+    what its own lane would be handed: the rows part only for attention
+    and the K/V writes, every product that reads weights runs once over
+    all of them. ``last_row`` (scalar int32) is the chunk row whose logits
+    the step keeps (`prefill_last_row`; -1: the prompt continues and the
+    row the head reads in its place is discarded), so the head reads
+    ``C + 1`` rows and not the chunk's ``CH``. The call's ``pos`` is a
+    ``[C + CH]`` vector, each row's cache position."""
+
+    def __init__(self, decode, prefill, last_row):
+        self.decode = decode
+        self.prefill = prefill
+        self.last_row = last_row
+
+    def tree_flatten(self):
+        return (self.decode, self.prefill, self.last_row), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
 def paged_prefill_reference(
     q: jnp.ndarray,
     pool_k: jnp.ndarray,

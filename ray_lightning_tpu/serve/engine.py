@@ -230,6 +230,16 @@ def _sample_one(logits, key, temp, top_k):
     return jnp.where(temp == 0.0, greedy, drawn)
 
 
+def joins_lanes(model, cfg: EngineConfig, fused: bool,
+                fused_prefill: bool) -> bool:
+    """Does a tick that carries a chunk go through ``model`` in ONE call
+    (`build_step`)? Where the decoder says it serves a joined view
+    (`joins_lanes`), both lanes are paged and the chunk is one slot's:
+    what the engine can observe, no option."""
+    return bool(getattr(model, "joins_lanes", False) and fused
+                and fused_prefill and cfg.prefill_batch == 1)
+
+
 def build_step(model, cfg: EngineConfig, fused: bool = False,
                fused_prefill: bool = False):
     """The jitted continuous-batching step for ``model`` (a decoder of
@@ -256,6 +266,24 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     from the positions it already holds, which rows of the prefill chunk
     are real and whose state the decode lane moves, and keeps the K/V of a
     row that is not real out of the attention group.
+
+    Which step a decoder gets (`joins_lanes`). A tick's products read
+    their weights whatever the rows, so a tick that calls the model once
+    for the C decode rows and again for the chunk reads them twice (63%
+    of the dense docs step was the second read: PERF.md section 6, PR
+    45). Where the decoder serves a `PagedJoinedView` (its
+    ``joins_lanes``), both lanes are paged and the chunk is one slot's
+    (``prefill_batch == 1``), the step is ``sample``, then ONE model call:
+    over ``C + CH`` rows in a tick that carries a chunk, each lane's view
+    what its own call would be handed, and the decode lane's call alone
+    in a tick without. Same arguments, results and donated buffers as the
+    two-pass step, which every other case keeps: the reference lanes (the
+    bitwise anchor against `generate()`), a batched prefill group, the
+    speculative step, and the decoders that have no joined branch yet
+    (their programs are untouched: `scripts/step_jaxpr_same.py`). The
+    choice is read off the model and the build's own arguments: no
+    option selects it. The two-pass path goes when the last decoder has
+    joined (ROADMAP Queue 1 item 2).
 
     ``fused`` selects the decode lane at BUILD time (the dispatch
     decision is static, like a kernel choice — it can never retrace):
@@ -301,6 +329,7 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     counters = tuple(model.tick_counters)
     tiled_decode = model.decode_tile_tokens(
         spec.block_size, cfg.blocks_per_slot) is not None
+    joined = joins_lanes(model, cfg, fused, fused_prefill)
     if not (fused and fused_prefill):
         # the reference lanes gather a dense K/V view: Llama's cache path
         L, HKV, HD = mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim
@@ -382,12 +411,8 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             pool_v = pool_v.at[:, bi, off].set(v_tok)
         return (pool_k, pool_v), logits2, None
 
-    def _decode_fused(params, pool, tables, pos, decoding,
-                      emitted, slot_pad):
-        # the fused lane: the pool IS the cache — the model's paged
-        # branch scatters the new K/V at the (scratch-redirected) write
-        # index and `paged_attention` streams block-table-named tiles,
-        # so no [L, C, G, Hkv, hd] copy exists on this path
+    def _decode_view(tables, pos, decoding):
+        # what the model's paged decode branch is told of the C slots
         from ray_lightning_tpu.ops.attention import PagedDecodeView
 
         bi, off = _write_index(tables, pos, decoding)
@@ -415,9 +440,17 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         if slot_state:
             # a slot that is idle or being prefilled keeps its state
             ring["state_moves"] = decoding
-        view = PagedDecodeView(tables=tables, lengths=lengths,
+        return PagedDecodeView(tables=tables, lengths=lengths,
                                write_block=bi, write_offset=off,
                                use_pallas=True, **ring)
+
+    def _decode_fused(params, pool, tables, pos, decoding,
+                      emitted, slot_pad):
+        # the fused lane: the pool IS the cache — the model's paged
+        # branch scatters the new K/V at the (scratch-redirected) write
+        # index and `paged_attention` streams block-table-named tiles,
+        # so no [L, C, G, Hkv, hd] copy exists on this path
+        view = _decode_view(tables, pos, decoding)
         logits2, pool, counts = _paged_apply(
             params, emitted[:, None], pool, pos, slot_pad, view)
         return pool, logits2[:, 0], counts
@@ -451,6 +484,47 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             pad=pad)
         return logits, nk, nv
 
+    def _chunk_view(slot, row, pos, prefill_pos, prefill_last_row):
+        # what the model's paged prefill branch is told of the one chunk
+        # (B == 1) that ``slot``, whose table row is ``row``, takes. The
+        # pool IS the cache: the branch scatters the CH-wide chunk at the
+        # table-named write indices and `paged_prefill` streams block
+        # tiles, so the [L, 1, G, Hkv, hd] gather never exists. The full
+        # CH-wide write stays safe past a partial tail chunk for the same
+        # reason as the reference lane: tail garbage lands in OWNED blocks
+        # and is overwritten before any mask exposes it.
+        from ray_lightning_tpu.ops.attention import PagedPrefillView
+
+        wpos = prefill_pos + jnp.arange(CH)
+        ring = {}
+        if window is not None:
+            # the window group's row: the blocks the chunk writes and its
+            # first row sees back to
+            wrow = window_ring_table(
+                spec, slot, jnp.maximum(prefill_pos - window + 1, 0),
+                prefill_pos + CH - 1)
+            ring = dict(window_tables=wrow,
+                        window_write_block=wrow[:, wpos // P])
+        tables1, wblock = row[None], row[wpos // P][None]
+        if slot_state:
+            # the chunk's REAL rows: from the slot's first unsent position
+            # (the scheduler may have slid the window back over rows it
+            # sent before) to the prompt's last (zeros follow it). A
+            # recurrence advances on those alone, and a row sent before
+            # computes its K/V from a state that has moved on: it goes to
+            # the scratch block, the first stays
+            first = jnp.clip(pos[slot] - prefill_pos, 0, CH)
+            last = jnp.where(prefill_last_row >= 0, prefill_last_row,
+                             CH - 1)
+            chunk_rows = jnp.arange(CH)[None]
+            wblock = jnp.where((chunk_rows >= first)
+                               & (chunk_rows <= last), wblock, 0)
+            ring.update(state_slot=slot,
+                        real_rows=jnp.stack([first, last]))
+        return PagedPrefillView(
+            tables=tables1, write_block=wblock,
+            write_offset=(wpos % P)[None], use_pallas=True, **ring)
+
     if B == 1:
         def step(params, *args):
             """One engine tick: ``step(params, *pool, last_logits,
@@ -483,6 +557,62 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             # ---- decode lane: sample, then advance every slot --------
             emitted, new_rngs = _sample(last_logits, decoding, temp,
                                         top_k, rngs)
+            if joined:
+                def decode_lane(*carried):
+                    # a tick WITHOUT a chunk: the decode lane alone
+                    pool, logits2, _ = _decode_fused(
+                        params, carried[:n_pool], tables, pos, decoding,
+                        emitted, None)
+                    return (*pool, jnp.where(decoding[:, None], logits2,
+                                             carried[n_pool]))
+
+                def both_lanes(*carried):
+                    # a tick WITH a chunk: the C decode rows and the CH
+                    # rows of the chunk in ONE model call, each lane's view
+                    # what its own call would be handed. The model parts
+                    # the rows only for attention and the K/V writes, and
+                    # its head reads the decode rows and the one row of
+                    # the chunk that `prefill_last_row` keeps.
+                    from ray_lightning_tpu.ops.attention import (
+                        PagedJoinedView,
+                    )
+
+                    slot = jnp.maximum(prefill_slot, 0)
+                    view = PagedJoinedView(
+                        _decode_view(tables, pos, decoding),
+                        _chunk_view(slot, tables[slot], pos, prefill_pos,
+                                    prefill_last_row), prefill_last_row)
+                    logits, pool, _ = _paged_apply(
+                        params,
+                        jnp.concatenate([emitted, prefill_tokens])[None],
+                        carried[:n_pool],
+                        jnp.concatenate([pos,
+                                         prefill_pos + jnp.arange(CH)]),
+                        None, view)
+                    last_logits = jnp.where(decoding[:, None],
+                                            logits[0, :C], carried[n_pool])
+                    # the slot that took the chunk was not decoding: where
+                    # the chunk ended its prompt, the kept row is what its
+                    # first token is drawn from
+                    last_logits = jnp.where(
+                        (jnp.arange(C) == slot)[:, None]
+                        & (prefill_last_row >= 0),
+                        logits[0, C][None, :], last_logits)
+                    return (*pool, last_logits)
+
+                # one of the two runs, each the TRUE branch of a cond of its
+                # own beside one that passes the pool through: XLA orders a
+                # conditional's branch 0 before its branch 1 when it looks
+                # for a buffer's last reader, so a pass that wrote the pool
+                # in place from branch 0 of ONE cond over both passes would
+                # copy every layer's stack in and out
+                # (tests/test_tpu_aot_compile.py pins the compile)
+                carried = jax.lax.cond(
+                    prefill_slot >= 0, both_lanes, lambda *a: a,
+                    *pool, last_logits)
+                carried = jax.lax.cond(
+                    prefill_slot < 0, decode_lane, lambda *a: a, *carried)
+                return (*carried, new_rngs, emitted)
             pool, logits2, counts = _decode(
                 params, pool, tables, pos, decoding, emitted, None)
             last_logits = jnp.where(decoding[:, None], logits2,
@@ -494,51 +624,8 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                 slot = jnp.maximum(prefill_slot, 0)
                 row = tables[slot]
                 if fused_prefill:
-                    # the fused lane: the pool IS the cache — the
-                    # model's paged-prefill branch scatters the CH-wide
-                    # chunk at the table-named write indices and
-                    # `paged_prefill` streams block tiles, so the
-                    # [L, 1, G, Hkv, hd] gather never exists. The full
-                    # CH-wide write stays safe past a partial tail
-                    # chunk for the same reason as the reference lane:
-                    # tail garbage lands in OWNED blocks and is
-                    # overwritten before any mask exposes it.
-                    from ray_lightning_tpu.ops.attention import (
-                        PagedPrefillView,
-                    )
-
-                    wpos = prefill_pos + jnp.arange(CH)
-                    ring = {}
-                    if window is not None:
-                        # the window group's row: the blocks the chunk
-                        # writes and its first row sees back to
-                        wrow = window_ring_table(
-                            spec, slot,
-                            jnp.maximum(prefill_pos - window + 1, 0),
-                            prefill_pos + CH - 1)
-                        ring = dict(window_tables=wrow,
-                                    window_write_block=wrow[:, wpos // P])
-                    tables1, wblock = row[None], row[wpos // P][None]
-                    if slot_state:
-                        # the chunk's REAL rows: from the slot's first
-                        # unsent position (the scheduler may have slid the
-                        # window back over rows it sent before) to the
-                        # prompt's last (zeros follow it). A recurrence
-                        # advances on those alone, and a row sent before
-                        # computes its K/V from a state that has moved on:
-                        # it goes to the scratch block, the first stays
-                        first = jnp.clip(pos[slot] - prefill_pos, 0, CH)
-                        last = jnp.where(prefill_last_row >= 0,
-                                         prefill_last_row, CH - 1)
-                        chunk_rows = jnp.arange(CH)[None]
-                        wblock = jnp.where((chunk_rows >= first)
-                                           & (chunk_rows <= last), wblock, 0)
-                        ring.update(state_slot=slot,
-                                    real_rows=jnp.stack([first, last]))
-                    view = PagedPrefillView(
-                        tables=tables1, write_block=wblock,
-                        write_offset=(wpos % P)[None], use_pallas=True,
-                        **ring)
+                    view = _chunk_view(slot, row, pos, prefill_pos,
+                                       prefill_last_row)
                     logits, pool, pf_counts = _paged_apply(
                         params, prefill_tokens[None], pool, prefill_pos,
                         None, view)
@@ -1201,6 +1288,8 @@ class DecodeEngine:
             # speculative_plan charges the gathered views.
             self.fused = False
             self.fused_prefill = False
+        #: a tick's chunk rides the decode lane's pass (`build_step`)
+        self.joined = joins_lanes(model, cfg, self.fused, self.fused_prefill)
         #: tokens of the fused decode kernel's KV tile (`_step_work`'s
         #: ``decode_tiles``); None on the reference lane and for a
         #: decoder that states none
@@ -1566,6 +1655,11 @@ class DecodeEngine:
                         are not work); 0 without a chunk
           prefill_ctx   tokens already in those rows' caches before the
                         chunk (summed over the group's rows)
+          joined_rows   rows of the chunk that went through the model in
+                        the decode lane's pass, the weights read once for
+                        both: the whole chunk's CH (pad and tail rows ride
+                        too) in a tick with a chunk of a decoder that joins
+                        its lanes (`joins_lanes`), else 0
           prefill_tiles KV tiles a layer the prefill kernel computes for
                         the chunk: for each row of the group (a vacant
                         one rides the scratch table and is computed too)
@@ -1617,6 +1711,8 @@ class DecodeEngine:
             "kv_tokens": int(lengths.sum()),
             "prefill_rows": int(((cols - lead) * active).sum()),
             "prefill_ctx": int((np.maximum(start - pads, 0) * active).sum()),
+            "joined_rows": (self.cfg.prefill_chunk
+                            if self.joined and active.any() else 0),
         }
         window = self.model.kv_window
         if window is not None:

@@ -7,11 +7,13 @@ others' programs did not change.
     python3 scripts/step_jaxpr_same.py _parent
 
 Traces `build_step` on the CPU at each decoder's tiny size (the dense, the
-latent-attention, the window, the state-space and the delta-rule decoder),
-both lanes fused
+latent-attention, the window, the state-space, the delta-rule and the
+short-convolution decoder), both lanes fused
 and (for the dense decoder) both on the reference lanes, and the dense
 decoder's batched-prefill step; each checkout in a process of its own.
 Prints SAME or DIFFERENT a program and exits non-zero on any difference.
+A decoder whose step a PR means to change reads DIFFERENT, and only that one
+(PR 45: `llama (True, True)`, the step that joins its lanes).
 """
 import os
 import subprocess
@@ -26,6 +28,7 @@ from ray_lightning_tpu.models.mla_moe import MlaMoe, MlaMoeConfig
 from ray_lightning_tpu.models.window_moe import WindowMoe, WindowMoeConfig
 from ray_lightning_tpu.models.ssm_hybrid import SsmHybrid, SsmHybridConfig
 from ray_lightning_tpu.models.delta_hybrid import DeltaHybrid, DeltaHybridConfig
+from ray_lightning_tpu.models.conv_moe import ConvMoe, ConvMoeConfig
 from ray_lightning_tpu.serve.engine import EngineConfig, build_step, idle_prefill
 from ray_lightning_tpu.serve.kv_cache import init_pool, state_pool_spec, window_pool_spec
 for name, cls, cfg, ekw in (
@@ -35,6 +38,7 @@ for name, cls, cfg, ekw in (
     ("window_moe", WindowMoe, WindowMoeConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
     ("ssm_hybrid", SsmHybrid, SsmHybridConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
     ("delta_hybrid", DeltaHybrid, DeltaHybridConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
+    ("conv_moe", ConvMoe, ConvMoeConfig.tiny(), dict(capacity=4, block_size=16, blocks_per_slot=8, prefill_chunk=16)),
 ):
     model = cls(cfg); ecfg = EngineConfig(**ekw)
     params = jax.eval_shape(model.init, jax.random.key(0), jnp.zeros((1,8),jnp.int32))["params"]

@@ -475,13 +475,15 @@ def test_fsdp4_cell_layers_move_weights_not_activations(v5e, as_on_tpu):
 
 # ---- the serving step -------------------------------------------------------
 
-def _serve_step_lowered(v5e, tp: int, param_dtype=None, **engine):
+def _serve_step_lowered(v5e, tp: int, param_dtype=None, joins=True,
+                        **engine):
     """Lower `build_step` as `DecodeEngine` jits it, params and pool
     abstract. tp == 1: one device, both fused lanes. tp > 1: a tensor
     mesh, where the engine takes the reference lanes. ``engine``
     overrides fields of the smoke's `EngineConfig`; ``param_dtype``
     casts the (float32-initialised) weights, as a served checkpoint
-    is."""
+    is. ``joins=False``: the dense decoder without `joins_lanes`, which
+    gets the two-pass step the other decoders still have."""
     import chip_smoke
     from ray_lightning_tpu.models.llama import Llama, LlamaConfig
     from ray_lightning_tpu.ops.attention import (
@@ -496,7 +498,8 @@ def _serve_step_lowered(v5e, tp: int, param_dtype=None, **engine):
     size = chip_smoke.SmokeSize.full()
     cfg = LlamaConfig(**size.model)
     ecfg = EngineConfig(**{**size.engine, **engine})
-    model = Llama(cfg)
+    model = Llama(cfg) if joins else type(
+        "TwoPassLlama", (Llama,), {"joins_lanes": False})(cfg)
     spec = ecfg.pool_spec
     pool_shape = (cfg.n_layers, spec.n_blocks, spec.block_size,
                   cfg.n_kv_heads, cfg.head_dim)
@@ -592,9 +595,13 @@ def _materialised_results(hlo: str, floor: int):
             for comp, opcode, name in rows if comp not in fused]
 
 
-@pytest.mark.parametrize("prefill_batch", [1, 2])
+@pytest.mark.parametrize("prefill_batch,joins", [
+    (1, True),      # the step that joins its lanes: a pass a cond
+    (2, True),      # a batched group never joins: the two-pass step
+    (1, False),     # the two-pass step of a decoder that does not join
+])
 def test_serving_step_moves_no_layer_of_the_pool(v5e, as_on_tpu,
-                                                 prefill_batch):
+                                                 prefill_batch, joins):
     """ISSUE 25: the stacked pool is carried through the layer scan and
     the kernels index the layer, so the compiled fused step holds no
     instruction that moves a layer's K pool (67 MB here) or more: the
@@ -603,13 +610,16 @@ def test_serving_step_moves_no_layer_of_the_pool(v5e, as_on_tpu,
     that write a tick's token rows into the carried stack. That those
     are in place is what the second assertion says: the compiler plans
     less than ONE K pool of temporaries (it planned a whole second
-    K + V pool while the pool rode the scan as xs / ys)."""
+    K + V pool while the pool rode the scan as xs / ys). The joined step
+    (ISSUE 45) holds its two passes in a cond each: in branch 0 of ONE
+    cond the decode pass copied the stack in and out of every layer
+    (1.6 GB of temporaries here)."""
     import chip_smoke
 
     capacity = 32
     compiled = _serve_step_lowered(
-        v5e, tp=1, param_dtype=jnp.bfloat16, capacity=capacity,
-        prefill_batch=prefill_batch).compile()
+        v5e, tp=1, param_dtype=jnp.bfloat16, joins=joins,
+        capacity=capacity, prefill_batch=prefill_batch).compile()
     assert _n_mosaic(compiled) >= 2
     size = chip_smoke.SmokeSize.full()
     m, e = size.model, size.engine
@@ -624,6 +634,42 @@ def test_serving_step_moves_no_layer_of_the_pool(v5e, as_on_tpu,
     assert not moved, moved
     k_pool = m["n_layers"] * layer_bytes
     assert compiled.memory_analysis().temp_size_in_bytes < k_pool
+
+
+def _weight_products(hlo: str) -> dict:
+    """{weight: sorted rows of every matrix product of the optimized module
+    that flax scoped under that weight's name} (a scanned layer's product
+    stands once, in the loop's body)."""
+    import re
+
+    rows = {}
+    for n, name in re.findall(
+            r"= f32\[(\d+),\d+\]\S* convolution\(.*"
+            r"op_name=\"[^\"]*/(\w+)/dot_general\"", hlo):
+        rows.setdefault(name, []).append(int(n))
+    return {name: sorted(r) for name, r in rows.items()}
+
+
+@pytest.mark.parametrize("joins", [True, False])
+def test_joined_step_reads_each_weight_once_a_tick(v5e, as_on_tpu, joins):
+    """ISSUE 45: where the dense decoder joins its lanes, the branch of a
+    tick with a chunk holds ONE product a weight a layer, over the C decode
+    rows and the chunk's CH together, and its head reads C + 1 rows; the
+    other branch is the decode pass over C. The two-pass step holds a
+    product over C and one over CH a weight, and a head over the chunk."""
+    import chip_smoke
+
+    c = 32
+    ch = chip_smoke.SmokeSize.full().engine["prefill_chunk"]
+    compiled = _serve_step_lowered(
+        v5e, tp=1, param_dtype=jnp.bfloat16, joins=joins,
+        capacity=c).compile()
+    got = _weight_products(compiled.as_text())
+    layer, head = ([c, c + ch], [c, c + 1]) if joins else ([c, ch],) * 2
+    assert got == {"wqkv": layer, "wo": layer, "w_gate_up": layer,
+                   "w_down": layer, "lm_head": head}
+    # a decode kernel a pass, and the chunk's prefill kernel
+    assert _n_mosaic(compiled) == (3 if joins else 2)
 
 
 def test_serving_step_lowers_under_tensor_parallel(v5e, as_on_tpu):

@@ -157,7 +157,10 @@ def _expected_work(sched, ecfg):
             "kv_tokens": sum(lengths),
             # the kernel's own tile: a slot costs ceil(length / tile)
             "decode_tiles": sum(-(-n // tile) for n in lengths),
-            "prefill_rows": 0, "prefill_ctx": 0, "prefill_tiles": 0}
+            "prefill_rows": 0, "prefill_ctx": 0, "prefill_tiles": 0,
+            # the dense decoder joins its lanes: a chunk rides the decode
+            # lane's pass whole, its padding too
+            "joined_rows": ecfg.prefill_chunk if sched.prefill_groups else 0}
     if sched.prefill_groups:
         slot = sched.slots[sched.prefill_groups[0].slots[0]]
         done, size = slot.prefill_next, slot.req.prompt.size
@@ -308,7 +311,8 @@ def test_driver_phase_brackets_the_scheduler_tick(traced, phase):
 @pytest.mark.parametrize("counter", ["decode_slots", "kv_tokens",
                                      "decode_tiles", "prefill_rows",
                                      "prefill_ctx", "prefill_tiles",
-                                     "sampled_slots", "topk_slots"])
+                                     "sampled_slots", "topk_slots",
+                                     "joined_rows"])
 def test_dispatch_counters_equal_the_schedulers_own(traced, counter):
     """The context convention must not over-count: a roofline share over
     105% is refused by the benchmark's driver."""
