@@ -23,13 +23,21 @@ tied embeddings.
     head of q and of k, rotation at ``rope_theta`` (rotate-half), causal
     softmax at scale hd^-0.5, GQA, o (H x hd -> D), no biases.
 
-Three calling conventions, one set of parameters (as `models/ssm_hybrid.py`):
+Four calling conventions, one set of parameters (as `models/ssm_hybrid.py`):
 
   * ``model(tokens)`` -> logits: the full forward pass from a zero state;
   * ``model(tokens, cache=pool, pos=.., paged=PagedPrefillView)``: a chunk
     of one slot's prompt;
   * ``model(tokens, cache=pool, pos=.., paged=PagedDecodeView)``: one token
-    a slot.
+    a slot;
+  * ``model(tokens, cache=pool, pos=.., paged=PagedJoinedView)``: both in
+    ONE pass (`joins_lanes`; a tick that carries a chunk): ``B == 1``, the
+    rows are the ``C`` slots' decode tokens and then the chunk's ``CH``,
+    ``pos`` each row's cache position. Every product that reads weights
+    runs once over all rows, so a layer's experts are streamed once a
+    tick; the rows part only where a lane has a state of its own: the
+    attention kernels and the convolution's tails. The head reads the
+    decode rows and the one chunk row the view's ``last_row`` keeps.
 
 The paged calls return ``(logits, pool, counts)``. **The pool has two
 groups** (`ConvMoeConfig.pool_leaf_shapes`): the attention layers' K and V,
@@ -220,6 +228,14 @@ class ConvMoeConfig:
         return cls(**base)
 
 
+def _is_joined_view(paged) -> bool:
+    """Is this paged view a tick's two lanes joined (the decode rows, then
+    the chunk's, in one call)?"""
+    from ray_lightning_tpu.ops.attention import PagedJoinedView
+
+    return isinstance(paged, PagedJoinedView)
+
+
 class ConvMoeBlock(nn.Module):
     """One layer: ``attention`` says which op, ``dense`` which ffn.
     ``group_layer`` is its index among the layers of its op's kind (its row
@@ -247,8 +263,11 @@ class ConvMoeBlock(nn.Module):
         q = head_norm("q_norm", _mm(u, wq, dt).reshape(b, s, nh, hd))
         k = head_norm("k_norm", _mm(u, wk, dt).reshape(b, s, nkv, hd))
         v = _mm(u, wv, dt).reshape(b, s, nkv, hd)
+        joined = _is_joined_view(paged)
         if cache is None:
             positions = None
+        elif joined:               # a position a row, decode rows first
+            positions = pos[None, :]
         elif pos.ndim == 0:        # a chunk: token j at pos + j
             positions = jnp.broadcast_to(
                 pos + jnp.arange(s)[None, :], (b, s))
@@ -271,17 +290,38 @@ class ConvMoeBlock(nn.Module):
                        pair_kv_heads(v))
         pk, pv = cache[:2]
         prefill = isinstance(paged, PagedPrefillView)
-        assert prefill or s == 1, "the decode path takes one token a slot"
-        rows = (lambda x: x) if prefill else (lambda x: x[:, 0])
+        if joined:
+            # both lanes' rows in one call: the decode rows land at their
+            # (scratch-redirected) write index and the chunk's through its
+            # own, ONE scatter a leaf. The slot that takes the chunk is not
+            # decoding, so neither lane sees a row the other wrote
+            assert b == 1, "one chunk a tick"
+            dec, chunk = paged.decode, paged.prefill
+            n_dec = dec.tables.shape[0]
+            rows = lambda x: x[0]
+            at = (group_layer,
+                  jnp.concatenate([dec.write_block, chunk.write_block[0]]),
+                  jnp.concatenate([dec.write_offset, chunk.write_offset[0]]))
+        else:
+            assert prefill or s == 1, "the decode path takes one token a slot"
+            rows = (lambda x: x) if prefill else (lambda x: x[:, 0])
+            at = (group_layer, paged.write_block, paged.write_offset)
         # write-then-attend, the paged lanes' ordering
         with jax.named_scope("kv_pool"):
-            at = (group_layer, paged.write_block, paged.write_offset)
             pk = pk.at[at].set(rows(k).astype(pk.dtype))
             pv = pv.at[at].set(rows(v).astype(pv.dtype))
         # the model's scale, whatever width the kernel sees
         kw = dict(scale=hd ** -0.5, use_pallas=use_pallas,
                   layer=group_layer)
-        if prefill:
+        if joined:
+            # the rows part for the kernels, each called as its own lane
+            # calls it, over the same carried pool
+            out = jnp.concatenate([
+                paged_attention(q[0, :n_dec], pk, pv, dec.tables,
+                                dec.lengths, **kw)[None],
+                paged_prefill(q[:, n_dec:], pk, pv, chunk.tables,
+                              pos[n_dec], **kw)], axis=1)
+        elif prefill:
             out = paged_prefill(q, pk, pv, paged.tables, pos, **kw)
         else:
             out = paged_attention(q[:, 0], pk, pv, paged.tables,
@@ -313,7 +353,32 @@ class ConvMoeBlock(nn.Module):
         from ray_lightning_tpu.ops.attention import PagedPrefillView
 
         tails = cache[2]
-        if isinstance(paged, PagedPrefillView):
+        if _is_joined_view(paged):
+            # both lanes' rows went through `in_proj` together and go
+            # through `out_proj` together; they part for the convolution
+            # alone, each lane's as its own branch below has it. The slot
+            # that takes the chunk is not decoding: its row of the leaf is
+            # the chunk's, every other row the decode lane's
+            dec, chunk = paged.decode, paged.prefill
+            n_dec = dec.tables.shape[0]
+            slot = chunk.state_slot
+            first, last = chunk.real_rows[0], chunk.real_rows[1]
+            with jax.named_scope("shortconv_state"):
+                tail = tails[group_layer]          # [C, K - 1, Ds, 128]
+                began = lane_join(jnp.where(
+                    pos[n_dec] + first > 0, tail[slot],
+                    0.0).astype(tails.dtype))
+            stepped, moved = causal_conv_update(v[0, :n_dec], tail, conv_w,
+                                                no_bias)
+            chunked, ended = causal_conv(v[0, n_dec:], began, conv_w,
+                                         no_bias, first, last)
+            conv = jnp.concatenate([stepped, chunked])[None]
+            with jax.named_scope("shortconv_state"):
+                moved = jnp.where(dec.state_moves[:, None, None, None],
+                                  moved, tail)
+                tails = tails.at[group_layer].set(
+                    moved.at[slot].set(lane_split(ended)))
+        elif isinstance(paged, PagedPrefillView):
             # one slot's chunk: its real rows, once
             slot = paged.state_slot
             first, last = paged.real_rows[0], paged.real_rows[1]
@@ -348,7 +413,9 @@ class ConvMoeBlock(nn.Module):
         d, dt = cfg.dim, cfg.dtype
         # the view's STATIC use_pallas (the serve engine's build-time
         # decision) pins the kernels; absent that, the ambient policy
-        use_pallas = None if paged is None else paged.use_pallas
+        # (a joined view's lanes carry the same one)
+        lane = paged.decode if _is_joined_view(paged) else paged
+        use_pallas = None if lane is None else lane.use_pallas
         if use_pallas is None and not cfg.use_flash:
             use_pallas = False
         norm = lambda name, v: rms_norm(
@@ -419,6 +486,10 @@ class ConvMoe(nn.Module):
     #: its convolution layers keep a row a slot in the pool
     #: (`serve/kv_cache.py` "a row a slot")
     slot_state = True
+    #: a `PagedJoinedView` is served: a tick's decode rows and its prefill
+    #: chunk in one pass, every expert layer streamed once
+    #: (`serve/engine.py:joins_lanes`)
+    joins_lanes = True
 
     @property
     def tick_counters(self):
@@ -490,23 +561,28 @@ class ConvMoe(nn.Module):
                              "pass cache=<its three leaves> together with "
                              "paged=<view>")
         slot_counts = None
+        joined = _is_joined_view(paged)
         if paged is not None:
             from ray_lightning_tpu.ops.attention import PagedPrefillView
 
-            if isinstance(paged, PagedPrefillView):
-                if paged.real_rows is None or paged.state_slot is None:
-                    raise ValueError(
-                        "ConvMoe's prefill view names the chunk's real "
-                        "rows and its slot (real_rows, state_slot)")
-                first, last = paged.real_rows[0], paged.real_rows[1]
-                slot_counts = jnp.stack([jnp.maximum(last - first + 1, 0),
-                                         jnp.int32(0)])
-            else:
-                if paged.state_moves is None:
-                    raise ValueError("ConvMoe's decode view says whose "
-                                     "tails move (state_moves)")
-                slot_counts = jnp.stack([jnp.int32(0), jnp.sum(
-                    paged.state_moves.astype(jnp.int32))])
+            # the slot-state pair, each half from the lane that has it
+            lanes = (paged.decode, paged.prefill) if joined else (paged,)
+            conv_rows = state_slots = jnp.int32(0)
+            for lane in lanes:
+                if isinstance(lane, PagedPrefillView):
+                    if lane.real_rows is None or lane.state_slot is None:
+                        raise ValueError(
+                            "ConvMoe's prefill view names the chunk's real "
+                            "rows and its slot (real_rows, state_slot)")
+                    first, last = lane.real_rows[0], lane.real_rows[1]
+                    conv_rows = jnp.maximum(last - first + 1, 0)
+                else:
+                    if lane.state_moves is None:
+                        raise ValueError("ConvMoe's decode view says whose "
+                                         "tails move (state_moves)")
+                    state_slots = jnp.sum(
+                        lane.state_moves.astype(jnp.int32))
+            slot_counts = jnp.stack([conv_rows, state_slots])
         embed = self.param("tok_embed", _normal(), (cfg.vocab_size, cfg.dim))
         x = embed[tokens].astype(cfg.dtype)
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
@@ -548,6 +624,14 @@ class ConvMoe(nn.Module):
                 counted.append(out)
         x, new_cache = carry
 
+        if joined:
+            # the head reads the C decode rows and the ONE row of the chunk
+            # the step keeps, taken before the product: [C + 1, V] logits,
+            # not [C + CH, V]
+            n_dec = paged.decode.tables.shape[0]
+            x = jnp.concatenate([
+                x[:, :n_dec], jax.lax.dynamic_slice_in_dim(
+                    x, n_dec + paged.last_row, 1, axis=1)], axis=1)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
                                    (cfg.dim,)), cfg.norm_eps,
                      use_pallas=False)

@@ -256,7 +256,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     hands them back. A model with `tick_counters` returns their values
     from a paged call too; the step joins the two lanes' as each
     counter says (sum / max, or the union of a bitset, counted once the
-    lanes are joined: `_join_words`) and returns them after ``emitted``.
+    lanes are joined: `_join_words`) and returns them after ``emitted``;
+    the step that joins its lanes (below) returns the ONE call's, which
+    already hold both lanes' rows.
     A decoder with sliding-window layers (`model.kv_window`) has a second
     group of leaves, a ring a slot (`serve/kv_cache.py` "two groups"): the step
     makes that group's table rows from the positions it holds
@@ -276,14 +278,16 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
     (``prefill_batch == 1``), the step is ``sample``, then ONE model call:
     over ``C + CH`` rows in a tick that carries a chunk, each lane's view
     what its own call would be handed, and the decode lane's call alone
-    in a tick without. Same arguments, results and donated buffers as the
-    two-pass step, which every other case keeps: the reference lanes (the
+    in a tick without. A counting decoder's counts ride the two passes'
+    conds (zeros in; the pass that runs puts its own in their place).
+    Same arguments, results and donated buffers as the two-pass step,
+    which every other case keeps: the reference lanes (the
     bitwise anchor against `generate()`), a batched prefill group, the
     speculative step, and the decoders that have no joined branch yet
     (their programs are untouched: `scripts/step_jaxpr_same.py`). The
     choice is read off the model and the build's own arguments: no
     option selects it. The two-pass path goes when the last decoder has
-    joined (ROADMAP Queue 1 item 2).
+    joined (ROADMAP Queue 1 item 1).
 
     ``fused`` selects the decode lane at BUILD time (the dispatch
     decision is static, like a kernel choice — it can never retrace):
@@ -342,6 +346,9 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         return out[0], tuple(out[1]), (out[2] if counters else None)
 
     unions = any(c[1] == "union" for c in counters)
+    # int32 words of one paged call's counts (a bitset takes its own)
+    n_count_words = sum(span.stop - span.start
+                        for _, span in _counter_spans(counters))
 
     def _join(a, b):
         # a tick's two lanes' counts, each as its counter says
@@ -350,6 +357,17 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
         return jnp.stack([a[i] + b[i] if how == "sum"
                           else jnp.maximum(a[i], b[i])
                           for i, (_, how) in enumerate(counters)])
+
+    def _counted(counts):
+        # a paged call's counts as the step carries them: none where the
+        # decoder has no `tick_counters`
+        return (counts,) if counters else ()
+
+    def _settled(counted):
+        # a tick's counts as the step returns them, after ``emitted``
+        if unions:
+            return (_settle_words(counters, counted[0]),)
+        return counted
 
     def _decode_one(params, tok, kc, vc, pos):
         # the model's OWN single-token cache path ([1, 1] batch), new
@@ -560,11 +578,12 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
             if joined:
                 def decode_lane(*carried):
                     # a tick WITHOUT a chunk: the decode lane alone
-                    pool, logits2, _ = _decode_fused(
+                    pool, logits2, counts = _decode_fused(
                         params, carried[:n_pool], tables, pos, decoding,
                         emitted, None)
                     return (*pool, jnp.where(decoding[:, None], logits2,
-                                             carried[n_pool]))
+                                             carried[n_pool]),
+                            *_counted(counts))
 
                 def both_lanes(*carried):
                     # a tick WITH a chunk: the C decode rows and the CH
@@ -582,7 +601,7 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                         _decode_view(tables, pos, decoding),
                         _chunk_view(slot, tables[slot], pos, prefill_pos,
                                     prefill_last_row), prefill_last_row)
-                    logits, pool, _ = _paged_apply(
+                    logits, pool, counts = _paged_apply(
                         params,
                         jnp.concatenate([emitted, prefill_tokens])[None],
                         carried[:n_pool],
@@ -598,7 +617,7 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                         (jnp.arange(C) == slot)[:, None]
                         & (prefill_last_row >= 0),
                         logits[0, C][None, :], last_logits)
-                    return (*pool, last_logits)
+                    return (*pool, last_logits, *_counted(counts))
 
                 # one of the two runs, each the TRUE branch of a cond of its
                 # own beside one that passes the pool through: XLA orders a
@@ -607,12 +626,17 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                 # in place from branch 0 of ONE cond over both passes would
                 # copy every layer's stack in and out
                 # (tests/test_tpu_aot_compile.py pins the compile)
+                # (a decoder that counts returns the one call's counts: the
+                # pass that runs puts them in place of the zeros)
+                no_counts = (jnp.zeros((n_count_words,), jnp.int32),
+                             ) if counters else ()
                 carried = jax.lax.cond(
                     prefill_slot >= 0, both_lanes, lambda *a: a,
-                    *pool, last_logits)
+                    *pool, last_logits, *no_counts)
                 carried = jax.lax.cond(
                     prefill_slot < 0, decode_lane, lambda *a: a, *carried)
-                return (*carried, new_rngs, emitted)
+                return (*carried[:n_pool + 1], new_rngs, emitted,
+                        *_settled(carried[n_pool + 1:]))
             pool, logits2, counts = _decode(
                 params, pool, tables, pos, decoding, emitted, None)
             last_logits = jnp.where(decoding[:, None], logits2,
@@ -657,13 +681,11 @@ def build_step(model, cfg: EngineConfig, fused: bool = False,
                             _join(carried[-1], pf_counts))
                 return (*pool, last_logits)
 
-            carried = (*pool, last_logits) + ((counts,) if counters else ())
+            carried = (*pool, last_logits, *_counted(counts))
             carried = jax.lax.cond(
                 prefill_slot >= 0, do_prefill, lambda *a: a, *carried)
-            counted = carried[n_pool + 1:]
-            if unions:
-                counted = (_settle_words(counters, counted[0]),)
-            return (*carried[:n_pool + 1], new_rngs, emitted, *counted)
+            return (*carried[:n_pool + 1], new_rngs, emitted,
+                    *_settled(carried[n_pool + 1:]))
 
         return step
 

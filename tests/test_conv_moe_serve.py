@@ -7,7 +7,10 @@ convolution's tail (a partial last chunk, the chunk slid back at a slot's
 end, a slot's second request, the prefilling slot under the decode lane, a
 preempted request's replay), each against the full forward pass's LOGITS;
 that the engine joins a tick's two lanes' expert bitsets by union; and what
-the engine refuses for it."""
+the engine refuses for it. Since ISSUE 46 the decoder joins its lanes: the
+engine builds the joined step for it, so every tick with a chunk below is
+ONE pass over the decode rows and the chunk (`tests/test_serve_joined.py`
+holds that step against the two-pass one)."""
 import contextlib
 import os
 import re
@@ -377,15 +380,25 @@ def test_the_ticks_annotations_carry_the_experts_and_the_tails_counters(
         "state_slots"}
     pairs = cfg.n_expert_layers * cfg.held
     k, layers = cfg.n_experts_per_tok, cfg.n_expert_layers
+    chunk_ticks = 0
     for s, work in zip((s for s in seen["serve.account"]
                         if "experts_hit" in s), seen["serve.dispatch"]):
-        # the decode lane routes all 3 slots' rows, a chunk its 16
-        rows = (3 + (16 if work["prefill_rows"] else 0)) * k * layers
+        # the decode lane routes all 3 slots' rows, a chunk its 16: in ONE
+        # product a layer since the decoder joins its lanes
+        tokens = 3 + (16 if work["prefill_rows"] else 0)
+        assert work["joined_rows"] == (16 if work["prefill_rows"] else 0)
+        chunk_ticks += bool(work["joined_rows"])
+        rows = tokens * k * layers
         assert s["expert_rows"] == rows
-        # at least an expert a layer, at most every pair or every row, and
-        # two lanes hit no more than their sum
+        # at least an expert a layer, at most every pair or every row
         assert layers <= s["experts_hit"] <= min(pairs, rows)
-        assert s["expert_rows_max"] * s["experts_hit"] >= rows // 2
+        # the fullest expert of the tick's ONE product a layer, in a tick
+        # with a chunk too: the pairs hit hold every row between them (two
+        # lanes' separate maxima pinned half of this), and an expert gets a
+        # token at most once, the decode rows' and the chunk's together
+        assert s["expert_rows_max"] * s["experts_hit"] >= rows
+        assert s["expert_rows_max"] <= tokens
+    assert chunk_ticks == 4
     assert {s["state_slots_live"] for s in seen["serve.account"]} <= {0, 1, 2}
 
 

@@ -672,6 +672,114 @@ def test_joined_step_reads_each_weight_once_a_tick(v5e, as_on_tpu, joins):
     assert _n_mosaic(compiled) == (3 if joins else 2)
 
 
+def _conv_moe_step_compiled(v5e, joins: bool):
+    """`build_step` for the expert decoder of gated short convolutions at
+    its published widths (64 experts of 1536 a layer, heads of 64 in
+    pairs, vocabulary 65,536; bfloat16) over four layers: a dense
+    convolution layer, an attention layer and two convolution layers with
+    experts. 64 slots of 2,176 tokens (a K leaf of 143 MB: one that fits
+    the chip's 128 MiB of VMEM the compiler may park there whole, which no
+    serving pool does), chunks of 256: (cfg, engine config, the pool's
+    leaves, compiled)."""
+    from ray_lightning_tpu.models.conv_moe import ConvMoe, ConvMoeConfig
+    from ray_lightning_tpu.serve.engine import (
+        EngineConfig, build_step, idle_prefill,
+    )
+    from ray_lightning_tpu.serve.kv_cache import init_pool, state_pool_spec
+
+    cfg = ConvMoeConfig(
+        layer_types=("conv", "full_attention", "conv", "conv"),
+        n_dense_layers=1, dtype=jnp.bfloat16)
+    ecfg = EngineConfig(capacity=64, block_size=128, blocks_per_slot=17,
+                        prefill_chunk=256)
+    model = ConvMoe(cfg) if joins else type(
+        "TwoPassConvMoe", (ConvMoe,), {"joins_lanes": False})(cfg)
+    assert model.paged_lanes(ecfg.capacity, 1, ecfg.prefill_chunk,
+                             (ecfg.n_blocks, ecfg.block_size), None) == (
+                                 True, True)
+    one = SingleDeviceSharding(v5e[0])
+    a_params = jax.tree.map(
+        # the router's weights stay float32 in a served checkpoint
+        lambda x: _sds(x.shape, x.dtype if x.ndim == 2 and x.shape[-1]
+                       == cfg.n_routed_experts else jnp.bfloat16, one),
+        jax.eval_shape(model.init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    spec = state_pool_spec(ecfg.pool_spec, model.slot_state, ecfg.capacity)
+    pool = [_sds(leaf.shape, leaf.dtype, one)
+            for leaf in jax.eval_shape(lambda: init_pool(cfg, spec))]
+    c = ecfg.capacity
+    runtime = (np.zeros((c, spec.blocks_per_slot), np.int32),
+               np.zeros(c, np.int32), np.zeros(c, bool),
+               np.zeros(c, np.float32), np.zeros(c, np.int32),
+               np.zeros((c, 2), np.uint32), *idle_prefill(ecfg))
+    step = jax.jit(build_step(model, ecfg, fused=True, fused_prefill=True),
+                   donate_argnums=tuple(range(1, len(pool) + 2)))
+    return cfg, ecfg, pool, step.lower(
+        a_params, *pool, _sds((c, cfg.vocab_size), jnp.float32, one),
+        *[_sds(np.shape(x), np.asarray(x).dtype, one)
+          for x in runtime]).compile()
+
+
+@pytest.mark.parametrize("joins", [True, False])
+def test_conv_moe_joined_step_streams_each_layers_experts_once(
+        v5e, as_on_tpu, joins):
+    """ISSUE 46: where the decoder joins its lanes, the branch of a tick
+    with a chunk holds ONE grouped product a weight a layer, over the rows
+    of the C decode tokens and the chunk's CH together, and a head over
+    C + 1 rows; the other branch is the decode pass. The two-pass step
+    holds a product over C tokens' rows and one over CH tokens' a weight,
+    and a head over the chunk. Neither moves a layer of the pool, the
+    tails or a layer's experts."""
+    import re
+
+    from ray_lightning_tpu.models.held_experts import held_rows_bound
+
+    cfg, ecfg, pool, compiled = _conv_moe_step_compiled(v5e, joins)
+    text = compiled.as_text()
+    c, ch = ecfg.capacity, ecfg.prefill_chunk
+    second = c + ch if joins else ch          # tokens of the other pass
+    bounds = sorted(held_rows_bound(cfg, t) for t in (c, second))
+    assert bounds == ([256, 1280] if joins else [256, 1024])
+    # the Mosaic calls by their result: a grouped product's is [rows, N]
+    grouped = {}
+    for rows, n in re.findall(
+            r"= bf16\[(\d+),(\d+)\]\S* custom-call\(.*tpu_custom_call", text):
+        grouped.setdefault(int(n), []).append(int(rows))
+    f, d = cfg.moe_hidden_dim, cfg.dim
+    # two runs of expert layers (the attention layer, the two convolution
+    # layers): a product a weight a run a pass
+    assert {n: sorted(r) for n, r in grouped.items()} == {
+        2 * f: sorted(bounds * 2), d: sorted(bounds * 2)}
+    # + a paged kernel a lane on the attention run: the decode kernel in
+    # both passes of the joined step
+    assert _n_mosaic(compiled) == 8 + (3 if joins else 2)
+    head = sorted(int(n) for n in re.findall(
+        r"= f32\[(\d+),65536\]\S* convolution\(", text))
+    assert head == ([c, c + 1] if joins else [c, ch])
+    # no leaf of the pool copied, no layer of K or V copied or sliced out
+    # (a layer's row of the tails, 1 MB, is what a convolution layer reads
+    # and writes), and no layer's experts
+    for leaf, layers in zip(pool, (r"(\d+,)?", r"(\d+,)?",
+                                   f"{pool[2].shape[0]},")):
+        rest = ",".join(str(x) for x in leaf.shape[1:])
+        assert not re.search(
+            r"= \(?(bf16|f32)\[" + layers + rest + r"\][^ ]* "
+            r"(copy|copy-start|dynamic-slice)\(", text), leaf.shape
+    assert not re.search(
+        r"= \(?bf16\[(\d+,)?64,(2048,3072|1536,2048)\]\{[^}]*\},? "
+        r".*(copy|copy-start|fusion|dynamic-slice)\(", text)
+    # nothing as large as a layer of K (143 MB; a layer's experts are 1.2
+    # GB) is materialised but the scatters that write a tick's rows into
+    # the carried stack
+    layer_bytes = int(np.prod(pool[0].shape[1:])) * 2
+    moved = [row for row in _materialised_results(text, layer_bytes)
+             if row[0] not in _MOVES_NOTHING
+             and row[0] not in ("scatter", "fusion:scatter",
+                                "fusion:bitcast")]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_bytes
+
+
 def test_serving_step_lowers_under_tensor_parallel(v5e, as_on_tpu):
     """A sharded replica takes the reference lanes (XLA cannot partition
     a Mosaic call): the step must lower with no Mosaic kernel in it."""
