@@ -399,7 +399,7 @@ def _trace_summary() -> dict:
             "hbm_budget_bytes": report.hbm_budget_bytes,
             "assumed_device_kind": topo.device_kind,
             "findings": len(report.findings),
-        }, **_overlap_summary(cfg, topology_for_kind)}
+        }}
     except Exception as exc:  # noqa: BLE001 — advisory data only; an
         # analysis bug must never cost the bench its perf evidence
         return {"tracecheck_error":
@@ -502,43 +502,6 @@ def _multislice_summary() -> dict:
         }
     except Exception as exc:  # noqa: BLE001 — advisory data only
         return {"multislice_error":
-                f"{type(exc).__name__}: {str(exc)[:200]}"}
-
-
-def _overlap_summary(cfg, topology_for_kind) -> dict:
-    """Static overlap audit for the bench JSON (ISSUE 6): the bench
-    model's ZeRO step on an 8-chip FSDP slice with the double-buffered
-    prefetch schedule on, classified hidden-vs-exposed by tracecheck's
-    roofline model. Like `_trace_summary`, pure jaxpr work — carried on
-    every line (success or backend-down) so the overlap evidence never
-    depends on a live TPU. The headline `overlap_hidden_fraction` is
-    duplicated at top level for the bench_gate ratchet."""
-    try:
-        from ray_lightning_tpu.analysis.tracecheck import audit_step
-        from ray_lightning_tpu.models.llama import LlamaModule
-        from ray_lightning_tpu.parallel.strategy import ShardedMesh
-
-        topo = topology_for_kind("TPU v5e", 8)
-        seq = min(2048, cfg.max_seq_len)
-        report = audit_step(
-            LlamaModule(cfg), ShardedMesh(fsdp=8, overlap="on"),
-            {"tokens": np.zeros((8, seq + 1), np.int32)},
-            topology=topo, label="bench flagship overlap=on")
-        ov = report.overlap or {}
-        return {
-            "overlap_hidden_fraction": round(
-                report.overlap_hidden_fraction, 4),
-            "overlap": {
-                "scheduled": bool(ov.get("scheduled")),
-                "ici_hidden_us": round(report.ici_hidden_us, 1),
-                "ici_exposed_us": round(report.ici_exposed_us, 1),
-                "ici_bytes_per_step": report.ici_bytes_per_step,
-                "assumed_topology": topo.name,
-                "findings": len(report.findings),
-            },
-        }
-    except Exception as exc:  # noqa: BLE001 — advisory data only
-        return {"overlap_error":
                 f"{type(exc).__name__}: {str(exc)[:200]}"}
 
 

@@ -212,12 +212,6 @@ def _plan_trace_section(args, module_factory, strategy_factory,
         return {
             "ici_bytes_per_step": report.ici_bytes_per_step,
             "ici_time_us": round(report.ici_time_us, 1),
-            "ici_hidden_us": round(report.ici_hidden_us, 1),
-            "ici_exposed_us": round(report.ici_exposed_us, 1),
-            "overlap_hidden_fraction": round(
-                report.overlap_hidden_fraction, 4),
-            "overlap_scheduled": bool(
-                (report.overlap or {}).get("scheduled")),
             "peak_hbm_bytes": report.peak_hbm_bytes,
             "hbm_budget_bytes": report.hbm_budget_bytes,
             "fits": report.fits,
@@ -240,12 +234,6 @@ def _print_trace_section(trace: dict) -> None:
           f"est. peak HBM {trace['peak_hbm_bytes'] / gib:.2f} GiB vs "
           f"budget {trace['hbm_budget_bytes'] / gib:.2f} GiB "
           f"({'fits' if trace['fits'] else 'DOES NOT FIT'})")
-    print(f"  overlap: "
-          f"{'prefetch schedule' if trace.get('overlap_scheduled') else 'no prefetch schedule'}"
-          f" — {trace.get('overlap_hidden_fraction', 0.0):.0%} of "
-          f"prefetchable collective time hidden "
-          f"({trace.get('ici_hidden_us', 0.0) / 1e3:.1f} ms hidden, "
-          f"{trace.get('ici_exposed_us', 0.0) / 1e3:.1f} ms exposed)")
     for f in trace["findings"]:
         print(f"  {f['severity']} {f['rule']} ({f['name']}): "
               f"{f['message']}")
@@ -423,7 +411,6 @@ def run_plan(args) -> int:
         dp_degree,
         find_max_local_batch,
         llama_activation_bytes,
-        llama_overlap_buffer_bytes,
         plan_train_memory,
     )
     from ray_lightning_tpu.parallel.strategy import ShardedMesh
@@ -458,25 +445,7 @@ def run_plan(args) -> int:
 
     def _strategy():
         return ShardedMesh(data=args.data, fsdp=args.fsdp,
-                           tensor=args.tensor, overlap=args.overlap)
-    # the double-buffer HBM the overlap schedule holds beyond the naive
-    # ZeRO path — charged on top of the activation bound so RLT302 /
-    # the FITS verdict stay honest with overlap= on (and named in the
-    # output: a surprise half-GiB would otherwise hide in "acts")
-    overlap_bytes = llama_overlap_buffer_bytes(
-        cfg, fsdp=args.fsdp, tensor=args.tensor, mode=args.overlap) \
-        if args.overlap != "off" else 0
-
-    def _print_overlap_bytes():
-        if not overlap_bytes:
-            return
-        what = ("in-flight grad shard — serial ablation: no double "
-                "buffer, no rolled xs" if args.overlap == "serial" else
-                "one prefetched layer gathered over fsdp + rolled xs "
-                "shard + in-flight grad shard")
-        print(f"overlap double-buffer: "
-              f"{overlap_bytes / 1024**2:.1f} MiB/device ({what}) "
-              f"charged in the activation bound")
+                           tensor=args.tensor)
     n_devices = args.data * args.fsdp * args.tensor
     dp = dp_degree(MeshSpec(data=args.data, fsdp=args.fsdp,
                             tensor=args.tensor))
@@ -502,8 +471,7 @@ def run_plan(args) -> int:
                                                   np.int32)},
                 activation_bytes_fn=lambda b: llama_activation_bytes(
                     cfg, b, args.seq,
-                    weight_shard_degree=args.fsdp * args.tensor)
-                + overlap_bytes,
+                    weight_shard_degree=args.fsdp * args.tensor),
                 device_kind=args.device_kind,
                 hbm_bytes_per_device=args.hbm_bytes,
             )
@@ -518,8 +486,6 @@ def run_plan(args) -> int:
                 "max_global_batch": local * dp,
                 "dp_degree": dp,
                 "fits": local >= 1,
-                "overlap": args.overlap,
-                "overlap_buffer_bytes": overlap_bytes,
                 "summary": summary,
             }
             trace = None
@@ -533,7 +499,6 @@ def run_plan(args) -> int:
                 print(f"max batch: {local}/device x dp {dp} = "
                       f"{local * dp} global")
                 print(summary)
-                _print_overlap_bytes()
                 if trace is not None:
                     _print_trace_section(trace)
             return 0 if local >= 1 else 1
@@ -545,8 +510,7 @@ def run_plan(args) -> int:
                                               np.int32)},
             activation_bytes_per_device=llama_activation_bytes(
                 cfg, args.batch // dp, args.seq,
-                weight_shard_degree=args.fsdp * args.tensor)
-            + overlap_bytes,
+                weight_shard_degree=args.fsdp * args.tensor),
             device_kind=args.device_kind,
             hbm_bytes_per_device=args.hbm_bytes,
         )
@@ -564,8 +528,6 @@ def run_plan(args) -> int:
             "per_device_bytes": plan.per_device_total,
             "budget_bytes": plan.budget,
             "fits": plan.fits,
-            "overlap": args.overlap,
-            "overlap_buffer_bytes": overlap_bytes,
             "summary": plan.summary(),
         }
         if trace is not None:
@@ -573,7 +535,6 @@ def run_plan(args) -> int:
         print(json.dumps(out))
     else:
         print(plan.summary())
-        _print_overlap_bytes()
         if trace is not None:
             _print_trace_section(trace)
     return 0 if plan.fits else 1
@@ -608,11 +569,6 @@ def main(argv=None) -> int:
     plan_p.add_argument("--ce-inline-bwd", action="store_true",
                         help="plan with the inline-backward fused CE "
                              "(charges its dx + sharded dW residuals)")
-    plan_p.add_argument("--overlap", choices=("off", "on", "serial"),
-                        default="off",
-                        help="plan with the collective-overlap schedule "
-                             "(docs/PERFORMANCE.md): charges the double-"
-                             "buffer HBM and traces the overlapped step")
     plan_p.add_argument("--mu-bf16", action="store_true",
                         help="plan with a bf16 Adam first moment "
                              "(mu_dtype=bfloat16 — halves the mu buffer; "

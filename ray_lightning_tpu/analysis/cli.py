@@ -204,7 +204,7 @@ def run_lint(args) -> int:
 # example (examples parse argv, build trainers, and train).
 
 
-def _build_llama_fsdp(topo, overlap: str = "off"):
+def _build_llama_fsdp(topo):
     import numpy as np
 
     from ray_lightning_tpu.models.llama import LlamaConfig, LlamaModule
@@ -234,10 +234,8 @@ def _build_llama_fsdp(topo, overlap: str = "off"):
         batch, seq = 2 * n, min(256, cfg.max_seq_len)
         label = (f"llama-tiny HSDP(data={data},fsdp={fsdp})" if data > 1
                  else f"llama-tiny FSDP({n})")
-    if overlap != "off":
-        label += f" overlap={overlap}"
     return (LlamaModule(cfg),
-            ShardedMesh(data=data, fsdp=fsdp, overlap=overlap),
+            ShardedMesh(data=data, fsdp=fsdp),
             {"tokens": np.zeros((batch, seq + 1), np.int32)}, label)
 
 
@@ -322,13 +320,6 @@ def add_trace_parser(sub) -> None:
              "itemizes ICI vs DCN bytes per step "
              "(families: v3 v4 v5e v5p v6e cpu)")
     p.add_argument(
-        "--overlap", choices=("off", "on", "serial"), default="off",
-        help="trace the llama targets with the collective-overlap "
-             "schedule (strategy overlap= knob, docs/PERFORMANCE.md "
-             "'collective overlap'); tracecheck then classifies each "
-             "collective hidden-vs-exposed against the prefetch "
-             "schedule it finds in the jaxpr")
-    p.add_argument(
         "--hbm-bytes", type=int, default=None,
         help="per-device usable HBM override in bytes")
     p.add_argument(
@@ -353,18 +344,12 @@ def add_trace_parser(sub) -> None:
                    default=argparse.SUPPRESS)
 
 
-def resolve_trace_target(target: str, topo, overlap: str = "off"):
+def resolve_trace_target(target: str, topo):
     """Resolve a trace target to ``(module, strategy, batch, label)``.
-    Returns None when the target is not recognizable (exit-2 path).
-    ``overlap`` reaches builders that take the knob (the llama FSDP
-    targets); others ignore it silently — the knob is advisory."""
+    Returns None when the target is not recognizable (exit-2 path)."""
     base = os.path.basename(target)
     builder = _TRACE_BUILDERS.get(base) or _TRACE_BUILDERS.get(target)
     if builder is not None:
-        import inspect
-
-        if "overlap" in inspect.signature(builder).parameters:
-            return builder(topo, overlap=overlap)
         return builder(topo)
     if ":" in target and os.sep not in target:
         mod_name, _, fn_name = target.partition(":")
@@ -401,9 +386,7 @@ def run_trace(args) -> int:
     except ValueError as exc:
         return invalid(str(exc))
     try:
-        built = resolve_trace_target(args.target, topo,
-                                     overlap=getattr(args, "overlap",
-                                                     "off"))
+        built = resolve_trace_target(args.target, topo)
     except Exception as exc:  # noqa: BLE001 — a factory that raises is
         # an invalid invocation, not a finding
         return invalid(f"building {args.target!r} failed: "

@@ -32,13 +32,11 @@ Model assumptions (documented in docs/STATIC_ANALYSIS.md):
     which is exactly why the mesh layer places only the `data` axis
     across slices (parallel/mesh.py order_devices_for_slices) and
     tracecheck flags any OTHER axis crossing the boundary (RLT306);
-  * the overlap model (`compute_time_us`, consumed by tracecheck's
-    hidden-vs-exposed classification): a scanned body's per-trip compute
-    window is its counted matmul FLOPs (dot_general only — pallas
-    kernels and elementwise work are NOT counted, an undercount that
-    makes the hidden fraction conservative) over the chip's spec-sheet
-    peak derated by MXU_EFFICIENCY. A prefetch-scheduled collective is
-    hidden up to that window; what does not fit stays exposed.
+  * the compute window (`compute_time_us`, read by `report`'s predicted
+    step floor): the matmul FLOPs tracecheck counts in a step's scanned
+    bodies (dot_general only — pallas kernels and elementwise work are
+    NOT counted) over the chip's spec-sheet peak derated by
+    MXU_EFFICIENCY.
 """
 from __future__ import annotations
 
@@ -154,7 +152,7 @@ class Topology:
     ici_hop_latency_us: float
     hbm_bytes: int        # usable HBM per chip
     #: spec-sheet peak bf16 TFLOP/s per chip — the compute side of the
-    #: overlap model's roofline. None resolves from device_kind via the
+    #: predicted step floor. None resolves from device_kind via the
     #: utils/probe.py table (one source of truth), so a directly
     #: constructed Topology prices compute the same as parse_topology.
     peak_tflops: Optional[float] = None
@@ -257,7 +255,7 @@ def topology_for_kind(device_kind: str, n_devices: int, *,
 
 
 def _peak_tflops(device_kind: str) -> float:
-    """Spec-sheet peak for the overlap roofline — one source of truth
+    """Spec-sheet peak for the compute window — one source of truth
     with the bench/doctor probe (utils/probe.py). The "cpu" pseudo-
     family states its own pseudo-figure like its ICI/DCN rows; any
     other kind outside the table raises."""
@@ -269,18 +267,16 @@ def _peak_tflops(device_kind: str) -> float:
 
 
 #: fraction of spec-sheet peak a well-tuned matmul-dominated step
-#: actually sustains — the compute window for hiding collectives is
-#: charged at peak x efficiency. 0.6 is an assumption no chip run has
-#: confirmed for the current code (ROADMAP Queue 3 item 6); a HIGHER
-#: efficiency would shrink the window and under-claim hiding, a lower
-#: one would over-claim. Documented in docs/STATIC_ANALYSIS.md.
+#: actually sustains — the compute window is charged at peak x
+#: efficiency. 0.6 is an assumption no chip run has confirmed for the
+#: current code (ROADMAP Queue 3 item 6). Documented in
+#: docs/STATIC_ANALYSIS.md.
 MXU_EFFICIENCY = 0.6
 
 
 def compute_time_us(flops: float, topo: Topology) -> float:
     """Time to execute ``flops`` per-device FLOPs on one chip of
-    ``topo`` at the derated roofline — the overlap model's per-trip
-    compute window."""
+    ``topo`` at the derated roofline."""
     if flops <= 0:
         return 0.0
     return flops / (topo.peak_tflops * 1e12 * MXU_EFFICIENCY) * 1e6

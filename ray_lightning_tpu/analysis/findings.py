@@ -102,14 +102,6 @@ RULES: Dict[str, Rule] = {r.id: r for r in (
          "un-prefetched device_put on the critical path — each one "
          "drains the device dispatch queue; fetch on a cadence and use "
          "the device prefetch pipeline (docs/PERFORMANCE.md)"),
-    Rule("RLT305", "exposed-collective-in-scan", "warning",
-         "a blocking collective inside a scanned layer body whose "
-         "operand is loop-invariant (a ZeRO/FSDP weight gather of a "
-         "parameter slice — prefetchable one trip ahead) sits exposed "
-         "on the critical path every trip; enable the sharding plan's "
-         "overlap knob (FSDP/ShardedMesh(overlap='on')) to hide it "
-         "behind the previous layer's compute "
-         "(docs/PERFORMANCE.md 'collective overlap')"),
     Rule("RLT306", "dcn-crossing-shard-axis", "warning",
          "a tensor/fsdp/seq/expert/pipe mesh axis spans DCN slices on a "
          "multi-slice topology: its per-layer collectives (weight "

@@ -95,8 +95,7 @@ def dce(closed):
     """Dead-code-eliminate a traced program (inputs and outputs kept) so
     an audit walks what XLA compiles: jit runs the same pass before
     lowering. Without it the walk charges residuals AD leaves behind
-    that nothing consumes (grad-of-scan under the overlap schedule
-    stacks the gathered weight carry: ~26 GiB on llama3-8b)."""
+    that nothing consumes."""
     from jax.extend.core import ClosedJaxpr
     from jax.interpreters import partial_eval
 

@@ -60,17 +60,16 @@ from typing import (
 )
 
 from ray_lightning_tpu.analysis.costmodel import (
-    Topology, collective_cost, compute_time_us, parse_topology,
+    Topology, collective_cost, parse_topology,
 )
 from ray_lightning_tpu.analysis.findings import Finding
 from ray_lightning_tpu.analysis.jaxpr import (
     call_body, dce, pallas_kernel_ident, source_of, sub_jaxprs, walk_eqns,
 )
-from ray_lightning_tpu.ops.dispatch import OVERLAP_PREFETCH_NAME
 
 __all__ = [
-    "CollectiveEvent", "TraceReport", "audit_step", "classify_overlap",
-    "trace_step", "check_permutation",
+    "CollectiveEvent", "TraceReport", "audit_step", "trace_step",
+    "check_permutation",
 ]
 
 #: per-dim mesh axes; None = unknown (propagation gave up — never a
@@ -144,10 +143,6 @@ class _VarInfo:
     #: not at the loop's trip count (lm_head inside the CE chunk scan:
     #: one gather per step, not one per chunk).
     born_mult: int = 1
-    #: a partial sum the walk finished as the ZeRO reduce-scatter into
-    #: its parameter's layout (`_resolve_partial`): a gradient at rest.
-    #: Survives casts and barriers, not arithmetic.
-    zero_grad: bool = False
 
 
 @dataclasses.dataclass
@@ -161,16 +156,8 @@ class CollectiveEvent:
     ``implicit`` marks collectives *inferred* from sharding propagation
     (GSPMD will insert them) as opposed to explicit shard_map
     collectives; ``unbounded`` marks sites inside a while-loop whose trip
-    count the trace cannot know (counted once).
-
-    Overlap accounting (docs/STATIC_ANALYSIS.md "overlap model"):
-    ``prefetchable`` marks collectives whose operand is known ahead of
-    its use — ZeRO weight gathers (parameter-derived operands) and the
-    grad reduce-scatters matched to a parameter; ``scope`` is the id of
-    the enclosing scanned body (None at top level); ``hidden_us`` is the
-    share of ``time_us`` the overlap classification proved hideable
-    behind that scope's per-trip compute window (0 when the traced
-    program carries no prefetch schedule)."""
+    count the trace cannot know (counted once); ``scope`` is the id of
+    the enclosing scanned body (None at top level)."""
 
     kind: str
     axes: Tuple[str, ...]
@@ -182,9 +169,7 @@ class CollectiveEvent:
     source: str
     param_path: Optional[str] = None
     unbounded: bool = False
-    prefetchable: bool = False
     scope: Optional[int] = None
-    hidden_us: float = 0.0
     #: bytes each chip puts on DCN (multi-slice topologies only): the
     #: inter-slice stage of a hierarchical collective whose group spans
     #: slices. ``wire_bytes`` stays the ICI tier; ``time_us`` includes
@@ -197,17 +182,9 @@ class CollectiveEvent:
     #: pre-dtype-threading events.
     dtype: Optional[str] = None
 
-    @property
-    def exposed_us(self) -> float:
-        return max(0.0, self.time_us - self.hidden_us)
-
     def describe(self) -> str:
         tag = "implicit" if self.implicit else "explicit"
         extra = " trip-count-unknown" if self.unbounded else ""
-        if self.hidden_us > 0 and self.time_us > 0:
-            extra += f" {self.hidden_us / self.time_us:.0%}-hidden"
-        elif self.prefetchable and self.scope is not None:
-            extra += " exposed"
         who = f"  <{self.param_path}>" if self.param_path else ""
         dcn = (f" +{_fmt_bytes(self.dcn_bytes).strip()} DCN"
                if self.dcn_bytes else "")
@@ -246,10 +223,9 @@ class TraceReport:
     peak_hbm_bytes: int
     hbm_budget_bytes: int
     label: str = ""
-    #: the overlap classification (`classify_overlap`): scheduled flag,
-    #: hidden/exposed ICI time, per-scope breakdown. None only when
-    #: classification was skipped.
-    overlap: Optional[Dict[str, Any]] = None
+    #: per-device matmul FLOPs a step inside scanned bodies (what
+    #: `telemetry/report.py` prices as the predicted compute window)
+    scan_flops: float = 0.0
     #: pallas kernel identities the walk met (`jaxpr.pallas_kernel_ident`)
     #: — the serve audit's "which attention path does this step run"
     #: evidence (empty on pure-XLA programs)
@@ -292,26 +268,6 @@ class TraceReport:
         return sum(e.time_us for e in self.collectives)
 
     @property
-    def ici_hidden_us(self) -> float:
-        return sum(e.hidden_us for e in self.collectives)
-
-    @property
-    def ici_exposed_us(self) -> float:
-        return sum(e.exposed_us for e in self.collectives)
-
-    @property
-    def overlap_hidden_fraction(self) -> float:
-        """Fraction of the PREFETCHABLE collective time (ZeRO weight
-        gathers + param-matched grad reduce-scatters) the schedule
-        hides behind compute; 0.0 when nothing is prefetchable or no
-        overlap schedule is present."""
-        pref = sum(e.time_us for e in self.collectives if e.prefetchable)
-        if pref <= 0:
-            return 0.0
-        return sum(e.hidden_us for e in self.collectives
-                   if e.prefetchable) / pref
-
-    @property
     def fits(self) -> bool:
         return self.peak_hbm_bytes <= self.hbm_budget_bytes
 
@@ -347,20 +303,6 @@ class TraceReport:
                     f"slices ({self.topology.dcn_gbps:.1f} GB/s per "
                     "chip) — inter-slice stage of the crossing "
                     "collectives, itemized above")
-            ov = self.overlap or {}
-            lines.append(
-                f"overlap: {'prefetch schedule detected' if ov.get('scheduled') else 'no prefetch schedule (overlap=off)'}"
-                f" — {self.overlap_hidden_fraction:.0%} of prefetchable "
-                f"collective time hidden behind compute "
-                f"({self.ici_hidden_us / 1e3:.2f} ms hidden, "
-                f"{self.ici_exposed_us / 1e3:.2f} ms exposed)")
-            for sc in ov.get("per_scope", ()):
-                lines.append(
-                    f"  scope {sc['source']} x{sc['trips']}: "
-                    f"compute {sc['compute_us_per_trip']:.0f} us/trip vs "
-                    f"prefetchable comm "
-                    f"{sc['prefetch_comm_us_per_trip']:.0f} us/trip -> "
-                    f"{sc['hidden_fraction']:.0%} hidden")
         else:
             lines.append("collective schedule: none (single-device or "
                          "fully replicated step)")
@@ -413,20 +355,14 @@ class TraceReport:
             "ici_bytes_per_step": self.ici_bytes_per_step,
             "dcn_bytes_per_step": self.dcn_bytes_per_step,
             "ici_time_us": round(self.ici_time_us, 1),
-            "ici_hidden_us": round(self.ici_hidden_us, 1),
-            "ici_exposed_us": round(self.ici_exposed_us, 1),
-            "overlap_hidden_fraction": round(
-                self.overlap_hidden_fraction, 4),
-            "overlap": self.overlap,
             "collectives": [
                 {"kind": e.kind, "axes": list(e.axes),
                  "payload_bytes": e.payload_bytes, "count": e.count,
                  "wire_bytes": e.wire_bytes, "dcn_bytes": e.dcn_bytes,
                  "time_us": round(e.time_us, 1), "implicit": e.implicit,
                  "source": e.source, "param_path": e.param_path,
-                 "unbounded": e.unbounded,
-                 "prefetchable": e.prefetchable, "scope": e.scope,
-                 "hidden_us": round(e.hidden_us, 1), "dtype": e.dtype}
+                 "unbounded": e.unbounded, "scope": e.scope,
+                 "dtype": e.dtype}
                 for e in sorted(self.collectives,
                                 key=lambda e: -e.wire_bytes)
             ],
@@ -541,14 +477,14 @@ class _StepAuditor:
         self._findings: Dict[Tuple, Finding] = {}
         self._quiet = 0          # scan-fixpoint passes record nothing
         self._unbounded = 0      # inside while bodies
-        #: overlap accounting: one entry per scanned body (the FINAL,
-        #: recording walk), keyed by a fresh id — trips, per-trip
-        #: dot_general FLOPs (per-device), source, prefetch marker
-        self.scopes: Dict[int, Dict[str, Any]] = {}
+        #: scanned bodies met on the recording walk, and the ids of
+        #: those the walk is inside of (`CollectiveEvent.scope`)
+        self._n_scopes = 0
         self._scope_stack: List[int] = []
-        #: the traced program carries the double-buffer fingerprint
-        #: (ops.dispatch.OVERLAP_PREFETCH_NAME name equations)
-        self.saw_prefetch_marker = False
+        #: per-device dot_general FLOPs a step inside scanned bodies
+        #: (pallas kernels and elementwise work are not counted): the
+        #: compute side of `report`'s predicted step floor
+        self.scan_flops = 0.0
         #: every pallas kernel the walk met, by its kernel-fn identity
         #: (`jaxpr.pallas_kernel_ident`) — surfaced as
         #: `TraceReport.pallas_kernels`, where the serve audit/smoke
@@ -631,7 +567,6 @@ class _StepAuditor:
     def record(self, kind: str, payload: int, axes: Sequence[str],
                mult: int, *, implicit: bool, source: str,
                param_path: Optional[str] = None,
-               prefetchable: bool = False,
                dtype: Optional[str] = None) -> None:
         if self._quiet or not axes:
             return
@@ -644,7 +579,7 @@ class _StepAuditor:
             dcn_group=self._dcn_span(axes))
         scope = self._scope_stack[-1] if self._scope_stack else None
         key = (kind, tuple(sorted(axes)), payload, source, implicit,
-               bool(self._unbounded), scope, prefetchable, dtype)
+               bool(self._unbounded), scope, dtype)
         ev = self._events.get(key)
         if ev is None:
             self._events[key] = CollectiveEvent(
@@ -652,8 +587,7 @@ class _StepAuditor:
                 count=mult, wire_bytes=cost.wire_bytes * mult,
                 time_us=cost.time_us * mult, implicit=implicit,
                 source=source, param_path=param_path,
-                unbounded=bool(self._unbounded),
-                prefetchable=prefetchable, scope=scope,
+                unbounded=bool(self._unbounded), scope=scope,
                 dcn_bytes=cost.dcn_bytes * mult, dtype=dtype)
         else:
             ev.count += mult
@@ -716,7 +650,7 @@ class _StepAuditor:
         payload = self._aval_bytes(aval, remaining)
         self.record("all_gather", payload, sorted(axes), mult,
                     implicit=True, source=source, param_path=info.path,
-                    prefetchable=info.param, dtype=_aval_dtype(aval))
+                    dtype=_aval_dtype(aval))
         if not info.param:
             self.flag(
                 "RLT301",
@@ -820,16 +754,15 @@ class _StepAuditor:
     def _resolve_partial(self, out_aval, out_spec: List[FrozenSet[str]],
                          partial: FrozenSet[str], mult: int,
                          source: str, path: Optional[str],
-                         ) -> Tuple[Spec, bool]:
+                         ) -> Spec:
         """A value is partial-summed over ``partial``: GSPMD finishes it
         with reduce_scatter when the result is parameter-shaped (its grad
         lands sharded like the param — ZeRO) and all-reduce otherwise.
-        Returns the finished spec and whether it was the reduce-scatter
-        (`_VarInfo.zero_grad`)."""
+        Returns the finished spec."""
         partial = partial - frozenset(
             ax for s in out_spec for ax in s)  # cannot both shard & reduce
         if not partial:
-            return tuple(out_spec), False
+            return tuple(out_spec)
         shape = tuple(getattr(out_aval, "shape", ()))
         match = self._param_match(shape, partial)
         if match is not None:
@@ -837,14 +770,14 @@ class _StepAuditor:
             payload = self._aval_bytes(out_aval, tuple(out_spec))
             self.record("reduce_scatter", payload, sorted(partial),
                         mult, implicit=True, source=source,
-                        param_path=mpath or path, prefetchable=True,
+                        param_path=mpath or path,
                         dtype=_aval_dtype(out_aval))
-            return tuple(s | m for s, m in zip(out_spec, mspec)), True
+            return tuple(s | m for s, m in zip(out_spec, mspec))
         payload = self._aval_bytes(out_aval, tuple(out_spec))
         self.record("psum", payload, sorted(partial), mult,
                     implicit=True, source=source, param_path=path,
                     dtype=_aval_dtype(out_aval))
-        return tuple(out_spec), False
+        return tuple(out_spec)
 
     # ---- the walk -------------------------------------------------------
 
@@ -953,17 +886,6 @@ class _StepAuditor:
         src = source_of(eqn)
         sub_peak = 0
 
-        if (name == "name"
-                and eqn.params.get("name") == OVERLAP_PREFETCH_NAME):
-            # the double-buffer fingerprint (ops.dispatch.prefetch_named):
-            # this trace runs the overlap schedule. Stamp only during
-            # the recording walk (same guard as the FLOP counter): a
-            # scan-fixpoint pass runs BEFORE the inner scope is pushed,
-            # so stamping there would credit the ENCLOSING scope
-            self.saw_prefetch_marker = True
-            if self._scope_stack and not self._quiet:
-                self.scopes[self._scope_stack[-1]]["marker"] = True
-
         def set_all(info_list):
             for v, info in zip(out, info_list):
                 env[v] = info
@@ -985,19 +907,8 @@ class _StepAuditor:
         if name == "optimization_barrier":
             # positional identity: each output mirrors ITS input (the
             # generic passthrough would smear the first operand's spec
-            # over every output — for the overlap barrier that would
-            # hand the activation a weight layout and invent reshards)
+            # over every output)
             set_all([dataclasses.replace(i) for i in infos[:len(out)]])
-        elif name == "shard_alike":
-            # jax.experimental.shard_alike: both outputs leave with the
-            # UNIFIED layout. The model adopts the first operand's known
-            # spec for both (the overlap path's only use pins each grad
-            # leaf to its param shard's layout — losing this to unknown
-            # used to charge every stacked layer grad at full size).
-            known = next((i for i in infos if i.spec is not None),
-                         _VarInfo(None))
-            set_all([_VarInfo(known.spec, param=i.param, path=i.path)
-                     for i in infos[:len(out)]])
         elif name in _PASSTHROUGH:
             base = next((i for i, a in zip(infos, avals)
                          if a is not None and i.spec is not None
@@ -1073,8 +984,7 @@ class _StepAuditor:
                     frozenset() if d == cd else frozenset.intersection(
                         *(i.spec[d] for i in infos))
                     for d in range(ondim))
-                # pieces of one leaf (the overlap schedule's rolled
-                # copy of the layer stack) keep that leaf's name
+                # pieces of one leaf keep that leaf's name
                 paths = {i.path for i in infos}
                 set_all([_VarInfo(spec, param=all(i.param for i in infos),
                                   path=paths.pop() if len(paths) == 1
@@ -1099,10 +1009,8 @@ class _StepAuditor:
             # norm out = x's). Unmatched outputs stay unknown. The walk
             # still RECURSES into the kernel jaxpr — for recognition
             # (which kernel runs: the serve audit reads
-            # `pallas_kernels`), for its dot_general FLOPs (counted
-            # once per call, an undercount of grid-many trips — the
-            # overlap compute window stays conservative), and so a collective
-            # hiding inside a future kernel is seen — but its internal
+            # `pallas_kernels`) and so a collective hiding inside a
+            # future kernel is seen — but its internal
             # buffers are VMEM, not HBM: they contribute NOTHING to the
             # liveness peak (sub_peak stays 0).
             if not self._quiet:
@@ -1301,21 +1209,18 @@ class _StepAuditor:
                 out_spec[lose_d] = out_spec[lose_d] - {ax}
                 if lose_d == prev:
                     seen[ax] = d
-        self._charge_flops(eqn, avals, out_spec, partial)
-        spec, zero_grad = self._resolve_partial(
+        self._charge_flops(eqn, avals, out_spec, partial, mult)
+        spec = self._resolve_partial(
             eqn.outvars[0].aval, out_spec, partial, mult, src,
             li.path if li.param else ri.path if ri.param else None)
-        return _VarInfo(spec, param=li.param and ri.param,
-                        zero_grad=zero_grad)
+        return _VarInfo(spec, param=li.param and ri.param)
 
-    def _charge_flops(self, eqn, avals, out_spec, partial) -> None:
-        """Accumulate this dot_general's per-device FLOPs into the
-        innermost scan scope — the compute window the overlap model
-        hides collectives behind. Per-device: the full contraction's
-        2·B·M·N·K divided by the product of mesh axes sharding the
-        output or reduced over (how SPMD splits the work). Counted only
-        on the recording walk, once per syntactic equation — i.e. per
-        scan trip."""
+    def _charge_flops(self, eqn, avals, out_spec, partial, mult) -> None:
+        """Add this dot_general's per-device FLOPs, every trip of the
+        scanned bodies around it, to `scan_flops`. Per-device: the full
+        contraction's 2·B·M·N·K divided by the product of mesh axes
+        sharding the output or reduced over (how SPMD splits the work).
+        Counted only on the recording walk."""
         if self._quiet or not self._scope_stack:
             return
         (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
@@ -1331,8 +1236,7 @@ class _StepAuditor:
         for s in out_spec:
             axes |= s
         div = math.prod(self.sizes.get(ax, 1) for ax in axes) or 1
-        self.scopes[self._scope_stack[-1]]["flops"] += (
-            2.0 * batch * m * n * k / div)
+        self.scan_flops += 2.0 * batch * m * n * k * mult / div
 
     def _gather_prim(self, eqn, infos, avals, mult, src) -> _VarInfo:
         """lax.gather (embedding lookups, take_along_axis): output batch
@@ -1389,13 +1293,13 @@ class _StepAuditor:
             ax for d in axes_param for ax in info.spec[d])
         out_spec = [s for d, s in enumerate(info.spec)
                     if d not in set(axes_param)]
-        spec, zero_grad = tuple(out_spec), False
+        spec = tuple(out_spec)
         if reduced and eqn.primitive.name in _REDUCE_COMM:
-            spec, zero_grad = self._resolve_partial(
+            spec = self._resolve_partial(
                 eqn.outvars[0].aval, out_spec, reduced, mult, src,
                 info.path)
         return _VarInfo(spec, param=all(i.param for i in infos),
-                        path=info.path, zero_grad=zero_grad)
+                        path=info.path)
 
     def _scatter_add(self, eqn, infos, avals, mult, src) -> _VarInfo:
         # operand, indices, updates. The canonical site: an embedding
@@ -1405,12 +1309,11 @@ class _StepAuditor:
         partial = _axes_in(upd.spec) - _axes_in(op.spec)
         base = list(op.spec) if op.spec is not None else [
             frozenset() for _ in getattr(eqn.outvars[0].aval, "shape", ())]
-        spec, zero_grad = tuple(base), False
+        spec = tuple(base)
         if partial:
-            spec, zero_grad = self._resolve_partial(
+            spec = self._resolve_partial(
                 eqn.outvars[0].aval, base, partial, mult, src, op.path)
-        return _VarInfo(spec, param=op.param and upd.param, path=op.path,
-                        zero_grad=zero_grad)
+        return _VarInfo(spec, param=op.param and upd.param, path=op.path)
 
     def _scatter_overwrite(self, eqn, infos, avals, mult, src) -> _VarInfo:
         # plain functional scatter (`x.at[idx].set(v)` — the serving
@@ -1481,20 +1384,11 @@ class _StepAuditor:
         annotated = self._canon(_spec_of_partition_spec(pspec, ndim))
         if info.spec is not None:
             lost = _axes_in(info.spec) - _axes_in(annotated)
-            if lost and info.zero_grad:
-                # AD transposes a weight-gather constraint onto the
-                # weight's cotangent, which the walk has just finished
-                # as the reduce-scatter into the parameter's layout —
-                # where the overlap schedule pins it (`shard_alike`).
-                # One reduction a layer, charged there: a gather on top
-                # would count it twice.
-                return dataclasses.replace(info)
             if lost:
                 payload = self._aval_bytes(aval, annotated)
                 self.record("all_gather", payload, sorted(lost), mult,
                             implicit=False, source=src,
-                            param_path=info.path,
-                            prefetchable=info.param)
+                            param_path=info.path)
         return _VarInfo(annotated, param=info.param, path=info.path)
 
     def _scan(self, eqn, infos, env, mult, manual) -> int:
@@ -1534,10 +1428,8 @@ class _StepAuditor:
                             param=a.param and b.param, path=a.path)
             if not changed:
                 break
-        sid = len(self.scopes)
-        self.scopes[sid] = {"trips": length, "flops": 0.0,
-                            "source": source_of(eqn), "marker": False}
-        self._scope_stack.append(sid)
+        self._scope_stack.append(self._n_scopes)
+        self._n_scopes += 1
         try:
             sub_peak, outs = self._seed_and_walk(
                 closed, consts + carry + xs, env, mult * length, manual)
@@ -1710,91 +1602,6 @@ def _collective_signature(jaxpr) -> List[Tuple[str, Tuple]]:
 
 
 # --------------------------------------------------------------------------
-# overlap classification (hidden vs exposed collective time)
-# --------------------------------------------------------------------------
-
-
-def classify_overlap(
-    events: Sequence[CollectiveEvent],
-    scopes: Mapping[int, Mapping[str, Any]],
-    topo: Topology,
-    scheduled: Optional[bool] = None,
-) -> Dict[str, Any]:
-    """Classify each collective as hidden-behind-compute vs exposed and
-    annotate ``events`` in place (``hidden_us``).
-
-    The model (docs/STATIC_ANALYSIS.md "overlap model"):
-
-      * only PREFETCHABLE collectives inside a scanned body are
-        hideable — ZeRO weight gathers (operands known ahead of use)
-        and param-matched grad reduce-scatters (retired per trip by the
-        backward scan);
-      * a scope's per-trip compute window is its counted dot_general
-        FLOPs at the derated roofline (`costmodel.compute_time_us`);
-        pallas kernels and elementwise work are not counted, so the
-        window — and with it the hidden share — is conservative;
-      * ``scheduled`` is the program-wide flag (the double-buffer
-        fingerprint `ops.dispatch.OVERLAP_PREFETCH_NAME` anywhere in
-        the trace; when None it defaults to "any scope carries the
-        marker") — but hidden credit is PER SCOPE: a scope earns it
-        only when its source location is one a marker was seen in.
-        The backward scan is the transpose of the marked forward and
-        shares its source (marker-free by construction, still
-        credited); an unrelated scan in the same program — e.g. the
-        fused-CE chunk loop — is NOT part of the schedule and hides
-        nothing, no matter how large its compute window;
-      * per scope: hidden fraction = min(1, window / per-trip
-        prefetchable comm); each event hides that fraction of its time.
-        A zero-compute scope (the pathological case: nothing to hide
-        behind) hides nothing.
-
-    Returns the overlap summary dict carried by `TraceReport.overlap`.
-    """
-    if scheduled is None:
-        scheduled = any(s.get("marker") for s in scopes.values())
-    marked_sources = {str(s.get("source", f"scan#{sid}"))
-                      for sid, s in scopes.items() if s.get("marker")}
-    for e in events:
-        e.hidden_us = 0.0
-    per_scope: List[Dict[str, Any]] = []
-    for sid in sorted(scopes):
-        sc = scopes[sid]
-        evs = [e for e in events if e.scope == sid and e.prefetchable]
-        if not evs:
-            continue
-        source = str(sc.get("source", f"scan#{sid}"))
-        in_schedule = source in marked_sources
-        trips = max(1, int(sc.get("trips", 1)))
-        comm = sum(e.time_us for e in evs)
-        comm_trip = comm / trips
-        window = compute_time_us(float(sc.get("flops", 0.0)), topo)
-        frac = 0.0
-        if scheduled and in_schedule and comm_trip > 0:
-            frac = min(1.0, window / comm_trip)
-        for e in evs:
-            e.hidden_us = e.time_us * frac
-        per_scope.append({
-            "source": source,
-            "trips": trips,
-            "scheduled": in_schedule,
-            "compute_us_per_trip": round(window, 1),
-            "prefetch_comm_us_per_trip": round(comm_trip, 1),
-            "hidden_fraction": round(frac, 4),
-        })
-    pref = sum(e.time_us for e in events if e.prefetchable)
-    hidden = sum(e.hidden_us for e in events)
-    total = sum(e.time_us for e in events)
-    return {
-        "scheduled": bool(scheduled),
-        "overlap_hidden_fraction": round(hidden / pref, 4) if pref else 0.0,
-        "ici_hidden_us": round(hidden, 1),
-        "ici_exposed_us": round(total - hidden, 1),
-        "prefetchable_time_us": round(pref, 1),
-        "per_scope": per_scope,
-    }
-
-
-# --------------------------------------------------------------------------
 # building + auditing the canonical step
 # --------------------------------------------------------------------------
 
@@ -1939,8 +1746,6 @@ def audit_step(
     opt_dev = sum(opt_by.values())
 
     events = auditor.events
-    overlap = classify_overlap(events, auditor.scopes, topo,
-                               scheduled=auditor.saw_prefetch_marker)
 
     findings = auditor.findings
     if topo.n_slices > 1 and n_devices == topo.n_devices:
@@ -1969,38 +1774,6 @@ def audit_step(
                 f"'{ax}' within a slice "
                 f"(<= {topo.devices_per_slice} devices)",
                 symbol=label or topo.name))
-    if not auditor.saw_prefetch_marker:
-        # RLT305 exposed-collective-in-scan: a per-trip ZeRO weight
-        # gather inside a scanned body with no prefetch schedule.
-        # Hoisted loop-invariant gathers are excluded by comparing the
-        # charged count against the scope's trip count: a hoisted
-        # gather is charged once per walk (fwd+bwd -> count 2), a
-        # per-trip one at least once per trip (e.g. the lm_head gather
-        # in the 512-trip CE chunk scan is hoisted — count 2 << 512 —
-        # and the overlap knob could not hide it anyway).
-        seen_305 = set()
-        for e in events:
-            scope_trips = int(
-                auditor.scopes.get(e.scope, {}).get("trips", 1))
-            if (e.prefetchable and e.kind == "all_gather"
-                    and e.scope is not None and not e.unbounded
-                    and scope_trips > 1 and e.count >= scope_trips):
-                key = (e.source, e.param_path)
-                if key in seen_305:
-                    continue
-                seen_305.add(key)
-                findings.append(Finding(
-                    "RLT305",
-                    f"blocking weight all-gather "
-                    f"({_fmt_bytes(e.wire_bytes).strip()} over "
-                    f"{'x'.join(e.axes)}, x{e.count} trips) sits "
-                    "exposed inside a scanned layer body; its operand "
-                    "is a parameter slice known one trip ahead — "
-                    "enable the sharding plan's overlap knob "
-                    "(FSDP/ShardedMesh(overlap='on')) to hide it "
-                    f"behind the previous layer's compute [at "
-                    f"{e.source}]",
-                    symbol=e.param_path or e.source))
     precision: Optional[Dict[str, Any]] = None
     if numerics:
         from ray_lightning_tpu.analysis import numcheck as _numcheck
@@ -2045,7 +1818,7 @@ def audit_step(
         topology=topo,
         mesh_axes={k: v for k, v in sizes.items() if v > 1},
         collectives=events,
-        overlap=overlap,
+        scan_flops=auditor.scan_flops,
         findings=findings,
         params_bytes_per_device=params_dev,
         opt_bytes_per_device=opt_dev,

@@ -62,9 +62,6 @@ class TpuModule:
         self.params: Any = None    # trained weights land here after fit (C5)
         self.trainer = None        # backref set by Trainer during fit
         self.mesh = None           # bound by Strategy.setup before setup()
-        self.overlap = False       # strategy overlap= knob (collective
-        #                            prefetch schedule; models that have
-        #                            an overlapped path honor it)
         self.hparams: Dict[str, Any] = {}
         self._logged: Dict[str, jnp.ndarray] = {}
 

@@ -788,11 +788,8 @@ def _stacked(spec: P, stacked: bool) -> P:
     return P("pipe", *spec) if stacked else spec
 
 
-#: per-layer tensor-parallel placement (no fsdp, no layer axis). Shared
-#: by `llama_param_specs` (which stacks/overlays it) and the overlap
-#: schedule's gather target (`LlamaModule._overlapped_hidden`): a
-#: double-buffered weight gather un-does exactly the strategy's fsdp
-#: overlay — the Megatron `tensor` split stays resident.
+#: per-layer tensor-parallel placement (no fsdp, no layer axis), which
+#: `llama_param_specs` stacks and the strategy overlays with fsdp.
 _PER_LAYER_SPECS: Dict[str, P] = {
     "wqkv/kernel": P(None, "tensor"),
     "wo/kernel": P("tensor", None),
@@ -1040,20 +1037,6 @@ class LlamaModule(TpuModule):
                 and self.mesh is not None
                 and self.mesh.shape.get("pipe", 1) > 1)
 
-    def _use_overlap(self) -> bool:
-        """The double-buffered weight-gather schedule is live when the
-        strategy asked for it (``FSDP/ShardedMesh(overlap="on")`` sets
-        ``self.overlap`` at bind time) AND there is FSDP latency to hide
-        (fsdp > 1) on a scanned stack deep enough to pipeline. The
-        pipeline path owns its own layer schedule, so they are mutually
-        exclusive."""
-        return (bool(getattr(self, "overlap", False))
-                and self.cfg.scan_layers
-                and self.cfg.n_layers >= 2
-                and self.mesh is not None
-                and self.mesh.shape.get("fsdp", 1) > 1
-                and not self._use_pipeline())
-
     def _pipelined_hidden(self, params, tokens):
         """GPipe decoder path: the SAME stacked `layers` params the scan
         path trains, stage-split over the mesh's `pipe` axis
@@ -1087,223 +1070,14 @@ class LlamaModule(TpuModule):
         )
         return rms_norm(h, params["final_norm"], cfg.norm_eps)
 
-    def _gathered_layer_shardings(self):
-        """NamedShardings for ONE layer's weights with the fsdp overlay
-        undone: the module's own per-layer tensor placement
-        (`_PER_LAYER_SPECS`) over the live mesh. This is the double
-        buffer's layout — gathered over `fsdp`, still `tensor`-split."""
-        from jax.sharding import NamedSharding
-
-        mesh = self.mesh
-        return {path: NamedSharding(mesh, spec)
-                for path, spec in _PER_LAYER_SPECS.items()}
-
-    def _overlapped_hidden(self, params, tokens):
-        """Double-buffered weight-gather prefetch over the scanned layer
-        stack (docs/PERFORMANCE.md "collective overlap"):
-
-          * the scan carry holds layer *i*'s weights ALREADY gathered
-            over `fsdp`; each trip first issues layer *i+1*'s gather
-            (`with_sharding_constraint` to the gathered layout, stamped
-            with the `rlt_overlap_prefetch` fingerprint and pinned
-            before the compute by `ops.dispatch.overlap_barrier`), then
-            runs layer *i* from the buffer — the gather's latency sits
-            under the layer's matmuls instead of on the critical path;
-          * the per-layer `custom_vjp` saves only the SHARDED slice and
-            the block input as residuals: the backward scan re-gathers
-            each layer's weights as it retires it (the remat-the-gather
-            discipline — carrying the gathered buffer as a residual
-            would stack L full layers of weights in HBM) and its grad
-            reduce-scatters are emitted per retired layer by GSPMD;
-          * per-layer recompute-from-inputs is inherent to the schedule
-            (the custom_vjp IS remat policy "nothing" for the block), so
-            `remat_policy` refinements are inert on this path;
-          * HBM cost: one extra layer of gathered weights + the in-flight
-            gradient — charged by `parallel.plan.llama_overlap_buffer_bytes`.
-
-        Numerics are bitwise-identical to the naive scan (test-pinned):
-        gathers move bytes, the per-layer math is the same block, and the
-        grad reductions ride the same fsdp ring.
-        """
-        import jax.tree_util as jtu
-
-        from ray_lightning_tpu.ops.dispatch import (
-            fusion_fence, overlap_barrier, prefetch_named,
-        )
-        from ray_lightning_tpu.utils.pytree import _path_str
-
-        cfg = self.cfg
-        emb = params["tok_embed"]["embedding"]
-        x = jnp.take(emb, tokens, axis=0).astype(cfg.dtype)
-        cos, sin = rope_frequencies(
-            cfg.head_dim, cfg.max_seq_len, cfg.rope_theta, dtype=jnp.float32
-        )
-        cos, sin = cos[: tokens.shape[1]], sin[: tokens.shape[1]]
-
-        from jax.sharding import NamedSharding
-
-        from ray_lightning_tpu.parallel.mesh import dp_axis_names
-
-        layers = params["layers"]
-        gshard = self._gathered_layer_shardings()
-        block = LlamaBlock(cfg, self.mesh)
-        hshard = NamedSharding(
-            self.mesh, P(dp_axis_names(self.mesh), None, None))
-
-        def gather(shard):
-            return jtu.tree_map_with_path(
-                lambda kp, t: jax.lax.with_sharding_constraint(
-                    t, gshard[_path_str(kp)]), shard)
-
-        def block_apply(w, h, cos, sin):
-            # fence the block region on both ends: the prefetched and
-            # serial schedules surround the block with different ops,
-            # and XLA fuses across those seams, reassociating the
-            # block's bf16/f32 reductions differently per schedule
-            # (measured: 1-2 bf16 ulp per layer at small shapes). With
-            # barrier-delimited input and output the block is an
-            # identical compilation region under every schedule — the
-            # overlapped-vs-serial bitwise pin rests on this. The
-            # barriers pin FUSION but not PARTITIONING, so the input
-            # layouts are pinned too (w to the gathered layout, h to
-            # batch-sharded): under the prefetched schedule w arrives as
-            # a scan carry, and GSPMD sharding a carry differently than
-            # the serial schedule's in-body gather would re-split the
-            # block's matmul reductions — a data-dependent last-bit
-            # divergence (observed at 1 ulp on CPU-SPMD).
-            w, h = fusion_fence((w, h))
-            w = gather(w)
-            h = jax.lax.with_sharding_constraint(h, hshard)
-            return fusion_fence(block.apply({"params": w}, h, cos, sin)[0])
-
-        def _bwd_core(res, g_h):
-            from jax.experimental.shard_alike import shard_alike
-
-            shard, h, cos_r, sin_r = res
-            w = gather(shard)  # re-gather at retirement (remat the gather)
-            _, vjp = jax.vjp(
-                lambda w, h: block_apply(w, h, cos_r, sin_r), w, h)
-            dw, dh = vjp(g_h)
-            # the layer's grad flows through the SHARD argument: GSPMD
-            # finishes the partial sums as per-layer reduce-scatters as
-            # the backward scan retires the layer. The gathered-carry
-            # argument gets zeros so no cotangent rides the prefetch
-            # chain (the prologue gather transposes to nothing).
-            # shard_alike pins each dw leaf to ITS param shard's layout
-            # (the reduce-scatter-at-retirement discipline) — without
-            # the pin GSPMD is free to carry dw partially replicated,
-            # and the prefetched and serial programs then compile the
-            # optimizer's elementwise chain under different layouts
-            # (observed: data-dependent 1-ulp drift in the updated
-            # params via FMA contraction differences).
-            dw = jax.tree.map(lambda s, d: shard_alike(s, d)[1], shard, dw)
-            return w, dw, dh, jnp.zeros_like(cos_r), jnp.zeros_like(sin_r)
-
-        def _primal(w, shard, h, cos, sin):
-            return block_apply(w, h, cos, sin)
-
-        def _fwd(w, shard, h, cos, sin):
-            # residuals: the SHARDED slice + block input, never the
-            # gathered buffer (which would stack L×full-layer weights)
-            return block_apply(w, h, cos, sin), (shard, h, cos, sin)
-
-        def _bwd(res, g):
-            w, dw, dh, dcos, dsin = _bwd_core(res, g)
-            return (jax.tree.map(jnp.zeros_like, w), dw, dh, dcos, dsin)
-
-        layer_apply = jax.custom_vjp(_primal)
-        layer_apply.defvjp(_fwd, _bwd)
-
-        def _primal_pf(w, w_next, shard, h, cos, sin):
-            # pin: the i+1 gather (producing w_next) is ordered before
-            # layer i's compute consumes h. The barrier lives INSIDE
-            # the custom_vjp so partial-eval never sees the primal-only
-            # w chain coupled to the differentiated h at scan-body
-            # level — outside, jax's grad-of-scan machinery saves the
-            # barrier's known inputs per trip, i.e. stacks a full
-            # gathered-layer copy of every weight as residual ys that
-            # nothing in the backward consumes (DCE cannot reach them
-            # through the custom_vjp call; measured: a phantom
-            # full-stack copy, ~26 GiB on llama3-8b v5p-64).
-            w_next, h = overlap_barrier((w_next, h))
-            return block_apply(w, h, cos, sin), w_next
-
-        def _fwd_pf(w, w_next, shard, h, cos, sin):
-            return (_primal_pf(w, w_next, shard, h, cos, sin),
-                    (shard, h, cos, sin))
-
-        def _bwd_pf(res, g):
-            g_h, _ = g  # the carried buffer's cotangent is dead weight
-            w, dw, dh, dcos, dsin = _bwd_core(res, g_h)
-            return (jax.tree.map(jnp.zeros_like, w),
-                    jax.tree.map(jnp.zeros_like, w), dw, dh, dcos, dsin)
-
-        layer_apply_pf = jax.custom_vjp(_primal_pf)
-        layer_apply_pf.defvjp(_fwd_pf, _bwd_pf)
-
-        prefetch = getattr(self, "overlap", False) != "serial"
-        if prefetch:
-            # stop_gradient: the prologue's cotangent is exactly zero by
-            # construction (_bwd returns zeros for the gathered-carry
-            # argument), but without the cut the p[0] slice TRANSPOSES
-            # to a full-stack pad + add_any of zeros — dead weight XLA
-            # must DCE and the HBM model would charge at full size.
-            head = jax.tree.map(
-                lambda p: jax.lax.stop_gradient(p[0]), layers)
-            w = gather(head)  # prologue: layer 0's exposed gather
-
-            def body(carry, xs_i):
-                h, w = carry
-                shard_i, shard_next = xs_i
-                w_next = prefetch_named(gather(shard_next))
-                h, w_next = layer_apply_pf(w, w_next, shard_i, h, cos, sin)
-                return (h, w_next), None
-
-            # every layer stays INSIDE the one scan — an unrolled
-            # epilogue would compile the last layer in a different
-            # fusion environment and break bitwise parity with the
-            # scanned body (measured: one bf16 ulp per unrolled layer).
-            # Trip i therefore prefetches layer (i+1) mod n_layers: the
-            # wrap-around trip re-gathers layer 0, which in steady-state
-            # training is the NEXT step's prologue warmed up (charged
-            # honestly by tracecheck as one extra gather per step).
-            # The rolled copy is stop_gradient'd OUTSIDE the scan: the
-            # prefetch chain is non-differentiable by design (layer
-            # i+1's gradient flows through its own trip's shard_i), and
-            # without the cut the scan transpose stacks a full-size
-            # zero cotangent for it and adds it through the roll's
-            # transpose — real HBM and a GSPMD layout wildcard.
-            xs = (layers,
-                  jax.tree.map(
-                      lambda p: jax.lax.stop_gradient(
-                          jnp.concatenate([p[1:], p[:1]], axis=0)),
-                      layers))
-            (x, _), _ = jax.lax.scan(body, (x, w), xs)
-        else:
-            # overlap="serial": the ablation control — the SAME explicit
-            # gather schedule with the double buffer removed, so the
-            # gather blocks at each layer's use. Bitwise-identical math
-            # to the prefetched schedule (test-pinned): the only delta
-            # between the two programs is where the gather latency sits.
-            def body(h, shard_i):
-                w = gather(shard_i)
-                h = layer_apply(w, shard_i, h, cos, sin)
-                return h, None
-
-            x, _ = jax.lax.scan(body, x, layers)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
-
     def _loss(self, params, inputs, targets, mask):
         cfg = self.cfg
         use_pipe = self._use_pipeline()
         use_fused = self._use_fused_ce()
-        use_overlap = self._use_overlap()
-        if not (use_pipe or use_fused or use_overlap):
+        if not (use_pipe or use_fused):
             return cross_entropy_loss(
                 self.apply(params, inputs), targets, mask)
         hidden = (self._pipelined_hidden(params, inputs) if use_pipe
-                  else self._overlapped_hidden(params, inputs)
-                  if use_overlap
                   else self.apply(params, inputs, return_hidden=True))
         if use_fused:
             if cfg.tie_embeddings:

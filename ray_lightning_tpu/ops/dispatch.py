@@ -66,81 +66,6 @@ def interpret_mode() -> bool:
     return not on_tpu()
 
 
-# ---- collective-overlap shim (models/llama.py double-buffered FSDP) ------
-
-#: the checkpoint_name tag the double-buffered weight-gather prefetch
-#: stamps on every prefetched leaf (models/llama.py _overlapped_hidden).
-#: tracecheck (analysis/tracecheck.py) keys its hidden-vs-exposed
-#: overlap classification on this exact string: the `name` equations it
-#: produces are the static fingerprint that the traced program runs the
-#: overlap schedule (same fingerprinting technique as the flash kernel's
-#: "flash_residuals" tag).
-OVERLAP_PREFETCH_NAME = "rlt_overlap_prefetch"
-
-
-def prefetch_named(tree):
-    """Stamp every leaf of a prefetched weight tree with the overlap
-    marker (`checkpoint_name`). Inert at runtime (an identity `name`
-    equation no remat policy in this repo matches); load-bearing for the
-    static audit."""
-    from jax.ad_checkpoint import checkpoint_name
-
-    return jax.tree.map(
-        lambda t: checkpoint_name(t, OVERLAP_PREFETCH_NAME), tree)
-
-
-@jax.custom_vjp
-def overlap_barrier(trees):
-    """Differentiable `lax.optimization_barrier`.
-
-    The double-buffered schedule must pin "issue layer i+1's weight
-    gather BEFORE layer i's compute consumes x" — without a data
-    dependence XLA's scheduler is free to sink the gather to its use and
-    re-expose the latency. `optimization_barrier` provides the ordering
-    and this wraps it in a custom_vjp: barrier applied in the forward,
-    cotangents passed straight through (the backward scan builds its
-    own schedule from the transposed collectives)."""
-    return jax.lax.optimization_barrier(trees)
-
-
-def _overlap_barrier_fwd(trees):
-    return jax.lax.optimization_barrier(trees), None
-
-
-def _overlap_barrier_bwd(_, g):
-    return (g,)
-
-
-overlap_barrier.defvjp(_overlap_barrier_fwd, _overlap_barrier_bwd)
-
-
-@jax.custom_vjp
-def fusion_fence(trees):
-    """Symmetric fusion fence: `optimization_barrier` on the value in
-    forward AND on its cotangent in backward.
-
-    XLA fuses a subgraph differently depending on the program AROUND
-    it, and fusion reassociates bf16/f32 reductions — so the same layer
-    block surrounded by two different (value-identical) gather
-    schedules can produce different bits (measured: 1-2 bf16 ulp per
-    layer at small shapes). The overlap path (models/llama.py) fences
-    the block region so it is an identical compilation unit under the
-    prefetched and serial schedules — the bitwise-parity guarantee
-    rests on it."""
-    return jax.lax.optimization_barrier(trees)
-
-
-def _fence_fwd(trees):
-    return jax.lax.optimization_barrier(trees), None
-
-
-def _fence_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-fusion_fence.defvjp(_fence_fwd, _fence_bwd)
-
-
 def use_pallas(override: bool | None = None,
                default: bool | None = None) -> bool:
     """Dispatch decision: explicit argument > force_xla context >

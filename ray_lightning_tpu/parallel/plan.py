@@ -284,59 +284,6 @@ def llama_activation_bytes(cfg, local_batch: int, seq: int,
     return int(1.5 * (saved + live + ce))
 
 
-def llama_overlap_buffer_bytes(cfg, fsdp: int = 1, tensor: int = 1,
-                               mode: str = "on") -> int:
-    """Extra per-device HBM the collective-overlap schedule holds beyond
-    the naive ZeRO path (models/llama.py `_overlapped_hidden`,
-    docs/PERFORMANCE.md "collective overlap"):
-
-      * the double buffer: ONE extra layer's weights gathered over
-        `fsdp` (the prefetched layer i+1 — layer i's gathered working
-        set exists transiently under the naive schedule too, so only
-        the second buffer is NEW). Still `tensor`-split — the gather
-        un-does only the fsdp overlay. Weights live at param_dtype
-        (f32, models/llama.py LlamaBlock);
-      * the rolled prefetch xs: the scan consumes a second stacked copy
-        of the layer weights (`jnp.concatenate([p[1:], p[:1]])`),
-        fsdp-sharded like the original — one layer-stack shard;
-      * the in-flight gradient: one layer's grad shard mid
-        reduce-scatter while the backward scan retires the next layer.
-
-    ``mode="serial"`` (the ablation) charges only the in-flight grad
-    shard: the serial schedule gathers in-body (no second buffer — the
-    transient gathered layer exists under the naive schedule too) and
-    scans the original stack (no rolled xs copy). ``mode="off"`` — or
-    any config where the schedule never goes live (models/llama.py
-    ``_use_overlap``) — returns 0, so callers can pass the knob through
-    unguarded (RLT302 HBM accounting stays honest either way).
-    """
-    if mode == "off":
-        return 0
-    # the schedule is only LIVE with fsdp latency to hide on a scanned
-    # stack deep enough to pipeline (models/llama.py _use_overlap) —
-    # on an inert config the compiled program is the naive one and the
-    # honest charge is zero (a phantom ~n_layers x layer_bytes charge
-    # here would flip a fitting fsdp=1 job to DOES-NOT-FIT)
-    if (fsdp <= 1 or not getattr(cfg, "scan_layers", True)
-            or cfg.n_layers < 2):
-        return 0
-    d, f, hd = cfg.dim, cfg.hidden_dim, cfg.head_dim
-    layer_params = (
-        d * (cfg.n_heads + 2 * cfg.n_kv_heads) * hd   # wqkv
-        + cfg.n_heads * hd * d                        # wo
-        + d * 2 * f                                   # w_gate_up
-        + f * d                                       # w_down
-        + 2 * d                                       # the two norm gains
-    )
-    layer_bytes = layer_params * 4  # param_dtype is f32
-    gathered = layer_bytes // max(1, tensor)
-    shard = layer_bytes // max(1, fsdp * tensor)
-    if mode == "serial":
-        return int(shard)
-    stack_shard = cfg.n_layers * shard
-    return int(gathered + stack_shard + shard)
-
-
 def find_max_local_batch(
     module,
     strategy,

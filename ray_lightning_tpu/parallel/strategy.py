@@ -52,31 +52,13 @@ class Strategy:
         init_hook: Optional[Callable[[], None]] = None,
         env: Optional[dict[str, str]] = None,
         devices: Optional[Sequence[jax.Device]] = None,
-        overlap: str = "off",
     ):
-        if overlap not in ("off", "on", "serial"):
-            raise ValueError(
-                f"overlap must be 'off', 'on' or 'serial', got {overlap!r}")
         self.num_workers = num_workers
         self.init_hook = init_hook
         self.env = dict(env or {})
         self._devices = list(devices) if devices is not None else None
-        #: collective-overlap schedule knob (docs/PERFORMANCE.md
-        #: "collective overlap"): "on" asks the bound module to run its
-        #: ZeRO/FSDP path with the double-buffered weight-gather
-        #: prefetch (modules that have no such path ignore it). "off"
-        #: (default) compiles the exact pre-knob program — test-pinned.
-        #: "serial" is the ablation control: the same explicit per-layer
-        #: gather schedule with the prefetch disabled (gather blocks at
-        #: use) — bitwise-identical training to "on" (test-pinned), so
-        #: any measured delta between the two is pure latency hiding.
-        self.overlap = overlap
         self.mesh: Optional[Mesh] = None
         self._module = None
-
-    @property
-    def overlap_enabled(self) -> bool:
-        return self.overlap != "off"
 
     # ---- lifecycle -------------------------------------------------------
 
@@ -107,7 +89,6 @@ class Strategy:
             # bind before the module builds its model so seq/tensor manual
             # islands (e.g. ring attention) can close over the mesh.
             module.mesh = self.mesh
-            module.overlap = self.overlap if self.overlap_enabled else False
         log.info(
             "strategy=%s mesh=%s over %d %s device(s)",
             type(self).__name__,
@@ -123,7 +104,6 @@ class Strategy:
         self._module = module
         if module is not None:
             module.mesh = self.mesh
-            module.overlap = self.overlap if self.overlap_enabled else False
 
     def teardown(self) -> None:
         self.mesh = None

@@ -333,7 +333,7 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
 
     from ray_lightning_tpu.analysis.findings import Finding
     from ray_lightning_tpu.analysis.tracecheck import (
-        TraceReport, _repl, _StepAuditor, _VarInfo, classify_overlap,
+        TraceReport, _repl, _StepAuditor, _VarInfo,
     )
 
     topo = (topology if isinstance(topology, Topology)
@@ -421,8 +421,6 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
             "automatically on TPU; docs/SERVING.md 'paged prefill "
             "kernel')",
             symbol=label))
-    overlap = classify_overlap(auditor.events, auditor.scopes, topo,
-                               scheduled=auditor.saw_prefetch_marker)
     precision = None
     if numerics:
         from ray_lightning_tpu.analysis import numcheck as _numcheck
@@ -468,7 +466,6 @@ def audit_decode_step(model_cfg, engine_cfg: EngineConfig,
         topology=topo,
         mesh_axes={"tensor": tp} if tp > 1 else {},
         collectives=auditor.events,
-        overlap=overlap,
         findings=findings,
         params_bytes_per_device=params_dev,
         opt_bytes_per_device=0,

@@ -9,8 +9,7 @@ telemetry-enabled run left under ``<run_dir>/telemetry`` and prints:
   * per-rank phase totals and warm-window step-time stats,
   * with ``--preset/--topo``: a DRIFT section joining the measured
     timeline against tracecheck's per-topology prediction for that step
-    (modeled compute window + exposed ICI vs measured step time, static
-    ``overlap_hidden_fraction`` restated next to the measured numbers).
+    (modeled compute window + serialized ICI time vs measured step time).
     When the run dir holds no measured spans — backend down, telemetry
     off — the drift section still emits, with a structured-skip
     placeholder in the measured slot, so consumers never see a shape
@@ -136,47 +135,42 @@ def _step_stats(ranks: Dict[int, Dict[str, Any]]) -> Optional[dict]:
 # ------------------------------------------------------------------ drift
 
 
-def predicted_step_composition(preset: str, topo_str: str,
-                               overlap: str = "off") -> Dict[str, Any]:
+def predicted_step_composition(preset: str,
+                               topo_str: str) -> Dict[str, Any]:
     """tracecheck's prediction for one (preset, topology) pair: the
-    modeled per-step compute window, exposed/hidden ICI time, and the
-    static overlap fraction — the numbers a measured run is reconciled
-    against. Degrades to {"error": ...} rather than raising (the drift
-    section is advisory; an analysis bug must not fail the report)."""
+    modeled per-step compute window and the collectives' ICI time — the
+    numbers a measured run is reconciled against. Degrades to
+    {"error": ...} rather than raising (the drift section is advisory;
+    an analysis bug must not fail the report)."""
     try:
         from ray_lightning_tpu.analysis.cli import resolve_trace_target
-        from ray_lightning_tpu.analysis.costmodel import parse_topology
+        from ray_lightning_tpu.analysis.costmodel import (
+            compute_time_us, parse_topology,
+        )
         from ray_lightning_tpu.analysis.tracecheck import audit_step
 
         topo = parse_topology(topo_str)
-        built = resolve_trace_target(preset, topo, overlap=overlap)
+        built = resolve_trace_target(preset, topo)
         if built is None:
             return {"error": f"unknown preset {preset!r}"}
         module, strategy, batch, label = built
         report = audit_step(module, strategy, batch, topology=topo,
                             label=label)
-        ov = report.overlap or {}
-        compute_us = 0.0
-        for sc in ov.get("per_scope", ()):
-            compute_us += float(sc.get("compute_us_per_trip", 0.0)) \
-                * float(sc.get("trips", 1))
+        compute_us = compute_time_us(report.scan_flops, topo)
         predicted: Dict[str, Any] = {
             "label": label,
             "topology": topo.name,
             "ici_time_us": round(report.ici_time_us, 1),
-            "ici_exposed_us": round(report.ici_exposed_us, 1),
-            "ici_hidden_us": round(report.ici_hidden_us, 1),
-            "overlap_hidden_fraction": round(
-                report.overlap_hidden_fraction, 4),
             "compute_us": round(compute_us, 1) if compute_us else None,
             "assumptions": (
                 "roofline compute window (costmodel.compute_time_us, "
-                "MXU-derated spec peak) over traced scan scopes + exposed "
-                "ICI serialized with compute; host time not modeled"),
+                "MXU-derated spec peak) over traced scan scopes + the "
+                "collectives' ICI time serialized with compute; host "
+                "time not modeled"),
         }
         if compute_us:
             predicted["step_us"] = round(
-                compute_us + report.ici_exposed_us, 1)
+                compute_us + report.ici_time_us, 1)
         else:
             predicted["step_us"] = None
         return predicted
@@ -220,12 +214,9 @@ def build_drift(predicted: Dict[str, Any],
             direction = "slower" if ratio > 1 else "faster"
             flags.append(
                 f"measured step {measured_us / 1e3:.2f} ms is "
-                f"{ratio:.2f}x the modeled compute+exposed-ICI floor "
+                f"{ratio:.2f}x the modeled compute+ICI floor "
                 f"({pred_us / 1e3:.2f} ms) — {direction} than the cost "
-                "model beyond the threshold; the static "
-                "overlap_hidden_fraction "
-                f"({predicted.get('overlap_hidden_fraction')}) may not "
-                "be realized on this hardware")
+                "model beyond the threshold")
     elif predicted.get("error"):
         flags.append(f"prediction unavailable: {predicted['error']}")
     else:
@@ -255,8 +246,6 @@ def add_report_parser(sub) -> None:
                         "llama3-8b, or a bundled example name)")
     p.add_argument("--topo", default="v5p-64",
                    help="topology the prediction is priced for")
-    p.add_argument("--overlap", choices=("off", "on", "serial"),
-                   default="off")
     p.add_argument("--drift-threshold", type=float,
                    default=DRIFT_THRESHOLD)
     p.add_argument("--json", action="store_true", dest="as_json",
@@ -512,7 +501,7 @@ def build_incidents_section(run_dir: str,
 
 
 def build_report(run_dir: str, preset: Optional[str] = None,
-                 topo: str = "v5p-64", overlap: str = "off",
+                 topo: str = "v5p-64",
                  threshold: float = DRIFT_THRESHOLD) -> Dict[str, Any]:
     timeline = load_timeline(run_dir)
     out: Dict[str, Any] = {
@@ -533,7 +522,7 @@ def build_report(run_dir: str, preset: Optional[str] = None,
     if incidents:
         out["incidents"] = incidents
     if preset:
-        predicted = predicted_step_composition(preset, topo, overlap)
+        predicted = predicted_step_composition(preset, topo)
         out["drift"] = build_drift(predicted, timeline, threshold)
     return out
 
@@ -666,9 +655,7 @@ def _print_report(out: Dict[str, Any]) -> None:
             print(f"  predicted step floor "
                   f"{pred['step_us'] / 1e3:.2f} ms (compute "
                   f"{(pred.get('compute_us') or 0) / 1e3:.2f} ms + "
-                  f"exposed ICI {pred['ici_exposed_us'] / 1e3:.2f} ms; "
-                  f"static overlap_hidden_fraction "
-                  f"{pred['overlap_hidden_fraction']})")
+                  f"ICI {pred['ici_time_us'] / 1e3:.2f} ms)")
         for flag in drift["flags"]:
             print(f"  DRIFT: {flag}")
         print(f"  verdict: {drift['verdict']}")
@@ -680,7 +667,6 @@ def run_report(args) -> int:
               file=sys.stderr)
         return 2
     out = build_report(args.run_dir, preset=args.preset, topo=args.topo,
-                       overlap=args.overlap,
                        threshold=args.drift_threshold)
     if getattr(args, "as_json", False):
         print(json.dumps(out))

@@ -11,8 +11,6 @@ prior value is the per-metric max — speed can only go up:
 
     value                    tokens/sec/chip (the headline metric)
     mfu                      model FLOPs utilization
-    overlap_hidden_fraction  hidden share of prefetchable ICI time
-                             (static, carried even on skip lines)
     goodput_fraction         productive share of the headline
                              measurement window (measured)
     slo_attainment_latency_critical
@@ -46,11 +44,9 @@ Bounded metrics (upper limits, not ratchets):
 Gate semantics:
 
   * fresh line with ``"skipped"`` — an environmental skip (backend
-    down, driver kill). The MEASURED metrics are waived: the ratchet
-    gates merit, not machine availability. The STATIC metrics
-    (overlap_hidden_fraction — computed without hardware and carried
-    on the skip line) still ratchet when present. A round with no
-    JSON at all FAILS — there is no line to pass.
+    down, driver kill). The ratcheted metrics are all measured and
+    are waived: the ratchet gates merit, not machine availability. A
+    round with no JSON at all FAILS — there is no line to pass.
   * fresh success line — every ratcheted metric present in both the
     fresh line and some prior round must satisfy
     ``fresh >= best_prior * (1 - tolerance)`` (default 5%, --tolerance).
@@ -78,7 +74,6 @@ from typing import Optional
 RATCHETED = {
     "tokens_per_sec_per_chip": "value",
     "mfu": "mfu",
-    "overlap_hidden_fraction": "overlap_hidden_fraction",
     "goodput_fraction": "goodput_fraction",
     # serving leg (ISSUE 8): steady-state continuous-batching decode
     # throughput — measured, so waived on environmental skip lines
@@ -96,10 +91,6 @@ RATCHETED = {
     # Measured: waived on environmental skip lines.
     "slo_attainment_latency_critical": "slo_attainment_latency_critical",
 }
-
-#: keys computed by static analysis (no hardware needed) — carried on
-#: backend-down skip lines and ratcheted there too, unlike measurements
-STATIC = {"overlap_hidden_fraction"}
 
 #: metric -> key for CEILING ratchets: lower is better, so the fresh
 #: value must stay <= the best (minimum) prior * (1 + tolerance).
@@ -242,9 +233,7 @@ def _last_json_line(text: str) -> Optional[dict]:
 
 
 def best_prior(prior_glob: str, repo_root: str) -> dict:
-    """Per-metric max over all prior rounds that measured it. A skip
-    round's static fields (e.g. overlap_hidden_fraction on a
-    backend-down line) still ratchet: they were honestly computed."""
+    """Per-metric max over all prior rounds that measured it."""
     best: dict = {}
     for path in sorted(glob.glob(os.path.join(repo_root, prior_glob))):
         try:
@@ -260,10 +249,9 @@ def best_prior(prior_glob: str, repo_root: str) -> dict:
         except (TypeError, ValueError):  # "value": null / non-numeric
             measured = False
         for name, key in RATCHETED.items():
-            # measurements count only from success lines; STATIC
-            # metrics (computed without hardware) from any line
+            # measurements count only from success lines
             v = line.get(key)
-            if v is None or (key not in STATIC and not measured):
+            if v is None or not measured:
                 continue
             try:
                 v = float(v)
@@ -308,29 +296,14 @@ def gate(fresh: dict, best: dict, tolerance: float,
                 "(missing 'metric')"]
     failures = []
     for name, key in RATCHETED.items():
-        if skipped:
-            # an environmental skip waives only the MEASURED metrics;
-            # the static ones (overlap_hidden_fraction) are computed
-            # without hardware, carried on the skip line, and still
-            # ratchet when present. Absent on a skip line passes — an
-            # analysis error (the line carries overlap_error instead)
-            # must not masquerade as a regression.
-            if key not in STATIC or fresh.get(key) is None:
-                continue
-        if name not in best:
+        if skipped or name not in best:
+            # an environmental skip waives the measured metrics
             continue
         prior, source = best[name]
         if prior <= 0:
             continue
         v = fresh.get(key)
         if v is None:
-            if key in STATIC and ("overlap_error" in fresh
-                                  or "tracecheck_error" in fresh):
-                # bench.py's contract: a static-analysis bug is reported
-                # as overlap_error (or tracecheck_error when the whole
-                # trace died) and must never cost perf evidence — that
-                # is an analysis failure, not a deleted field
-                continue
             failures.append(
                 f"{name}: prior rounds track it ({prior:g} in {source}) "
                 f"but the fresh line dropped the field '{key}'")
@@ -352,8 +325,7 @@ def gate(fresh: dict, best: dict, tolerance: float,
         if v is None:
             if any(w in fresh for w in CEILING_WAIVERS[name]):
                 # the static analysis died — a failure is reported as
-                # its own error field, never as a deleted metric (same
-                # contract as the STATIC ratchet above)
+                # its own error field, never as a deleted metric
                 continue
             failures.append(
                 f"{name}: prior rounds track it ({prior:g} in {source}) "
@@ -458,13 +430,7 @@ def main(argv=None) -> int:
               f"{args.prior_glob!r}; bounded metrics only")
         return 0
     if "skipped" in fresh:
-        checked = ", ".join(
-            f"{name}={float(fresh[key]):g} (best {best[name][0]:g})"
-            for name, key in RATCHETED.items()
-            if key in STATIC and name in best
-            and fresh.get(key) is not None)
-        print(f"bench_gate: pass (environmental skip: {fresh['skipped']}; "
-              f"static ratchet: {checked or 'not exercised'})")
+        print(f"bench_gate: pass (environmental skip: {fresh['skipped']})")
     else:
         checked = ", ".join(
             f"{name}={float(fresh[key]):g} (best {best[name][0]:g})"

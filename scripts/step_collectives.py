@@ -3,7 +3,6 @@ CPU sandbox: what the partitioner makes the chips move, read before any chip
 time is spent (docs/PERFORMANCE.md "Collective overlap").
 
     python3 scripts/step_collectives.py fsdp4
-    python3 scripts/step_collectives.py fsdp4 overlap=on     # a strategy key
 
 The argument names a traffic file of kind `train` (`benchmarks/traffic/`);
 the configuration is the one its cell names in `BENCHMARK.json`. Prints the
@@ -33,17 +32,15 @@ def main(argv):
     from ray_lightning_tpu.ops import dispatch
     from tests.test_tpu_aot_compile import cell_step_compiled
 
-    extra = dict(a.split("=", 1) for a in argv[1:] if "=" in a)
-
     # a compile for a described chip cannot be read back from the cache
     jax.config.update("jax_enable_compilation_cache", False)
     dispatch.on_tpu = lambda: True      # kernels lower through Mosaic
     v5e = list(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices)
-    cell, compiled = cell_step_compiled(argv[0], v5e, **extra)
+    cell, compiled = cell_step_compiled(argv[0], v5e)
 
     mem = compiled.memory_analysis()
-    print(f"{cell['name']} {extra or ''}: temporaries "
+    print(f"{cell['name']}: temporaries "
           f"{mem.temp_size_in_bytes / 2**30:.2f} GiB a chip, arguments "
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
     cols = step_collectives(compiled.as_text())

@@ -9,6 +9,8 @@ a layer a chip against 0.38 GB (PERF.md section 6, PR 42). The compiled
 step's collectives are read off its HLO (`analysis/collectives.py`); the
 twin at the cell's shapes for the v5e is in tests/test_tpu_aot_compile.py.
 """
+import collections
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -44,20 +46,31 @@ def _cfg(**kw):
         dtype=jnp.bfloat16), **kw})
 
 
-def test_fsdp_layer_scan_moves_weights_not_activations():
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_fsdp_layer_scan_moves_weights_not_activations(n):
     compiled = _train_step_compiled(
-        FSDP(num_workers=4, devices=jax.devices()[:4]),
+        FSDP(num_workers=n, devices=jax.devices()[:n]),
         batch=BATCH, seq=SEQ, cfg=_cfg())
     moves = layer_scan_activation_moves(compiled, BATCH, SEQ)
     assert not moves, format_collectives(moves)
     # and what is left is the four weights, gathered where a product needs
-    # them, forward and backward
-    gathered = {c.op_name.split("layers/")[-1]
-                for c in step_collectives(compiled.as_text())
-                if c.kind == "all-gather" and "layers/" in c.op_name}
-    assert {"attn/wqkv/dot_general", "attn/wo/dot_general",
-            "mlp/w_gate_up/dot_general",
-            "mlp/w_down/dot_general"} <= gathered
+    # them: once a layer forward; backward once for the recomputed forward
+    # (remat) and at most once more for the product's transpose
+    weights = ["attn/wo/dot_general", "attn/wqkv/dot_general",
+               "mlp/w_down/dot_general", "mlp/w_gate_up/dot_general"]
+    gathers = collections.Counter(
+        (c.loop.rsplit("/", 2)[-2], c.op_name.split("layers/")[-1])
+        for c in step_collectives(compiled.as_text())
+        if c.kind == "all-gather" and c.loop)
+    forward = {w: k for (loop, w), k in gathers.items()
+               if loop == "jvp(Llama)"}
+    backward = {w: k for (loop, w), k in gathers.items()
+                if loop == "transpose(jvp(Llama))"}
+    assert sum(gathers.values()) == sum(forward.values()) + sum(
+        backward.values()), gathers
+    assert forward == dict.fromkeys(weights, 1)
+    assert sorted(backward) == weights
+    assert all(1 <= k <= 2 for k in backward.values()), backward
 
 
 def _block_constraints(cfg, mesh, batch=BATCH):

@@ -427,11 +427,10 @@ def layer_scan_activation_moves(compiled, batch, seq):
         and any(dims[:2] == (batch, seq) for _, dims in c.shapes))]
 
 
-def cell_step_compiled(traffic, devices, **strategy_keys):
+def cell_step_compiled(traffic, devices):
     """The train step of the benchmark's cell with that traffic file, from
-    the cell's own files: (cell, compiled). ``strategy_keys`` override the
-    traffic file's (``overlap="on"``). `scripts/step_collectives.py` prints
-    what this compiles."""
+    the cell's own files: (cell, compiled). `scripts/step_collectives.py`
+    prints what this compiles."""
     import json
     import os
 
@@ -450,7 +449,7 @@ def cell_step_compiled(traffic, devices, **strategy_keys):
     config = load(f"benchmarks/configs/{cell['config']}.json")
     keys = {k: v for k, v in tr["strategy"].items() if k != "name"}
     strategy = getattr(rlt, tr["strategy"]["name"])(
-        devices=devices[:cell["chips"]], **{**keys, **strategy_keys})
+        devices=devices[:cell["chips"]], **keys)
     cfg = adapter.llama_config(
         config, adapter.hyperparams(config, "train"), "train")
     return cell, _train_step_compiled(

@@ -88,15 +88,7 @@ def test_trace_cli_json_llama(tmp_path):
     # a clean verdict is not a blind one: nothing unentered, no spec
     # lost at a primitive the walk has no rule for
     assert d["unentered"] == [] and d["lost_specs"] == {}
-    # the un-overlapped ZeRO scan legitimately draws RLT305 advisories
-    # (exposed per-trip weight gathers — the overlap knob's pointer);
-    # anything else is a regression
-    assert all(f["rule"] == "RLT305" for f in d["findings"]), d["findings"]
-    # ...but only for PER-TRIP gathers: the lm_head gather is
-    # loop-invariant in the CE chunk scan and hoisted — the knob could
-    # not hide it, so flagging it would be a false advisory
-    assert not any("lm_head" in (f.get("symbol") or "")
-                   for f in d["findings"]), d["findings"]
+    assert d["findings"] == [], d["findings"]
 
 
 def test_trace_cli_unknown_target_exits_2():
