@@ -69,6 +69,8 @@ from ray_lightning_tpu.telemetry.spans import (
     PH_RESHARD,
     PH_STEP,
     TelemetryRecorder,
+    annotate,
+    tracing,
 )
 from ray_lightning_tpu.utils import get_logger, seed_everything
 
@@ -391,6 +393,13 @@ class Trainer:
                 if self.global_step % max(1, self.log_every_n_steps) == 0:
                     with rec.span(PH_METRICS, step=self.global_step):
                         host = _to_host(metrics)
+                    # the integer counts the module logged in the step
+                    # just read, on the profiler's clock (as the serving
+                    # side's `rlt.serve.account`; nothing outside a session)
+                    if tracing():
+                        with annotate("train.account", step=self.global_step,
+                                      **_logged_counts(metrics, host)):
+                            pass
                     self.callback_metrics.update(host)
                     pending = host
                 # telemetry persistence on its own configured cadence
@@ -1059,6 +1068,14 @@ def _gather_out(tree) -> Any:
         tree = multihost_utils.process_allgather(tree, tiled=True)
         return jax.tree.map(np.asarray, tree)
     return _to_host(tree)
+
+
+def _logged_counts(metrics, host) -> Dict[str, int]:
+    """The metrics of a step that are integer scalars on the device (counts
+    a module logged, such as rows routed to its experts), as host ints."""
+    return {k: int(host[k]) for k, v in metrics.items()
+            if getattr(v, "ndim", None) == 0
+            and jnp.issubdtype(v.dtype, jnp.integer)}
 
 
 def _to_host(tree) -> Any:

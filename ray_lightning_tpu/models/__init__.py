@@ -34,6 +34,9 @@ from ray_lightning_tpu.models.ssm_hybrid import SsmHybrid, SsmHybridConfig
 from ray_lightning_tpu.models.delta_hybrid import (
     DeltaHybrid, DeltaHybridConfig,
 )
+from ray_lightning_tpu.models.swa_moe import (
+    SwaMoe, SwaMoeConfig, SwaMoeModule,
+)
 from ray_lightning_tpu.models.window_moe import WindowMoe, WindowMoeConfig
 from ray_lightning_tpu.models.conv_moe import ConvMoe, ConvMoeConfig
 from ray_lightning_tpu.models.resnet import (
@@ -76,6 +79,9 @@ __all__ = [
     "DeltaHybridConfig",
     "SsmHybrid",
     "SsmHybridConfig",
+    "SwaMoe",
+    "SwaMoeConfig",
+    "SwaMoeModule",
     "WindowMoe",
     "WindowMoeConfig",
 ]

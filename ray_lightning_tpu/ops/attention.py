@@ -53,8 +53,10 @@ def dot_product_attention(
     mask: jnp.ndarray | None = None,
     q_offset: int = 0,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
-    """Reference SDPA: [B, S, H, D] in, [B, S, H, D] out; f32 softmax."""
+    """Reference SDPA: [B, S, H, D] in, [B, S, H, D] out; f32 softmax.
+    ``window``: a query at ``t`` sees the keys in ``(t - window, t]``."""
     if k.shape[2] != q.shape[2]:
         n_rep = q.shape[2] // k.shape[2]
         k = repeat_kv(k, n_rep)
@@ -67,6 +69,10 @@ def dot_product_attention(
     if causal:
         cm = make_causal_mask(q.shape[1], k.shape[1], q_offset)
         scores = jnp.where(cm[None, None], scores, -jnp.inf)
+    if window is not None:
+        q_pos = jnp.arange(q.shape[1])[:, None] + q_offset
+        band = q_pos - jnp.arange(k.shape[1])[None, :] < window
+        scores = jnp.where(band[None, None], scores, -jnp.inf)
     if mask is not None:
         # mask: [B, S_kv] padding mask or [B, 1, S_q, S_kv]
         if mask.ndim == 2:
@@ -113,19 +119,22 @@ def flash_attention(
     mask: jnp.ndarray | None = None,
     q_offset: int = 0,
     use_pallas: bool | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Tiled attention. Dispatches to the pallas TPU kernel when on TPU
     (or forced via RLT_PALLAS=1 with interpret mode on CPU) and the shape
     tiles cleanly; otherwise the XLA reference path (which XLA still fuses
-    reasonably — flash matters at long S where the S×S scores don't fit)."""
+    reasonably — flash matters at long S where the S×S scores don't fit).
+    ``window`` (static; needs ``causal``): a query at ``t`` sees the keys in
+    ``(t - window, t]``; the kernels skip the blocks behind the band."""
     if flash_uses_pallas(q.shape, k.shape, use_pallas,
                          masked=mask is not None):
         from ray_lightning_tpu.ops.pallas.flash import flash_attention_pallas
 
         return flash_attention_pallas(q, k, v, causal=causal,
-                                      q_offset=q_offset)
+                                      q_offset=q_offset, window=window)
     return dot_product_attention(q, k, v, causal=causal, mask=mask,
-                                 q_offset=q_offset)
+                                 q_offset=q_offset, window=window)
 
 
 def flash_attention_on_mesh(
@@ -135,6 +144,7 @@ def flash_attention_on_mesh(
     mesh,
     causal: bool = True,
     use_pallas: bool | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """`flash_attention` for GSPMD-sharded [B, S, H, D] operands.
 
@@ -152,7 +162,7 @@ def flash_attention_on_mesh(
     if (mesh is None or mesh.size == 1
             or not flash_uses_pallas(q.shape, k.shape, use_pallas)):
         return flash_attention(q, k, v, causal=causal,
-                               use_pallas=use_pallas)
+                               use_pallas=use_pallas, window=window)
     from jax.sharding import PartitionSpec as P
 
     from ray_lightning_tpu.parallel.mesh import (
@@ -172,7 +182,7 @@ def flash_attention_on_mesh(
 
     def local(q, k, v):
         return flash_attention(q, k, v, causal=causal,
-                               use_pallas=use_pallas)
+                               use_pallas=use_pallas, window=window)
 
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)

@@ -22,14 +22,30 @@ elsewhere) it is the Pallas grouped matmul jax ships
 row tiles that hold a row, so the work follows the rows that are there
 and an expert's weights are read once a row tile it owns; elsewhere
 `lax.ragged_dot`, the `jax.numpy` twin with the same semantics.
+
+``trained=True`` is the product a backward pass can cross. `gmm` has no
+transpose rule, so the Pallas branch then runs under a `jax.custom_vjp`
+(`_gmm_trained`): the rows' cotangent is the same grouped product against
+the transposed weights (``gmm(.., transpose_rhs=True)``), the weights' is
+`megablox.gmm.tgmm` (``lhs[rows of g].T @ grad[rows of g]`` a group; an
+empty group's is zero), and the rows of no group stay zero in both
+directions. Off the TPU `lax.ragged_dot` differentiates itself. The
+serving steps leave it False and trace the bare call they always have.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 #: rows a tile of the Pallas product holds; callers pad R to a multiple
 ROW_TILE = 128
+#: rows a tile of the trained product holds where the rows divide by it:
+#: a tile's rows share one read of an expert's weights, and at 128 rows
+#: that read bounds the product (110 FLOPs a byte at 640 x 768 tiles
+#: against the v5e's ridge of 240)
+TRAINED_ROW_TILE = 512
 
 
 def row_tile(rows: int) -> int:
@@ -38,8 +54,67 @@ def row_tile(rows: int) -> int:
     return ROW_TILE if rows >= ROW_TILE else -(-rows // 8) * 8
 
 
+def _tile(n: int) -> int:
+    """The largest multiple of 128 up to 1024 that divides ``n``; ``n``
+    capped at 1024 where none does."""
+    return next((t for t in range(1024, 0, -128) if n % t == 0),
+                min(n, 1024))
+
+
+def _trained_tiling(rows: int, k: int, n: int):
+    tm = TRAINED_ROW_TILE if rows % TRAINED_ROW_TILE == 0 else row_tile(rows)
+    return tm, _tile(k), _tile(n)
+
+
+def _gmm(lhs, rhs, group_sizes, out_dtype: str, transpose_rhs: bool = False):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from ray_lightning_tpu.ops import dispatch
+
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return gmm(lhs, rhs, group_sizes,
+               preferred_element_type=jnp.dtype(out_dtype),
+               tiling=_trained_tiling(*lhs.shape, n),
+               transpose_rhs=transpose_rhs,
+               interpret=dispatch.interpret_mode())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_trained(lhs, rhs, group_sizes, out_dtype: str):
+    """The Pallas grouped product [R, K] x [G, K, N] with a backward pass;
+    rows past ``sum(group_sizes)`` are left unwritten, as `gmm` leaves
+    them (the caller zeroes them)."""
+    return _gmm(lhs, rhs, group_sizes, out_dtype)
+
+
+def _gmm_trained_fwd(lhs, rhs, group_sizes, out_dtype):
+    return _gmm(lhs, rhs, group_sizes, out_dtype), (lhs, rhs, group_sizes)
+
+
+def _gmm_trained_bwd(out_dtype, res, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    from ray_lightning_tpu.ops import dispatch
+
+    lhs, rhs, group_sizes = res
+    rows, k = lhs.shape
+    valid = (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+    # a row of no group has no product: its cotangent is nobody's
+    grad = jnp.where(valid, grad, jnp.zeros((), grad.dtype))
+    d_lhs = _gmm(grad, rhs, group_sizes, lhs.dtype.name, transpose_rhs=True)
+    d_lhs = jnp.where(valid, d_lhs, jnp.zeros((), d_lhs.dtype))
+    d_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes,
+                 preferred_element_type=rhs.dtype,
+                 tiling=_trained_tiling(rows, k, rhs.shape[2]),
+                 interpret=dispatch.interpret_mode())
+    return d_lhs, d_rhs, None
+
+
+_gmm_trained.defvjp(_gmm_trained_fwd, _gmm_trained_bwd)
+
+
 def grouped_matmul(lhs, rhs, group_sizes, use_pallas: bool | None = None,
-                   out_dtype=None, layer=0):
+                   out_dtype=None, layer=0, trained: bool = False):
     from ray_lightning_tpu.ops import dispatch
 
     out_dtype = out_dtype or lhs.dtype
@@ -57,7 +132,9 @@ def grouped_matmul(lhs, rhs, group_sizes, use_pallas: bool | None = None,
             (jnp.asarray(layer, jnp.int32) * groups,))
     elif rhs.ndim == 4:
         rhs = jax.lax.dynamic_index_in_dim(rhs, layer, keepdims=False)
-    if pallas:
+    if pallas and trained:
+        out = _gmm_trained(lhs, rhs, group_sizes, jnp.dtype(out_dtype).name)
+    elif pallas:
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
         out = gmm(lhs, rhs, group_sizes,

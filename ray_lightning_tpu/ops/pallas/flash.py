@@ -14,6 +14,13 @@ into neighboring ops). GQA is handled by an index_map trick: KV tiles are
 indexed with h // n_rep, so KV heads are read in place — no repeat, no
 extra HBM traffic.
 
+Sliding window (``window``, static): a query at ``t`` sees the keys in
+``(t - window, t]``. The grid's inner axis then runs over the blocks a
+band can reach and no further, counted from the band's first block
+(`_band_first`), so the blocks wholly behind the band are neither fetched
+nor visited; the blocks on the band's two edges are masked. With
+``window=None`` the kernels lower as they did before the window existed.
+
 Tiling constraints: block sizes start from the tuned defaults (512 Q /
 1024 KV) and halve until they divide S (`_fit_block`), so any S that is
 a multiple of a small power of two tiles; D should be a multiple of 128
@@ -50,6 +57,27 @@ def _fit_block(block: int, s: int) -> int:
     return b
 
 
+def _band_first(outer, block_outer, block_inner, shift):
+    """First inner block that the band of outer block ``outer`` reaches:
+    the block that holds position ``outer * block_outer + shift``, or
+    block 0 where that lies before the sequence's start."""
+    return jnp.maximum(outer * block_outer + shift, 0) // block_inner
+
+
+def _band_blocks(block_outer: int, block_inner: int, window: int,
+                 n_inner: int) -> int:
+    """Inner blocks a band can reach from one outer block: the
+    ``block_outer + window - 1`` positions of its span, wherever the span
+    starts in an inner block, and never more than there are."""
+    return min(n_inner, (block_outer + window - 2) // block_inner + 2)
+
+
+def _in_band(run, q_start, kv_start, block_k: int, window: int):
+    """``run`` and: the KV block's last key is inside the window of the Q
+    block's first row (else the whole block lies behind the band)."""
+    return run & (kv_start + block_k - 1 > q_start - window)
+
+
 def shapes_supported(q_shape, k_shape) -> bool:
     """[B, S, H, D]: blocks must tile S; D must be lane-aligned."""
     b, sq, hq, d = q_shape
@@ -71,11 +99,15 @@ def shapes_supported(q_shape, k_shape) -> bool:
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-                *, scale, causal, q_offset, block_q, block_k, num_kv_blocks):
+                *, scale, causal, q_offset, block_q, block_k, num_kv_blocks,
+                window=None, kv_blocks=None):
     i = pl.program_id(2)  # q block
     j = pl.program_id(3)  # kv block (innermost: scratch persists across it)
+    step = j              # the inner axis's step; under a window j moves on
+    if window is not None:
+        j = step + _band_first(i, block_q, block_k, q_offset - window + 1)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
@@ -85,6 +117,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
     q_start = i * block_q + q_offset
     kv_start = j * block_k
     run = (not causal) or (q_start + block_q - 1 >= kv_start)
+    if window is not None:
+        run = _in_band(run, q_start, kv_start, block_k, window) & (
+            j < kv_blocks)
 
     @pl.when(run)
     def _body():
@@ -98,7 +133,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
         if causal:
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= kv_pos, s, _NEG_INF)
+            visible = q_pos >= kv_pos
+            if window is not None:
+                visible = visible & (q_pos - kv_pos < window)
+            s = jnp.where(visible, s, _NEG_INF)
         m_prev = m_scr[:, 0]  # [bq]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
@@ -110,7 +148,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
         )
         m_scr[:, 0] = m_new
 
-    @pl.when(j == num_kv_blocks - 1)
+    @pl.when(step == num_kv_blocks - 1)
     def _finish():
         l = l_scr[:, 0]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -118,7 +156,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
         lse_ref[0, 0, :, 0] = m_scr[:, 0] + jnp.log(safe_l)
 
 
-def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k):
+def _kv_specs(bq, bk, d, n_rep, nk, q_offset, window):
+    """The K and V block specs of a grid (b, h, q block, kv step). Under a
+    window the step counts from the band's first block, and a step past the
+    sequence's end re-reads the last block (no fetch; the kernel skips it)."""
+    if window is None:
+        at = lambda b_, h_, i, j, n_rep=n_rep: (b_, h_ // n_rep, j, 0)
+    else:
+        def at(b_, h_, i, j):
+            first = _band_first(i, bq, bk, q_offset - window + 1)
+            return (b_, h_ // n_rep, jnp.minimum(first + j, nk - 1), 0)
+    return [pl.BlockSpec((1, 1, bk, d), at), pl.BlockSpec((1, 1, bk, d), at)]
+
+
+def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k, window=None):
     """q,k,v: [B, H, S, D] (kv may have fewer heads). Returns (o, lse)."""
     b, h, sq, d = q.shape
     hk = k.shape[1]
@@ -126,21 +177,20 @@ def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k):
     bq = _fit_block(block_q, sq)
     bk = _fit_block(block_k, k.shape[2])
     nq, nk = sq // bq, k.shape[2] // bk
-    grid = (b, h, nq, nk)
+    steps = nk if window is None else _band_blocks(bq, bk, window, nk)
+    grid = (b, h, nq, steps)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, q_offset=q_offset,
-        block_q=bq, block_k=bk, num_kv_blocks=nk,
+        block_q=bq, block_k=bk, num_kv_blocks=steps, window=window,
+        kv_blocks=nk,
     )
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j, n_rep=n_rep: (b_, h_ // n_rep, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j, n_rep=n_rep: (b_, h_ // n_rep, j, 0)),
+            *_kv_specs(bq, bk, d, n_rep, nk, q_offset, window),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
@@ -167,11 +217,14 @@ def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k):
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, q_offset, block_q, block_k,
-                    num_q_blocks):
+                    num_q_blocks, window=None, q_blocks=None):
     j = pl.program_id(2)  # kv block (outer)
     i = pl.program_id(3)  # q block (inner: accumulators persist)
+    step = i
+    if window is not None:    # the first Q block that sees this KV block
+        i = step + _band_first(j, block_k, block_q, -q_offset)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -179,6 +232,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start = i * block_q + q_offset
     kv_start = j * block_k
     run = (not causal) or (q_start + block_q - 1 >= kv_start)
+    if window is not None:
+        run = _in_band(run, q_start, kv_start, block_k, window) & (
+            i < q_blocks)
 
     @pl.when(run)
     def _body():
@@ -195,7 +251,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= kv_pos, s, _NEG_INF)
+            visible = q_pos >= kv_pos
+            if window is not None:
+                visible = visible & (q_pos - kv_pos < window)
+            s = jnp.where(visible, s, _NEG_INF)
         p = jnp.exp(s - lse[:, None])  # [bq, bk]
         # dV += P^T dO
         dv_acc[:] += jax.lax.dot_general(
@@ -213,7 +272,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(i == num_q_blocks - 1)
+    @pl.when(step == num_q_blocks - 1)
     def _finish():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -222,17 +281,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc,
                    *, scale, causal, q_offset, block_q, block_k,
-                   num_kv_blocks):
+                   num_kv_blocks, window=None, kv_blocks=None):
     i = pl.program_id(2)  # q block (outer)
     j = pl.program_id(3)  # kv block (inner)
+    step = j
+    if window is not None:
+        j = step + _band_first(i, block_q, block_k, q_offset - window + 1)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start = i * block_q + q_offset
     kv_start = j * block_k
     run = (not causal) or (q_start + block_q - 1 >= kv_start)
+    if window is not None:
+        run = _in_band(run, q_start, kv_start, block_k, window) & (
+            j < kv_blocks)
 
     @pl.when(run)
     def _body():
@@ -249,7 +314,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= kv_pos, s, _NEG_INF)
+            visible = q_pos >= kv_pos
+            if window is not None:
+                visible = visible & (q_pos - kv_pos < window)
+            s = jnp.where(visible, s, _NEG_INF)
         p = jnp.exp(s - lse[:, None])
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -261,12 +329,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == num_kv_blocks - 1)
+    @pl.when(step == num_kv_blocks - 1)
     def _finish():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd(scale, causal, q_offset, block_q, block_k, res, do):
+def _bwd(scale, causal, q_offset, block_q, block_k, res, do, window=None):
     q, k, v, o, lse = res
     b, h, sq, d = q.shape
     hk = k.shape[1]
@@ -282,22 +350,34 @@ def _bwd(scale, causal, q_offset, block_q, block_k, res, do):
     # pass 1: dK, dV — grid over kv blocks, accumulate over q blocks.
     # GQA: compute per-Q-head dk/dv at [B, H, Sk, D], then segment-sum the
     # rep groups down to [B, Hk, Sk, D] outside the kernel (one reshape-sum).
+    if window is None:
+        q_steps, kv_steps = nq, nk
+        q_at = lambda b_, h_, j, i: (b_, h_, i, 0)
+    else:
+        q_steps = _band_blocks(bk, bq, window, nq)
+        kv_steps = _band_blocks(bq, bk, window, nk)
+
+        def q_at(b_, h_, j, i):
+            first = _band_first(j, bk, bq, -q_offset)
+            return (b_, h_, jnp.minimum(first + i, nq - 1), 0)
+
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, q_offset=q_offset,
-        block_q=bq, block_k=bk, num_q_blocks=nq,
+        block_q=bq, block_k=bk, num_q_blocks=q_steps, window=window,
+        q_blocks=nq,
     )
     dk_full, dv_full = pl.pallas_call(
         dkv_kernel,
-        grid=(b, h, nk, nq),
+        grid=(b, h, nk, q_steps),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bq, d), q_at),
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, j, i, n_rep=n_rep: (b_, h_ // n_rep, j, 0)),
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, j, i, n_rep=n_rep: (b_, h_ // n_rep, j, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, j, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bq, d), q_at),
+            pl.BlockSpec((1, 1, bq, 1), q_at),
+            pl.BlockSpec((1, 1, bq, 1), q_at),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
@@ -323,17 +403,15 @@ def _bwd(scale, causal, q_offset, block_q, block_k, res, do):
     # pass 2: dQ — grid over q blocks, accumulate over kv blocks.
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, q_offset=q_offset,
-        block_q=bq, block_k=bk, num_kv_blocks=nk,
+        block_q=bq, block_k=bk, num_kv_blocks=kv_steps, window=window,
+        kv_blocks=nk,
     )
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(b, h, nq, nk),
+        grid=(b, h, nq, kv_steps),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j, n_rep=n_rep: (b_, h_ // n_rep, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j, n_rep=n_rep: (b_, h_ // n_rep, j, 0)),
+            *_kv_specs(bq, bk, d, n_rep, nk, q_offset, window),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
@@ -350,14 +428,17 @@ def _bwd(scale, causal, q_offset, block_q, block_k, res, do):
 # ------------------------------------------------------------------ public
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, scale, causal, q_offset, block_q, block_k):
-    o, _ = _fwd(q, k, v, scale, causal, q_offset, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_bhsd(q, k, v, scale, causal, q_offset, block_q, block_k,
+                window=None):
+    o, _ = _fwd(q, k, v, scale, causal, q_offset, block_q, block_k, window)
     return o
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, q_offset, block_q, block_k):
-    o, lse = _fwd(q, k, v, scale, causal, q_offset, block_q, block_k)
+def _flash_fwd_rule(q, k, v, scale, causal, q_offset, block_q, block_k,
+                    window=None):
+    o, lse = _fwd(q, k, v, scale, causal, q_offset, block_q, block_k,
+                  window)
     # Label the VJP residuals for jaxpr readability. NOTE these names
     # alone cannot make a remat policy save the residuals — a custom_vjp
     # fwd rule is not part of the primal trace, so a named-saveable
@@ -372,8 +453,9 @@ def _flash_fwd_rule(q, k, v, scale, causal, q_offset, block_q, block_k):
     return o, res
 
 
-def _flash_bwd_rule(scale, causal, q_offset, block_q, block_k, res, do):
-    return _bwd(scale, causal, q_offset, block_q, block_k, res, do)
+def _flash_bwd_rule(scale, causal, q_offset, block_q, block_k, window, res,
+                    do):
+    return _bwd(scale, causal, q_offset, block_q, block_k, res, do, window)
 
 
 # optimize_remat: without it a custom_vjp is OPAQUE to remat policies —
@@ -420,8 +502,12 @@ def flash_attention_pallas(
     scale: float | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Flash attention on [B, S, H, D] tensors (framework layout).
+
+    ``window`` (static): a query at ``t`` sees the keys in ``(t - window,
+    t]``; it needs ``causal``. None is no window.
 
     ``block_q``/``block_k`` default to the tuned module constants,
     overridable per-process via ``RLT_FLASH_BLOCK_Q``/``RLT_FLASH_BLOCK_K``
@@ -430,9 +516,13 @@ def flash_attention_pallas(
         block_q = _env_block("RLT_FLASH_BLOCK_Q", DEFAULT_BLOCK_Q)
     if block_k is None:
         block_k = _env_block("RLT_FLASH_BLOCK_K", DEFAULT_BLOCK_K)
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a sliding window is a band under the diagonal: it "
+                         f"needs causal=True and window >= 1, got {window}")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     qt = q.transpose(0, 2, 1, 3)  # [B, H, S, D]
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    o = _flash_bhsd(qt, kt, vt, scale, causal, q_offset, block_q, block_k)
+    o = _flash_bhsd(qt, kt, vt, scale, causal, q_offset, block_q, block_k,
+                    window)
     return o.transpose(0, 2, 1, 3)

@@ -119,6 +119,12 @@ def annotate(phase: str, **counters: Any) -> TraceAnnotation:
         **{k: v for k, v in counters.items() if v is not None})
 
 
+def tracing() -> bool:
+    """Is a profiler session recording? The flag an annotation tests, for a
+    caller whose counters cost something to gather."""
+    return TraceAnnotation.is_enabled()
+
+
 def _span_annotation(phase: str, step: Optional[int],
                      meta: Optional[dict]) -> TraceAnnotation:
     """A recorder span's annotation: its `step` and its `meta` as stats."""

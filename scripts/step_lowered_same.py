@@ -1,4 +1,4 @@
-"""The lowered train step of both training cells, this checkout against
+"""The lowered train step of every training cell, this checkout against
 another (the parent commit unpacked by `git archive` into a directory
 `.gitignore` lists): how a PR that must not move training shows that the
 programs did not change. The serving steps' twin is `step_jaxpr_same.py`.
@@ -11,8 +11,8 @@ compile; no chip, ~20 s a checkout) and compares the StableHLO text by length
 and SHA-256. A Mosaic kernel is in that text as serialized MLIR that carries
 the file and line of every caller, so an edit anywhere above a kernel's call
 site would change its bytes: each kernel body is printed without locations
-before the comparison. Prints SAME or DIFFERENT a cell and exits non-zero on
-any difference.
+before the comparison. Prints SAME or DIFFERENT a cell (NEW for a cell the
+other checkout does not have) and exits non-zero on any difference.
 """
 import os
 import subprocess
@@ -41,7 +41,11 @@ def kernel(match):
         module = ir.Module.parse(base64.b64decode(match.group(1)))
         return module.operation.get_asm(enable_debug_info=False)
 
-for traffic in ("fsdp4", "s4096"):
+import json
+bench = json.load(open("BENCHMARK.json"))
+trained = [w["traffic"] for w in bench["workloads"] if json.load(open(
+    "benchmarks/traffic/" + w["traffic"] + ".json"))["kind"] == "train"]
+for traffic in trained:      # each checkout's own training cells
     cell, _ = cell_step_compiled(traffic, v5e)
     text, n = re.subn(r'\\22body\\22: \\22([^\\]+)\\22', kernel, lowered.pop())
     print("STEP", cell["name"], n, len(text), hashlib.sha256(text.encode()).hexdigest())
@@ -60,7 +64,9 @@ for label, root in (("change", HERE),
 bad = 0
 for cell, (kernels, length, sha) in out["change"].items():
     same = out["parent"].get(cell) == [kernels, length, sha]
-    bad += not same
-    print("SAME" if same else "DIFFERENT", cell, f"{kernels} kernels",
+    new = cell not in out["parent"]      # a cell the other checkout lacks
+    bad += not (same or new)
+    print("NEW" if new else "SAME" if same else "DIFFERENT", cell,
+          f"{kernels} kernels",
           length, sha, "|", (out["parent"].get(cell) or ["", "", "-"])[2][:12])
 sys.exit(1 if bad or not out["change"] else 0)

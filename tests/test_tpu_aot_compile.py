@@ -351,16 +351,16 @@ def test_latent_attention_kernels_compile_at_the_published_dims(
 
 # ---- the 0.5B train step, through the Trainer's own step builder -----------
 
-def _train_step_compiled(strategy, batch=8, seq=2048, cfg=None):
+def _train_step_compiled(strategy, batch=8, seq=2048, cfg=None, module=None):
     """AOT-compile `Trainer._make_train_step` for the chip_smoke model
-    (or ``cfg``) with every array abstract, sharded as the strategy
-    shards it."""
+    (or ``cfg``; or ``module``, any `TpuModule` not yet set up) with every
+    array abstract, sharded as the strategy shards it."""
     import chip_smoke
     from ray_lightning_tpu import Trainer
     from ray_lightning_tpu.core.state import TrainState
     from ray_lightning_tpu.models.llama import LlamaConfig, LlamaModule
 
-    module = LlamaModule(
+    module = module or LlamaModule(
         cfg or LlamaConfig(**chip_smoke.SmokeSize.full().model))
     trainer = Trainer(strategy=strategy, enable_checkpointing=False,
                       enable_progress_bar=False)
@@ -435,7 +435,7 @@ def cell_step_compiled(traffic, devices):
     import os
 
     import ray_lightning_tpu as rlt
-    from benchmarks.models import dense_decoder as adapter
+    from benchmarks.harness import common
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -450,10 +450,30 @@ def cell_step_compiled(traffic, devices):
     keys = {k: v for k, v in tr["strategy"].items() if k != "name"}
     strategy = getattr(rlt, tr["strategy"]["name"])(
         devices=devices[:cell["chips"]], **keys)
-    cfg = adapter.llama_config(
-        config, adapter.hyperparams(config, "train"), "train")
+    # the module of the adapter the cell's configuration names, as its
+    # `training_module` builds it, taken at the first thing that does with
+    # it (`strategy.setup`), before any weight is made
+    adapter = common.load_model_file(root, "models", config["model"])
+
+    class Built(Exception):
+        pass
+
+    class TakeModule:
+        def setup(self, module):
+            raise Built(module)
+
+    try:
+        adapter.training_module(config, adapter.hyperparams(config, "train"),
+                                0, TakeModule(), tr)
+    except Built as built:
+        module, = built.args
+    # the adapter's class and configuration; the optimizer's schedule at
+    # the class's defaults, as this helper has always compiled it (its
+    # constants are literals of the program: the traffic's would read every
+    # earlier checkout DIFFERENT in `scripts/step_lowered_same.py`)
+    module = type(module)(module.cfg)
     return cell, _train_step_compiled(
-        strategy, batch=tr["batch"], seq=tr["seq"], cfg=cfg)
+        strategy, batch=tr["batch"], seq=tr["seq"], module=module)
 
 
 def test_fsdp4_cell_layers_move_weights_not_activations(v5e, as_on_tpu):
@@ -470,6 +490,25 @@ def test_fsdp4_cell_layers_move_weights_not_activations(v5e, as_on_tpu):
     assert not moves, format_collectives(moves)
     # 6.51 GiB with the activations resharded, 5.63 pinned
     assert compiled.memory_analysis().temp_size_in_bytes < 6.0 * 1024**3
+
+
+def test_ctx16k_cell_step_compiles_from_its_own_adapter(v5e, as_on_tpu):
+    """`train.SmallThinker-21BA3B-Instruct.ctx16k`: the second trainable
+    decoder's step through `cell_step_compiled`, which loads the adapter the
+    cell's configuration names. The window kernels lower through Mosaic at
+    28 / 4 heads and S = 16,384, the grouped products both ways, and the
+    plan fits (`benchmarks/tests/test_aot_swa_moe.py` holds it to the
+    traffic file's number)."""
+    cell, compiled = cell_step_compiled("ctx16k", v5e)
+    assert cell["config"] == "SmallThinker-21BA3B-Instruct"
+    text = compiled.as_text()
+    for kernel in ("rlt_flash_fwd", "rlt_flash_bwd_dkdv", "rlt_flash_bwd_dq",
+                   "gmm", "tgmm"):
+        assert f"%{kernel}" in text, kernel
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.75 * 1024**3, f"{total / 1024**3:.2f} GiB"
 
 
 # ---- the serving step -------------------------------------------------------
