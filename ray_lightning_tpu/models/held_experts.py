@@ -34,23 +34,49 @@ What it reads of a decoder's configuration, whatever its class:
                                or "relu" (ReGLU)
 
 ``trained=True`` is the layer a backward pass can cross (`models/swa_moe.py`
-trains it): the rows are gathered and combined BY INDEX (`_take_rows`, whose
-transpose gathers too) where the serving decoders' one-hot products would
-cost ``2 R T D`` FLOPs each, and the grouped products are the
-differentiable ones (`ops/grouped_matmul.py`, ``trained``). Gradients reach
-the router through the chosen weights, never through the choice. The bound
-on rows and what the layer computes are the same either way.
+trains it), and it works over the rows that are there. The bound stays the
+most it can run, so no routing drops a row; its sorted rows are cut into
+`STRETCHES` equal stretches (`stretch_rows`), and everything as wide as a
+row (``D``, ``F`` or ``2F``) happens inside ONE loop over the stretches that
+hold a row: the trip count is the layer's own ``ceil(rows / stretch)``, known
+on the device once the pairs are sorted (`_held_rows`, a `jax.custom_vjp`
+whose forward and backward are each one `lax.fori_loop`, so the compiler
+sees one stretch-shaped body a pass; under `jax.jit`, so a decoder's layers
+share one trace and one lowered function). A stretch gathers its rows of ``h`` BY
+INDEX, runs the differentiable grouped products (`ops/grouped_matmul.py`,
+``trained``) with the layer's group sizes clipped to it (`stretch_sizes`),
+and adds its weighted rows into their tokens (`_sum_by_token`: the stretch's
+rows re-ordered by token tile, then `ops.grouped_matmul.grouped_row_sums`,
+float32 products and sums with the weights unrounded). The transposes are
+the same two moves the other way round, so nothing scatters (XLA's own
+transpose of a gather is a scatter-add, row after row on a TPU) and the
+serving decoders' one-hot products (``2 R T D`` FLOPs each) are not paid.
+Only integers and one float a pair are as long as the (token, expert)
+pairs: the key, the two sorts, the places and the weights; no array over
+the pairs or over the whole bound is as wide as a row. Gradients reach the
+router through the chosen weights, never through the choice. The bound on
+rows and what the layer computes are the same either way.
 """
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_lightning_tpu.ops.grouped_matmul import grouped_matmul, row_tile
+from ray_lightning_tpu.ops.grouped_matmul import (
+    TRAINED_ROW_TILE, grouped_matmul, grouped_row_sums, row_tile,
+)
 
 CHOICES = ("noaux_tc", "topk", "topk_softmax")
 ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+#: stretches the trained layer cuts its bound of rows into, where the
+#: bound's row tiles divide so: a layer runs as many as hold a row. At a
+#: sixth, the rows a seeded router sends to 16 held experts of 64 (a tenth
+#: of the bound) fit one stretch with room, even routing's quarter takes two
+STRETCHES = 6
 
 
 def _normal(std: float = 0.02):
@@ -106,6 +132,23 @@ def held_rows_bound(cfg, tokens: int) -> int:
     return -(-rows // tm) * tm
 
 
+def stretch_rows(rows: int) -> int:
+    """Rows of one stretch of a bound of ``rows``: the bound over the most
+    stretches, up to `STRETCHES`, that its row tiles divide into."""
+    tile = TRAINED_ROW_TILE if rows % TRAINED_ROW_TILE == 0 else row_tile(rows)
+    return rows // max(n for n in range(1, STRETCHES + 1)
+                       if (rows // tile) % n == 0)
+
+
+def stretch_sizes(sizes, lo, rows: int):
+    """group_sizes [G] of rows sorted by group -> the group sizes of the
+    rows ``[lo, lo + rows)`` among them (they sum to `sizes` over the
+    stretches of a bound)."""
+    ends = jnp.cumsum(sizes)
+    return (jnp.clip(ends, lo, lo + rows)
+            - jnp.clip(ends - sizes, lo, lo + rows)).astype(jnp.int32)
+
+
 def _pairs_by_expert(cfg, experts):
     """(key [T * k], order [T * k]): each (token, expert) pair's held
     expert (``held`` where its expert lives elsewhere), and the pairs
@@ -116,14 +159,9 @@ def _pairs_by_expert(cfg, experts):
     return key, jnp.argsort(key, stable=True)
 
 
-def held_dispatch(cfg, experts, weights, pairs=None):
-    """The (token, expert) pairs of held experts, sorted by expert.
-    Returns (token [R] int32, weight [R] float32, group_sizes [held]
-    int32); rows past ``sum(group_sizes)`` carry weight 0."""
-    t, k = experts.shape
-    held = cfg.held
-    key, order = pairs or _pairs_by_expert(cfg, experts)
-    rows = held_rows_bound(cfg, t)
+def _sorted_rows(held: int, k: int, rows: int, weights, key, order):
+    """The bound's ``rows`` rows from the sorted pairs (`_pairs_by_expert`):
+    (token [R] int32, weight [R] float32, group_sizes [held] int32)."""
     if rows <= order.shape[0]:
         order = order[:rows]       # held pairs sort first and fit the bound
     else:
@@ -135,37 +173,176 @@ def held_dispatch(cfg, experts, weights, pairs=None):
     return (order // k).astype(jnp.int32), weight, sizes.astype(jnp.int32)
 
 
-@jax.custom_vjp
-def _take_rows(x, index, readers, read):
-    """``x[index]`` ([N, D] rows at [R] -> [R, D]) whose transpose gathers
-    as well: ``readers`` [N, m] lists, for each row of ``x``, the places of
-    the result that read it, ``read`` [N, m] which of those are real. XLA's
-    own transpose of a gather is a scatter-add, row after row on a TPU; the
-    sort that made ``index`` knows its inverse, so the cotangent of row n
-    is the sum of ``grad[readers[n]]`` where ``read[n]``."""
-    return jnp.take(x, index, axis=0)
+def held_dispatch(cfg, experts, weights):
+    """The (token, expert) pairs of held experts, sorted by expert.
+    Returns (token [R] int32, weight [R] float32, group_sizes [held]
+    int32); rows past ``sum(group_sizes)`` carry weight 0."""
+    t, k = experts.shape
+    key, order = _pairs_by_expert(cfg, experts)
+    return _sorted_rows(cfg.held, k, held_rows_bound(cfg, t), weights, key,
+                        order)
 
 
-def _take_rows_fwd(x, index, readers, read):
-    return jnp.take(x, index, axis=0), (readers, read)
+def _sum_by_token(into, rows, scale, token, n, use_pallas):
+    """into [T, D] float32 + y, ``y[t]`` the sum of ``scale[r] * rows[r]``
+    over the r < n with ``token[r] == t`` (``scale`` None is 1), each
+    product and sum float32's. The rows are re-ordered by token tile (a sort
+    of their keys and one gather of the rows) and each tile sums its own by
+    the place each row's token has in it (`grouped_row_sums`): nothing here
+    is longer than the rows."""
+    tokens, d = into.shape
+    tile = next((t for t in (512, 256, 128) if tokens % t == 0), tokens)
+    tiles = tokens // tile
+    at = jnp.arange(token.shape[0], dtype=jnp.int32)
+    key = jnp.where(at < n, token // tile, tiles)
+    # the sort carries each row's place in its tile along
+    _, slot, order = jax.lax.sort((key, token % tile, at), num_keys=1)
+    sizes = jnp.sum(key[:, None] == jnp.arange(tiles)[None, :], axis=0)
+    if scale is not None:
+        scale = jnp.take(scale, order, mode="clip")
+    return grouped_row_sums(
+        jnp.take(rows, order, axis=0, mode="clip"), slot, sizes,
+        into.reshape(tiles, tile, d), scale, use_pallas).reshape(into.shape)
 
 
-def _take_rows_bwd(res, grad):
-    readers, read = res
-    picked = jnp.take(grad, readers, axis=0)            # [N, m, D]
-    d_x = jnp.sum(jnp.where(read[..., None], picked, 0).astype(jnp.float32),
-                  axis=1).astype(grad.dtype)
-    return d_x, None, None, None
+class _Stretched(NamedTuple):
+    """What `_held_rows` is compiled for: static, and the same for every
+    layer of a decoder."""
+    held: int
+    k: int                        # experts a token takes
+    rows: int                     # the bound (`held_rows_bound`)
+    stretch: int                  # rows of a stretch (`stretch_rows`)
+    use_pallas: Optional[bool]
+    activation: str
+
+    def sorted_rows(self, weights, key, order):
+        return _sorted_rows(self.held, self.k, self.rows, weights, key,
+                            order)
+
+    def live(self, sizes):
+        """The stretches that hold a row, of group sizes [held]."""
+        return -(-jnp.sum(sizes) // self.stretch)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _stretch_of(how, s, token, weight, sizes):
+    """Stretch ``s`` of the sorted rows: (first row, tokens [R1], weights
+    [R1], group sizes [held], rows of a group it holds)."""
+    r1 = how.stretch
+    lo = s * r1
+    sz = stretch_sizes(sizes, lo, r1)
+    return (lo, jax.lax.dynamic_slice(token, (lo,), (r1,)),
+            jax.lax.dynamic_slice(weight, (lo,), (r1,)), sz, jnp.sum(sz))
+
+
+def _products(how, x, w_gate_up, w_down, sizes, index):
+    """A stretch's rows through its experts: [R1, D] -> [R1, D]."""
+    gate, up = jnp.split(grouped_matmul(
+        x, w_gate_up, sizes, how.use_pallas, layer=index, trained=True),
+        2, axis=-1)
+    return grouped_matmul(ACTIVATIONS[how.activation](gate) * up, w_down,
+                          sizes, how.use_pallas, layer=index, trained=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows(how, h, w_gate_up, w_down, weights, key, order, index):
+    """The held experts over the sorted (token, expert) pairs, a stretch of
+    the bound at a time: h [T, D], the experts' stacks, weights [T, k]
+    float32, (key, order) of `_pairs_by_expert` -> (y [T, D] float32,
+    group_sizes [held] int32).
+    ``how``: a `_Stretched`, static.
+
+    One `lax.fori_loop` a pass whose trips are the stretches that hold a
+    row; JAX differentiates nothing of it. The backward pass runs a
+    stretch's gather and products again inside its own trip and carries the
+    cotangents of ``h`` and of the stacks in float32."""
+    return _held_rows_fwd(how, h, w_gate_up, w_down, weights, key, order,
+                          index)[0]
+
+
+def _held_rows_fwd(how, h, w_gate_up, w_down, weights, key, order, index):
+    dt = h.dtype
+    with jax.named_scope("moe_dispatch"):
+        token, weight, sizes = how.sorted_rows(weights, key, order)
+    with jax.named_scope("moe_experts"):      # once a pass, not a stretch
+        stacks = w_gate_up.astype(dt), w_down.astype(dt)
+
+    def stretch(s, y):
+        with jax.named_scope("moe_dispatch"):
+            _, tok, w, sz, n = _stretch_of(how, s, token, weight, sizes)
+            x = jnp.take(h, tok, axis=0, mode="clip")
+        with jax.named_scope("moe_experts"):
+            out = _products(how, x, *stacks, sz, index)
+        with jax.named_scope("moe_dispatch"):
+            return _sum_by_token(y, out, w, tok, n, how.use_pallas)
+
+    y = jax.lax.fori_loop(0, how.live(sizes), stretch,
+                          jnp.zeros(h.shape, jnp.float32))
+    return (y, sizes), (h, w_gate_up, w_down, weights, key, order, index)
+
+
+def _held_rows_bwd(how, res, grads):
+    grad, _ = grads                   # the sizes are integers
+    h, w_gate_up, w_down, weights, key, order, index = res
+    dt = h.dtype
+    with jax.named_scope("moe_dispatch"):
+        token, weight, sizes = how.sorted_rows(weights, key, order)
+    with jax.named_scope("moe_experts"):
+        stacks = w_gate_up.astype(dt), w_down.astype(dt)
+
+    def stretch(s, carry):
+        d_h, d_stacks, d_row = carry
+        with jax.named_scope("moe_dispatch"):
+            lo, tok, w, sz, n = _stretch_of(how, s, token, weight, sizes)
+            x = jnp.take(h, tok, axis=0, mode="clip")
+            # the transpose of the sum by token: each row reads its token's
+            d_y = jnp.take(grad, tok, axis=0, mode="clip")
+        with jax.named_scope("moe_experts"):
+            out, pull = jax.vjp(
+                lambda x, a, b: _products(how, x, a, b, sz, index),
+                x, *stacks)
+        with jax.named_scope("moe_dispatch"):
+            d_w = jnp.where(jnp.arange(how.stretch) < n, jnp.sum(
+                d_y * out.astype(jnp.float32), axis=-1), 0.0)
+            d_out = (d_y * w[:, None]).astype(dt)
+        with jax.named_scope("moe_experts"):
+            d_x, *d_ws = pull(d_out)
+            d_stacks = tuple(a + b.astype(jnp.float32)
+                             for a, b in zip(d_stacks, d_ws))
+        with jax.named_scope("moe_dispatch"):
+            # the transpose of the gather: a token sums its rows'
+            d_h = _sum_by_token(d_h, d_x, None, tok, n, how.use_pallas)
+            d_row = jax.lax.dynamic_update_slice(d_row, d_w, (lo,))
+        return d_h, d_stacks, d_row
+
+    d_h, d_stacks, d_row = jax.lax.fori_loop(
+        0, how.live(sizes), stretch,
+        (jnp.zeros(h.shape, jnp.float32),
+         tuple(jnp.zeros(w.shape, jnp.float32) for w in stacks),
+         jnp.zeros((how.rows,), jnp.float32)))
+    with jax.named_scope("moe_dispatch"):
+        # place[p]: where pair p stands among the sorted rows; a pair of an
+        # expert held elsewhere sorts behind the held ones and has no weight
+        place = jnp.argsort(order).astype(jnp.int32)
+        d_weights = jnp.where(
+            (key < how.held) & (place < how.rows),
+            jnp.take(d_row, place, mode="clip"), 0.0)
+    return (d_h.astype(dt), d_stacks[0].astype(w_gate_up.dtype),
+            d_stacks[1].astype(w_down.dtype),
+            d_weights.reshape(weights.shape), None, None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+#: a decoder's expert layers have one shape: traced and lowered once, as a
+#: function each layer calls, forward and transposed
+_held_rows_once = jax.jit(_held_rows, static_argnums=0)
 
 
 class HeldExperts(nn.Module):
     """The routed part of an expert layer on the chip that holds experts
     ``[cfg.experts_first, cfg.experts_first + cfg.held)``: rows h [T, D]
     -> (sum over a row's chosen HELD experts of w_i E_i(h) [T, D] float32, counts
-    int32 [2]: rows routed here, the fullest expert's rows) and, with
+    int32 [2]: rows routed here, the fullest expert's rows; ``trained``
+    adds a third, the stretches of the bound that held a row) and, with
     ``with_hits``, a third: bool [held], the experts that got a row.
 
     ``cfg`` is any configuration with the members the module's text lists.
@@ -209,9 +386,9 @@ class HeldExperts(nn.Module):
                       else jax.nn.sigmoid(logits))
             experts, weights = route(cfg, scores, bias)
         if self.trained:
-            y, sizes = self._by_index(h, experts, weights, stacks, index,
-                                      use_pallas, act)
-            counts = jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
+            y, sizes, live = self._by_index(h, experts, weights, stacks,
+                                            index, use_pallas)
+            counts = jnp.stack([jnp.sum(sizes), jnp.max(sizes), live])
             return (y, counts, sizes > 0) if self.with_hits else (y, counts)
         with jax.named_scope("moe_dispatch"):
             token, weight, sizes = held_dispatch(cfg, experts, weights)
@@ -235,40 +412,20 @@ class HeldExperts(nn.Module):
             return y, counts, sizes > 0
         return y, counts              # float32, as the combine summed it
 
-    def _by_index(self, h, experts, weights, stacks, index, use_pallas, act):
-        """The trained layer: (y [T, D] float32, group_sizes [held])."""
+    def _by_index(self, h, experts, weights, stacks, index, use_pallas):
+        """The trained layer: (y [T, D] float32, group_sizes [held], the
+        stretches of the bound that held a row)."""
         cfg = self.cfg
-        dt = cfg.dtype
-        w_gate_up, w_down = stacks
         t, k = experts.shape
+        rows = held_rows_bound(cfg, t)
+        how = _Stretched(cfg.held, k, rows, stretch_rows(rows), use_pallas,
+                         getattr(cfg, "expert_activation", "silu"))
         with jax.named_scope("moe_dispatch"):
-            key, order = pairs = _pairs_by_expert(cfg, experts)
-            token, _, sizes = held_dispatch(cfg, experts, weights, pairs)
-            rows = token.shape[0]
-            # place[p]: where pair p stands among the sorted rows; a pair
-            # of an expert held elsewhere sorts behind the held ones, and
-            # past the bound it has no row at all
-            place = jnp.argsort(order).astype(jnp.int32)
-            at = jnp.minimum(place, rows - 1)
-            has_row = ((key < cfg.held) & (place < rows)).reshape(t, k)
-            x = _take_rows(h.astype(dt), token, at.reshape(t, k), has_row)
-        with jax.named_scope("moe_experts"):
-            gate, up = jnp.split(grouped_matmul(
-                x, w_gate_up.astype(dt), sizes, use_pallas, layer=index,
-                trained=True), 2, axis=-1)
-            out = grouped_matmul(act(gate) * up, w_down.astype(dt), sizes,
-                                 use_pallas, layer=index, trained=True)
-        with jax.named_scope("moe_dispatch"):
-            # each pair reads its own row back; row r is read by the pair
-            # sorted r-th and, at weight 0, by no one who counts
-            n_pairs = order.shape[0]
-            reader = jnp.pad(order[:rows], (0, max(rows - n_pairs, 0)))
-            back = _take_rows(out, at, reader[:, None].astype(jnp.int32),
-                              (jnp.arange(rows) < n_pairs)[:, None])
-            w = jnp.where(has_row, weights, 0.0)      # float32, unrounded
-            y = jnp.sum(back.reshape(t, k, -1).astype(jnp.float32)
-                        * w[..., None], axis=1)
-        return y, sizes
+            key, order = _pairs_by_expert(cfg, experts)
+        y, sizes = _held_rows_once(how, h.astype(cfg.dtype), *stacks,
+                                   weights, key, order,
+                                   jnp.asarray(index, jnp.int32))
+        return y, sizes, how.live(sizes)
 
 
 def generate_greedy(model, params, prompt, max_new_tokens: int):

@@ -20,9 +20,11 @@ The second decoder `Trainer.fit` trains (`SwaMoeModule`; `models/llama.py`
 has the first). What training needed of the shared parts: a static window in
 the flash kernels (`ops/pallas/flash.py`), a grouped product with a backward
 pass (`ops/grouped_matmul.py`) and `HeldExperts(trained=True)`, which
-gathers its rows by index. The layer is told ``(experts_first, held)``: it
-routes over all ``n_routed_experts``, and adds the part its own experts
-give; no routing drops a row (`held_experts.held_rows_bound`).
+works over the rows that are there: the bound's rows run as equal stretches
+in a loop whose trips are the layer's own count. The layer is told
+``(experts_first, held)``: it routes over all ``n_routed_experts``, and adds
+the part its own experts give; no routing drops a row
+(`held_experts.held_rows_bound`).
 
 The layers are written out (``layer_0``, ..), each its own `jax.checkpoint`
 with the flash kernels' residuals saveable (``remat_policy``, the policies
@@ -38,7 +40,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_lightning_tpu.core.module import TpuModule
 from ray_lightning_tpu.models.held_experts import HeldExperts, _mm, _normal
@@ -126,9 +128,25 @@ class SwaMoeConfig:
         return cls(**base)
 
 
+def _whole(mesh):
+    """``pin(a)``: ``a`` whole on every device of ``mesh`` (the identity
+    with no mesh or one device). The expert layer's loops run as many trips
+    as the layer has rows for, so a trip must wait for no other device:
+    with the layer's operands and its result whole, a strategy's split of
+    the weights or of the batch puts its collectives in front of the loops
+    and behind them, none inside. (A Mosaic kernel cannot be partitioned
+    automatically either.) `with_sharding_constraint` transposes to itself,
+    so the cotangents are whole too."""
+    if mesh is None or mesh.size == 1:
+        return lambda a: a
+    whole = NamedSharding(mesh, P())
+    return lambda a: jax.lax.with_sharding_constraint(a, whole)
+
+
 class SwaMoeBlock(nn.Module):
     """Layer ``layer`` of the decoder: x [B, S, D] -> (x', counts int32
-    [2]: rows routed to the experts held here, the fullest one's rows)."""
+    [3]: rows routed to the experts held here, the fullest one's rows, the
+    stretches of the bound the expert layer ran)."""
 
     cfg: SwaMoeConfig
     layer: int = 0
@@ -169,20 +187,22 @@ class SwaMoeBlock(nn.Module):
         z = rms_norm(h, p("moe_norm", nn.initializers.ones, (d,)),
                      cfg.norm_eps)
         # the layer's own held experts; the router reads the layer's INPUT
-        stacks = (p("experts_gate_up", _normal(), (held, d, 2 * f)),
-                  p("experts_down", _normal(), (held, f, d)))
+        pin = _whole(self.mesh)
+        stacks = (pin(p("experts_gate_up", _normal(), (held, d, 2 * f))),
+                  pin(p("experts_down", _normal(), (held, f, d))))
         y, counts = HeldExperts(cfg, trained=True, name="experts")(
-            z.reshape(b * s, d), stacks, 0, use_pallas,
-            route_from=x.reshape(b * s, d))
-        return h + y.reshape(b, s, d).astype(dt), counts
+            pin(z.reshape(b * s, d)), stacks, 0, use_pallas,
+            route_from=pin(x.reshape(b * s, d)))
+        return h + pin(y).reshape(b, s, d).astype(dt), counts
 
 
 class SwaMoe(nn.Module):
-    """Token ids [B, S] -> (logits [B, S, V] float32, counts int32 [2]);
+    """Token ids [B, S] -> (logits [B, S, V] float32, counts int32 [3]);
     with ``return_hidden`` the final-normed states [B, S, D] in place of the
     logits (the fused loss projects them a chunk at a time). ``counts``:
-    rows routed to held experts, and the fullest held expert's rows, each
-    summed over the layers."""
+    rows routed to held experts, the fullest held expert's rows, and the
+    stretches of the bound that held a row (`held_experts.stretch_rows`),
+    each summed over the layers."""
 
     cfg: SwaMoeConfig
     mesh: Optional[Any] = None
@@ -201,7 +221,7 @@ class SwaMoe(nn.Module):
         block = SwaMoeBlock
         if cfg.remat:
             block = nn.remat(block, policy=_remat_policy(cfg.remat_policy))
-        counts = jnp.zeros((2,), jnp.int32)
+        counts = jnp.zeros((3,), jnp.int32)
         for i in range(cfg.n_layers):
             x, c = block(cfg, i, self.mesh, name=f"layer_{i}")(x, cos, sin)
             counts = counts + c
@@ -237,8 +257,8 @@ def swa_moe_param_specs(cfg: SwaMoeConfig) -> Dict[str, P]:
 class SwaMoeModule(TpuModule):
     """Next-token prediction on {"tokens": [B, S + 1]} through `SwaMoe`:
     mean cross-entropy over the vocabulary held, by `ops/fused_ce.py`. Each
-    step logs the device-side counts ``expert_rows`` and ``expert_rows_max``
-    (`SwaMoe`'s ``counts``) beside ``train_loss``."""
+    step logs the device-side counts ``expert_rows``, ``expert_rows_max``
+    and ``expert_stretches`` (`SwaMoe`'s ``counts``) beside ``train_loss``."""
 
     def __init__(self, cfg: Optional[SwaMoeConfig] = None, lr: float = 3e-4,
                  weight_decay: float = 0.1, warmup_steps: int = 100,
@@ -285,6 +305,7 @@ class SwaMoeModule(TpuModule):
         self.log("train_loss", loss)
         self.log("expert_rows", counts[0])
         self.log("expert_rows_max", counts[1])
+        self.log("expert_stretches", counts[2])
         return loss
 
     def validation_step(self, params, batch):

@@ -31,6 +31,23 @@ the transposed weights (``gmm(.., transpose_rhs=True)``), the weights' is
 empty group's is zero), and the rows of no group stay zero in both
 directions. Off the TPU `lax.ragged_dot` differentiates itself. The
 serving steps leave it False and trace the bare call they always have.
+
+The trained expert layer (`models/held_experts.py:_held_rows`) never calls
+this over its whole bound of rows: it cuts the bound into equal stretches
+and calls it once a stretch that holds a row, inside a loop, with the
+layer's group sizes clipped to the stretch (a group that a stretch's end
+cuts has its rows in two calls, and a group outside the stretch is an
+empty one there). The backward trip differentiates the two products of its
+own stretch (`jax.vjp` inside the loop's body) and adds the weights'
+cotangents, one `tgmm` a stretch, into a float32 carry. ``lhs`` is then a
+stretch's rows, a multiple of `TRAINED_ROW_TILE` where the bound is.
+
+`grouped_row_sums` is the same `tgmm` read as a sum: the rows of a group
+added up by a slot each carries, ``out[g, j] += sum of scale[r] * rows[r]
+over the rows r of g with slot[r] == j``, as the product of a one-hot of
+the slots with the rows, accumulated in float32 into what it is given
+(`tgmm`'s ``existing_out``, in place). The trained expert layer adds a
+stretch's rows into their tokens with it, the token tiles as groups.
 """
 from __future__ import annotations
 
@@ -147,3 +164,56 @@ def grouped_matmul(lhs, rhs, group_sizes, use_pallas: bool | None = None,
             preferred_element_type=jnp.float32).astype(out_dtype)
     # the Pallas product leaves the rows of no group unwritten
     return jnp.where(valid, out, jnp.zeros((), out.dtype))
+
+
+def grouped_row_sums(rows, slot, group_sizes, into, scale=None,
+                     use_pallas: bool | None = None):
+    """rows [R, N] sorted by group, slot [R] int32 in [0, W), group_sizes
+    [G], into [G, W, N] float32, scale [R] float32 or None (1) -> ``into``
+    plus, at ``[g, j]``, the sum of ``scale[r] * rows[r]`` over the rows r
+    of group g whose slot is j; rows past ``sum(group_sizes)`` are in no
+    sum. Each product and each sum is float32's.
+
+    On TPU `tgmm` over a one-hot ``[W, R]`` of the slots at the rows' type,
+    whose grid visits only the row tiles that hold a row and which adds into
+    ``into`` in place. The MXU multiplies two bfloat16 exactly, so a float32
+    ``scale`` goes in as the three bfloat16 pieces it is the sum of, one
+    `tgmm` a piece: no product is rounded before it is added. Elsewhere a
+    scatter-add by ``group * W + slot``."""
+    from ray_lightning_tpu.ops import dispatch
+
+    r, n = rows.shape
+    groups, width, _ = into.shape
+    group_sizes = group_sizes.astype(jnp.int32)
+    if (dispatch.use_pallas(use_pallas) and width % 128 == 0
+            and r % ROW_TILE == 0):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+        hot = slot[None, :] == jnp.arange(width)[:, None]
+        if scale is None:
+            pieces = [hot.astype(rows.dtype)]
+        else:
+            pieces, rest = [], scale.astype(jnp.float32)
+            for _ in range(-(-24 // (jnp.finfo(rows.dtype).nmant + 1))):
+                part = rest.astype(rows.dtype)
+                pieces.append(jnp.where(hot, part[None, :],
+                                        jnp.zeros((), rows.dtype)))
+                rest = rest - part.astype(jnp.float32)
+        for lhs in pieces:
+            into = tgmm(lhs, rows, group_sizes,
+                        preferred_element_type=jnp.float32,
+                        tiling=_trained_tiling(r, width, n),
+                        existing_out=into,
+                        interpret=dispatch.interpret_mode())
+        return into
+    ends = jnp.cumsum(group_sizes)
+    at = jnp.arange(r)
+    to = jnp.where(
+        at < ends[-1],
+        jnp.searchsorted(ends, at, side="right") * width + slot,
+        groups * width)
+    rows = rows.astype(jnp.float32)
+    if scale is not None:
+        rows = rows * scale[:, None]
+    return into.reshape(groups * width, n).at[to].add(
+        rows, mode="drop").reshape(into.shape)

@@ -1,7 +1,8 @@
 """The second decoder `Trainer.fit` trains (`models/swa_moe.py`) and what
 training it needed of the shared parts: a sliding window in the flash
 kernels, a grouped product with a backward pass, an expert layer that
-gathers its rows by index. On the CPU at tiny widths with the real
+works over the rows that are there, a stretch of its bound at a time. On the
+CPU at tiny widths with the real
 structure: four layers of the two kinds, a window shorter than the sequence,
 8 experts of which a chip holds 2-4, 3 a token."""
 import glob
@@ -13,7 +14,9 @@ import numpy as np
 import pytest
 
 from ray_lightning_tpu import FSDP, DataLoader, SingleDevice, Trainer
-from ray_lightning_tpu.models.held_experts import HeldExperts
+from ray_lightning_tpu.models.held_experts import (
+    HeldExperts, held_rows_bound, route, stretch_rows, stretch_sizes,
+)
 from ray_lightning_tpu.models.swa_moe import (
     SwaMoe, SwaMoeBlock, SwaMoeConfig, SwaMoeModule, swa_moe_param_specs,
 )
@@ -21,7 +24,9 @@ from ray_lightning_tpu.ops import dispatch
 from ray_lightning_tpu.ops.attention import (
     dot_product_attention, flash_attention,
 )
-from ray_lightning_tpu.ops.grouped_matmul import grouped_matmul
+from ray_lightning_tpu.ops.grouped_matmul import (
+    grouped_matmul, grouped_row_sums,
+)
 from ray_lightning_tpu.ops.pallas.flash import flash_attention_pallas
 from ray_lightning_tpu.ops.rope import rope_frequencies
 
@@ -174,6 +179,31 @@ def test_the_bare_product_is_the_call_it_was():
     assert "custom_vjp" in text(trained=True)
 
 
+@pytest.mark.parametrize("scaled", [False, True])
+@pytest.mark.parametrize("sizes", [[100, 0, 60], [0, 0, 0], [256, 0, 0]])
+def test_grouped_row_sums_adds_unrounded_products_into_what_it_is_given(
+        sizes, scaled):
+    """The kernel (interpreted) over bfloat16 rows with a float32 scale: the
+    scale goes in as three bfloat16 pieces, so each product is float32's
+    own and not the 3 digits a rounded one keeps; rows of no group are in no
+    sum and an empty group keeps what it was given."""
+    ks = jax.random.split(jax.random.key(21), 4)
+    rows = jax.random.normal(ks[0], (256, 128), jnp.bfloat16)
+    slot = jax.random.randint(ks[1], (256,), 0, 128)
+    scale = jax.random.uniform(ks[2], (256,)) if scaled else None
+    into = jax.random.normal(ks[3], (3, 128, 128), jnp.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    want = np.array(into, np.float64)
+    ends = np.cumsum(sizes)
+    for r in range(int(ends[-1])):
+        want[np.searchsorted(ends, r, side="right"), int(slot[r])] += (
+            np.float64(rows[r].astype(jnp.float32))
+            * (np.float64(scale[r]) if scaled else 1.0))
+    for use_pallas in (True, False):
+        got = grouped_row_sums(rows, slot, sizes, into, scale, use_pallas)
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+
 # ---- the expert layer ---------------------------------------------------------
 
 
@@ -190,17 +220,152 @@ def _layer(cfg, trained, seed=3, tokens=48):
     return layer, params, h, x, stacks
 
 
-def test_dispatch_by_index_is_the_one_hot_dispatch():
+#: tokens of a routed layer below: with 3 experts a token and 4 of 8 held the
+#: bound is 768 rows, six stretches of 128
+ROUTED_TOKENS = 256
+#: routing -> the rows each of the 4 held experts gets, a token sending at
+#: most one pair to a held expert; "every_pair" sends all three
+ROUTINGS = {
+    "no_row": [0, 0, 0, 0],
+    "one_full_stretch": [128, 0, 0, 0],
+    "one_row_more": [129, 0, 0, 0],
+    "boundary_inside_a_group": [100, 100, 0, 0],
+    "an_empty_expert_between": [150, 0, 100, 0],
+    "every_pair": [192, 192, 192, 192],
+}
+
+
+def _routed(routing, seed=11):
+    """A layer whose router is told what to choose: the rows the router
+    reads carry the logits themselves (the router's matrix is the identity
+    on the first 8 columns), so `ROUTINGS[routing]` decides the group sizes
+    and a seeded draw the weights."""
     cfg = SwaMoeConfig.tiny()
-    outs = {}
-    for trained in (False, True):
-        layer, params, h, x, stacks = _layer(cfg, trained)
-        outs[trained] = layer.apply({"params": params}, h, stacks,
-                                    route_from=x)
-    np.testing.assert_allclose(outs[True][0], outs[False][0], rtol=1e-5,
-                               atol=1e-6)
-    np.testing.assert_array_equal(outs[True][1], outs[False][1])
-    assert int(outs[True][1][0]) > 0
+    layer, params, h, x, stacks = _layer(cfg, True, tokens=ROUTED_TOKENS)
+    t, e = ROUTED_TOKENS, cfg.n_routed_experts
+    chosen = np.tile(np.asarray([5, 6, 7]), (t, 1))       # held elsewhere
+    if routing == "every_pair":
+        chosen = (np.arange(t)[:, None] + np.arange(3)[None, :]) % 4
+    else:
+        ends = np.cumsum(ROUTINGS[routing])
+        chosen[:ends[-1], 0] = np.searchsorted(ends, np.arange(ends[-1]),
+                                               side="right")
+    logits = np.array(jax.random.normal(jax.random.key(seed), (t, e)))
+    np.put_along_axis(logits, chosen, 8.0 + np.take_along_axis(
+        logits, chosen, axis=1), axis=1)
+    x = jnp.zeros_like(x).at[:, :e].set(logits)
+    params = {"router": jnp.eye(cfg.dim, e)}
+    return cfg, layer, params, h, x, stacks
+
+
+def _layer_grads(layer, params, h, x, stacks):
+    """Output, counts and the gradient of a weighted sum of the output
+    with respect to the router, the rows, the router's rows and both
+    stacks."""
+    ct = jax.random.normal(jax.random.key(12), h.shape)
+
+    def loss(params, h, x, stacks):
+        return (layer.apply({"params": params}, h, stacks,
+                            route_from=x)[0] * ct).sum()
+
+    y, counts = layer.apply({"params": params}, h, stacks, route_from=x)
+    return y, counts, jax.tree.leaves(
+        jax.grad(loss, (0, 1, 2, 3))(params, h, x, stacks))
+
+
+@pytest.mark.parametrize("routing", ["seeded", *ROUTINGS])
+def test_dispatch_by_index_is_the_one_hot_dispatch(routing):
+    """The looped layer against the serving decoders' one-hot dispatch under
+    XLA's own transposes: output, counts and every gradient, at routings
+    that run no stretch, one to its last row, two by one row, with a
+    stretch's end inside a group and past an empty one, and all six (the
+    bound itself: nothing dropped)."""
+    if routing == "seeded":
+        cfg = SwaMoeConfig.tiny()
+        layer, params, h, x, stacks = _layer(cfg, True)
+    else:
+        cfg, layer, params, h, x, stacks = _routed(routing)
+    y, counts, grads = _layer_grads(layer, params, h, x, stacks)
+    want_y, want_counts, want_grads = _layer_grads(
+        HeldExperts(cfg, trained=False), params, h, x, stacks)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(counts[:2], want_counts)
+    rows = int(counts[0])
+    if routing != "seeded":
+        assert rows == sum(ROUTINGS[routing])
+    r1 = stretch_rows(held_rows_bound(cfg, h.shape[0]))
+    assert r1 == 128 and int(counts[2]) == -(-rows // r1)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
+
+
+def _plain_layer(cfg, params, h, x, stacks):
+    """What the layer computes, written down: every held expert over every
+    row, weighted by what the router gave that (token, expert) pair."""
+    logits = jnp.dot(x, params["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    experts, weights = route(cfg, logits)
+    y = jnp.zeros(h.shape, jnp.float32)
+    for e in range(cfg.held):
+        gate, up = jnp.split(h @ stacks[0][e], 2, axis=-1)
+        w = jnp.sum(jnp.where(experts == cfg.experts_first + e, weights,
+                              0.0), axis=-1)
+        y = y + w[:, None] * ((jax.nn.relu(gate) * up) @ stacks[1][e])
+    return y
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_looped_layer_is_the_plain_sum_over_held_experts(routing):
+    cfg, layer, params, h, x, stacks = _routed(routing)
+    ct = jax.random.normal(jax.random.key(12), h.shape)
+    with jax.default_matmul_precision("highest"):
+        y, _, grads = _layer_grads(layer, params, h, x, stacks)
+        want = _plain_layer(cfg, params, h, x, stacks)
+        want_grads = jax.tree.leaves(jax.grad(
+            lambda *a: (_plain_layer(cfg, *a) * ct).sum(), (0, 1, 2, 3))(
+                params, h, x, stacks))
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    moved = [float(jnp.abs(g).max()) > 0 for g in grads]
+    assert moved == [routing != "no_row"] * 5, moved
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("routing", ["boundary_inside_a_group", "every_pair"])
+def test_a_stretch_goes_through_the_pallas_products(routing):
+    """The kernels (interpreted) inside the loop, both ways, against their
+    `jax.numpy` twin: a stretch's own group sizes and 128 rows each call."""
+    cfg, layer, params, h, x, stacks = _routed(routing)
+    ct = jax.random.normal(jax.random.key(12), h.shape)
+
+    def grads(use_pallas):
+        return jax.tree.leaves(jax.grad(lambda p, h, x, s: (layer.apply(
+            {"params": p}, h, s, 0, use_pallas, route_from=x)[0] * ct).sum(),
+            (0, 1, 2, 3))(params, h, x, stacks))
+
+    for got, ref in zip(grads(True), grads(False)):
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("sizes", [*ROUTINGS.values(), [0, 768, 0, 0],
+                                   [1, 0, 0, 766]])
+def test_a_bounds_stretches_share_out_its_group_sizes(sizes):
+    sizes = jnp.asarray(sizes, jnp.int32)
+    parts = np.stack([stretch_sizes(sizes, lo, 128)
+                      for lo in range(0, 768, 128)])
+    np.testing.assert_array_equal(parts.sum(0), sizes)
+    np.testing.assert_array_equal(
+        parts.sum(1), np.clip(int(sizes.sum()) - np.arange(0, 768, 128),
+                              0, 128))
+
+
+# the cell's bound; bounds of 6, 5 and 2 tiles of 128 rows, and of 6, 2 and 1
+# of 512; seven tiles have one stretch, the bound itself; less than a tile
+@pytest.mark.parametrize("rows,want", [
+    (98304, 16384), (768, 128), (640, 128), (256, 128), (3072, 512),
+    (1024, 512), (512, 512), (896, 896), (48, 48)])
+def test_a_stretch_is_whole_row_tiles_and_divides_the_bound(rows, want):
+    assert stretch_rows(rows) == want
 
 
 def test_gradients_reach_the_router_through_the_weights():
@@ -246,8 +411,6 @@ def test_the_four_shares_sum_to_the_uncut_layer():
 
 
 def test_topk_softmax_weights_are_the_softmax_over_the_chosen():
-    from ray_lightning_tpu.models.held_experts import route
-
     cfg = SwaMoeConfig.tiny()
     logits = jax.random.normal(jax.random.key(4), (16, 8))
     experts, weights = route(cfg, logits)
@@ -330,6 +493,7 @@ def test_loss_and_every_leafs_gradient_match_the_plain_reference():
     logged = module.pop_logged()
     assert logged["expert_rows"].dtype == jnp.int32
     assert 0 < int(logged["expert_rows_max"]) <= int(logged["expert_rows"])
+    assert 4 <= int(logged["expert_stretches"]) <= 4 * 3
     canon = weights.canonical(hp, ref.tables, s32, False)
     want, want_grads = train.batch_loss_and_grads(
         ref, hp, canon, tokens[:, None, :])
@@ -344,6 +508,26 @@ def test_loss_and_every_leafs_gradient_match_the_plain_reference():
         assert scale > 0, name
         np.testing.assert_allclose(got[name], w, atol=2e-4 * scale,
                                    rtol=2e-3, err_msg=name)
+
+
+def test_expert_stretches_is_each_layers_rows_over_a_stretch_rounded_up():
+    """The third count: a layer runs ``ceil(rows / stretch)`` trips of its
+    loop, and the decoder sums them over its layers. 96 tokens of 3 experts
+    with 4 of 8 held: a bound of 384 rows, three stretches of 128."""
+    cfg = SwaMoeConfig.tiny(remat=False)
+    tokens = jax.random.randint(jax.random.key(13), (2, 48), 0, 96)
+    model = SwaMoe(cfg)
+    params = model.init(jax.random.key(14), tokens)["params"]
+    (_, counts), state = model.apply(
+        {"params": params}, tokens, capture_intermediates=True,
+        mutable=["intermediates"])
+    r1 = stretch_rows(held_rows_bound(cfg, 96))
+    assert r1 == 128
+    layers = [state["intermediates"][f"layer_{i}"]["experts"]["__call__"][0][1]
+              for i in range(cfg.n_layers)]
+    for c in layers:
+        assert int(c[2]) == -(-int(c[0]) // r1) >= 1
+    np.testing.assert_array_equal(counts, np.sum(layers, axis=0))
 
 
 def test_param_specs_name_every_leaf():
@@ -420,6 +604,7 @@ def test_a_fetch_puts_the_steps_counts_in_the_trace(tmp_path):
     for step, (_, _, stats) in enumerate(events[1::2], start=1):
         assert int(stats["step"]) == step
         assert 0 < int(stats["expert_rows_max"]) <= int(stats["expert_rows"])
+        assert 4 <= int(stats["expert_stretches"]) <= 4 * 6
         assert "loss" not in stats and "grad_norm" not in stats
     assert int(events[-1][2]["expert_rows"]) == int(
         trainer.callback_metrics["expert_rows"])
